@@ -26,7 +26,6 @@ def main():
     ap.add_argument("--batch", type=int, default=None)
     args = ap.parse_args()
 
-    paddle.set_device("tpu")      # no-op fallback to the default backend
     paddle.seed(0)
 
     from paddle_tpu.vision.models import resnet18, resnet50

@@ -1,79 +1,16 @@
-"""Version shims over the installed jax.
+"""The one jax API this package reaches through a seam.
 
-The codebase targets the current jax spellings `jax.shard_map(...,
-check_vma=)` and `jax.lax.axis_size(name)`. Older installs (<=0.4.x) only
-ship `jax.experimental.shard_map.shard_map(..., check_rep=)` — same
-semantics, pre-rename — and spell the axis size as `lax.psum(1, name)`
-(which constant-folds to a python int inside a manual region). Rather than
-sprinkling try/except at every call site (manual collectives, gpt_spmd,
-ring attention, pipeline compile, graft entry), install adapters under the
-modern names when they are missing. Idempotent; a no-op on jax versions
-that already expose them.
+The code is written for the installed jax (0.9): `jax.shard_map(...,
+check_vma=)`, `jax.lax.axis_size(name)` and `jax.profiler.ProfileData`
+are called by their own names at every call site — there are no version
+shims. What stays here is `profile_data()`, because
+`observability.deviceprof` must never IMPORT jax itself (its parser also
+runs in processes that stay off the chip): it finds this module through
+`sys.modules`, i.e. only in a process that already has jax.
 """
-import jax
-
-
-def install():
-    if not hasattr(jax, "shard_map"):
-        from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-        def shard_map(f, *args, **kwargs):
-            if "check_vma" in kwargs:
-                kwargs["check_rep"] = kwargs.pop("check_vma")
-            return _exp_shard_map(f, *args, **kwargs)
-
-        jax.shard_map = shard_map
-
-    if not hasattr(jax.lax, "axis_size"):
-        def axis_size(axis_name):
-            return jax.lax.psum(1, axis_name)
-
-        jax.lax.axis_size = axis_size
-
-
-# jax version that first ships the typed XPlane reader
-# jax.profiler.ProfileData (the binding observability.deviceprof prefers
-# when present; the stdlib XSpace wire decoder covers everything older)
-PROFILE_DATA_MIN_JAX = "0.5.1"
-
-
-class ProfileDataUnavailableError(ImportError):
-    """The running jax has no jax.profiler.ProfileData binding."""
 
 
 def profile_data():
-    """A normalized loader over `jax.profiler.ProfileData` across jax
-    versions: returns `load(path) -> ProfileData` resolving the
-    `from_file` / `from_serialized_xspace` API drift, or raises a
-    curated ProfileDataUnavailableError naming the minimum jax version —
-    never a raw ImportError/AttributeError mid-capture (ISSUE 9
-    satellite). Callers that can read raw `.xplane.pb` bytes themselves
-    (observability.deviceprof) catch it and fall back to the stdlib
-    XSpace decoder (`observability/xplane.py`)."""
-    import jaxlib
-
-    versions = (f"installed: jax {jax.__version__}, "
-                f"jaxlib {jaxlib.__version__}")
-    try:
-        from jax.profiler import ProfileData
-    except ImportError:
-        raise ProfileDataUnavailableError(
-            f"jax.profiler.ProfileData requires jax>={PROFILE_DATA_MIN_JAX} "
-            f"({versions}); paddle_tpu.observability.deviceprof falls back "
-            "to its stdlib XSpace decoder automatically — only code that "
-            "insists on the native binding needs a jax upgrade") from None
-    if hasattr(ProfileData, "from_file"):
-        return ProfileData.from_file
-    if hasattr(ProfileData, "from_serialized_xspace"):
-        def load(path):
-            with open(path, "rb") as f:
-                return ProfileData.from_serialized_xspace(f.read())
-        return load
-    raise ProfileDataUnavailableError(
-        "jax.profiler.ProfileData exposes neither from_file nor "
-        f"from_serialized_xspace ({versions}); this jax's reader API has "
-        f"drifted past the shim — jax>={PROFILE_DATA_MIN_JAX} with either "
-        "constructor is required for the native path")
-
-
-install()
+    """`load(path) -> ProfileData` for an `.xplane.pb` file."""
+    from jax.profiler import ProfileData
+    return ProfileData.from_file

@@ -28,10 +28,15 @@ class Place:
         return hash((self.kind, self.index))
 
     def jax_device(self):
-        devs = [d for d in jax.devices() if d.platform == _JAX_PLATFORM.get(self.kind, self.kind)]
-        if not devs:
-            devs = jax.devices()
-        return devs[min(self.index, len(devs) - 1)]
+        platform = _JAX_PLATFORM.get(self.kind, self.kind)
+        devs = [d for d in jax.devices() if d.platform == platform]
+        if self.index >= len(devs):
+            # never another platform's device in its place: a program that
+            # asked for the chip must not run on the host without a word
+            raise RuntimeError(
+                f"{self!r}: jax reports {len(devs)} {platform!r} device(s) "
+                f"(backend {jax.default_backend()!r})")
+        return devs[self.index]
 
 
 _JAX_PLATFORM = {"tpu": "tpu", "cpu": "cpu", "gpu": "gpu"}
@@ -71,7 +76,9 @@ def set_device(device):
         name = "gpu"
     else:
         raise ValueError(f"Unsupported device {device!r}; expected 'tpu' or 'cpu'")
-    _current_place = Place(name, int(idx) if idx else 0)
+    place = Place(name, int(idx) if idx else 0)
+    place.jax_device()              # raises when there is no such device
+    _current_place = place
     return _current_place
 
 

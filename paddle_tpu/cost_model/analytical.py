@@ -17,7 +17,8 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
-__all__ = ["DeviceSpec", "OpCost", "CostReport", "estimate", "DEVICES"]
+__all__ = ["DeviceSpec", "OpCost", "CostReport", "estimate", "DEVICES",
+           "device_spec"]
 
 
 @dataclass(frozen=True)
@@ -25,18 +26,40 @@ class DeviceSpec:
     name: str
     peak_flops: float          # FLOP/s at the matmul dtype
     hbm_bw: float              # bytes/s
+    kinds: tuple = ()          # jax `device_kind` spellings of this chip
 
     def roofline_s(self, flops, bytes_):
         return max(flops / self.peak_flops, bytes_ / self.hbm_bw)
 
 
-# bf16 MXU peak / HBM bandwidth (public chip specs)
+# THE peaks table: bf16 MXU peak / HBM bandwidth per chip (Google Cloud TPU
+# documentation, the "TPU v5e" / "TPU v4" / "TPU v5p" system pages), found
+# from a live device through its `device_kind` by `device_spec`. The "cpu"
+# row is a nominal host for pricing jaxprs in tests — never a peak to
+# divide a measured rate by.
 DEVICES = {
-    "tpu-v5e": DeviceSpec("tpu-v5e", 197e12, 819e9),
-    "tpu-v4": DeviceSpec("tpu-v4", 275e12, 1228e9),
-    "tpu-v5p": DeviceSpec("tpu-v5p", 459e12, 2765e9),
-    "cpu": DeviceSpec("cpu", 1e11, 5e10),
+    "tpu-v5e": DeviceSpec("tpu-v5e", 197e12, 819e9,
+                          ("TPU v5 lite", "TPU v5e")),
+    "tpu-v4": DeviceSpec("tpu-v4", 275e12, 1228e9, ("TPU v4",)),
+    "tpu-v5p": DeviceSpec("tpu-v5p", 459e12, 2765e9, ("TPU v5p", "TPU v5")),
+    "cpu": DeviceSpec("cpu", 1e11, 5e10, ("cpu",)),
 }
+
+
+def device_spec(device_kind=None):
+    """The DeviceSpec of the attached device (`jax.devices()[0]`), or of
+    the given `device_kind`. A kind the table does not list is an error,
+    not a default: pricing an unknown chip as a v5e is how a number gets
+    written under the wrong device's name."""
+    if device_kind is None:
+        import jax
+        device_kind = jax.devices()[0].device_kind
+    for spec in DEVICES.values():
+        if device_kind in spec.kinds:
+            return spec
+    raise KeyError(
+        f"device_kind {device_kind!r} is not in cost_model.analytical."
+        f"DEVICES; add its peaks (with their source) before measuring on it")
 
 
 @dataclass

@@ -74,6 +74,16 @@ a `gc_old`-style sweep runs at commit time, evicting least-recently-USED
 first (dir mtime; lookup hits refresh it), never the entry just
 committed. 0 = unlimited (the default).
 
+Placement (docs/compile_cache.md): ONE rule, `place()`, called by every
+entry point that compiles for the chip. `$JAX_COMPILATION_CACHE_DIR`, when
+set, is the root and code sets no jax cache directory (jax reads the
+variable itself); unset, the root is `<checkout>/.jax_cache`. jax's own
+persistent cache writes into the root, this module's entries default to
+its `executables/` sub-directory (`default_dir()`), and an explicit
+`compile_cache_dir=` from a caller still wins for that caller. The path
+is never derived from a temporary name, a pid or a clock — a cache that
+moves never hits.
+
 Observability: `compile_cache_hits_total` / `compile_cache_misses_total`
 counters (the hits/misses rate-rule in tools/metrics_report.py gates a
 hit-rate drop as a failure-class regression), per-executable compile and
@@ -93,7 +103,8 @@ from ..observability import metrics as _metrics
 
 __all__ = ["FORMAT_VERSION", "ENTRY_SCHEMA", "CompileCache",
            "CachedFunction", "cached_jit", "attach", "detach", "active",
-           "invalidate_active", "framework_fingerprint", "aval_signature"]
+           "invalidate_active", "framework_fingerprint", "aval_signature",
+           "CACHE_ENV", "cache_root", "default_dir", "place"]
 
 FORMAT_VERSION = 1
 ENTRY_SCHEMA = "paddle_tpu.compile_cache.v1"
@@ -115,6 +126,44 @@ _M_LOAD_S = _metrics.histogram(
     "compile_cache_load_seconds",
     "Per-executable deserialize/compile-at-load wall time on a hit",
     labelnames=("executable",))
+
+
+# -------------------------------------------------------------- placement
+
+CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+
+def cache_root():
+    """Where compiled artefacts live: `$JAX_COMPILATION_CACHE_DIR` when
+    set, else `<checkout>/.jax_cache` (git-ignored)."""
+    return os.path.abspath(os.environ.get(CACHE_ENV)
+                           or os.path.join(_CHECKOUT, ".jax_cache"))
+
+
+def default_dir():
+    """Default directory of this module's executable entries: a
+    sub-directory of the root, so one variable places both caches."""
+    return os.path.join(cache_root(), "executables")
+
+
+def place(explicit=None):
+    """Turn on jax's persistent compilation cache under the placement
+    rule and return its directory. With `$JAX_COMPILATION_CACHE_DIR` set
+    this sets NO directory (jax already reads the variable); otherwise
+    the directory is `explicit` (a caller's own `compile_cache_dir=`) or
+    the checkout default. Every executable is made cacheable — the
+    serving set is many sub-second compiles."""
+    import jax
+    if os.environ.get(CACHE_ENV):
+        path = cache_root()
+    else:
+        path = os.path.abspath(str(explicit)) if explicit else cache_root()
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    return path
 
 
 # ------------------------------------------------------------ fingerprint
@@ -321,9 +370,16 @@ class CompileCache:
     def _load_runner(self, full, meta):
         if meta["format"] == "executable":
             from jax.experimental import serialize_executable as _se
+            import jax
             with open(os.path.join(full, EXEC_FILE), "rb") as f:
                 payload, in_tree, out_tree = pickle.load(f)
-            return _se.deserialize_and_load(payload, in_tree, out_tree)
+            # load onto the devices the executable was compiled for: the
+            # default (every device of the backend) breaks a one-device
+            # executable on any multi-device host
+            by_id = {d.id: d for d in jax.devices()}
+            devices = [by_id[i] for i in meta["device_ids"]]
+            return _se.deserialize_and_load(payload, in_tree, out_tree,
+                                            execution_devices=devices)
         if meta["format"] == "exported":
             import jax
             from jax import export as _jexport
@@ -350,10 +406,16 @@ class CompileCache:
         import jax
         payload = None
         fmt = None
+        device_ids = None
         try:
             from jax.experimental import serialize_executable as _se
             payload = pickle.dumps(_se.serialize(compiled))
             fmt = "executable"
+            # the same private handle serialize() reads: the ordered
+            # device assignment the executable must be reloaded onto
+            device_ids = [int(d.id) for d in
+                          compiled._executable._unloaded_executable
+                          .device_list]
         except Exception as e:                               # noqa: BLE001
             exported_bytes = export_fn() if export_fn is not None else None
             if exported_bytes is not None:
@@ -369,6 +431,7 @@ class CompileCache:
                 "backend": jax.default_backend(),
                 "fingerprint": framework_fingerprint(),
                 "compile_seconds": compile_seconds,
+                "device_ids": device_ids,
                 **(extra_meta or {})}
         final = self._entry_dir(dirname)
         try:
@@ -447,6 +510,30 @@ def invalidate_active():
 
 
 # ------------------------------------------------------- cached functions
+
+_JAX_CACHE_HITS = [0]
+_LISTENING = False
+# entry dirnames whose executable jax's own cache served in this process
+# (a later compile of the same program reuses it in memory, with no event)
+_JAX_SERVED = set()
+
+
+def _jax_cache_hits():
+    """How many compiles jax's OWN persistent cache has served in this
+    process (its monitoring event; the listener is installed on first
+    use)."""
+    global _LISTENING
+    if not _LISTENING:
+        import jax.monitoring
+
+        def on_event(event, **_):
+            if event == "/jax/compilation_cache/cache_hits":
+                _JAX_CACHE_HITS[0] += 1
+        jax.monitoring.register_event_listener(on_event)
+        _LISTENING = True
+    return _JAX_CACHE_HITS[0]
+
+
 
 class CachedFunction:
     """`jax.jit` plus the persistent executable tier.
@@ -539,13 +626,25 @@ class CachedFunction:
         if runner is None:
             if lowered is None:
                 lowered = self._jit.lower(*args)
+            hits0 = _jax_cache_hits()
             t0 = time.perf_counter()
             compiled = lowered.compile()
             compile_s = time.perf_counter() - t0
             _M_COMPILE_S.labels(executable=self.name).observe(compile_s)
-            cache.store(self.name, dirname, digest, compiled,
-                        lambda: self._export_bytes(args), compile_s,
-                        extra_meta={"key_mode": self._key_mode})
+            if _jax_cache_hits() > hits0:
+                _JAX_SERVED.add(dirname)
+            if dirname in _JAX_SERVED:
+                # jax's own persistent cache served this compile (same
+                # program, new key: e.g. the source fingerprint moved).
+                # An executable jax LOADED does not re-serialize into a
+                # loadable payload (XLA:CPU: "Function ... not found" at
+                # the next process's first call), and jax already holds
+                # it — so it is not stored a second time, here.
+                cache.stats["uncacheable"] += 1
+            else:
+                cache.store(self.name, dirname, digest, compiled,
+                            lambda: self._export_bytes(args), compile_s,
+                            extra_meta={"key_mode": self._key_mode})
             runner = compiled
         self._runners[sig] = runner
         self._sole_runner = runner if len(self._runners) == 1 else None

@@ -25,17 +25,13 @@ _FLAGS = {
     "FLAGS_conv_workspace_size_limit": 512,
     "FLAGS_flash_attention": True,         # route MHA through pallas kernel
     "FLAGS_profile": False,
-    # persistent compiled-executable cache (reference intent: AnalysisPredictor
-    # pays analysis once, inference/api/analysis_predictor.h:95). Set to a
-    # directory to have XLA executables serialized there and reloaded by
-    # later processes, skipping compilation.
-    "FLAGS_compilation_cache_dir": "",
     # first-class persistent executable cache (framework/compile_cache.py):
     # set to a directory to attach the process-global tier — device-layer op
     # runners and serving engines without a private cache then serialize
-    # executables there and deserialize them on later runs. Unlike the jax
-    # cache above, entries ride the ckpt_commit atomic protocol (torn-write
-    # safe) and report through compile_cache_{hits,misses}_total.
+    # executables there and deserialize them on later runs. Entries ride the
+    # ckpt_commit atomic protocol (torn-write safe) and report through
+    # compile_cache_{hits,misses}_total. jax's own persistent cache is placed
+    # by $JAX_COMPILATION_CACHE_DIR alone (compile_cache.place()).
     "FLAGS_compile_cache_dir": "",
     # retention cap for compile-cache dirs (ROADMAP item 5 debt): keep at
     # most this many committed entries per cache directory, sweeping the
@@ -50,23 +46,6 @@ _FLAGS = {
     # (per-call form: Tensor.numpy(force_int64=True)).
     "FLAGS_int64_numpy_boundary": False,
 }
-
-
-def enable_compilation_cache(path=None):
-    """Turn on jax's persistent compilation cache (executables serialized to
-    disk; warm processes skip XLA compilation). Called automatically on
-    import when FLAGS_compilation_cache_dir is set, and by the inference
-    Predictor for its artifact directory."""
-    import jax
-
-    path = path or _FLAGS.get("FLAGS_compilation_cache_dir")
-    if not path:
-        return False
-    _FLAGS["FLAGS_compilation_cache_dir"] = path
-    jax.config.update("jax_compilation_cache_dir", path)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    return True
 
 
 def _load_env():
@@ -85,9 +64,6 @@ def _load_env():
 
 
 _load_env()
-
-if _FLAGS["FLAGS_compilation_cache_dir"]:
-    enable_compilation_cache()
 
 if _FLAGS["FLAGS_compile_cache_dir"]:
     # attach is import-light (no jax until the first lookup/compile)

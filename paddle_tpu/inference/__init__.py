@@ -60,9 +60,12 @@ class Config:
         self._profile = False
         self._glog_info = True
         self._cpu_math_threads = 1
-        # persistent executable cache: serialized XLA executables live next
-        # to the artifact so a second process skips compilation entirely
-        # (AnalysisPredictor's pay-analysis-once intent). None = default dir.
+        # persistent executable cache: a second process deserializes XLA
+        # executables instead of compiling (AnalysisPredictor's
+        # pay-analysis-once intent). None = the one placement rule
+        # (framework/compile_cache.place: $JAX_COMPILATION_CACHE_DIR, else
+        # <checkout>/.jax_cache) — never a directory beside the artifact,
+        # which moves with it and then never hits.
         self._compile_cache_dir = None
         self._compile_cache = True
         # AOT serving warmup: when the artifact's .gencfg records a serving
@@ -192,11 +195,8 @@ class Predictor:
 
         self._config = config
         if getattr(config, "_compile_cache", False):
-            from ..framework.flags import enable_compilation_cache
-            cache_dir = config._compile_cache_dir or os.path.join(
-                os.path.dirname(os.path.abspath(config.prog_file())),
-                "_xla_cache")
-            enable_compilation_cache(cache_dir)
+            from ..framework import compile_cache as _compile_cache
+            _compile_cache.place(config._compile_cache_dir)
         with open(config.prog_file(), "rb") as f:
             self._exported = jexport.deserialize(f.read())
         payload = _load(config.params_file(), return_numpy=True)
@@ -223,9 +223,10 @@ class Predictor:
         self._outputs = {}
 
         # AOT serving warmup: a .gencfg that records a serving engine is
-        # built NOW (executables deserialize from the artifact's compile
-        # cache when warm), so the first generate() compiles nothing.
-        # Failure degrades to the lazy path — load must never break.
+        # built NOW (executables deserialize from the compile cache when
+        # warm), so the first generate() compiles nothing. A failure
+        # here raises: on the chip it is a compile refusal, and a lazy
+        # fallback would only hide it until the first request.
         self._gen_sched = None
         self._gen_sched_from_record = False
         self._serving_meta = self._read_serving_meta()
@@ -234,23 +235,11 @@ class Predictor:
             import time as _time
             from ..observability import metrics as _obs_metrics
             t0 = _time.perf_counter()
-            try:
-                self._generation_scheduler()
-            except Exception as e:                           # noqa: BLE001
-                import warnings
-                # the recorded engine cannot be rebuilt under THIS build
-                # (config/kind skew): drop the record so the lazy path
-                # takes the plain pre-record engine instead of retrying
-                # the same deterministic failure on every generate()
-                self._serving_meta = None
-                warnings.warn(f"AOT serving warmup failed "
-                              f"({type(e).__name__}: {str(e)[:200]}); "
-                              f"falling back to lazy engine build")
-            else:
-                _obs_metrics.gauge(
-                    "predictor_executable_ready_seconds",
-                    "Predictor load-to-serving-ready wall time (AOT "
-                    "warmup included)").set(_time.perf_counter() - t0)
+            self._generation_scheduler()
+            _obs_metrics.gauge(
+                "predictor_executable_ready_seconds",
+                "Predictor load-to-serving-ready wall time (AOT "
+                "warmup included)").set(_time.perf_counter() - t0)
 
     def _read_serving_meta(self):
         """The .gencfg 'serving' record (engine kind + config +
@@ -324,8 +313,8 @@ class Predictor:
                     not getattr(self, "_gen_sched_from_record", False):
                 return self._gen_sched
             self._gen_sched = None     # record-built, caller overrides
-        from ..serving.engine import (default_compile_cache_dir,
-                                      load_generation_model, make_engine)
+        from ..framework import compile_cache as _compile_cache
+        from ..serving.engine import load_generation_model, make_engine
         model = load_generation_model(self._config.prog_file(), self._params)
         if model is None:
             raise RuntimeError(
@@ -343,19 +332,11 @@ class Predictor:
             cache_dir = None
             if getattr(self._config, "_compile_cache", False):
                 cache_dir = self._config._compile_cache_dir or \
-                    default_compile_cache_dir(self._config.prog_file())
+                    _compile_cache.default_dir()
             engine = make_engine(model, meta["engine"], meta["config"],
                                  compile_cache_dir=cache_dir)
             if getattr(self._config, "_aot_warmup", False):
-                try:
-                    engine.precompile()
-                except Exception as e:                       # noqa: BLE001
-                    # the engine itself is healthy — serve lazily (the
-                    # executables compile on first use) rather than fail
-                    import warnings
-                    warnings.warn(f"AOT precompile failed "
-                                  f"({type(e).__name__}: {str(e)[:200]});"
-                                  f" serving will compile lazily")
+                engine.precompile()
         else:
             engine = GenerationEngine(model, **engine_kwargs)
         self._gen_sched = Scheduler(engine, **sched_kwargs)

@@ -73,10 +73,11 @@ io.DataLoader (wait-time histogram), device op-cache (hits/misses via a
 collector), and live/peak device bytes (collector below).
 
 Every submodule is stdlib-only at import time: importable before (or
-without) jax, which is what lets bench.py write a postmortem for a
-wedged backend init and the offline tools parse a device capture next
-to a wedged grant (deviceprof's capture entry points import jax lazily,
-only when a trace is actually started).
+without) jax. A chip belongs to ONE process, so whatever must run beside
+the process that holds it — bench.py's --cold-start parent writing a
+postmortem, the offline tools parsing a device capture or replaying a
+ledger — has to work without initialising jax (deviceprof's capture
+entry points import jax lazily, only when a trace is actually started).
 """
 import sys
 

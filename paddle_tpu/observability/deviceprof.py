@@ -33,8 +33,8 @@ as a manual runbook step whose parser had never seen real output
               capture fires leaves "armed, never fired" in its
               postmortem instead of losing the evidence.
 
-Decoder resolution: `jax.profiler.ProfileData` when the running jax
-exposes it (see `_jax_compat.profile_data` for the curated guard), else
+Decoder resolution: `jax.profiler.ProfileData` in a process that already
+has jax (through `_jax_compat.profile_data`, found via sys.modules), else
 the stdlib XSpace wire decoder (`xplane.py`). Parse/validate/render are
 stdlib-only and standalone-loadable (importlib by file path) so the
 offline tools never import the backend.
@@ -80,17 +80,15 @@ def _xplane_mod():
 
 
 def _load_planes(path):
-    """(planes, decoder_name). Prefers the typed jax binding when the
-    process already has a jax that ships it; falls back to the stdlib
-    wire decoder. Never triggers a jax import (wedged-grant rule)."""
+    """(planes, decoder_name). Uses the typed jax binding when the
+    process already has jax; falls back to the stdlib wire decoder.
+    Never triggers a jax import itself: the offline tools run beside the
+    process that holds the chip."""
     compat = sys.modules.get("paddle_tpu._jax_compat")
     native_err = None
-    if compat is not None and hasattr(compat, "profile_data"):
+    if compat is not None:
         try:
-            load = compat.profile_data()
-            return list(load(path).planes), "native"
-        except ImportError:
-            pass                      # curated unavailable: use the fallback
+            return list(compat.profile_data()(path).planes), "native"
         except Exception as e:                               # noqa: BLE001
             # a *parse* failure from the native binding is worth retrying
             # with the wire decoder, but keep the reason if both fail

@@ -20,10 +20,12 @@ postmortem JSON artifact containing:
 
 Deliberately stdlib-only with NO paddle_tpu imports at module level:
 bench.py loads this file standalone (importlib, bypassing the package)
-so a postmortem can be written even from a process whose `import jax`
-is the thing that wedged. Tracer and registry are discovered through
-sys.modules — never imported — so a standalone load cannot trigger the
-hang it is documenting.
+so a postmortem can be written from a process that must not initialise
+jax — a chip belongs to one process, and bench.py's --cold-start parent
+stays off it so its children can have it — or whose own import hung.
+Tracer and registry are discovered through sys.modules — never imported
+— so a standalone load can neither claim the chip nor trigger the hang
+it is documenting.
 """
 import collections
 import itertools

@@ -194,8 +194,8 @@ class ShadowPool:
 
     Stdlib-only on purpose (plain-list refcounts): the package contract
     is that every observability submodule imports before/without the
-    accelerator stack, so offline tools can replay a ledger stream next
-    to a wedged grant."""
+    accelerator stack, so offline tools can replay a ledger stream
+    beside the process that holds the chip."""
 
     _MAX_ERRORS = 32
 

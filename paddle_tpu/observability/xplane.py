@@ -2,11 +2,10 @@
 
 `jax.profiler.trace` writes the device timeline as an XSpace protobuf
 (tensorflow/tsl `xplane.proto`) — planes of lines of events, with names
-and per-event stats interned through metadata tables. Newer jax exposes a
-typed reader (`jax.profiler.ProfileData`, see `_jax_compat.profile_data`),
-but the binding is absent from the jaxlib generations this repo supports,
-and the offline tools must be able to read a capture from a process that
-cannot (or must not — wedged-grant rule) import jax at all.
+and per-event stats interned through metadata tables. jax has a typed
+reader (`jax.profiler.ProfileData`, see `_jax_compat.profile_data`), but
+the offline tools must be able to read a capture from a process that must
+not import jax at all (a chip belongs to one process).
 
 This module is a minimal protobuf *wire-format* decoder for exactly the
 XSpace fields the deviceprof parser needs. The wire format is stable by

@@ -61,27 +61,34 @@ def _use_pallas(q, k):
     return S == k.shape[2] and S % 128 == 0 and D in (64, 128, 256)
 
 
+def flash_blocks(B, H, S, D, causal):
+    """(block_q, block_k) the Pallas kernel will run with for this
+    geometry, or (None, None) for the kernel's own default: the autotune
+    cache's row (incubate.autotune — the phi AlgorithmsCache role) when
+    it tiles the call. A row that does not — it fails to divide S, or
+    breaks the causal square-block requirement — is reported and
+    ignored; it must not raise mid-forward, and it must not be taken
+    without a word either."""
+    from ..incubate.autotune import lookup_flash_blocks
+    hit = lookup_flash_blocks(B, H, S, D, causal)
+    if not hit:
+        return None, None
+    bq, bk = int(hit[0]), int(hit[1])
+    if S % bq or S % bk or (causal and bq != bk):
+        import warnings
+        warnings.warn(
+            f"flash autotune row {(bq, bk)} for (H={H}, S={S}, D={D}, "
+            f"causal={causal}) does not tile the call; using the kernel "
+            f"default blocks")
+        return None, None
+    return bq, bk
+
+
 def _pallas_flash_bhsd(q, k, v, causal, scale, mask=None, dropout_rate=0.0,
                        dropout_seed=None):
     from .pallas.flash_attention import flash_attention
 
-    # consult the autotune cache (incubate.autotune — the phi
-    # AlgorithmsCache role); None -> the kernel's static default
-    bq = bk = None
-    try:
-        from ..incubate.autotune import lookup_flash_blocks
-        B, H, S, D = q.shape
-        hit = lookup_flash_blocks(B, H, S, D, causal)
-        if hit:
-            bq, bk = int(hit[0]), int(hit[1])
-            # a tuned entry must actually tile this call (a stale or
-            # hand-edited table row that doesn't divide S, or breaks the
-            # causal square-block requirement, would raise mid-forward);
-            # fall back to the kernel's static default instead
-            if S % bq or S % bk or (causal and bq != bk):
-                bq = bk = None
-    except Exception:                                        # noqa: BLE001
-        bq = bk = None
+    bq, bk = flash_blocks(*q.shape, causal)
     return flash_attention(q, k, v, mask=mask, causal=causal, sm_scale=scale,
                            dropout_rate=dropout_rate,
                            dropout_seed=dropout_seed,

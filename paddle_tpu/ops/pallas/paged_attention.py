@@ -17,6 +17,13 @@ VMEM and folds it into an online-softmax accumulator (the flash
 recurrence, `flash_attention.py`). K/V stream through VMEM once; nothing
 is materialized per-slot in HBM.
 
+Layout seen by the kernel (what Mosaic tiles, checked on a v5e): heads
+and head_dim are FOLDED into one lane axis — q `[S, T, H*D]`, pools
+`[N, block_size, H*D]`, both free reshapes of the contiguous arrays — so
+a head is a static lane slice `[hh*D, (hh+1)*D)` of a 2-D tile, never a
+slice of a middle (sublane) axis. Per-head softmax state lives in
+scratch with the head as the LEADING axis for the same reason.
+
 Masking is identical to `kv_cache.attend` (the exactness oracle the
 tier-1 tests assert against, in interpret mode):
 
@@ -30,18 +37,18 @@ tier-1 tests assert against, in interpret mode):
   * rows with no visible key emit exact zeros.
 
 Blocks whose first key position is past the tile's last visible query
-position are predicated off with `pl.when` — for a slot at position p
-only ceil((p+T)/block_size) of the table's entries cost MXU work (the
-index map clamps their DMA to whatever the table holds, which for
-unallocated entries is the garbage block).
+position are predicated off with `pl.when`, and the index map clamps
+their block index to the tile's last live block — a repeated index is
+not re-fetched, so for a slot at position p only ceil((p+T)/block_size)
+of the table's entries cost DMA or MXU work.
 
 Tiling knobs (`q_tile`, `head_tile`) are CAPS served through the
 `incubate.autotune` shipped-table machinery (`lookup_paged_blocks`,
 keyed on (heads, padded_len, head_dim, block_size)): the effective tile
-is the largest divisor of the live extent not exceeding the cap, so a
-stale shipped entry can never raise mid-forward — it degrades to a
-smaller tile (the same fall-back-don't-raise contract the flash lookup
-got in PR 6).
+is the largest divisor of the live extent under the cap THAT THE TPU
+CAN TILE (q rows a multiple of 8 or all of T; head lanes a multiple of
+128 or all of H*D), so a stale shipped entry can never raise
+mid-forward — it degrades to another legal tile.
 """
 import functools
 
@@ -52,29 +59,48 @@ from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["paged_attention", "DEFAULT_Q_TILE", "DEFAULT_HEAD_TILE"]
 
-# Conservative VMEM-minded caps (see docs/PERF_NOTES.md for the pricing):
-# a (q_tile, head_tile, D) f32 query tile + accumulator + two
-# (q_tile, head_tile, 128) softmax-stat tiles stay under ~1 MB at
-# D<=128, leaving the budget to the streamed K/V blocks. Shipped tuned
-# entries (ops/pallas/flash_blocks_tuned.json, kernel="paged") override.
+# VMEM-minded caps: at D=128 a (128, 8*128) f32 query tile, its output
+# tile (both double-buffered), the accumulator and the two softmax-stat
+# scratches come to ~4 MB, leaving the scoped budget to the streamed
+# K/V blocks. Shipped tuned entries
+# (ops/pallas/flash_blocks_tuned.json, kernel="paged") override.
 DEFAULT_Q_TILE = 128
-DEFAULT_HEAD_TILE = 4
+DEFAULT_HEAD_TILE = 8
 _LANE = 128           # TPU lane width for the softmax-stat scratch
+_SUBLANE = 8          # TPU sublane count (second-minor tile extent)
 _MASK_VALUE = -1e30   # same finite fill as kv_cache.attend / flash
 
 
-def _largest_divisor_leq(n, cap):
-    """Largest divisor of n that is <= cap (>=1 always)."""
+def _largest_divisor_leq(n, cap, legal=None):
+    """Largest divisor of n that is <= cap and passes `legal` (>=1
+    always when `legal` is None; None when no divisor is legal)."""
     cap = max(1, min(int(cap), int(n)))
     for d in range(cap, 0, -1):
-        if n % d == 0:
+        if n % d == 0 and (legal is None or legal(d)):
             return d
-    return 1
+    return None
 
 
-def _kernel(tables_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
-            acc_ref, m_ref, l_ref, *, bs, tq, hq, nb, scale,
-            ks_ref=None, vs_ref=None, qmax=127.0):
+def _tiles(T, H, D, q_cap, head_cap, aligned):
+    """(tq, hq): the largest divisors of T / H under the caps. With
+    `aligned` (compiled for the TPU, not interpreted) a tile must also
+    satisfy Mosaic's block rule — second-minor extent a multiple of 8 or
+    the whole axis, minor extent a multiple of 128 or the whole axis —
+    and an extent with no such divisor is taken whole."""
+    if not aligned:
+        return (_largest_divisor_leq(T, q_cap),
+                _largest_divisor_leq(H, head_cap))
+    tq = _largest_divisor_leq(T, q_cap, lambda d: d % _SUBLANE == 0)
+    hq = _largest_divisor_leq(H, head_cap, lambda d: (d * D) % _LANE == 0)
+    return tq or T, hq or H
+
+
+def _kernel(tables_ref, pos_ref, q_ref, k_ref, v_ref, *rest, bs, tq, hq, D,
+            nb, scale, quant, qmax):
+    if quant:
+        ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = rest
+    else:
+        o_ref, acc_ref, m_ref, l_ref = rest
     s = pl.program_id(0)
     qi = pl.program_id(2)
     j = pl.program_id(3)          # kv block — innermost: the online scan
@@ -93,33 +119,32 @@ def _kernel(tables_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(run)
     def _body():
-        qblk = q_ref[0]           # (tq, hq, D)
-        kblk = k_ref[0]           # (bs, hq, D) — ONE physical pool block
-        vblk = v_ref[0]
         rows = jax.lax.broadcasted_iota(jnp.int32, (tq, bs), 0) + qi * tq
         cols = jax.lax.broadcasted_iota(jnp.int32, (tq, bs), 1) + j * bs
         visible = cols <= p0 + rows
         # V rows no query of this tile ever sees may hold inf/NaN scatter
         # junk (the garbage block): a zero probability is not enough
         # against 0*inf == NaN, zero the rows themselves
-        ever = (jax.lax.iota(jnp.int32, bs) + j * bs) <= q_hi
+        ever = (jax.lax.broadcasted_iota(jnp.int32, (bs, D), 0)
+                + j * bs) <= q_hi
         for hh in range(hq):
-            qh = qblk[:, hh, :]
-            kh = kblk[:, hh, :]
-            vh_raw = vblk[:, hh, :]
-            if ks_ref is not None:
+            lanes = slice(hh * D, (hh + 1) * D)
+            qh = q_ref[0, :, lanes]           # (tq, D)
+            kh = k_ref[0, :, lanes]           # (bs, D) of ONE pool block
+            vh = v_ref[0, :, lanes]
+            if quant:
                 # in-VMEM dequant of the streamed int8 block: the exact
                 # expression serving.blocks.dequant computes, so the
                 # kernel and the gather oracle see identical f32 values
-                kh = kh.astype(jnp.float32) * (ks_ref[0, hh] / qmax)
-                vh_raw = vh_raw.astype(jnp.float32) * (vs_ref[0, hh] / qmax)
-            vh = jnp.where(ever[:, None], vh_raw, 0.0)
+                kh = kh.astype(jnp.float32) * (ks_ref[0, 0, j, hh] / qmax)
+                vh = vh.astype(jnp.float32) * (vs_ref[0, 0, j, hh] / qmax)
+            vh = jnp.where(ever, vh, 0.0)
             sc = jax.lax.dot_general(
                 qh, kh, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale
             sc = jnp.where(visible, sc, _MASK_VALUE)
-            m_prev = m_ref[:, hh, :1]                         # (tq, 1)
-            l_prev = l_ref[:, hh, :1]
+            m_prev = m_ref[hh, :, :1]                         # (tq, 1)
+            l_prev = l_ref[hh, :, :1]
             m_cur = jnp.max(sc, axis=-1, keepdims=True)
             m_new = jnp.maximum(m_prev, m_cur)
             alpha = jnp.exp(m_prev - m_new)
@@ -130,15 +155,17 @@ def _kernel(tables_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
             pv = jax.lax.dot_general(
                 p.astype(vh.dtype), vh, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
-            acc_ref[:, hh, :] = acc_ref[:, hh, :] * alpha + pv
-            m_ref[:, hh, :] = jnp.broadcast_to(m_new, (tq, _LANE))
-            l_ref[:, hh, :] = jnp.broadcast_to(l_new, (tq, _LANE))
+            acc_ref[hh] = acc_ref[hh] * alpha + pv
+            m_ref[hh] = jnp.broadcast_to(m_new, (tq, _LANE))
+            l_ref[hh] = jnp.broadcast_to(l_new, (tq, _LANE))
 
     @pl.when(j == nb - 1)
     def _finalize():
-        l = l_ref[:, :, :1]                                   # (tq, hq, 1)
-        l_safe = jnp.where(l == 0.0, 1.0, l)                  # all-masked: 0
-        o_ref[0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
+        for hh in range(hq):
+            l = l_ref[hh, :, :1]                              # (tq, 1)
+            l_safe = jnp.where(l == 0.0, 1.0, l)              # all-masked: 0
+            o_ref[0, :, hh * D:(hh + 1) * D] = \
+                (acc_ref[hh] / l_safe).astype(o_ref.dtype)
 
 
 def paged_attention(q, k_pool, v_pool, tables, pos, scale=None,
@@ -155,14 +182,15 @@ def paged_attention(q, k_pool, v_pool, tables, pos, scale=None,
 
     With `k_scale`/`v_scale` ([N, H] float32, the quantized pools'
     per-block per-head scales) the pools are int8 and dequantize
-    IN-kernel: each grid step's scale row rides the same block-table
-    index map as its K/V block (one tiny [1, head_tile] DMA alongside
-    the block), so the dense f32 view is never materialized and the HBM
+    IN-kernel. The scale rows are gathered through the block table
+    outside the kernel (a [S, max_blocks, H] float gather — bytes, not
+    the pool) and each (slot, head-tile) strip rides in as one small
+    VMEM block, so the dense f32 view is never materialized and the HBM
     read bill is the int8 bytes.
 
     q_tile/head_tile are caps (tuned via the shipped autotune table);
-    the effective tile is the largest divisor of T / H under the cap.
-    On non-TPU backends the kernel runs in Pallas interpret mode.
+    see `_tiles` for how the effective tile is chosen. On non-TPU
+    backends the kernel runs in Pallas interpret mode.
     """
     S, T, H, D = q.shape
     N, bs = k_pool.shape[0], k_pool.shape[1]
@@ -190,60 +218,73 @@ def paged_attention(q, k_pool, v_pool, tables, pos, scale=None,
         if tuned is not None:
             q_tile = tuned[0] if q_tile is None else q_tile
             head_tile = tuned[1] if head_tile is None else head_tile
-    tq = _largest_divisor_leq(T, q_tile or DEFAULT_Q_TILE)
-    hq = _largest_divisor_leq(H, head_tile or DEFAULT_HEAD_TILE)
+    tq, hq = _tiles(T, H, D, q_tile or DEFAULT_Q_TILE,
+                    head_tile or DEFAULT_HEAD_TILE, aligned=not interpret)
+    return _paged_call(q, k_pool, v_pool, tables.astype(jnp.int32),
+                       pos.astype(jnp.int32), k_scale, v_scale, tq=tq,
+                       hq=hq, scale=float(scale), qmax=float(qmax),
+                       interpret=bool(interpret))
+
+
+# jitted so the 24+ identical per-layer calls of one model trace and lower
+# the kernel ONCE (one shared function in the module, not one copy a layer)
+@functools.partial(jax.jit, static_argnames=("tq", "hq", "scale", "qmax",
+                                             "interpret"))
+def _paged_call(q, k_pool, v_pool, tables, pos, k_scale, v_scale, *, tq, hq,
+                scale, qmax, interpret):
+    S, T, H, D = q.shape
+    N, bs = k_pool.shape[0], k_pool.shape[1]
+    nb = tables.shape[1]
+    quant = k_scale is not None
     nh, nq = H // hq, T // tq
 
-    tables = tables.astype(jnp.int32)
-    pos = pos.astype(jnp.int32)
-
     def q_index(s, h, qi, j, tables_ref, pos_ref):
-        return (s, qi, h, 0)
+        return (s, qi, h)
 
     def kv_index(s, h, qi, j, tables_ref, pos_ref):
         # THE block-table walk: this grid step's K/V block is whatever
-        # physical block the slot's table maps logical block j to
-        return (tables_ref[s, j], 0, h, 0)
+        # physical block the slot's table maps logical block j to —
+        # clamped to the tile's last live block, so the predicated-off
+        # tail repeats one index and is never fetched
+        last = jnp.clip((pos_ref[s] + (qi + 1) * tq - 1) // bs, 0, nb - 1)
+        return (tables_ref[s, jnp.minimum(j, last)], 0, h)
 
     def scale_index(s, h, qi, j, tables_ref, pos_ref):
-        # the scale row rides the same walk: one [1, hq] strip per block
-        return (tables_ref[s, j], h)
+        return (s, h, 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, tq, hq, D), q_index),
-        pl.BlockSpec((1, bs, hq, D), kv_index),
-        pl.BlockSpec((1, bs, hq, D), kv_index),
+        pl.BlockSpec((1, tq, hq * D), q_index),
+        pl.BlockSpec((1, bs, hq * D), kv_index),
+        pl.BlockSpec((1, bs, hq * D), kv_index),
     ]
-    operands = [q, k_pool, v_pool]
+    operands = [q.reshape(S, T, H * D), k_pool.reshape(N, bs, H * D),
+                v_pool.reshape(N, bs, H * D)]
     if quant:
-        in_specs += [pl.BlockSpec((1, hq), scale_index),
-                     pl.BlockSpec((1, hq), scale_index)]
-        operands += [k_scale.astype(jnp.float32),
-                     v_scale.astype(jnp.float32)]
+        def strips(sc):
+            # [N, H] -> this call's [S, nh, nb, hq] table-ordered strips
+            g = sc.astype(jnp.float32)[tables]            # [S, nb, H]
+            return g.reshape(S, nb, nh, hq).transpose(0, 2, 1, 3)
+        in_specs += [pl.BlockSpec((1, 1, nb, hq), scale_index,
+                                  memory_space=pltpu.SMEM)] * 2
+        operands += [strips(k_scale), strips(v_scale)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,                    # tables, pos
         grid=(S, nh, nq, nb),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, tq, hq, D), q_index),
+        out_specs=pl.BlockSpec((1, tq, hq * D), q_index),
         scratch_shapes=[
-            pltpu.VMEM((tq, hq, D), jnp.float32),      # acc
-            pltpu.VMEM((tq, hq, _LANE), jnp.float32),  # running max
-            pltpu.VMEM((tq, hq, _LANE), jnp.float32),  # running sum
+            pltpu.VMEM((hq, tq, D), jnp.float32),      # acc
+            pltpu.VMEM((hq, tq, _LANE), jnp.float32),  # running max
+            pltpu.VMEM((hq, tq, _LANE), jnp.float32),  # running sum
         ],
     )
-    base = functools.partial(_kernel, bs=bs, tq=tq, hq=hq, nb=nb,
-                             scale=float(scale), qmax=float(qmax))
-    if quant:
-        def kernel(tables_ref, pos_ref, q_ref, k_ref, v_ref, ks_ref,
-                   vs_ref, o_ref, acc_ref, m_ref, l_ref):
-            base(tables_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
-                 acc_ref, m_ref, l_ref, ks_ref=ks_ref, vs_ref=vs_ref)
-    else:
-        kernel = base
-    return pl.pallas_call(
+    kernel = functools.partial(_kernel, bs=bs, tq=tq, hq=hq, D=D, nb=nb,
+                               scale=scale, quant=quant, qmax=qmax)
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, T, H, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((S, T, H * D), q.dtype),
         interpret=interpret,
     )(tables, pos, *operands)
+    return out.reshape(S, T, H, D)

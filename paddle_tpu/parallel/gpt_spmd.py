@@ -847,6 +847,11 @@ def make_train_step(cfg: GPTSpmdConfig, plan: MeshPlan, mesh=None,
         lr_val = jnp.asarray(learning_rate if lr is None else lr, jnp.float32)
         return jitted(params, opt_state, tokens, labels, lr_val)
 
+    # the jitted program itself, for callers that inspect what was compiled
+    # (chip_smoke asserts the Pallas custom call is in it): step_fn.jitted
+    # .lower(params, opt_state, tokens, labels, lr_array)
+    step_fn.jitted = jitted
+
     def init_fn(key):
         params = init_gpt_params(cfg, key)
         if plan.vpp > 1:

@@ -410,15 +410,14 @@ class AnalyzeReport:
 
 
 def _resolve_device(device):
-    from ..cost_model.analytical import DEVICES, DeviceSpec
+    from ..cost_model.analytical import DEVICES, DeviceSpec, device_spec
     if isinstance(device, DeviceSpec):
         return device
     if device is None:
         import os
         device = os.environ.get("PADDLE_TPU_DEVICE_SPEC")
     if device is None:
-        import jax
-        device = "cpu" if jax.default_backend() == "cpu" else "tpu-v5e"
+        return device_spec()        # the attached device, by device_kind
     return DEVICES[device]
 
 

@@ -44,7 +44,7 @@ from .blocks import (  # noqa: F401
 )
 from .engine import (  # noqa: F401
     EngineConfig, GenerationEngine, PagedEngineConfig, PagedGenerationEngine,
-    default_compile_cache_dir, make_engine, save_for_generation,
+    make_engine, save_for_generation,
 )
 from .prefix_cache import PrefixCache  # noqa: F401
 from .scheduler import (  # noqa: F401
@@ -62,7 +62,6 @@ __all__ = [
     "PRIORITIES",
     "EngineConfig", "GenerationEngine", "PagedEngineConfig",
     "PagedGenerationEngine", "save_for_generation", "make_engine",
-    "default_compile_cache_dir",
     "SpecDecodeConfig", "SpeculativeEngine", "truncated_draft",
     "Scheduler", "ServingConfig", "Request", "RequestHandle",
     "QueueFullError", "LoadShedError", "RateLimitedError",

@@ -54,11 +54,13 @@ def main(argv=None):
     args = p.parse_args(argv)
 
     import paddle_tpu
+    from paddle_tpu.framework import compile_cache
     from paddle_tpu.serving import ServingConfig, make_engine
     from paddle_tpu.serving.distributed.worker import (
         ServingWorker, load_checkpoint_params)
     from paddle_tpu.text import models as _models
 
+    compile_cache.place()
     prof = None
     trace_dir = os.environ.get("PTN_TRACE_EXPORT_DIR")
     if trace_dir:

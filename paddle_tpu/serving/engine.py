@@ -62,12 +62,10 @@ def _quantize_weight(w, axis):
     return blocks.quantize_codes(w, s_b), s_b
 
 __all__ = ["EngineConfig", "GenerationEngine", "PagedEngineConfig",
-           "PagedGenerationEngine", "save_for_generation", "make_engine",
-           "default_compile_cache_dir"]
+           "PagedGenerationEngine", "save_for_generation", "make_engine"]
 
 DEFAULT_BUCKETS = (32, 64, 128, 256, 512, 1024)
 GENCFG_SUFFIX = ".gencfg"
-COMPILE_CACHE_DIRNAME = "_compile_cache"
 
 
 class EngineConfig:
@@ -1980,14 +1978,6 @@ class PagedGenerationEngine(GenerationEngine):
         return self._pos.copy()
 
 
-def default_compile_cache_dir(path):
-    """The persistent executable cache that lives NEXT TO a serving
-    artifact — what artifact-build precompile writes and a cold
-    Predictor loads."""
-    return os.path.join(os.path.dirname(os.path.abspath(path)),
-                        COMPILE_CACHE_DIRNAME)
-
-
 def _engine_kind(config):
     """"dense" | "paged" | "spec" | "tp" | "pp" | "spec_pp" for an
     EngineConfig-family instance (most-derived class first). The TP/PP
@@ -2064,8 +2054,8 @@ def save_for_generation(model, path, input_spec=None, engine_config=None,
     prefill bucket + the speculative draft/verify set), so a Predictor
     rebuilds the EXACT engine the artifact was built for. With
     `precompile=True` the whole set is AOT-compiled right now into the
-    artifact's persistent compile cache (`compile_cache_dir`, default a
-    `_compile_cache/` sibling) — a cold Predictor then deserializes
+    persistent compile cache (`compile_cache_dir`, default
+    `compile_cache.default_dir()`) — a cold Predictor then deserializes
     executables instead of compiling and is serving in seconds. Returns
     the precompile report ({executable: hit|miss|off}) or None."""
     from ..jit import save as jit_save
@@ -2094,7 +2084,7 @@ def save_for_generation(model, path, input_spec=None, engine_config=None,
                          "executable set to AOT-build is derived from it")
     if engine_config is not None:
         kind = _engine_kind(engine_config)
-        cache_dir = compile_cache_dir or default_compile_cache_dir(path)
+        cache_dir = compile_cache_dir or _cc.default_dir()
         if precompile:
             engine = make_engine(model, kind, engine_config.as_dict(),
                                  compile_cache_dir=cache_dir)

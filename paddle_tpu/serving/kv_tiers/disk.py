@@ -27,7 +27,7 @@ records to a temp file and atomically replaces the log (tmp + fsync +
 os.replace + directory fsync — the `update_latest` pattern).
 
 Stdlib + numpy only: importable without jax, so offline tools can
-inspect a spill log next to a wedged grant.
+inspect a spill log beside the process that holds the chip.
 """
 import hashlib
 import json

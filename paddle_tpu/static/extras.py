@@ -282,14 +282,12 @@ def cpu_places(device_count=None):
 
 
 def cuda_places(device_ids=None):
-    """reference: cuda_places -> accelerator places (TPU here)."""
-    from ..core.device import TPUPlace
-    try:
-        n = len(jax.devices())
-    except Exception:
-        n = 1
-    ids = device_ids if device_ids is not None else range(n)
-    return [TPUPlace(i) for i in ids]
+    """reference: cuda_places -> places of the default backend's devices
+    (the TPU when there is one)."""
+    from ..core.device import Place
+    devs = jax.devices()
+    ids = device_ids if device_ids is not None else range(len(devs))
+    return [Place(devs[0].platform, i) for i in ids]
 
 
 def xpu_places(device_ids=None):

@@ -13,10 +13,7 @@ The load-bearing properties:
     snapshot — the ROADMAP item-5 isolation substrate;
   - tenant labels are OBSERVABILITY-ONLY: a labeled run's greedy token
     streams and engine trace counts are bit-identical to an unlabeled
-    run over the same engine config (zero compile-count changes);
-  - tools/bench_trend.py classifies the committed wedged-grant rounds
-    (BENCH_r03-r05) as WEDGED, keeping them out of the trend line and
-    the compare-baseline choice.
+    run over the same engine config (zero compile-count changes).
 """
 import json
 import os
@@ -33,7 +30,6 @@ from paddle_tpu.text.models import gpt_tiny
 
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(_ROOT, "tools"))
-import bench_trend  # noqa: E402
 import load_harness  # noqa: E402
 import serve_report  # noqa: E402
 
@@ -194,32 +190,3 @@ def test_tenant_labels_are_observability_only(tiny):
              for k, v in eng.trace_counts.items()}, default=str))
     assert streams[0] == streams[1]        # bit-identical output
     assert traces[0] == traces[1]          # zero trace/compile changes
-
-
-# ----------------------------------------------------------- bench trend
-
-def test_bench_trend_classifies_the_committed_history(tmp_path):
-    """r01 is the only healthy committed round; r03-r05 are the wedged
-    grant (rc=124 / backend-probe-hung zeros) and must be excluded from
-    the trend AND never chosen as the compare baseline; r02 (a real
-    OOM) is FAILED, not WEDGED."""
-    paths = sorted(
-        os.path.join(_ROOT, f) for f in os.listdir(_ROOT)
-        if f.startswith("BENCH_r") and f.endswith(".json"))
-    assert len(paths) >= 5
-    rows = bench_trend.load_rows(paths)
-    by_run = {r["run"]: r for r in rows}
-    assert by_run["r01"]["class"] == bench_trend.HEALTHY
-    assert by_run["r01"]["value"] > 0
-    assert by_run["r02"]["class"] == bench_trend.FAILED
-    for r in ("r03", "r04", "r05"):
-        assert by_run[r]["class"] == bench_trend.WEDGED, by_run[r]
-    base = bench_trend.healthy_baseline(rows)
-    assert base["run"] == "r01"
-    # JSONL + render round trip
-    out = str(tmp_path / "trend.jsonl")
-    assert bench_trend.main([*paths, "--jsonl", out]) == 0
-    trend = [json.loads(line) for line in open(out)]
-    assert all(t["schema"] == bench_trend.SCHEMA for t in trend)
-    text = bench_trend.render(rows)
-    assert "WEDGED" in text and "compare baseline: r01" in text

@@ -1,6 +1,7 @@
 """bench.py ladder semantics: the race phase measures the near-best configs
-and reports the fastest; OOM-class failures fall to the step-down tail;
-non-OOM failures surface as real errors (never silently stepped over)."""
+and reports the fastest; out-of-memory failures — and nothing else — fall
+to the step-down tail; any other failure ends the run, in the race as in
+the tail (never stepped over, never folded into `extra`)."""
 import os
 
 import pytest
@@ -14,10 +15,10 @@ def bench_mocked(monkeypatch):
 
     monkeypatch.setenv("BENCH_SKIP_PREFLIGHT", "1")
     emitted = []
-    monkeypatch.setattr(bench, "probe_backend", lambda *a, **k: "tpu")
+    monkeypatch.setattr(bench, "attached_device", lambda: {
+        "platform": "tpu", "kind": "TPU v5 lite", "count": 1})
     monkeypatch.setattr(bench, "emit",
                         lambda v, vb, extra=None: emitted.append((v, extra)))
-    monkeypatch.setattr(bench, "flash_parity_preflight", lambda S: {})
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     return bench, emitted
 
@@ -41,7 +42,7 @@ def test_race_reports_fastest_config(bench_mocked, monkeypatch):
     assert extra["ladder_rung"] == "B=12,remat=dots,fused_ce"
     assert set(extra["race"]) == {"B=12,remat=dots,fused_ce",
                                   "B=12,remat=dots", "B=12,remat=dots+attn"}
-    assert "B=16,remat=dots,fused_ce" in extra["race_errors"]
+    assert "B=16,remat=dots,fused_ce" in extra["race_oom"]
     assert calls == [(16, "dots", True), (12, "dots", True),
                      (12, "dots", False), (12, "dots+attn", False)]
 
@@ -73,8 +74,10 @@ def test_non_oom_failure_raises(bench_mocked, monkeypatch):
     assert not emitted
 
 
-def test_race_error_with_other_success_lands_in_extra(bench_mocked,
-                                                      monkeypatch):
+def test_race_non_oom_failure_ends_the_run(bench_mocked, monkeypatch):
+    """A race rung that fails for any reason but device memory is a real
+    failure even when another rung succeeded: it raises (bench.py then
+    prints the failure record and exits non-zero)."""
     bench, emitted = bench_mocked
 
     def fake(B, S, remat, n_steps, on_tpu, scan_k, fused_ce=False):
@@ -83,7 +86,28 @@ def test_race_error_with_other_success_lands_in_extra(bench_mocked,
         return {"value": 0.33, "vs_baseline": 0.82, "extra": {"step_ms": 420.0}}
 
     monkeypatch.setattr(bench, "run_config", fake)
-    bench.main()
-    _, extra = emitted[0]
-    assert extra["ladder_rung"] == "B=16,remat=dots,fused_ce"
-    assert "impossible MFU" in extra["race_errors"]["B=12,remat=dots+attn"]
+    with pytest.raises(AssertionError, match="impossible MFU"):
+        bench.main()
+    assert not emitted
+
+
+def test_is_oom_is_only_what_the_runtime_says():
+    import bench
+    assert bench._is_oom(RuntimeError("RESOURCE_EXHAUSTED: Ran out of "
+                                      "memory in memory space hbm"))
+    assert bench._is_oom(RuntimeError("Exceeded hbm capacity by 3.96G"))
+    # a bare substring or a compile-service failure is NOT out-of-memory
+    assert not bench._is_oom(RuntimeError("BLOOM filter mismatch: OOM?"))
+    assert not bench._is_oom(RuntimeError("INTERNAL: compile failed"))
+
+
+def test_no_chip_no_default_train_rung(monkeypatch):
+    """Without a TPU the default rung fails — it never runs a small
+    config on the host under the MFU metric's name."""
+    import bench
+    monkeypatch.delenv("BENCH_B", raising=False)
+    monkeypatch.delenv("BENCH_REMAT", raising=False)
+    monkeypatch.setattr(bench, "run_config", lambda *a, **k: pytest.fail(
+        "train rung ran without a chip"))
+    with pytest.raises(RuntimeError, match="no TPU"):
+        bench.main()

@@ -426,15 +426,20 @@ def test_gencfg_records_executable_set(tmp_path):
 def test_bench_cold_start_rung(tmp_path):
     """`bench.py --cold-start` emits the driver schema, the warm child
     beats the cold child to serving-ready, and the rung's own
-    zero-compile assertions held (it would have failed otherwise)."""
-    env = dict(os.environ, JAX_PLATFORMS="cpu", BENCH_INIT_BUDGET_S="120",
-               BENCH_COLDSTART_DIR=str(tmp_path))
+    zero-compile assertions held (it would have failed otherwise). The
+    parent never initialises jax (it asserts so itself: one process per
+    chip), and every cache file of the measured children is under the
+    rung's one cache directory."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               BENCH_COLDSTART_DIR=str(tmp_path / "rung"))
     out = subprocess.run(
         [sys.executable, os.path.join(_ROOT, "bench.py"), "--cold-start"],
         capture_output=True, text=True, timeout=560, env=env, cwd=_ROOT)
+    assert out.returncode == 0, out.stderr[-2000:]
     rec = json.loads(out.stdout.strip().splitlines()[-1])
     assert rec["metric"] == "gpt_cold_start_warm_ready_s"
     assert "error" not in rec, rec
+    assert rec["device"]["platform"] == "cpu"
     extra = rec["extra"]
     assert extra["warm_beats_cold"] is True
     assert rec["vs_baseline"] > 1.0
@@ -442,6 +447,28 @@ def test_bench_cold_start_rung(tmp_path):
     assert extra["warm"]["trace_counts"]["decode"] == 0
     assert extra["cold"]["compile_cache"]["misses"] >= 2
     assert extra["warm"]["first_token"] == extra["cold"]["first_token"]
+    cache_dir = str(tmp_path / "rung" / "cache")
+    assert extra["cache_dir"] == cache_dir
+    for child in ("cold", "warm"):
+        assert extra[child]["compile_cache_dir"] == \
+            os.path.join(cache_dir, "executables")
+    assert os.listdir(os.path.join(cache_dir, "executables"))
+
+
+def test_bench_failure_exits_nonzero(tmp_path):
+    """Every failure path of bench.py prints the failure record AND
+    exits non-zero — here the default train rung without a chip."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PADDLE_TPU_POSTMORTEM_DIR=str(tmp_path / "pm"))
+    env.pop("BENCH_B", None)
+    env.pop("BENCH_REMAT", None)
+    out = subprocess.run(
+        [sys.executable, os.path.join(_ROOT, "bench.py")],
+        capture_output=True, text=True, timeout=240, env=env, cwd=_ROOT)
+    assert out.returncode != 0
+    rec = json.loads(out.stdout.strip().splitlines()[-1])
+    assert rec["value"] == 0.0 and "no TPU" in rec["error"]
+    assert rec["device"]["platform"] == "cpu"
 
 
 def test_retention_cap_evicts_lru_by_mtime(tmp_path):
@@ -483,3 +510,141 @@ def test_retention_cap_evicts_lru_by_mtime(tmp_path):
     finally:
         _flags.set_flags({"FLAGS_compile_cache_max_entries": 0})
     assert cc.CompileCache(str(tmp_path)).max_entries == 0  # unlimited
+
+
+# ------------------------------------------------ placement (ISSUE 21)
+
+def test_entry_records_devices_and_reloads_on_them(tmp_path):
+    """An executable reloads onto the devices it was compiled for — one
+    device of eight, or a four-device mesh — never onto "every device of
+    the backend" (the jax default that broke every warm load on a
+    multi-device host)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    cache = cc.CompileCache(str(tmp_path))
+    x1 = jnp.arange(8.0)
+    mesh = Mesh(np.asarray(jax.devices()[2:6]), ("x",))
+    x4 = jax.device_put(jnp.arange(8.0), NamedSharding(mesh, P("x")))
+    for name, x, want_ids in (("one", x1, [0]), ("four", x4, [2, 3, 4, 5])):
+        assert cc.cached_jit(_mul_add, name, static_sig=name,
+                             cache=cache).warm(x, x) == "miss"
+        entry, = [e for e in cache.entries() if e.startswith(name)]
+        with open(os.path.join(cache.path, entry, cc.ENTRY_META)) as f:
+            assert json.load(f)["device_ids"] == want_ids
+        again = cc.cached_jit(_mul_add, name, static_sig=name, cache=cache)
+        assert again.warm(x, x) == "hit"
+        np.testing.assert_allclose(np.asarray(again(x, x)),
+                                   np.arange(8.0) ** 2 + 1.0)
+    assert cache.stats["corrupt"] == 0
+
+
+def test_cache_root_unset_is_the_checkout(monkeypatch):
+    """With $JAX_COMPILATION_CACHE_DIR unset the one root is
+    <checkout>/.jax_cache (git-ignored), and the executable entries
+    default to a sub-directory of it."""
+    monkeypatch.delenv(cc.CACHE_ENV)
+    assert cc.cache_root() == os.path.join(_ROOT, ".jax_cache")
+    assert cc.default_dir() == os.path.join(_ROOT, ".jax_cache",
+                                            "executables")
+    with open(os.path.join(_ROOT, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()
+
+
+_PLACEMENT_SCRIPT = r"""
+import json, os, sys
+import numpy as np
+import jax
+import paddle_tpu as paddle
+import paddle_tpu.nn as nn
+from paddle_tpu.framework import compile_cache as cc
+from paddle_tpu.inference import Config, create_predictor
+from paddle_tpu.static import InputSpec
+after_import = jax.config.jax_compilation_cache_dir
+net = nn.Sequential(nn.Linear(8, 4))
+path = os.path.join(sys.argv[1], "net")
+paddle.jit.save(net, path, input_spec=[InputSpec([2, 8], "float32")])
+pred = create_predictor(Config(path + ".pdmodel", path + ".pdiparams"))
+pred.run([np.ones((2, 8), np.float32)])
+print(json.dumps({"after_import": after_import,
+                  "after_predictor": jax.config.jax_compilation_cache_dir,
+                  "root": cc.cache_root()}))
+"""
+
+
+@pytest.mark.parametrize("env_set", [True, False])
+def test_one_variable_places_every_cache(tmp_path, env_set):
+    """$JAX_COMPILATION_CACHE_DIR set: jax's cache directory is that value
+    after `import paddle_tpu` and still after building a Predictor (code
+    sets no directory), and nothing is written beside the artifact.
+    Unset: building the Predictor places the cache at the checkout
+    default — here an exported copy of the package, so the test never
+    writes into the real checkout."""
+    art = tmp_path / "artifact"
+    art.mkdir()
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    if env_set:
+        cache = str(tmp_path / "cache")
+        env[cc.CACHE_ENV] = cache
+        root, want_import = _ROOT, cache
+    else:
+        # a stand-in checkout: <copy>/paddle_tpu -> the package
+        env.pop(cc.CACHE_ENV)
+        root = str(tmp_path / "checkout")
+        os.makedirs(root)
+        import shutil
+        shutil.copytree(os.path.join(_ROOT, "paddle_tpu"),
+                        os.path.join(root, "paddle_tpu"),
+                        ignore=shutil.ignore_patterns("__pycache__"))
+        cache, want_import = os.path.join(root, ".jax_cache"), None
+    env["PYTHONPATH"] = root
+    out = subprocess.run([sys.executable, "-c", _PLACEMENT_SCRIPT, str(art)],
+                         capture_output=True, text=True, timeout=300,
+                         env=env, cwd=str(tmp_path))
+    assert out.returncode == 0, out.stderr[-2000:]
+    rec = json.loads(out.stdout.strip().splitlines()[-1])
+    assert rec["after_import"] == want_import
+    assert rec["after_predictor"] == cache
+    assert rec["root"] == cache
+    assert os.listdir(cache), "the placed cache directory was never written"
+    # nothing beside the artifact: no _xla_cache / _compile_cache sibling
+    assert sorted(os.listdir(art)) == ["net.pdiparams", "net.pdmodel"]
+
+
+_RESERIALIZE_SCRIPT = r"""
+import json, sys
+import jax.numpy as jnp
+from paddle_tpu.framework import compile_cache as cc
+cc.place()
+cache = cc.CompileCache(sys.argv[1])
+f = cc.cached_jit(lambda x, y: jnp.where(x > 0, x + y, y) @ y.T, "reser",
+                  static_sig="reser", cache=cache)
+x = jnp.ones((64, 64))
+print(json.dumps({"out": float(f(x, x).sum()), "stats": cache.stats,
+                  "entries": cache.entries()}))
+"""
+
+
+def test_executable_from_jax_cache_is_not_stored_again(tmp_path):
+    """An executable that jax's own persistent cache SERVED is not
+    re-serialized into this cache: on XLA:CPU such a payload loads and
+    then fails at its first call ("Function ... not found"). Reached when
+    the entry key moved (a source edit) while the program did not."""
+    import shutil
+    entries = str(tmp_path / "entries")
+
+    def run():
+        out = subprocess.run(
+            [sys.executable, "-c", _RESERIALIZE_SCRIPT, entries],
+            capture_output=True, text=True, timeout=240, cwd=_ROOT,
+            env=dict(os.environ, JAX_PLATFORMS="cpu"))
+        assert out.returncode == 0, out.stderr[-2000:]
+        return json.loads(out.stdout.strip().splitlines()[-1])
+
+    fresh = run()                       # compiles; both caches store it
+    assert len(fresh["entries"]) == 1 and fresh["stats"]["misses"] == 1
+    shutil.rmtree(entries)              # as if the entry key had moved
+    served = run()                      # jax's cache serves the compile
+    assert served["entries"] == [] and served["stats"]["uncacheable"] == 1
+    assert fresh["out"] == served["out"]

@@ -242,21 +242,13 @@ def test_join_gauges_exported(capture):
                for k in flat), sorted(flat)
 
 
-# --------------------------------------------------- compat guard satellite
+# --------------------------------------------------- compat seam satellite
 
-def test_profile_data_guard_is_curated():
-    """_jax_compat.profile_data() either works (newer jax) or raises the
-    curated error naming the minimum jax version — never a raw
-    ImportError whose message is just a module path."""
-    try:
-        load = _jax_compat.profile_data()
-    except _jax_compat.ProfileDataUnavailableError as e:
-        msg = str(e)
-        assert _jax_compat.PROFILE_DATA_MIN_JAX in msg
-        assert "installed: jax" in msg
-        assert "XSpace decoder" in msg       # names the fallback
-    else:
-        assert callable(load)
+def test_profile_data_is_the_installed_spelling():
+    """No version shim: profile_data() IS jax.profiler.ProfileData's one
+    installed constructor."""
+    from jax.profiler import ProfileData
+    assert _jax_compat.profile_data() == ProfileData.from_file
 
 
 def test_parser_works_without_native_binding(capture):
@@ -303,7 +295,7 @@ def test_xplane_summary_cli_fails_loudly(tmp_path):
 _BENCH_ENV = dict(
     JAX_PLATFORMS="cpu",
     BENCH_B="2", BENCH_S="64", BENCH_LAYERS="2", BENCH_HIDDEN="64",
-    BENCH_HEADS="4", BENCH_VOCAB="512", BENCH_INIT_BUDGET_S="120")
+    BENCH_HEADS="4", BENCH_VOCAB="512")
 
 
 @pytest.fixture(scope="module")
@@ -382,7 +374,7 @@ def test_wedged_run_postmortem_records_armed_capture(tmp_path):
         [sys.executable, os.path.join(_ROOT, "bench.py"),
          "--xplane", out_dir],
         capture_output=True, text=True, timeout=240, cwd=_ROOT, env=env)
-    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert proc.returncode != 0, "a hung rung must fail the process"
     rec = json.loads(proc.stdout.strip().splitlines()[-1])
     assert "wedged" in rec["error"]
     pm_path = rec["extra"]["postmortem"]

@@ -21,8 +21,7 @@ The load-bearing properties:
   - per-tenant residency lands everywhere it should: load_harness
     summaries + serving_load_tenant_kv_blocks_* gauges, fleet-merged
     serving_kv_blocks{tenant,kind} series, serve_report's residency and
-    prefix-share tables;
-  - tools/bench_trend.py --json emits the machine-readable document.
+    prefix-share tables.
 """
 import json
 import os
@@ -40,7 +39,6 @@ from paddle_tpu.text.models import gpt_tiny
 
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(_ROOT, "tools"))
-import bench_trend  # noqa: E402
 import load_harness  # noqa: E402
 import metrics_report  # noqa: E402
 import serve_report  # noqa: E402
@@ -377,17 +375,3 @@ def test_fleet_priming_creates_kv_children_at_zero():
             f"serving_kv_blocks{{kind={kind},tenant=primed_t}}"] == 0
         assert flat[
             f"serving_kv_bytes{{kind={kind},tenant=primed_t}}"] == 0
-
-
-# ----------------------------------------------------- bench trend --json
-
-def test_bench_trend_json_document(capsys):
-    paths = sorted(
-        os.path.join(_ROOT, f) for f in os.listdir(_ROOT)
-        if f.startswith("BENCH_r") and f.endswith(".json"))
-    assert bench_trend.main([*paths, "--json"]) == 0
-    doc = json.loads(capsys.readouterr().out)
-    assert doc["schema"] == bench_trend.SCHEMA
-    assert len(doc["rows"]) == len(paths)
-    assert doc["baseline"]["run"] == "r01"
-    assert doc["rows"] == bench_trend.load_rows(paths)

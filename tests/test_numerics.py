@@ -15,7 +15,7 @@ Three layers under test:
 
 Satellites ride along: host-tier requant saturation, the kvledger
 `sat` field + serve_report residency join, metrics_report gating,
-bench_trend NUMERIC classification, optimizer-side taps.
+optimizer-side taps.
 """
 import json
 import math
@@ -35,7 +35,6 @@ from paddle_tpu.text.models.gpt import gpt_tiny
 
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(_ROOT, "tools"))
-import bench_trend  # noqa: E402
 import metrics_report  # noqa: E402
 import serve_report  # noqa: E402
 
@@ -413,51 +412,6 @@ def test_metrics_compare_gates_finite_frac_drop(tmp_path):
     assert any("finite fraction dropped" in w for w in why.values()), regs
     # identical runs stay clean
     assert metrics_report.compare_counters(snap(1.0), snap(1.0)) == []
-
-
-# -------------------------------------------------- bench_trend NUMERIC
-
-def _trend_doc(n, rc, parsed, tail=""):
-    return {"n": n, "cmd": "bench", "rc": rc, "tail": tail,
-            "parsed": parsed}
-
-
-def test_bench_trend_classifies_numeric_casualties(tmp_path):
-    docs = {
-        "BENCH_r01.json": _trend_doc(
-            1, 0, {"metric": "m", "value": 0.4,
-                   "extra": {"numerics": {"anomalies": 0}}}),
-        "BENCH_r02.json": _trend_doc(
-            2, 1, {"metric": "m", "value": 0.0,
-                   "error": "numerics anomalies latched on the healthy "
-                            "train rung: {'decode.logits:nonfinite': 1}"}),
-        "BENCH_r03.json": _trend_doc(
-            3, 1, {"metric": "m", "value": 0.0,
-                   "extra": {"numerics": {"anomalies": 3}}}),
-        "BENCH_r04.json": _trend_doc(
-            4, 124, {"metric": "m", "value": 0.0,
-                     "error": "backend probe hung"}),
-        "BENCH_r05.json": _trend_doc(
-            5, 1, {"metric": "m", "value": 0.0, "error": "HBM OOM"}),
-    }
-    paths = []
-    for name, doc in docs.items():
-        p = str(tmp_path / name)
-        with open(p, "w") as f:
-            json.dump(doc, f)
-        paths.append(p)
-    rows = bench_trend.load_rows(paths)
-    cls = {r["run"]: r["class"] for r in rows}
-    assert cls == {"r01": bench_trend.HEALTHY,
-                   "r02": bench_trend.NUMERIC,
-                   "r03": bench_trend.NUMERIC,
-                   "r04": bench_trend.WEDGED,
-                   "r05": bench_trend.WEDGED}
-    # NUMERIC rounds can never be picked as the compare baseline
-    assert bench_trend.healthy_baseline(rows)["run"] == "r01"
-    table = bench_trend.render(rows)
-    assert "numeric casualties" in table
-    assert "r02, r03" in table
 
 
 # ---------------------------------------------------------- optimizer taps

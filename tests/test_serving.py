@@ -318,7 +318,7 @@ def test_bench_decode_rung_runs():
     """bench.py --decode emits the schema the driver parses."""
     import json
     import subprocess
-    env = dict(os.environ, JAX_PLATFORMS="cpu", BENCH_INIT_BUDGET_S="120",
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                BENCH_DECODE_STEPS="2", BENCH_DECODE_SLOTS="2",
                BENCH_DECODE_MAXLEN="32", BENCH_DECODE_PROMPT="4")
     out = subprocess.run(

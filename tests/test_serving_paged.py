@@ -429,7 +429,7 @@ def test_bench_serve_load_rung_runs():
     the paged-vs-dense comparison in extra."""
     import json
     import subprocess
-    env = dict(os.environ, JAX_PLATFORMS="cpu", BENCH_INIT_BUDGET_S="120",
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                BENCH_SERVE_REQUESTS="8", BENCH_SERVE_SLOTS="2",
                BENCH_SERVE_MAXLEN="64", BENCH_SERVE_PAGED_SLOTS="4")
     out = subprocess.run(

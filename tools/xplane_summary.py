@@ -13,13 +13,11 @@ per-op markdown table, and optionally appends the schema-validated
 
 The parser modules are loaded STANDALONE by file path (they are
 stdlib-only by contract) — this tool never imports jax or paddle_tpu,
-so it can read a capture from a box whose backend is wedged (the
-on-chip runbook case tools/tpu_capture.sh scripts).
+so it runs beside the process that holds the chip (one process per
+chip).
 
 Exit is NONZERO with the reason on any failure — an empty or host-only
-capture can no longer produce a silently empty xplane_top_ops.md
-(ISSUE 9 satellite; the `|| true` that swallowed this is gone from
-tpu_capture.sh).
+capture cannot produce a silently empty table.
 """
 import argparse
 import importlib.util
