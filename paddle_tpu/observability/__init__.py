@@ -31,7 +31,7 @@ one substrate they all report through:
                        parser to deviceprof.v1 JSONL, the join against
                        host spans + the analytical cost model, and the
                        one-shot healthy-window capture orchestration
-                       (bench --xplane / scheduler.capture_decode_steps).
+                       (bench --xplane).
   fleet.py           — the LIVE fleet plane (ISSUE 12): metrics
                        federation (merge N per-process metrics.v1
                        snapshots into one worker_id/role-labeled fleet

@@ -27,10 +27,9 @@ as a manual runbook step whose parser had never seen real output
               roofline attribution, now on device time — exported as
               `deviceprof_*` registry gauges and a bench `extra` block.
   orchestrate — `OneShotCapture`: an armed capture that fires once in a
-              healthy window (bench.py --xplane, the serving scheduler's
-              capture_decode_steps). Every state transition is annotated
-              into the flight recorder, so a run that wedges BEFORE the
-              capture fires leaves "armed, never fired" in its
+              healthy window (bench.py --xplane). Every state transition
+              is annotated into the flight recorder, so a run that wedges
+              BEFORE the capture fires leaves "armed, never fired" in its
               postmortem instead of losing the evidence.
 
 Decoder resolution: `jax.profiler.ProfileData` in a process that already

@@ -37,8 +37,8 @@ import threading
 import time
 import traceback
 
-__all__ = ["FlightRecorder", "POSTMORTEM_SCHEMA", "enable", "get",
-           "dump_postmortem", "annotate", "thread_stacks"]
+__all__ = ["FlightRecorder", "SpanLog", "POSTMORTEM_SCHEMA", "enable",
+           "get", "dump_postmortem", "annotate", "thread_stacks"]
 
 POSTMORTEM_SCHEMA = "paddle_tpu.postmortem.v1"
 DEFAULT_DIR_ENV = "PADDLE_TPU_POSTMORTEM_DIR"
@@ -89,6 +89,8 @@ def _json_safe(v):
 
 
 def _compact_span(rec):
+    if isinstance(rec, list):           # open, and only the span log's
+        rec = dict(zip(SpanLog.FIELDS, rec))
     out = {"name": rec.get("name"), "type": rec.get("type"),
            "tid": rec.get("tid"), "ts": rec.get("ts"),
            "dur": rec.get("dur"), "depth": rec.get("depth"),
@@ -98,6 +100,68 @@ def _compact_span(rec):
     if attrs:
         out["attrs"] = {k: _json_safe(v) for k, v in attrs.items()}
     return out
+
+
+class SpanLog:
+    """The serve path's always-on ring: closed `serving::*` spans as
+    tuples of `FIELDS`, whether or not a profiler or a flight recorder
+    is attached. Where the FlightRecorder's ring keeps the last few
+    hundred spans of anything for a postmortem, this one keeps minutes
+    of one subsystem for a reader that wants a whole window: a tuple a
+    span (no dict, no json), and it counts what it overwrites so a
+    reader is never handed part of a window as if it were all of it.
+
+    Capacity: a 30 s window + 2 s traced + the drain at three times the
+    PR 25 step rate (87 steps/s x ~12 spans) is ~37k records; 65 536
+    holds three minutes of today's serve cell."""
+
+    FIELDS = ("name", "ts", "dur", "span_id", "parent", "attrs")
+    PREFIX = "serving::"
+    DEFAULT_CAPACITY = 1 << 16
+
+    def __init__(self, capacity=DEFAULT_CAPACITY):
+        self._ring = collections.deque(maxlen=int(capacity))
+        self._lock = threading.Lock()
+        self.appended = 0
+
+    @property
+    def capacity(self):
+        return self._ring.maxlen
+
+    @property
+    def dropped(self):
+        """Records overwritten since the last clear()."""
+        return max(self.appended - self._ring.maxlen, 0)
+
+    def append(self, record):
+        # under the lock: spans close on several threads, and a count
+        # that lost an increment would let window() hand out part of a
+        # window as the whole of it
+        with self._lock:
+            self._ring.append(record)
+            self.appended += 1
+
+    def spans(self):
+        with self._lock:
+            return list(self._ring)
+
+    def window(self, start_ns, end_ns):
+        """The records that START in [start_ns, end_ns), oldest first,
+        as dicts keyed by FIELDS; None when a record of the window may
+        already have been overwritten (something was dropped and the
+        oldest record kept had not closed before the window opened)."""
+        with self._lock:
+            kept = list(self._ring)
+            dropped = self.appended > self._ring.maxlen
+        if dropped and (not kept or kept[0][1] + kept[0][2] > start_ns):
+            return None
+        return [dict(zip(self.FIELDS, r)) for r in kept
+                if start_ns <= r[1] < end_ns]
+
+    def clear(self):
+        with self._lock:
+            self._ring.clear()
+            self.appended = 0
 
 
 class FlightRecorder:

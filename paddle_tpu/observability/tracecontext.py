@@ -23,6 +23,7 @@ is set — and None is the signal NOT to spend wire bytes on propagation.
 Stdlib-only: imported by the profiler's hot path and by the standalone
 flight recorder.
 """
+import itertools
 import json
 import os
 import struct
@@ -45,8 +46,27 @@ def new_trace_id():
     return os.urandom(16).hex()
 
 
+def _span_counter():
+    return itertools.count(int.from_bytes(os.urandom(8), "big"))
+
+
+# span ids count up from a random 64-bit start: unique in the process,
+# as unlikely to collide across processes as random ones, and no system
+# call per span (os.urandom is one; under a sandboxed kernel it costs
+# tens of microseconds, and the serve path opens a dozen spans a step)
+_span_ids = _span_counter()
+
+
+def _reseed_span_ids():
+    global _span_ids
+    _span_ids = _span_counter()
+
+
+os.register_at_fork(after_in_child=_reseed_span_ids)
+
+
 def new_span_id():
-    return os.urandom(8).hex()
+    return "%016x" % (next(_span_ids) & 0xFFFFFFFFFFFFFFFF)
 
 
 _tls = threading.local()

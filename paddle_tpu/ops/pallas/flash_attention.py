@@ -207,6 +207,7 @@ def _mha_forward(q, k, v, mask, seed, causal, sm_scale, block_q, block_k,
             pltpu.VMEM((block_q, 128), jnp.float32),   # running sum
         ],
         interpret=interpret,
+        name="flash_fwd", metadata={"kernel": "flash_fwd"},
     )(*operands)
     return o, lse
 
@@ -415,6 +416,7 @@ def _mha_backward(q, k, v, o, lse, do, mask, seed, causal, sm_scale,
         out_shape=jax.ShapeDtypeStruct((BH, S, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         interpret=interpret,
+        name="flash_dq", metadata={"kernel": "flash_dq"},
     )(*operands)
 
     class _DkvOrder:
@@ -450,6 +452,7 @@ def _mha_backward(q, k, v, o, lse, do, mask, seed, causal, sm_scale,
             pltpu.VMEM((block_k, D), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_dkv", metadata={"kernel": "flash_dkv"},
     )(*operands)
     return dq, dk, dv
 

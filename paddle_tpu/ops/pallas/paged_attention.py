@@ -286,5 +286,6 @@ def _paged_call(q, k_pool, v_pool, tables, pos, k_scale, v_scale, *, tq, hq,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, T, H * D), q.dtype),
         interpret=interpret,
+        name="paged_attn", metadata={"kernel": "paged_attn"},
     )(tables, pos, *operands)
     return out.reshape(S, T, H, D)

@@ -25,10 +25,12 @@ import json
 import os
 import threading
 import time
+from time import perf_counter_ns as _now_ns
 
 import jax
 import numpy as np
 
+from ..observability.flight_recorder import SpanLog
 from ..observability.tracecontext import (
     clear_trace as _clear_trace, current_trace_id as _current_trace_id,
     ensure_trace as _ensure_trace, new_span_id as _new_span_id,
@@ -38,7 +40,7 @@ from ..observability.tracecontext import (
 __all__ = ["Profiler", "ProfilerTarget", "ProfilerState", "RecordEvent",
            "TracerEventType", "SortedKeys", "SummaryView",
            "make_scheduler", "export_chrome_tracing", "export_protobuf",
-           "load_profiler_result"]
+           "load_profiler_result", "span_log", "record_span", "span_attrs"]
 
 STEP_TIMELINE_SCHEMA = "paddle_tpu.step_timeline.v1"
 
@@ -115,7 +117,19 @@ class _HostTracer:
     Flight recorder: when `ring` is attached (observability.
     flight_recorder), closed spans are ALSO pushed there — including
     spans recorded while the profiler is CLOSED, so a postmortem always
-    has recent history."""
+    has recent history.
+
+    Span log: `log` (flight_recorder.SpanLog) is attached from the
+    start and takes every closed span named `serving::*` as a tuple,
+    profiler or not, recorder or not: the serve step is read from it
+    (docs/observability.md, "What a serving operator gets"). It is the
+    one store that is on by default, so only that prefix feeds it; the
+    per-op spans of the eager path stay behind `enabled`. While the
+    log is the ONLY store that is on (the serving default), an open
+    span is the log's own record, a list in `SpanLog.FIELDS` order
+    that `end` turns into the tuple: no dict, no thread id, depth or
+    trace id that nothing would read (a third off the cost of a span,
+    PERF.md §6, PR 26). `_span_id` / `_attrs` read either kind."""
 
     def __init__(self):
         self.enabled = False
@@ -123,8 +137,10 @@ class _HostTracer:
         self.with_flops = True
         self.events = []
         self.ring = None                 # FlightRecorder, when enabled
+        self.log = SpanLog()             # serving::* spans, always on
         self._lock = threading.Lock()
         self._stacks = {}                # thread id -> open-span stack
+        self._inherit = {}               # thread id -> span_attrs() scope
         self._ref_seen = set()
 
     def _stack(self):
@@ -134,16 +150,41 @@ class _HostTracer:
             st = self._stacks.setdefault(tid, [])
         return st
 
+    def _open(self, attrs):
+        """This thread's stack, and `attrs` with what a span_attrs()
+        scope adds (in place: the caller may go on filling its dict)."""
+        tid = threading.get_ident()
+        st = self._stacks.get(tid)
+        if st is None:
+            st = self._stacks.setdefault(tid, [])
+        inherited = self._inherit.get(tid)
+        if inherited:
+            attrs = {} if attrs is None else attrs
+            for k, v in inherited.items():
+                attrs.setdefault(k, v)
+        return tid, st, attrs
+
     def begin(self, name, event_type, attrs=None, ref=None):
         if not self.enabled and self.ring is None:
-            return None
-        st = self._stack()
+            if not name.startswith(SpanLog.PREFIX):
+                return None
+            # the serving default, only the log is on: the open span
+            # is the log's own record, a list until it closes
+            tid = threading.get_ident()
+            st = self._stacks.get(tid)
+            if st is None or tid in self._inherit:
+                _, st, attrs = self._open(attrs)
+            rec = [name, _now_ns(), None, _new_span_id(),
+                   _span_id(st[-1]) if st else None, attrs]
+            st.append(rec)
+            return rec
+        tid, st, attrs = self._open(attrs)
         rec = {"name": name, "type": event_type,
-               "tid": threading.get_ident(),
-               "ts": time.perf_counter_ns(), "dur": None,
+               "tid": tid,
+               "ts": _now_ns(), "dur": None,
                "depth": len(st),
                "span_id": _new_span_id(),
-               "parent": st[-1]["span_id"] if st else None,
+               "parent": _span_id(st[-1]) if st else None,
                "trace": _current_trace_id()}
         if not self.enabled:             # ring-only span: keep it out of
             rec["_fr_only"] = True       # the profiler's window events
@@ -156,19 +197,40 @@ class _HostTracer:
         st.append(rec)
         return rec
 
-    def end(self, rec):
-        if rec is None:
-            return
-        st = self._stack()
+    def _pop(self, rec):
+        tid = threading.get_ident()
+        st = self._stacks.get(tid, ())
         if st and st[-1] is rec:
             st.pop()
         elif rec in st:                   # unbalanced nesting: drop through
             st.remove(rec)
         if not st:                        # evict: dead threads must not
-            self._stacks.pop(threading.get_ident(), None)  # leak entries
-        rec["dur"] = time.perf_counter_ns() - rec["ts"]
+            self._stacks.pop(tid, None)   # leak entries
+
+    def end(self, rec):
+        if rec is None:
+            return
+        if rec.__class__ is list:         # the log's own record
+            rec[2] = _now_ns() - rec[1]
+            st = self._stacks.get(threading.get_ident())
+            if st and st[-1] is rec and len(st) > 1:
+                st.pop()                  # the usual case: a child closes
+            else:
+                self._pop(rec)
+            self.log.append(tuple(rec))
+            return
+        rec["dur"] = _now_ns() - rec["ts"]
+        self._pop(rec)
         if self.sample_memory:
             rec["mem1"] = _live_bytes()
+        self._closed(rec)
+
+    def _closed(self, rec):
+        """A closed span goes to every store that is on."""
+        if rec["name"].startswith(SpanLog.PREFIX):
+            self.log.append((rec["name"], rec["ts"], rec["dur"],
+                             rec["span_id"], rec["parent"],
+                             rec.get("attrs")))
         ring = self.ring
         if ring is not None:
             ring.record_span(rec)
@@ -177,25 +239,46 @@ class _HostTracer:
         with self._lock:
             self.events.append(rec)
 
+    def record(self, name, event_type, ts_ns, dur_ns, attrs=None):
+        """A span that was timed elsewhere (a request's queue wait, from
+        the stamps its PhaseTrail already holds): recorded closed, no
+        parent, on no stack. It is in no jax.profiler trace: a TraceMe
+        cannot be opened in the past."""
+        if not self.enabled and self.ring is None:
+            if name.startswith(SpanLog.PREFIX):
+                self.log.append((name, int(ts_ns), int(dur_ns),
+                                 _new_span_id(), None, attrs))
+            return
+        rec = {"name": name, "type": event_type,
+               "tid": threading.get_ident(), "ts": int(ts_ns),
+               "dur": int(dur_ns), "depth": 0,
+               "span_id": _new_span_id(), "parent": None,
+               "trace": _current_trace_id()}
+        if not self.enabled:
+            rec["_fr_only"] = True
+        if attrs is not None:
+            rec["attrs"] = attrs
+        self._closed(rec)
+
     def cancel(self, rec):
         """Abandon an open span without recording it (e.g. the DataLoader
         span opened around a `next` that raised StopIteration)."""
-        if rec is None:
-            return
-        st = self._stack()
-        if st and st[-1] is rec:
-            st.pop()
-        elif rec in st:
-            st.remove(rec)
-        if not st:
-            self._stacks.pop(threading.get_ident(), None)
+        if rec is not None:
+            self._pop(rec)
 
     def note(self, key, value):
         """Attach a key to the innermost open span on this thread (used by
         apply_op to mark the eager-cache outcome from inside the dispatch)."""
         st = self._stack()
-        if st:
-            st[-1].setdefault("attrs", {})[key] = value
+        if not st:
+            return
+        rec = st[-1]
+        if rec.__class__ is list:
+            if rec[5] is None:
+                rec[5] = {}
+            rec[5][key] = value
+        else:
+            rec.setdefault("attrs", {})[key] = value
 
     def mark(self):
         with self._lock:
@@ -222,6 +305,15 @@ class _HostTracer:
         return ev
 
 
+def _span_id(rec):
+    """The id of an open span of either kind (see _HostTracer)."""
+    return rec[3] if rec.__class__ is list else rec["span_id"]
+
+
+def _attrs(rec):
+    return rec[5] if rec.__class__ is list else rec.get("attrs")
+
+
 _tracer = _HostTracer()
 
 
@@ -237,10 +329,27 @@ class TracerEventType:
     UserDefined = "UserDefined"
 
 
+def _trace_metadata(attrs):
+    """The attrs a TraceMe can carry: plain numbers, strings, bools."""
+    return {k: v for k, v in attrs.items()
+            if isinstance(v, (bool, int, float, str))}
+
+
 class RecordEvent:
     """User-code span (reference: platform/profiler/event_tracing.h:49;
-    python surface profiler/utils.py RecordEvent). Also forwards to
-    jax.profiler.TraceAnnotation so spans show up inside XPlane captures."""
+    python surface profiler/utils.py RecordEvent).
+
+    Every span that is recorded at all (profiler window, flight
+    recorder, or the serve path's span log) is also a
+    jax.profiler.TraceAnnotation, so ANY jax.profiler session — this
+    package's Profiler, a benchmark's start_trace, an operator's
+    start_server — holds it on /host:CPU on the clock of the device
+    planes. With no session open it costs one flag check
+    (`TraceAnnotation.is_enabled()`). The attrs are attached when the
+    span closes (set_metadata), so counts taken at the end of the span
+    are in the trace too."""
+
+    __slots__ = ("name", "event_type", "attrs", "_rec", "_ann")
 
     def __init__(self, name, event_type=TracerEventType.PythonOp, attrs=None):
         self.name = name
@@ -249,26 +358,58 @@ class RecordEvent:
         self._rec = None
         self._ann = None
 
-    def begin(self):
-        self._rec = _tracer.begin(self.name, self.event_type, self.attrs)
-        if self._rec is not None and _tracer.enabled:
+    def __enter__(self):
+        rec = self._rec = _tracer.begin(self.name, self.event_type,
+                                        self.attrs)
+        if rec is not None and jax.profiler.TraceAnnotation.is_enabled():
             self._ann = jax.profiler.TraceAnnotation(self.name)
             self._ann.__enter__()
+        return self
 
-    def end(self):
+    def __exit__(self, *exc):
         if self._ann is not None:
+            attrs = _attrs(self._rec)
+            if attrs:
+                self._ann.set_metadata(**_trace_metadata(attrs))
             self._ann.__exit__(None, None, None)
             self._ann = None
         _tracer.end(self._rec)
         self._rec = None
-
-    def __enter__(self):
-        self.begin()
-        return self
-
-    def __exit__(self, *exc):
-        self.end()
         return False
+
+    begin = __enter__
+    end = __exit__
+
+
+def span_log():
+    """The process's log of closed `serving::*` spans
+    (flight_recorder.SpanLog): on by default, bounded, counts what it
+    drops. `span_log().window(start_ns, end_ns)` on the
+    time.perf_counter_ns clock reads a window, None on overflow."""
+    return _tracer.log
+
+
+def record_span(name, ts_ns, dur_ns, attrs=None,
+                event_type=TracerEventType.UserDefined):
+    """Record a span timed elsewhere (see _HostTracer.record)."""
+    _tracer.record(name, event_type, ts_ns, dur_ns, attrs)
+
+
+@contextlib.contextmanager
+def span_attrs(**attrs):
+    """Every span opened on this thread inside the scope also carries
+    `attrs` — how the scheduler names the request an engine call works
+    for without the engine's signature knowing about requests."""
+    tid = threading.get_ident()
+    outer = _tracer._inherit.get(tid)
+    _tracer._inherit[tid] = {**outer, **attrs} if outer else attrs
+    try:
+        yield
+    finally:
+        if outer is None:
+            _tracer._inherit.pop(tid, None)
+        else:
+            _tracer._inherit[tid] = outer
 
 
 # ------------------------------------------------------------- trace handlers

@@ -31,7 +31,9 @@ harmlessly and the gather for masked positions reads it invisibly.
 """
 import collections
 import contextlib
+import functools
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -44,6 +46,7 @@ __all__ = ["BlockAllocError", "BlockPool", "PagedLayerKV",
            "alloc_quant_pools", "write", "quant_write", "gather",
            "gather_quant", "dequant", "attend", "attend_quant",
            "attend_kernel", "attend_kernel_quant", "attention_impl",
+           "attention_scope",
            "current_attention_impl", "blocks_for_tokens", "GARBAGE_BLOCK",
            "QMAX"]
 
@@ -256,6 +259,36 @@ def gather_quant(pool, scales, tables):
     return f.reshape(S, nb * pool.shape[1], pool.shape[2], pool.shape[3])
 
 
+# The name the attention of an executable goes by in a trace: the engine's
+# decode and prefill functions trace inside `attention_scope("decode_attn")`
+# / `("prefill_attn")`, and whichever of the four attend arms implements it
+# opens that jax.named_scope. So the gather arm and the kernel arm are the
+# SAME named work to a trace reader (XProf's name stack today). Trace-time
+# only; outside an engine executable there is no scope.
+_ATTEND_SCOPE = None
+
+
+@contextlib.contextmanager
+def attention_scope(name):
+    global _ATTEND_SCOPE
+    prev, _ATTEND_SCOPE = _ATTEND_SCOPE, name
+    try:
+        yield
+    finally:
+        _ATTEND_SCOPE = prev
+
+
+def _scoped(fn):
+    @functools.wraps(fn)
+    def wrapper(*args, **kwargs):
+        if _ATTEND_SCOPE is None:
+            return fn(*args, **kwargs)
+        with jax.named_scope(_ATTEND_SCOPE):
+            return fn(*args, **kwargs)
+    return wrapper
+
+
+@_scoped
 def attend(q, k_pool, v_pool, tables, pos, scale=None):
     """Block-table attention: gather the slot's blocks into the dense
     layout, then run the exact dense masked attention (`kv_cache.attend`)
@@ -266,6 +299,7 @@ def attend(q, k_pool, v_pool, tables, pos, scale=None):
                       pos, scale)
 
 
+@_scoped
 def attend_quant(q, k_pool, v_pool, k_scale, v_scale, tables, pos,
                  scale=None):
     """Quantized block-table attention, gather reference: dequantize the
@@ -276,6 +310,7 @@ def attend_quant(q, k_pool, v_pool, k_scale, v_scale, tables, pos,
                       gather_quant(v_pool, v_scale, tables), pos, scale)
 
 
+@_scoped
 def attend_kernel(q, k_pool, v_pool, tables, pos, scale=None):
     """Block-table attention via the Pallas paged-attention kernel: the
     block table is walked IN-kernel (scalar-prefetch index maps), so the
@@ -288,6 +323,7 @@ def attend_kernel(q, k_pool, v_pool, tables, pos, scale=None):
     return paged_attention(q, k_pool, v_pool, tables, pos, scale=scale)
 
 
+@_scoped
 def attend_kernel_quant(q, k_pool, v_pool, k_scale, v_scale, tables, pos,
                         scale=None):
     """Quantized block-table attention, in-kernel dequant: the scale

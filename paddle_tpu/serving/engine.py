@@ -64,6 +64,11 @@ def _quantize_weight(w, axis):
 __all__ = ["EngineConfig", "GenerationEngine", "PagedEngineConfig",
            "PagedGenerationEngine", "save_for_generation", "make_engine"]
 
+
+def _span(name, attrs=None):
+    """A `serving::*` span (the scheduler's phases use it too)."""
+    return RecordEvent(name, TracerEventType.UserDefined, attrs)
+
 DEFAULT_BUCKETS = (32, 64, 128, 256, 512, 1024)
 GENCFG_SUFFIX = ".gencfg"
 
@@ -705,26 +710,30 @@ class GenerationEngine:
         with RecordEvent("serving::decode_step",
                          TracerEventType.UserDefined,
                          {"slots": self.config.slots}):
-            tokens = self._last_tokens
-            # decode consumes _decode_params (identity == _params here;
-            # the paged engine's weight-quant hook makes them differ) so
-            # the hook's contract holds on every engine
-            args = (
-                self._decode_params, [l.k for l in self._cache.layers],
-                [l.v for l in self._cache.layers], self._cache.pos,
-                jnp.asarray(tokens), self._next_key(),
-                *self._adapter_args(), *self._rng_args())
+            with _span("serving::decode.upload"):
+                tokens = self._last_tokens
+                # decode consumes _decode_params (identity == _params
+                # here; the paged engine's weight-quant hook makes them
+                # differ) so the hook's contract holds on every engine
+                args = (
+                    self._decode_params,
+                    [l.k for l in self._cache.layers],
+                    [l.v for l in self._cache.layers], self._cache.pos,
+                    jnp.asarray(tokens), self._next_key(),
+                    *self._adapter_args(), *self._rng_args())
             if self._numerics_armed:
                 self._last_decode_args = args    # the localizer's replay
-            out = self._decode(*args)
+            with _span("serving::decode.dispatch"):
+                res = self._decode(*args)
+            with _span("serving::decode.wait"):
+                out = np.asarray(res[0], np.int32)
         if self._numerics_armed:
-            nxt, gk, gv, pos, sink = out
+            nxt, gk, gv, pos, sink = res
             self._ingest_numerics(sink)
         else:
-            nxt, gk, gv, pos = out
+            nxt, gk, gv, pos = res
         self._set_cache(gk, gv, pos)
         self._slot_gen += 1
-        out = np.asarray(nxt, np.int32)
         self._last_tokens = out.copy()
         return out
 
@@ -1398,9 +1407,10 @@ class PagedGenerationEngine(GenerationEngine):
                 _numerics.tap_tree("weights.q",
                                    [w["q"] for w in quant],
                                    sat_threshold=127)
-            logits, npool = self._run_model_paged(
-                self._dequant_params(params), pool, tables, pos,
-                tokens[:, None], adapters=adapters)
+            with blocks.attention_scope("decode_attn"):
+                logits, npool = self._run_model_paged(
+                    self._dequant_params(params), pool, tables, pos,
+                    tokens[:, None], adapters=adapters)
             nxt = self._select_slots(logits[:, 0, :], key, *rng)
             _numerics.tap("decode.logits", logits[:, 0, :])
             if adapters is not None:
@@ -1428,9 +1438,10 @@ class PagedGenerationEngine(GenerationEngine):
             # blocks; `start` = tokens already resident (prefix hit)
             row = jax.lax.dynamic_slice(tables, (slot, 0), (1, nb))
             with self._numerics_scope() as sink:
-                logits, npool = self._run_model_paged(
-                    params, pool, row, start[None], ids[None, :],
-                    valid=length[None])
+                with blocks.attention_scope("prefill_attn"):
+                    logits, npool = self._run_model_paged(
+                        params, pool, row, start[None], ids[None, :],
+                        valid=length[None])
                 pos = jax.lax.dynamic_update_slice(
                     pos, (start + length)[None].astype(pos.dtype), (slot,))
                 last = jax.lax.dynamic_index_in_dim(logits[0], length - 1,
@@ -1560,32 +1571,38 @@ class PagedGenerationEngine(GenerationEngine):
         self.ensure_decode_capacity()
         with RecordEvent("serving::decode_step",
                          TracerEventType.UserDefined,
-                         {"slots": self.config.slots, "paged": True,
+                         {"slots": self.config.slots,
+                          "active": int(self._slot_active.sum()),
+                          "paged": True,
                           "kv_dtype": self.config.kv_dtype,
                           "attend": self.config.attention_impl}), \
                 blocks.attention_impl(self.config.attention_impl):
-            tokens = self._last_tokens
-            args = (
-                self._decode_params, self._pool, jnp.asarray(self._tables),
-                jnp.asarray(self._pos), jnp.asarray(tokens),
-                self._next_key(), *self._adapter_args(),
-                *self._rng_args())
+            # the three host phases of a decode step, each a child span:
+            # tables, positions, tokens and keys go up; the executable is
+            # enqueued; the host blocks until positions and tokens are
+            # back (the device's time and the copy)
+            with _span("serving::decode.upload"):
+                tokens = self._last_tokens
+                args = (
+                    self._decode_params, self._pool,
+                    jnp.asarray(self._tables), jnp.asarray(self._pos),
+                    jnp.asarray(tokens), self._next_key(),
+                    *self._adapter_args(), *self._rng_args())
             if self._numerics_armed:
                 self._last_decode_args = args    # the localizer's replay
-            res = self._decode(*args)
+            with _span("serving::decode.dispatch"):
+                res = self._decode(*args)
+            with _span("serving::decode.wait"):
+                self._pos = np.array(res[2], np.int32)   # owned, writable
+                out = np.asarray(res[0], np.int32)
         if self._numerics_armed:
             sink = res[-1]
             res = res[:-1]
             self._ingest_numerics(sink)
         if self.config.capture_logits:
-            nxt, pool, pos, logits = res
-            self.last_logits = np.asarray(logits, np.float32)
-        else:
-            nxt, pool, pos = res
-        self._pool = pool
-        self._pos = np.array(pos, np.int32)   # owned, writable copy
+            self.last_logits = np.asarray(res[3], np.float32)
+        self._pool = res[1]
         self._slot_gen += 1
-        out = np.asarray(nxt, np.int32)
         self._last_tokens = out.copy()
         return out
 

@@ -75,9 +75,10 @@ from ..observability import kvledger as _kvl
 from ..observability import metrics as _metrics
 from ..observability import reqtimeline as _rt
 from ..observability import tracecontext as _tc
-from ..profiler import RecordEvent, TracerEventType
+from ..profiler import (RecordEvent, TracerEventType, record_span,
+                        span_attrs)
 from .blocks import BlockAllocError
-from .engine import _engine_kind
+from .engine import _engine_kind, _span
 
 __all__ = ["ServingConfig", "Scheduler", "Request", "RequestHandle",
            "QueueFullError", "LoadShedError", "RateLimitedError",
@@ -439,6 +440,8 @@ class Scheduler:
         except Exception:                                # noqa: BLE001
             self._engine_kind = "unknown"
         self._clock = clock
+        self._clock_is_span_clock = clock in (time.monotonic,
+                                              time.perf_counter)
         self._queue = collections.deque()
         self._slots = [None] * engine.slots   # Request or None
         self._quarantined = set()             # slots held out after a failure
@@ -447,10 +450,10 @@ class Scheduler:
         self._steps = 0
         self._decode_tokens = 0
         self._decode_time_s = 0.0
+        self._placed = 0                      # placements, and the prompt
+        self._placed_tokens = 0               # tokens they brought
         self._spec_proposed = 0
         self._spec_accepted = 0
-        self._capture = None                  # armed decode-step capture
-        self.last_capture = None              # finalize() summary block
         self._pending_swaps = collections.deque()   # armed hot-swaps
         self._pending_adapter_swaps = collections.deque()
         self.last_adapter_swap = None
@@ -718,72 +721,6 @@ class Scheduler:
                 "shed_pool_free": c.shed_pool_free}
 
     # -- the iteration loop --------------------------------------------------
-    def capture_decode_steps(self, steps=1, out_dir="./serving_xplane"):
-        """Arm a one-shot device-profile capture (observability.deviceprof)
-        spanning the next `steps` decode steps, fired only in a HEALTHY
-        window: at least one decode step has already succeeded (the
-        executable is compiled and warm — a capture that spans the first
-        step would record compilation, not serving) and no slot is
-        quarantined by a failure. Artifacts land under `out_dir` (raw
-        .xplane.pb + deviceprof.v1 JSONL + join report); the armed/
-        capturing/reported state rides the flight-recorder annotations,
-        so a wedged serving process leaves the capture's fate in its
-        postmortem. Returns the controller; the parsed summary block is
-        on `scheduler.last_capture` after the window closes."""
-        from ..observability import deviceprof
-        ctrl = deviceprof.OneShotCapture(out_dir, label="serving")
-        self._capture = {"ctrl": ctrl, "steps": max(int(steps), 1),
-                         "remaining": max(int(steps), 1), "wall_s": 0.0}
-        return ctrl
-
-    def _capture_healthy(self):
-        return (self._decode_time_s > 0.0 and not self._quarantined
-                and self._decode_failures == 0)
-
-    def _capture_step_done(self, dt):
-        """One successful decode step closed while a capture is in
-        flight: count it, and close + report the window when the last
-        captured step retires."""
-        cap = self._capture
-        if cap is None or not cap["ctrl"].state == "capturing":
-            return
-        cap["wall_s"] += dt
-        cap["remaining"] -= 1
-        if cap["remaining"] > 0:
-            return
-        ctrl = cap["ctrl"]
-        ctrl.stop()
-        done = cap["steps"] - cap["remaining"]
-        self.last_capture = ctrl.finalize(
-            steps=max(done, 1),
-            wall_step_ms=1000.0 * cap["wall_s"] / max(done, 1))
-        self._capture = None
-
-    def _capture_abort(self, why):
-        """A decode failure while a capture is pending: the capture's
-        fate must never be silent. Mid-window, close it and report the
-        artifacts marked `aborted_by` (gauges are NOT exported — the
-        window is known-sick, --compare must not gate against it).
-        Still-armed, mark the controller failed so the flight-recorder
-        annotation and `last_capture` both carry the reason."""
-        cap = self._capture
-        if cap is None:
-            return
-        ctrl = cap["ctrl"]
-        if ctrl.state == "capturing":
-            ctrl.stop()
-            done = max(cap["steps"] - cap["remaining"], 1)
-            self.last_capture = ctrl.finalize(
-                steps=done,
-                wall_step_ms=(1000.0 * cap["wall_s"] / done)
-                if cap["wall_s"] else None,
-                aborted_by=why)
-        else:
-            ctrl.abort(why)
-            self.last_capture = {"state": ctrl.state, "error": ctrl.error,
-                                 "aborted_by": why}
-        self._capture = None
-
     # -- zero-downtime weight hot-swap (ISSUE 10) ----------------------------
     def schedule_weight_swap(self, params, version=None):
         """Arm a weight hot-swap: `params` ({name: array}, e.g. a
@@ -907,26 +844,53 @@ class Scheduler:
             swap["event"].set()
 
     def step(self):
-        """One scheduling iteration. Returns True while work remains."""
+        """One scheduling iteration. Returns True while work remains.
+
+        One `serving::step` span covers it, with a child per phase
+        (retire, refill -> prefill, grow, decode_step, emit,
+        bookkeeping); the counts in its attrs are taken as the step
+        ends (docs/observability.md, "What a serving operator gets")."""
+        attrs = {"step": self._steps}
+        preempted = self.counts["serving.preempted"]
+        with _span("serving::step", attrs):
+            more = self._step()
+            attrs["preempted"] = self.counts["serving.preempted"] - preempted
+            self._boundary_counts(attrs)
+        return more
+
+    def _boundary_counts(self, attrs):
+        """What the step leaves behind it: queue, slots, and on a paged
+        engine the pool's blocks against the tokens resident in them."""
+        attrs["queue_depth"] = len(self._queue)
+        attrs["active_slots"] = self.active_slots()
+        attrs["slots"] = len(self._slots)
+        pool = getattr(self.engine, "block_pool", None)
+        if pool is None or not hasattr(self.engine, "slot_positions"):
+            return
+        attrs["kv_blocks_in_use"] = pool.in_use
+        attrs["kv_blocks_total"] = pool.capacity
+        pos = self.engine.slot_positions()
+        attrs["kv_tokens_held"] = sum(
+            int(pos[slot]) for slot, req in enumerate(self._slots)
+            if req is not None)
+
+    def _step(self):
         self.apply_pending_swap()
         self.apply_pending_adapter_swap()
         now = self._clock()
-        self._expire_queued(now)
-        self._retire(now)
-        self._refill(now)
-        self._grow_paged_slots(now)
+        with _span("serving::retire"):
+            self._expire_queued(now)
+            self._retire(now)
+        refill = {}
+        with _span("serving::refill", refill):
+            placed, tokens = self._placed, self._placed_tokens
+            self._refill(now)
+            refill["admitted"] = self._placed - placed
+            refill["prefill_tokens"] = self._placed_tokens - tokens
+        with _span("serving::grow"):
+            self._grow_paged_slots(now)
         active = [r for r in self._slots if r is not None]
         if active:
-            cap = self._capture
-            if cap is not None and cap["ctrl"].armed \
-                    and self._capture_healthy() \
-                    and not cap["ctrl"].start():
-                # the trace could not open (e.g. another capture is
-                # active): report the dead controller instead of leaving
-                # it armed forever
-                self.last_capture = {"state": cap["ctrl"].state,
-                                     "error": cap["ctrl"].error}
-                self._capture = None
             t0 = self._clock()
             # a speculative engine advances each slot by a whole verify
             # window per step; everything else stays a 1-wide window
@@ -938,55 +902,61 @@ class Scheduler:
                     toks = np.asarray(self.engine.decode()).reshape(-1, 1)
                     counts = np.ones((toks.shape[0],), np.int32)
             except Exception as e:                       # noqa: BLE001
-                self._capture_abort(f"decode failure: "
-                                    f"{type(e).__name__}: {str(e)[:120]}")
                 self._on_decode_failure(e)
             else:
                 dt = self._clock() - t0
-                self._capture_step_done(dt)
                 self._decode_time_s += dt
                 _M_DECODE_SECONDS.observe(dt)
-                proposed = toks.shape[1] - 1     # γ for spec, 0 otherwise
-                eos = self.engine.config.eos_token_id
-                for slot, req in enumerate(self._slots):
-                    if req is None:
-                        continue
-                    if proposed:
-                        accepted = int(counts[slot]) - 1
-                        req.spec_proposed += proposed
-                        req.spec_accepted += accepted
-                        self._spec_proposed += proposed
-                        self._spec_accepted += accepted
-                        _M_SPEC_PROPOSED.labels(
-                            engine=self._engine_kind).inc(proposed)
-                        _M_SPEC_ACCEPTED.labels(
-                            engine=self._engine_kind).inc(accepted)
-                    # append the slot's emitted run, truncating where the
-                    # one-token loop would have stopped (eos / max_new) —
-                    # the delivered stream stays bit-identical to it
-                    for j in range(int(counts[slot])):
-                        req.tokens.append(int(toks[slot, j]))
-                        self._decode_tokens += 1
-                        self._count("serving.tokens", req)
-                        if req.finished(eos):
-                            break
+                emitted = {"tokens": 0}
+                with _span("serving::emit", emitted):
+                    before = self._decode_tokens
+                    self._emit(toks, counts)
+                    emitted["tokens"] = self._decode_tokens - before
                 # a healthy step is the reprobe proof: reopen every
                 # quarantined slot for the next refill (and a fresh
                 # hot-swap leaves probation — it did not poison decode)
                 self._quarantined.clear()
                 self._swap_probation = False
-        self._steps += 1
-        _M_QUEUE_DEPTH.set(len(self._queue))
-        _M_OCCUPANCY.set(self.active_slots() / max(self.engine.slots, 1))
-        # the KV ledger watchdog (ISSUE 16): every step boundary, replay-
-        # vs-reality — a leaked block is caught within ONE step of the
-        # damage, and the step's lifecycle events land in the JSONL
-        # ahead of the step record that closed them
-        if self._kv_reconciler is not None:
-            self._kv_reconciler.check()
-            self._write_kvledger_records()
-        self._write_step_record(now, len(active))
+        with _span("serving::bookkeeping"):
+            self._steps += 1
+            _M_QUEUE_DEPTH.set(len(self._queue))
+            _M_OCCUPANCY.set(self.active_slots() / max(self.engine.slots, 1))
+            # the KV ledger watchdog (ISSUE 16): every step boundary,
+            # replay-vs-reality — a leaked block is caught within ONE
+            # step of the damage, and the step's lifecycle events land in
+            # the JSONL ahead of the step record that closed them
+            if self._kv_reconciler is not None:
+                self._kv_reconciler.check()
+                self._write_kvledger_records()
+            self._write_step_record(now, len(active))
         return bool(self._queue or any(s is not None for s in self._slots))
+
+    def _emit(self, toks, counts):
+        """Append each slot's emitted run to its request's stream."""
+        proposed = toks.shape[1] - 1     # γ for spec, 0 otherwise
+        eos = self.engine.config.eos_token_id
+        for slot, req in enumerate(self._slots):
+            if req is None:
+                continue
+            if proposed:
+                accepted = int(counts[slot]) - 1
+                req.spec_proposed += proposed
+                req.spec_accepted += accepted
+                self._spec_proposed += proposed
+                self._spec_accepted += accepted
+                _M_SPEC_PROPOSED.labels(
+                    engine=self._engine_kind).inc(proposed)
+                _M_SPEC_ACCEPTED.labels(
+                    engine=self._engine_kind).inc(accepted)
+            # append the slot's emitted run, truncating where the
+            # one-token loop would have stopped (eos / max_new) —
+            # the delivered stream stays bit-identical to it
+            for j in range(int(counts[slot])):
+                req.tokens.append(int(toks[slot, j]))
+                self._decode_tokens += 1
+                self._count("serving.tokens", req)
+                if req.finished(eos):
+                    break
 
     def active_slots(self):
         """Occupied decode slots right now (the concurrency figure the
@@ -1289,9 +1259,9 @@ class Scheduler:
             finished = req.finished(eos)
             timed_out = req.deadline is not None and now > req.deadline
             if finished or timed_out:
-                with RecordEvent("serving::retire",
+                with RecordEvent("serving::retire.slot",
                                  TracerEventType.UserDefined,
-                                 {"slot": slot, "request": req.id,
+                                 {"slot": slot, "request_id": req.id,
                                   "tenant": req.tenant,
                                   "tokens": len(req.tokens),
                                   "timeout": timed_out}):
@@ -1343,9 +1313,9 @@ class Scheduler:
         staged = req._staged
         if staged is None:
             self._restore_staged_prefix(req)
-            req.trail.begin(_rt.PH_PREFILL, self._clock())
+            self._leave_queue(req, _rt.PH_PREFILL)
             return self._engine_prefill(slot, req)
-        req.trail.begin(_rt.PH_ADOPT, self._clock())
+        self._leave_queue(req, _rt.PH_ADOPT)
         try:
             # a v3 bundle's 5th element is the prefill host's post-first-
             # token (seed, gen). An rng-less (v1/v2) bundle still arms
@@ -1377,6 +1347,26 @@ class Scheduler:
         _M_ADOPTED.inc()
         return first
 
+    def _leave_queue(self, req, phase):
+        """Open `phase` on the request's trail. The first time a request
+        leaves the queue its wait becomes a `serving::queue` span, from
+        the two stamps the trail already holds. Only a scheduler on the
+        system's clock emits it (time.monotonic and the span clock are
+        one clock on Linux): an injected clock's stamps are on another
+        timeline than the log's."""
+        req.trail.begin(phase, self._clock())
+        if not self._clock_is_span_clock:
+            return
+        segments = req.trail.segments
+        # the segment that just closed is the request's FIRST queue
+        # wait: a router may have filled the trail before it (prefill,
+        # kv_handoff, place), a preemption queues the request again
+        if segments and segments[-1][0] == _rt.PH_QUEUE and sum(
+                1 for s in segments if s[0] == _rt.PH_QUEUE) == 1:
+            _, t0, t1 = segments[-1]
+            record_span("serving::queue", t0 * 1e9, (t1 - t0) * 1e9,
+                        {"request_id": req.id})
+
     def _restore_staged_prefix(self, req):
         """Register a fleet-shipped prefix chain (ISSUE 18) into the
         local prefix cache as its own named `kv_restore` timeline phase,
@@ -1389,7 +1379,7 @@ class Scheduler:
         if sp is None:
             return
         req._staged_prefix = None
-        req.trail.begin(_rt.PH_KV_RESTORE, self._clock())
+        self._leave_queue(req, _rt.PH_KV_RESTORE)
         t0 = time.perf_counter()
         try:
             with self._kv_attr(req, "kv_restore"):
@@ -1435,7 +1425,7 @@ class Scheduler:
         kwargs = {}
         if req.prefix_namespace is not None:
             kwargs["namespace"] = req.prefix_namespace
-        with self._kv_attr(req, "prefill"):
+        with self._kv_attr(req, "prefill"), span_attrs(request_id=req.id):
             if not hasattr(self.engine, "set_slot_rng"):
                 return self.engine.prefill(slot, req.exec_prompt,
                                            **kwargs)
@@ -1473,6 +1463,8 @@ class Scheduler:
             return "stop"
         req.slot = slot
         req.status = RUNNING
+        self._placed += 1
+        self._placed_tokens += len(req.exec_prompt)
         if req.first_token_at is None:
             req.first_token_at = self._clock()
         req.trail.begin(_rt.PH_DECODE, self._clock())

@@ -383,90 +383,3 @@ def test_wedged_run_postmortem_records_armed_capture(tmp_path):
     assert note["state"] == "armed", note      # armed, never fired
     assert note["dir"] == os.path.abspath(out_dir)
     assert not os.path.exists(os.path.join(out_dir, "deviceprof.jsonl"))
-
-
-# ------------------------------------- serving capture-N-decode-steps hook
-
-def test_scheduler_capture_decode_steps(tmp_path):
-    from paddle_tpu.serving import GenerationEngine, Scheduler
-    from paddle_tpu.text.models import gpt_tiny
-    tiny = gpt_tiny()
-    tiny.eval()
-    eng = GenerationEngine(tiny, slots=2, max_len=48)
-    sched = Scheduler(eng, max_queue=8)
-    out = str(tmp_path / "serving_xplane")
-    ctrl = sched.capture_decode_steps(steps=2, out_dir=out)
-    rng = np.random.RandomState(0)
-    for i in range(2):
-        sched.submit(rng.randint(0, tiny.cfg.vocab_size, 4 + i),
-                     max_new_tokens=8)
-    # the FIRST active step is warmup (compile), never captured
-    sched.step()
-    assert ctrl.armed
-    sched.run_until_idle()
-    assert ctrl.state == "reported", (ctrl.state, ctrl.error)
-    block = sched.last_capture
-    assert block["state"] == "reported"
-    records = deviceprof.load_records(block["jsonl"])
-    join = records[-1]["join"]
-    assert join["steps"] == 2
-    assert join["device_ms_per_step"] > 0
-    # decode-step wall alignment: the join's wall is the scheduler's own
-    # measured decode wall, and the device side must fit inside it
-    assert join["wall_ms_per_step"] > 0
-    assert join["reconciles"], join
-    fr_note = flight_recorder.get().annotations.get("deviceprof.serving")
-    assert fr_note and fr_note["state"] == "reported"
-
-
-def test_scheduler_capture_abort_is_never_silent(tmp_path, monkeypatch):
-    """A decode failure while a capture is pending: an ARMED capture is
-    marked failed (not left 'armed' forever in the annotations), a
-    MID-WINDOW capture is closed and reported with `aborted_by` — and
-    the sick window's gauges are NOT exported into the registry that
-    --compare gates."""
-    from paddle_tpu.observability import metrics
-    from paddle_tpu.serving import GenerationEngine, Scheduler
-    from paddle_tpu.text.models import gpt_tiny
-    tiny = gpt_tiny()
-    tiny.eval()
-
-    # --- armed, first active step fails before any healthy step
-    eng = GenerationEngine(tiny, slots=1, max_len=32)
-    sched = Scheduler(eng, max_queue=4)
-    ctrl = sched.capture_decode_steps(
-        steps=2, out_dir=str(tmp_path / "armed"))
-    monkeypatch.setattr(eng, "decode",
-                        lambda: (_ for _ in ()).throw(RuntimeError("boom")))
-    sched.submit([1, 2, 3], max_new_tokens=4)
-    sched.step()
-    assert ctrl.state == "failed", ctrl.state
-    assert sched.last_capture["state"] == "failed"
-    assert "boom" in sched.last_capture["aborted_by"]
-    note = flight_recorder.get().annotations["deviceprof.serving"]
-    assert note["state"] == "failed"
-
-    # --- mid-window: one healthy captured step, then a failure
-    eng2 = GenerationEngine(tiny, slots=1, max_len=32)
-    sched2 = Scheduler(eng2, max_queue=4)
-    out2 = str(tmp_path / "midwindow")
-    ctrl2 = sched2.capture_decode_steps(steps=10, out_dir=out2)
-    sched2.submit([4, 5, 6], max_new_tokens=8)
-    sched2.step()                       # warmup (uncaptured)
-    sched2.step()                       # captured step 1 of 10
-    assert ctrl2.state == "capturing"
-    metrics.registry().reset()          # clean slate for the gauge check
-    real_decode = eng2.decode
-    monkeypatch.setattr(eng2, "decode",
-                        lambda: (_ for _ in ()).throw(RuntimeError("sick")))
-    sched2.step()
-    monkeypatch.setattr(eng2, "decode", real_decode)
-    block = sched2.last_capture
-    assert block["state"] == "reported"
-    assert "sick" in block["aborted_by"]
-    rec = deviceprof.load_records(block["jsonl"])[-1]
-    assert "sick" in rec["aborted_by"]  # marker PERSISTED in the record
-    assert rec["join"]["steps"] == 1    # only the captured step counted
-    flat = metrics.flatten_snapshot(metrics.registry().snapshot())
-    assert flat.get("deviceprof_total_device_ms_per_step", 0.0) == 0.0, \
-        "sick-window gauges must not reach the --compare gate"
