@@ -302,10 +302,14 @@ class CompileCache:
         return out
 
     # -- load -------------------------------------------------------------
-    def lookup(self, name, dirname, digest):
+    def lookup(self, name, dirname, digest, donate_argnums=()):
         """A callable runner for the entry, or None (miss). Never raises:
         torn/corrupt/version-skewed/undeserializable entries are deleted
-        and reported as misses."""
+        and reported as misses. `donate_argnums` is the function's own
+        (already in `digest`): a serialized executable carries its input
+        aliasing, an exported lowering does not and is re-jitted with
+        it, so no tier hands back a runner that copies what the compile
+        run updated in place."""
         full = self._entry_dir(dirname)
         if not os.path.isdir(full):
             self._miss()
@@ -326,7 +330,7 @@ class CompileCache:
         try:
             meta = self._read_meta(full, digest)
             t0 = time.perf_counter()
-            runner = self._load_runner(full, meta)
+            runner = self._load_runner(full, meta, donate_argnums)
             _M_LOAD_S.labels(executable=name).observe(
                 time.perf_counter() - t0)
         except Exception as e:                               # noqa: BLE001
@@ -367,7 +371,7 @@ class CompileCache:
             raise ValueError("framework source fingerprint skew")
         return meta
 
-    def _load_runner(self, full, meta):
+    def _load_runner(self, full, meta, donate_argnums=()):
         if meta["format"] == "executable":
             from jax.experimental import serialize_executable as _se
             import jax
@@ -386,8 +390,9 @@ class CompileCache:
             with open(os.path.join(full, EXPORT_FILE), "rb") as f:
                 exported = _jexport.deserialize(f.read())
             # compile-at-load tier: the python trace is skipped, the XLA
-            # compile happens on the first call of this jit
-            return jax.jit(exported.call)
+            # compile happens on the first call of this jit. jax.export
+            # keeps no donation, so the entry's own is put back here
+            return jax.jit(exported.call, donate_argnums=donate_argnums)
         raise ValueError(f"unknown entry format {meta['format']!r}")
 
     def _miss(self):
@@ -622,7 +627,7 @@ class CachedFunction:
             parts = ("signature", self.name, repr(self._static_sig),
                      sig, self._donate)
         dirname, digest = cache.entry_key(self.name, parts)
-        runner = cache.lookup(self.name, dirname, digest)
+        runner = cache.lookup(self.name, dirname, digest, self._donate)
         if runner is None:
             if lowered is None:
                 lowered = self._jit.lower(*args)

@@ -38,6 +38,7 @@ from ..observability import flight_recorder as _flight_recorder
 from ..observability import kvledger as _kvl
 from ..observability import numerics as _numerics
 from ..profiler import RecordEvent, TracerEventType
+from ..profiler import _tracer as _TRACER
 from . import blocks
 from . import kv_cache as kvc
 from . import sampling
@@ -191,9 +192,12 @@ class GenerationEngine:
         # numerics health plane (ISSUE 19): armed at build time like
         # capture_logits. The monitor classifies every step's sink;
         # `_last_decode_args` keeps the last step's inputs alive for the
-        # bisection localizer (serving executables never donate their
-        # inputs, so the refs are free); the probe flags route localizer
-        # re-traces of `_decode_fn` away from the 'decode' counter.
+        # bisection localizer to replay, so an ARMED engine's decode
+        # donates nothing (`_decode_donate`): it already compiles
+        # another program, the sink rides last, and pays a second pool
+        # for the replay. Every other paged engine's decode takes its
+        # pool in place. The probe flags route localizer re-traces of
+        # `_decode_fn` away from the 'decode' counter.
         self.numerics_monitor = _numerics.NumericsMonitor(
             auto_bundle=False) if self._numerics_armed else None
         self.last_numerics = None
@@ -205,18 +209,26 @@ class GenerationEngine:
             if self.config.compile_cache_dir else None
         self._alloc_state()                    # cache layout hook
         self._build_decode_params()            # weight-quant hook
-        self._decode = self._cached(self._decode_fn, "decode")
+        self._decode = self._cached(self._decode_fn, "decode",
+                                    self._decode_donate)
         self._prefill = {}   # bucket -> cached-jitted fn
 
-    def _cached(self, fn, name):
+    # the arguments of `_decode_fn` its executable updates in place: none
+    # for the dense per-slot buffers; the paged engine names its pool
+    _decode_donate = ()
+
+    def _cached(self, fn, name, donate_argnums=()):
         """cached_jit over the engine's persistent tier (engine-private
         cache first, process-global cache second, plain jit when
         neither). The static signature pins model + engine config, so
-        avals alone can never alias two different programs."""
+        avals alone can never alias two different programs.
+        `donate_argnums` is part of the entry key and survives every
+        cache tier (compile_cache.CompileCache.lookup)."""
         return _cc.cached_jit(
             fn, f"serving.{name}",
             static_sig=self._compile_signature(),
-            cache=lambda: self.compile_cache)
+            cache=lambda: self.compile_cache,
+            donate_argnums=donate_argnums)
 
     def _compile_signature(self):
         """Model config + engine config, the signature-mode key half
@@ -995,7 +1007,16 @@ class PagedGenerationEngine(GenerationEngine):
     decode executable's avals (pools, tables, pos, tokens) never change,
     so it still compiles exactly once; prefill compiles per SUFFIX
     bucket — a prefix-cache hit shortens the suffix, it never adds an
-    executable."""
+    executable.
+
+    The pool is donated to every executable that takes it and returns
+    the new one (decode, prefill[bucket], adopt[bucket], the tier
+    restore): the scatter of the step's K/V lands in the buffers that
+    came in, not in a copy of all of them. So `self._pool` is rebound
+    to the result right after each dispatch, and nothing may keep a
+    pool tuple across a call: the one that went in is deleted. Each
+    step's span says whether it engaged (`pool_donated`,
+    docs/serving.md)."""
 
     def __init__(self, model, config=None, **kwargs):
         config = config or PagedEngineConfig(**kwargs)
@@ -1005,6 +1026,22 @@ class PagedGenerationEngine(GenerationEngine):
         # every other executable
         self.trace_counts["adopt"] = {}
         self._adopt = {}
+
+    @property
+    def _decode_donate(self):
+        """Decode takes its pool (argument 1 of `_decode_fn`) in place,
+        unless numerics taps are armed: the localizer replays
+        `_last_decode_args`, which must then outlive the call."""
+        return () if self._numerics_armed else (1,)
+
+    @staticmethod
+    def _pool_donated(pool):
+        """0/1 for a span's `pool_donated`, read right after a dispatch
+        that was handed `pool`: 1 when the executable consumed it. 0 on
+        an engine that should donate means an executable came back from
+        some cache tier without its input aliasing, and copies the whole
+        pool every call."""
+        return int(pool[0].k.is_deleted())
 
     def _constrain_pools(self, pool):
         """Trace-time sharding hook on every new-pool output (decode,
@@ -1086,8 +1123,10 @@ class PagedGenerationEngine(GenerationEngine):
                 self.kv_tiers.attach_ledger(self.kv_ledger)
             self.prefix_cache.attach_tier(self.kv_tiers)
             # the ONE compiled restore scatter (fixed lane count —
-            # GARBAGE_BLOCK pads short runs); audited next to decode
-            self._tier_writer = jax.jit(self._tier_writer_fn)
+            # GARBAGE_BLOCK pads short runs); audited next to decode,
+            # and like decode it takes the pool in place
+            self._tier_writer = jax.jit(self._tier_writer_fn,
+                                        donate_argnums=(0,))
             self.trace_counts["tier_restore"] = 0
         self.last_prefill_stats = {}
         self.last_logits = None
@@ -1452,7 +1491,8 @@ class PagedGenerationEngine(GenerationEngine):
             if sink is None:
                 return first_token, npool, pos
             return first_token, npool, pos, sink
-        return self._cached(prefill_fn, f"prefill[{bucket}]")
+        return self._cached(prefill_fn, f"prefill[{bucket}]",
+                            donate_argnums=(1,))
 
     # -- public compute API --------------------------------------------------
     def prefill(self, slot, prompt_ids, rng=None, namespace=None):
@@ -1546,17 +1586,17 @@ class PagedGenerationEngine(GenerationEngine):
         stages in chunks instead. Returns the first token (host int)."""
         if bucket not in self._prefill:
             self._prefill[bucket] = self._make_prefill(bucket)
+        pool_in = self._pool
         out = self._prefill[bucket](
-            self._params, self._pool, jnp.asarray(self._tables),
+            self._params, pool_in, jnp.asarray(self._tables),
             jnp.asarray(self._pos), jnp.asarray(slot, jnp.int32),
             jnp.asarray(padded), jnp.asarray(length, jnp.int32),
             jnp.asarray(start, jnp.int32), self._slot_key(slot))
+        # the pool that went in is gone: rebind before anything can raise
+        first, self._pool, pos = out[:3]
+        _TRACER.note("pool_donated", self._pool_donated(pool_in))
         if self._numerics_armed:
-            first, pool, pos, sink = out
-            self._ingest_numerics(sink)
-        else:
-            first, pool, pos = out
-        self._pool = pool
+            self._ingest_numerics(out[3])
         self._pos = np.array(pos, np.int32)   # owned, writable copy
         return int(first)
 
@@ -1588,20 +1628,27 @@ class PagedGenerationEngine(GenerationEngine):
                     jnp.asarray(self._tables), jnp.asarray(self._pos),
                     jnp.asarray(tokens), self._next_key(),
                     *self._adapter_args(), *self._rng_args())
-            if self._numerics_armed:
-                self._last_decode_args = args    # the localizer's replay
             with _span("serving::decode.dispatch"):
                 res = self._decode(*args)
-            with _span("serving::decode.wait"):
-                self._pos = np.array(res[2], np.int32)   # owned, writable
+            # the pool that went in is gone: rebind before the fetches,
+            # so one that raises leaves the engine on live buffers
+            self._pool = res[1]
+            if self._numerics_armed:
+                self._last_decode_args = args    # the localizer's replay
+            with _span("serving::decode.wait",
+                       {"pool_donated": self._pool_donated(args[1])}):
+                pos = np.array(res[2], np.int32)         # owned, writable
                 out = np.asarray(res[0], np.int32)
+        # positions advance only once the tokens are on the host too: a
+        # step whose fetch failed is run again at the same positions and
+        # writes the same K/V
+        self._pos = pos
         if self._numerics_armed:
             sink = res[-1]
             res = res[:-1]
             self._ingest_numerics(sink)
         if self.config.capture_logits:
             self.last_logits = np.asarray(res[3], np.float32)
-        self._pool = res[1]
         self._slot_gen += 1
         self._last_tokens = out.copy()
         return out
@@ -1779,9 +1826,11 @@ class PagedGenerationEngine(GenerationEngine):
         into that stage's own resident pool."""
         if bucket not in self._adopt:
             self._adopt[bucket] = self._make_adopt(bucket)
+        pool_in = self._pool
         self._pool = self._adopt[bucket](
-            self._pool, jnp.asarray(self._tables),
+            pool_in, jnp.asarray(self._tables),
             jnp.asarray(slot, jnp.int32), pad_ks, pad_vs)
+        _TRACER.note("pool_donated", self._pool_donated(pool_in))
 
     def _make_adopt(self, bucket):
         """One fixed-shape KV-adopt executable per bucket: scatter the
@@ -1812,7 +1861,8 @@ class PagedGenerationEngine(GenerationEngine):
                         blocks.write(layer.k, k[None], row, zero),
                         blocks.write(layer.v, v[None], row, zero)))
             return self._constrain_pools(tuple(npool))
-        return self._cached(adopt_fn, f"adopt[{bucket}]")
+        return self._cached(adopt_fn, f"adopt[{bucket}]",
+                            donate_argnums=(0,))
 
     # -- fleet-global prefix cache halves (ISSUE 18) -------------------------
     def prefix_probe(self, prompt_ids, namespace=None):
