@@ -42,7 +42,9 @@ from ..observability import metrics as _metrics
 from . import kv_cache as kvc
 
 __all__ = ["BlockAllocError", "BlockPool", "PagedLayerKV",
-           "QuantPagedLayerKV", "PagedDecodeCache", "alloc_pools",
+           "QuantPagedLayerKV", "PagedDecodeCache", "LatentSpec",
+           "StateSpec", "LatentLayer", "StateLayer", "SlotStateStore",
+           "alloc_layers", "gather_rows", "alloc_pools",
            "alloc_quant_pools", "write", "quant_write", "gather",
            "gather_quant", "dequant", "attend", "attend_quant",
            "attend_kernel", "attend_kernel_quant", "attention_impl",
@@ -93,10 +95,28 @@ QuantPagedLayerKV = collections.namedtuple(
 # pool must not let the padding tokens' K/V inflate the tail block's
 # abs-max scale (the float path never cared — padding is position-masked
 # out of attention either way). None means all T tokens are real (decode,
-# verify, the float path).
+# verify, the float path). `slot` (int32 scalar or None) is the slot a
+# prefill fills: a layer whose cache is per slot and not paged (StateLayer)
+# writes that row; None means every slot advances (decode).
 PagedDecodeCache = collections.namedtuple(
-    "PagedDecodeCache", ["layers", "tables", "pos", "valid"],
-    defaults=(None,))
+    "PagedDecodeCache", ["layers", "tables", "pos", "valid", "slot"],
+    defaults=(None, None))
+
+# What a layer of a model declares it caches (`model.cache_layout()`, one
+# spec a layer), and the arrays the engine allocates for each. Two kinds
+# live side by side in one pool tuple, under one block table and one
+# allocator, and are donated together:
+#   LatentSpec(width)   one row of `width` values a token, paged like K/V:
+#                       LatentLayer(rows [num_blocks, block_size, width])
+#   StateSpec(state, tail)  a fixed-size state a SLOT, whatever its length
+#                       (a linear-attention layer's matrix and the last
+#                       inputs of its short convolution): StateLayer(
+#                       state [slots, *state] float32, tail [slots, *tail])
+# A model without `cache_layout` (GPT) caches K and V per layer, as above.
+LatentSpec = collections.namedtuple("LatentSpec", ["width"])
+StateSpec = collections.namedtuple("StateSpec", ["state", "tail"])
+LatentLayer = collections.namedtuple("LatentLayer", ["rows"])
+StateLayer = collections.namedtuple("StateLayer", ["state", "tail"])
 
 
 def blocks_for_tokens(n_tokens, block_size):
@@ -111,6 +131,35 @@ def alloc_pools(num_layers, num_blocks, block_size, num_heads, head_dim,
     return tuple(PagedLayerKV(jnp.zeros(shape, dtype),
                               jnp.zeros(shape, dtype))
                  for _ in range(num_layers))
+
+
+def alloc_layers(layout, num_blocks, block_size, slots, dtype):
+    """Zeroed cache arrays for a model that declares its layers' caches:
+    one LatentLayer or StateLayer per spec of `layout`. Latent rows and
+    convolution tails take `dtype`; the recurrent state is float32."""
+    out = []
+    for spec in layout:
+        if isinstance(spec, LatentSpec):
+            out.append(LatentLayer(jnp.zeros(
+                (num_blocks, block_size, spec.width), dtype)))
+        elif isinstance(spec, StateSpec):
+            out.append(StateLayer(
+                jnp.zeros((slots,) + tuple(spec.state), jnp.float32),
+                jnp.zeros((slots,) + tuple(spec.tail), dtype)))
+        else:
+            raise TypeError(f"unknown cache spec {spec!r}")
+    return tuple(out)
+
+
+def layout_bytes(layout, block_size, dtype):
+    """(bytes one pool block pins over the latent layers, bytes one slot's
+    state pins over the state layers)."""
+    item = np.dtype(dtype).itemsize
+    block = sum(block_size * s.width * item for s in layout
+                if isinstance(s, LatentSpec))
+    slot = sum(4 * int(np.prod(s.state)) + item * int(np.prod(s.tail))
+               for s in layout if isinstance(s, StateSpec))
+    return block, slot
 
 
 def alloc_quant_pools(num_layers, num_blocks, block_size, num_heads,
@@ -249,6 +298,14 @@ def gather(pool, tables):
     return g.reshape(S, nb * pool.shape[1], pool.shape[2], pool.shape[3])
 
 
+def gather_rows(pool, tables):
+    """`gather` for a latent pool [N, block_size, width]: each slot's
+    contiguous [S, max_blocks*block_size, width] rows."""
+    S, nb = tables.shape
+    g = pool[tables.astype(jnp.int32)]        # [S, nb, bs, w]
+    return g.reshape(S, nb * pool.shape[1], pool.shape[2])
+
+
 def gather_quant(pool, scales, tables):
     """Quantized `gather`: rebuild each slot's contiguous dense f32 view
     from an int8 pool + its scale array — the dequantizing reference the
@@ -363,6 +420,32 @@ def attention_impl(impl):
         yield
     finally:
         _ATTEND_IMPL = prev
+
+
+class SlotStateStore:
+    """Host-side account of the per-slot state rows (StateLayer): which
+    slots hold a request's state, and the bytes that pins. The rows need no
+    allocator (slot `s` owns row `s` of every state layer) and no device
+    work on release: a prefill starts from a zero state and overwrites the
+    row, so what a finished request left there is never read."""
+
+    def __init__(self, slots, bytes_per_slot):
+        self.bytes_per_slot = int(bytes_per_slot)
+        self._held = np.zeros((int(slots),), bool)
+
+    def acquire(self, slot):
+        self._held[int(slot)] = True
+
+    def release(self, slot):
+        self._held[int(slot)] = False
+
+    @property
+    def in_use(self):
+        return int(self._held.sum())
+
+    @property
+    def bytes_in_use(self):
+        return self.in_use * self.bytes_per_slot
 
 
 class BlockPool:

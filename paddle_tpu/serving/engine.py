@@ -153,8 +153,17 @@ class GenerationEngine:
         if isinstance(model, GPTForGeneration):
             model = model.gpt
         if not isinstance(model, GPT):
-            raise TypeError("GenerationEngine serves GPT-family models; got "
-                            f"{type(model).__name__}")
+            # a model that says what each of its layers caches
+            # (`cache_layout`, docs/serving.md) is served by the paged
+            # engine itself and by none of its relatives
+            if not hasattr(model, "cache_layout"):
+                raise TypeError("GenerationEngine serves GPT-family models "
+                                f"; got {type(model).__name__}")
+            if type(self) is not PagedGenerationEngine:
+                raise TypeError(
+                    f"{type(model).__name__} declares its own cache layout:"
+                    f" PagedGenerationEngine serves it, "
+                    f"{type(self).__name__} does not (yet)")
         self.config = config or EngineConfig(**kwargs)
         if self.config.max_len > model.cfg.max_position_embeddings:
             raise ValueError(
@@ -542,6 +551,10 @@ class GenerationEngine:
         executables take the bank's stacked arrays + per-slot adapter
         ids as extra runtime inputs (one new trace per executable —
         adapters change the program once, tenants never do)."""
+        if getattr(self, "_layout", None) is not None:   # dense engine: none
+            raise NotImplementedError(
+                f"per-tenant adapters hook GPT's projections; "
+                f"{type(self._model).__name__} has none of them yet")
         self._adapter_bank = bank
         self._refresh_adapters()
 
@@ -962,11 +975,15 @@ class PagedEngineConfig(EngineConfig):
         # with per-output-channel scales (prefill stays float — it is
         # compute-bound and runs once per request; decode is bandwidth-
         # bound and runs per token). Validated here, like attention_impl.
+        # "bfloat16": weights (but the parameters a model pins to float32)
+        # are cast once at construction and serve prefill and decode alike;
+        # the pools (K/V, latent rows, convolution tails) are bfloat16.
+        # "float32" keeps whatever type the model's parameters have.
         for knob, val in (("kv_dtype", kv_dtype),
                           ("weight_dtype", weight_dtype)):
-            if val not in ("float32", "int8"):
-                raise ValueError(f"{knob} must be 'float32' or 'int8', "
-                                 f"got {val!r}")
+            if val not in ("float32", "bfloat16", "int8"):
+                raise ValueError(f"{knob} must be 'float32', 'bfloat16' or "
+                                 f"'int8', got {val!r}")
         self.kv_dtype = kv_dtype
         self.weight_dtype = weight_dtype
         # capture_logits=True makes the decode executable additionally
@@ -1020,6 +1037,14 @@ class PagedGenerationEngine(GenerationEngine):
 
     def __init__(self, model, config=None, **kwargs):
         config = config or PagedEngineConfig(**kwargs)
+        # what each layer caches: None for GPT (K and V per layer), else
+        # the model's own declaration (blocks.LatentSpec / StateSpec);
+        # and the counters its forward returns with the logits
+        self._layout = model.cache_layout() \
+            if hasattr(model, "cache_layout") else None
+        self._counter_names = tuple(getattr(model, "serving_counters", ()))
+        self.last_counters = {}
+        self.state_store = None
         super().__init__(model, config)
         # KV-adopt executables (multi-host handoff sink, ISSUE 10): one
         # per prefill bucket, compiled on first use and counted like
@@ -1041,7 +1066,7 @@ class PagedGenerationEngine(GenerationEngine):
         an engine that should donate means an executable came back from
         some cache tier without its input aliasing, and copies the whole
         pool every call."""
-        return int(pool[0].k.is_deleted())
+        return int(jax.tree_util.tree_leaves(pool)[0].is_deleted())
 
     def _constrain_pools(self, pool):
         """Trace-time sharding hook on every new-pool output (decode,
@@ -1056,19 +1081,67 @@ class PagedGenerationEngine(GenerationEngine):
     def kv_quantized(self):
         return self.config.kv_dtype == "int8"
 
+    def _pool_dtype(self):
+        if self.config.kv_dtype == "bfloat16":
+            return jnp.dtype(jnp.bfloat16)
+        # "float32" reads: as the weights are (the embedding's type)
+        name = "wte.weight" if self._layout is None \
+            else next(iter(self._params))
+        return jnp.dtype(self._params[name].dtype)
+
     def _alloc_state(self):
         cfg = self._model.cfg
         c = self.config
-        if self.kv_quantized:
+        if self._layout is not None:
+            self._check_layout_config()
+        if c.weight_dtype == "bfloat16":
+            keep = self._model.float32_parameters() \
+                if hasattr(self._model, "float32_parameters") else ()
+            self._params = {
+                n: a if n in keep or a.dtype != jnp.float32
+                else a.astype(jnp.bfloat16)
+                for n, a in self._params.items()}
+        if self._layout is not None:
+            self._pool = blocks.alloc_layers(
+                self._layout, c.num_blocks, c.block_size, c.slots,
+                self._pool_dtype())
+            _, slot_bytes = blocks.layout_bytes(
+                self._layout, c.block_size, self._pool_dtype())
+            if slot_bytes:
+                self.state_store = blocks.SlotStateStore(c.slots,
+                                                         slot_bytes)
+        elif self.kv_quantized:
             self._pool = blocks.alloc_quant_pools(
                 cfg.num_layers, c.num_blocks, c.block_size, cfg.num_heads,
                 cfg.hidden_size // cfg.num_heads)
         else:
             self._pool = blocks.alloc_pools(
                 cfg.num_layers, c.num_blocks, c.block_size, cfg.num_heads,
-                cfg.hidden_size // cfg.num_heads,
-                self._params["wte.weight"].dtype)
+                cfg.hidden_size // cfg.num_heads, self._pool_dtype())
         self._alloc_host_state()
+
+    def _check_layout_config(self):
+        """What a model with its own cache layout cannot be combined with
+        yet raises here, at construction, and not in the middle of a
+        request (docs/serving.md lists them)."""
+        c = self.config
+        bad = [f"{k}={getattr(c, k)!r}" for k, ok in (
+            ("kv_dtype", ("float32", "bfloat16")),
+            ("weight_dtype", ("float32", "bfloat16")),
+            ("attention_impl", ("gather",)), ("enable_kv_tiers", (False,)),
+            ("numerics_taps", (False,))) if getattr(c, k) not in ok]
+        if bad:
+            raise ValueError(
+                f"{type(self._model).__name__} declares its own cache "
+                f"layout; the paged engine cannot serve it with "
+                f"{', '.join(bad)}")
+
+    def _require_kv_layout(self, what):
+        if self._layout is not None:
+            raise NotImplementedError(
+                f"{what} moves K/V blocks; {type(self._model).__name__} "
+                f"caches latent rows and per-slot state, which it cannot "
+                f"carry yet")
 
     def _alloc_host_state(self):
         """The mesh-oblivious host half of the paged state: per-slot
@@ -1087,7 +1160,12 @@ class PagedGenerationEngine(GenerationEngine):
         # so mid-decode block growth evicts under the same requester
         self._slot_namespace = {}
         self.block_pool = blocks.BlockPool(c.num_blocks, c.block_size)
-        self.prefix_cache = PrefixCache(self.block_pool, c.block_size) \
+        # block-identity prefix reuse is wrong for a model with per-slot
+        # state (the state at the end of a shared prefix is not in any
+        # block): its cache reports no hit and counts the lookups
+        self.prefix_cache = PrefixCache(
+            self.block_pool, c.block_size,
+            bypass=self.state_store is not None) \
             if c.enable_prefix_cache else None
         # KV attribution ledger (observability.kvledger): because every
         # engine kind — paged, spec, tp, pp, spec_pp — funnels through
@@ -1096,10 +1174,11 @@ class PagedGenerationEngine(GenerationEngine):
         # allocator via the `_pool` property's whole-model view).
         # Construction-time opt-out is the zero-cost contract: disabled,
         # the pool/cache pay one `is None` check per operation.
+        self.kv_block_bytes = self._kv_block_bytes()
         self.kv_ledger = None
         if _kvl.enabled():
             self.kv_ledger = _kvl.KVLedger(
-                c.num_blocks, block_bytes=self._kv_block_bytes())
+                c.num_blocks, block_bytes=self.kv_block_bytes)
             self.block_pool.attach_ledger(self.kv_ledger)
             if self.prefix_cache is not None:
                 self.prefix_cache.attach_ledger(self.kv_ledger)
@@ -1139,6 +1218,9 @@ class PagedGenerationEngine(GenerationEngine):
         4-byte-per-head scale row next to the codes."""
         cfg = self._model.cfg
         c = self.config
+        if self._layout is not None:
+            return blocks.layout_bytes(self._layout, c.block_size,
+                                       self._pool_dtype())[0]
         heads = cfg.num_heads
         head_dim = cfg.hidden_size // heads
         if self.kv_quantized:
@@ -1424,9 +1506,44 @@ class PagedGenerationEngine(GenerationEngine):
                 tuple(type(l)(*(x._data for x in l))
                       for l in new_cache.layers))
 
+    def _run_layout_model(self, params, pool, tables, pos, ids, valid=None,
+                          slot=None):
+        """The cached forward of a model with its own cache layout ->
+        (logits, new pool, counters int32 [len(serving_counters)])."""
+        cache = blocks.PagedDecodeCache(
+            tuple(type(l)(*(Tensor(x) for x in l)) for l in pool),
+            Tensor(tables), Tensor(pos),
+            None if valid is None else Tensor(valid),
+            None if slot is None else Tensor(slot))
+        out, _ = functional_call(
+            self._model, params, self._buffers, args=(Tensor(ids),),
+            kwargs={"cache": cache}, train=False)
+        logits, new_cache, counters = out
+        return (logits._data,
+                tuple(type(l)(*(x._data for x in l))
+                      for l in new_cache.layers), counters._data)
+
+    def _layout_decode_fn(self, params, pool, tables, pos, tokens, key,
+                          *rng):
+        """`_decode_fn` for a model with its own cache layout: the tokens
+        come back with the model's counters behind them in ONE int32
+        array, so the step's single fetch brings both."""
+        logits, npool, counters = self._run_layout_model(
+            params, pool, tables, pos, tokens[:, None])
+        nxt = self._select_slots(logits[:, 0, :], key, *rng)
+        out = (jnp.concatenate([nxt.astype(jnp.int32), counters]),
+               self._constrain_pools(npool),
+               jnp.minimum(pos + 1, self.config.max_len - 1))
+        if self.config.capture_logits:
+            out = out + (logits[:, 0, :],)
+        return out
+
     # -- decode: ONE executable ---------------------------------------------
     def _decode_fn(self, params, pool, tables, pos, tokens, key, *extra):
         self._bump_decode_trace()            # trace-time only
+        if self._layout is not None:
+            return self._layout_decode_fn(params, pool, tables, pos, tokens,
+                                          key, *extra)
         adapters, rng = self._split_extra(extra)
         with self._numerics_scope() as sink:
             if self.kv_quantized:
@@ -1476,6 +1593,19 @@ class PagedGenerationEngine(GenerationEngine):
             # suffix K/V and the gather over the (possibly shared) prefix
             # blocks; `start` = tokens already resident (prefix hit)
             row = jax.lax.dynamic_slice(tables, (slot, 0), (1, nb))
+            if self._layout is not None:
+                # always from position 0 (the prefix cache is bypassed);
+                # first token and counters in one int32 array
+                logits, npool, counters = self._run_layout_model(
+                    params, pool, row, start[None], ids[None, :],
+                    valid=length[None], slot=slot)
+                pos = jax.lax.dynamic_update_slice(
+                    pos, length[None].astype(pos.dtype), (slot,))
+                last = jax.lax.dynamic_index_in_dim(logits[0], length - 1,
+                                                    keepdims=False)
+                first = self._select(last[None, :], key).astype(jnp.int32)
+                return (jnp.concatenate([first, counters]),
+                        self._constrain_pools(npool), pos)
             with self._numerics_scope() as sink:
                 with blocks.attention_scope("prefill_attn"):
                     logits, npool = self._run_model_paged(
@@ -1544,6 +1674,9 @@ class PagedGenerationEngine(GenerationEngine):
         self._tables[slot] = row
         self._slot_active[slot] = True
         self._slot_namespace[slot] = namespace
+        if self.state_store is not None:
+            assert nshared == 0, "a stateful model's prefill starts at 0"
+            self.state_store.acquire(slot)
         seed, gen = rng if rng is not None \
             else (self._default_slot_seed(), 0)
         self.set_slot_rng(slot, seed, gen)
@@ -1598,6 +1731,11 @@ class PagedGenerationEngine(GenerationEngine):
         if self._numerics_armed:
             self._ingest_numerics(out[3])
         self._pos = np.array(pos, np.int32)   # owned, writable copy
+        if self._counter_names:
+            first = np.asarray(first, np.int32)
+            for name, n in zip(self._counter_names, first[1:]):
+                _TRACER.note(name, int(n))
+            first = first[0]
         return int(first)
 
     def decode(self):
@@ -1635,10 +1773,16 @@ class PagedGenerationEngine(GenerationEngine):
             self._pool = res[1]
             if self._numerics_armed:
                 self._last_decode_args = args    # the localizer's replay
-            with _span("serving::decode.wait",
-                       {"pool_donated": self._pool_donated(args[1])}):
+            wait = {"pool_donated": self._pool_donated(args[1])}
+            with _span("serving::decode.wait", wait):
                 pos = np.array(res[2], np.int32)         # owned, writable
                 out = np.asarray(res[0], np.int32)
+                if self._counter_names:
+                    # the model's counters ride behind the tokens
+                    out, counts = np.split(out, [self.config.slots])
+                    self.last_counters = dict(zip(
+                        self._counter_names, map(int, counts)))
+                    wait.update(self.last_counters)
         # positions advance only once the tokens are on the host too: a
         # step whose fetch failed is run again at the same positions and
         # writes the same K/V
@@ -1690,6 +1834,7 @@ class PagedGenerationEngine(GenerationEngine):
         are bit-identical to what a local prefill would have written,
         which is what makes cross-host greedy streams exact. Returns
         (ks, vs, plen)."""
+        self._require_kv_layout("extract_kv")
         row, plen, nb = self._extract_row(slot)
         ks, vs = [], []
         for layer in self._pool:
@@ -1729,6 +1874,7 @@ class PagedGenerationEngine(GenerationEngine):
         [nblocks, heads] float32 per layer) and "scale_block" (this
         pool's block size — the span each scale row covers), so the
         bundle ships the int8 bytes instead of a 4x dequantized copy."""
+        self._require_kv_layout("extract_kv_wire")
         if not self.kv_quantized:
             ks, vs, plen = self.extract_kv(slot)
             return {"ks": ks, "vs": vs, "plen": plen}
@@ -1758,6 +1904,7 @@ class PagedGenerationEngine(GenerationEngine):
         None (v1/v2 bundles) arms a fresh local seed: greedy-only
         failover, as before ISSUE 13. Raises BlockAllocError under
         pressure — the scheduler's cue to preempt, like prefill."""
+        self._require_kv_layout("adopt_kv")
         slot = int(slot)
         plen = int(plen)
         cfg = self._model.cfg
@@ -1885,6 +2032,7 @@ class PagedGenerationEngine(GenerationEngine):
         Entries stay resident here; the peer registers a COPY. Tiered
         records are verified (sha256 on disk) before export — a corrupt
         record ends the walk, shipping only the good prefix."""
+        self._require_kv_layout("extract_prefix_kv")
         if self.prefix_cache is None:
             return [], [], 0
         toks = [int(t) for t in
@@ -1949,6 +2097,7 @@ class PagedGenerationEngine(GenerationEngine):
         (BlockAllocError after eviction) ends the walk early: the good
         prefix registered so far still matches. Returns tokens now
         servable from the restored chain (multiple of block_size)."""
+        self._require_kv_layout("restore_prefix")
         if self.prefix_cache is None or int(plen) < 1:
             return 0
         from .kv_tiers.store import corrupt_counter
@@ -2040,6 +2189,10 @@ class PagedGenerationEngine(GenerationEngine):
         self.set_slot_rng(slot, 0, 0)
         self._slot_adapter[slot] = 0
         self._slot_namespace.pop(slot, None)
+        if self.state_store is not None:
+            # the state rows are dead from here: the next prefill into
+            # this slot starts from zeros and overwrites them
+            self.state_store.release(slot)
 
     def slot_positions(self):
         return self._pos.copy()

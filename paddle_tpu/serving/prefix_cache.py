@@ -101,9 +101,15 @@ def prefix_key(tokens, namespace=None):
 
 
 class PrefixCache:
-    def __init__(self, pool, block_size):
+    def __init__(self, pool, block_size, bypass=False):
         self.pool = pool
         self.block_size = int(block_size)
+        # bypass: the engine's model keeps per-slot state that no block
+        # holds, so reusing a prefix's blocks would be wrong. Every lookup
+        # then reports no hit (and is counted in `bypassed`), nothing is
+        # registered, and the pool's blocks go back when a request ends.
+        self.bypass = bool(bypass)
+        self.bypassed = 0
         self._entries = {}        # key -> block id
         self._lru = {}            # key -> last-use sequence number
         self._parent = {}         # key -> chain-parent key (None at k=0)
@@ -207,6 +213,12 @@ class PrefixCache:
         re-prefill) count via `record_lookup` once the placement
         actually sticks, so pressure retries cannot inflate the
         CI-gated hit rate."""
+        if self.bypass:
+            self.bypassed += 1
+            self.last_tier_stats = {"promoted_blocks": 0, "restore_s": 0.0}
+            if record:
+                self.record_lookup(False)
+            return [], 0
         bs = self.block_size
         usable = (len(prompt) - 1) // bs      # full blocks, 1 token spared
         ids = []
@@ -255,6 +267,8 @@ class PrefixCache:
         refs, no LRU touches, no promotion, no counters — counts HBM
         entries AND tiered continuations. The `OP_PREFIX_LOOKUP` fabric
         verb answers from this (readonly verbs must not mutate)."""
+        if self.bypass:
+            return 0
         bs = self.block_size
         usable = (len(prompt) - 1) // bs
         n = 0
@@ -337,6 +351,8 @@ class PrefixCache:
         Already-cached chains keep their existing block (the duplicate
         stays request-private); newly cached blocks gain one cache-owned
         reference."""
+        if self.bypass:
+            return
         bs = self.block_size
         prev_key = None
         for k in range(int(upto_tokens) // bs):

@@ -1,0 +1,315 @@
+"""The mechanisms of the hybrid decoder as pure functions over raw arrays:
+KDA linear attention (a chunked form for prefill, the one-step recurrence
+for decode), MLA over a latent cache (expanded for prefill, absorbed for
+decode), and the expert layer of a chip that holds a share of the experts.
+Plain XLA; `text/models/hybrid.py` wires them into a model and
+`serving/blocks.py` holds the two kinds of cache they read and write.
+
+Weights are bfloat16 (matmuls accumulate in float32), the residual stream,
+the norms, the router and the recurrent state are float32.
+"""
+import jax
+import jax.numpy as jnp
+
+HIGHEST = jax.lax.Precision.HIGHEST
+KDA_CHUNK = 64
+KDA_SUBCHUNK = 16
+
+
+def mm(spec, a, b):
+    """bfloat16 operands, float32 result."""
+    return jnp.einsum(spec, a.astype(jnp.bfloat16), b.astype(jnp.bfloat16),
+                      preferred_element_type=jnp.float32)
+
+
+def f32mm(spec, a, b):
+    """float32 operands at full precision (the state's arithmetic)."""
+    return jnp.einsum(spec, a, b, precision=HIGHEST)
+
+
+def rms_norm(x, w, eps):
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * w
+
+
+def l2norm(x):
+    return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-6)
+
+
+def swiglu(x, gate, up, down):
+    return mm("...f,fh->...h",
+              jax.nn.silu(mm("...h,hf->...f", x, gate))
+              * mm("...h,hf->...f", x, up), down)
+
+
+# ------------------------------------------------------------------- KDA
+
+def kda_inputs(x, w, cfg, conv_in, history):
+    """What the recurrence consumes, from the normed input `x` [..., T, H]:
+    q, k, v [..., T, n, d], log-decay g [..., T, n, d] (<= 0), beta
+    [..., T, n], and the output gate [..., T, n*d]. `conv_in` [..., T, 3nd]
+    are the projections the convolution reads (`kda_conv_in`) and `history`
+    [..., K-1, 3nd] the ones before them (zeros at the start of a request):
+    the cache keeps the last K-1 rows of their concatenation."""
+    n, d = cfg.num_heads, cfg.head_dim
+    k = cfg.conv_kernel
+    taps = jnp.concatenate([w["conv_q"], w["conv_k"], w["conv_v"]], -1) \
+        .astype(jnp.float32)
+    seq = jnp.concatenate([history, conv_in], -2).astype(jnp.float32)
+    t = conv_in.shape[-2]
+    conv = sum(taps[j] * jax.lax.slice_in_dim(seq, j, j + t, axis=-2)
+               for j in range(k))
+    q, kk, v = (part.reshape(part.shape[:-1] + (n, d))
+                for part in jnp.split(jax.nn.silu(conv), 3, -1))
+    q = l2norm(q) * (d ** -0.5)
+    kk = l2norm(kk)
+    gate_in = (mm("...h,hc->...c", x, w["wf"]) + w["bf"]) \
+        .reshape(x.shape[:-1] + (n, d))
+    g = cfg.kda_lower_bound * jax.nn.sigmoid(
+        jnp.exp(w["a_log"])[:, None] * gate_in)
+    beta = jax.nn.sigmoid(mm("...h,hn->...n", x, w["wb"]))
+    out_gate = jax.nn.sigmoid(mm("...h,hc->...c", x, w["wg"]))
+    return q, kk, v, g, beta, out_gate
+
+
+def kda_conv_in(x, w, dtype):
+    """The three projections the convolution reads, in the type the cache
+    keeps their last rows in."""
+    return jnp.concatenate([mm("...h,hc->...c", x, w[name])
+                            for name in ("wq", "wk", "wv")],
+                           -1).astype(dtype)
+
+
+def kda_output(o, out_gate, w, cfg):
+    """Per-head RMSNorm of the state's read-out, the output gate, W_o."""
+    y = rms_norm(o, w["onorm"], cfg.rms_norm_eps)
+    y = y.reshape(y.shape[:-2] + (-1,)) * out_gate
+    return mm("...c,ch->...h", y, w["wo"])
+
+
+def kda_recurrent_step(state, q, k, v, g, beta):
+    """One token for every slot: state [S, n, dk, dv]; q, k, v, g [S, n, d];
+    beta [S, n]. Returns (new state, o [S, n, dv])."""
+    with jax.named_scope("kda_state"):
+        state = jnp.exp(g)[..., None] * state
+        u = beta[..., None] * (v - f32mm("snk,snkv->snv", k, state))
+        state = state + k[..., None] * u[..., None, :]
+        return state, f32mm("snk,snkv->snv", q, state)
+
+
+def kda_chunked(q, k, v, g, beta, valid):
+    """The same recurrence over one sequence from a zero state, a chunk of
+    KDA_CHUNK tokens at a time: q, k, v, g [T, n, d], beta [T, n], `valid`
+    [T] bool (bucket padding is False: it neither decays nor writes).
+    Returns (o [T, n, dv], final state [n, dk, dv]).
+
+    With G the cumulative log-decay inside a chunk and S0 the state it
+    starts from, `u_t = beta_t (v_t - (k_t e^{G_t})^T S0 - sum_{i<t} A_ti
+    u_i)`, `A_ti = sum_c k_t[c] k_i[c] e^{G_t[c] - G_i[c]}`, is a unit lower
+    triangular system solved by forward substitution for all chunks at
+    once; only `S0 -> S_C` runs chunk after chunk. `e^{G_t - G_i}` is never
+    split into `e^{G_t} e^{-G_i}` across a whole chunk (64 steps of decay
+    down to e^-320 leave float32): each sub-chunk of KDA_SUBCHUNK rows
+    measures its exponents from its own first row, so every factor lies
+    within e^+-80."""
+    t, n, d = q.shape
+    c = min(KDA_CHUNK, -(-t // KDA_SUBCHUNK) * KDA_SUBCHUNK)
+    sub = KDA_SUBCHUNK
+    pad = -t % c
+    if pad:
+        q, k, v, g = (jnp.pad(a, ((0, pad), (0, 0), (0, 0)))
+                      for a in (q, k, v, g))
+        beta = jnp.pad(beta, ((0, pad), (0, 0)))
+        valid = jnp.pad(valid, (0, pad))
+    g = jnp.where(valid[:, None, None], g, 0.0)
+    beta = jnp.where(valid[:, None], beta, 0.0)
+    nc, ns = (t + pad) // c, c // sub
+
+    def chunks(a):                       # [T, n, ...] -> [N, n, C, ...]
+        return jnp.moveaxis(a.reshape((nc, c) + a.shape[1:]), 1, 2)
+
+    with jax.named_scope("kda_state"):
+        q, k, v, g, beta = map(chunks, (q, k, v, g, beta))
+        cum = jnp.cumsum(g, axis=2)                          # G, inclusive
+        # r_I: G just before sub-chunk I's first row
+        ref = (cum - g)[:, :, ::sub]                         # [N, n, ns, d]
+        row_ref = jnp.repeat(ref, sub, axis=2)               # [N, n, C, d]
+        hat = jnp.exp(cum - row_ref)                 # e^{G_t - r_I(t)} <= 1
+        # e^{r_I - G_i} for the columns i of sub-chunks <= I, else 0
+        expo = ref[:, :, :, None, :] - cum[:, :, None, :, :]  # [N,n,ns,C,d]
+        col_sub = jnp.arange(c) // sub
+        seen = col_sub[None, :] <= jnp.arange(ns)[:, None]    # [ns, C]
+        bar_k = k[:, :, None] * jnp.exp(
+            jnp.where(seen[:, :, None], jnp.minimum(expo, 80.0), -jnp.inf))
+
+        def against_bar(rows):           # [N, n, C, d] -> [N, n, C, C]
+            r = rows.reshape(rows.shape[:2] + (ns, sub, d))
+            return f32mm("bnIsc,bnIic->bnIsi", r, bar_k) \
+                .reshape(rows.shape[:2] + (c, c))
+
+        lower = jnp.tril(jnp.ones((c, c), bool))
+        a_mat = jnp.where(lower & ~jnp.eye(c, dtype=bool),
+                          against_bar(k * hat), 0.0) * beta[..., None]
+        b_mat = jnp.where(lower, against_bar(q * hat), 0.0)
+        k_dec = k * jnp.exp(cum)                              # k e^{G_t}
+        rhs = jnp.concatenate([v, k_dec], -1) * beta[..., None]
+
+        def substitute(i, sol):
+            row = jax.lax.dynamic_index_in_dim(a_mat, i, 2, keepdims=False)
+            new = jax.lax.dynamic_index_in_dim(rhs, i, 2, keepdims=False) \
+                - f32mm("bni,bnie->bne", row, sol)
+            return jax.lax.dynamic_update_index_in_dim(sol, new, i, 2)
+
+        sol = jax.lax.fori_loop(0, c, substitute, jnp.zeros_like(rhs))
+        tv, w_mat = sol[..., :d], sol[..., d:]
+        q_dec = q * jnp.exp(cum)
+        total = cum[:, :, -1]                                 # G_C [N, n, d]
+        k_rest = k * jnp.exp(total[:, :, None] - cum)         # k e^{G_C-G_i}
+
+        def chunk_step(state, xs):
+            tv_c, w_c, qd_c, b_c, kr_c, tot_c = xs
+            u = tv_c - f32mm("nck,nkv->ncv", w_c, state)
+            o = f32mm("nck,nkv->ncv", qd_c, state) \
+                + f32mm("nci,niv->ncv", b_c, u)
+            state = jnp.exp(tot_c)[..., None] * state \
+                + f32mm("nck,ncv->nkv", kr_c, u)
+            return state, o
+
+        state, o = jax.lax.scan(
+            chunk_step, jnp.zeros((n, d, d), jnp.float32),
+            (tv, w_mat, q_dec, b_mat, k_rest, total))
+        o = jnp.moveaxis(o, 1, 2).reshape(t + pad, n, d)[:t]
+        return o, state
+
+
+# ------------------------------------------------------------------- MLA
+
+def rotary(x, positions, theta):
+    """x [..., T, (heads,) R] at `positions` [..., T], half-split pairs."""
+    half = x.shape[-1] // 2
+    inv = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    ang = positions.astype(jnp.float32)[..., None] * inv
+    if x.ndim == ang.ndim + 1:                                # a heads axis
+        ang = ang[..., None, :]
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    a, b = x[..., :half], x[..., half:]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], -1)
+
+
+def mla_project(x, w, cfg, positions):
+    """(q_n [..., T, n, nope], rotated q_r [..., T, n, rope], the latent row
+    [..., T, rank + rope] = [RMSNorm(c), rotated k_r] as cached, the
+    head-wise gate [..., T, n])."""
+    n = cfg.num_heads
+    nope, rope, rank = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, \
+        cfg.kv_lora_rank
+    q = mm("...h,hc->...c", x, w["wq"]).reshape(x.shape[:-1]
+                                                + (n, nope + rope))
+    q_n, q_r = q[..., :nope], rotary(q[..., nope:], positions,
+                                     cfg.rope_theta)
+    a = mm("...h,hc->...c", x, w["wa"])
+    latent = jnp.concatenate(
+        [rms_norm(a[..., :rank], w["cnorm"], cfg.rms_norm_eps),
+         rotary(a[..., rank:], positions, cfg.rope_theta)], -1)
+    gate = jax.nn.sigmoid(mm("...h,hn->...n", x, w["wgate"]))
+    return q_n, q_r, latent.astype(jnp.bfloat16), gate
+
+
+def mla_prefill(q_n, q_r, latent, gate, w, cfg):
+    """Expanded form over one request's own tokens [T] (a request starts at
+    position 0: no earlier rows to read). Padding sits after the real
+    tokens, so causality keeps it out of every row that is read."""
+    with jax.named_scope("mla_attn"):
+        n, nope, vd = cfg.num_heads, cfg.qk_nope_head_dim, cfg.v_head_dim
+        rank = cfg.kv_lora_rank
+        t = latent.shape[0]
+        kv = mm("tr,rc->tc", latent[:, :rank], w["wkvb"]) \
+            .reshape(t, n, nope + vd)
+        scores = (mm("qnd,knd->nqk", q_n, kv[..., :nope])
+                  + mm("qnd,kd->nqk", q_r, latent[:, rank:])) \
+            * ((nope + cfg.qk_rope_head_dim) ** -0.5)
+        scores = jnp.where(jnp.tril(jnp.ones((t, t), bool)), scores,
+                           -jnp.inf)
+        o = mm("nqk,knd->qnd", jax.nn.softmax(scores, -1), kv[..., nope:])
+        o = (o * gate[..., None]).reshape(t, n * vd)
+        return mm("tc,ch->th", o, w["wo"])
+
+
+def mla_decode(q_n, q_r, rows, pos, gate, w, cfg):
+    """Absorbed form, one token a slot: q_n [S, n, nope], q_r [S, n, rope],
+    `rows` [S, L, rank + rope] the slot's latent rows (its own new row
+    written), `pos` [S] the new token's position. `q_n W_b^K` meets `c`
+    directly, and the softmax-weighted `c` goes through `W_b^V`."""
+    with jax.named_scope("mla_attn"):
+        n, nope, vd = cfg.num_heads, cfg.qk_nope_head_dim, cfg.v_head_dim
+        rank = cfg.kv_lora_rank
+        wkvb = w["wkvb"].reshape(rank, n, nope + vd)
+        q_c = mm("snd,rnd->snr", q_n, wkvb[..., :nope])
+        scores = (mm("snr,slr->snl", q_c, rows[..., :rank])
+                  + mm("snd,sld->snl", q_r, rows[..., rank:])) \
+            * ((nope + cfg.qk_rope_head_dim) ** -0.5)
+        seen = jnp.arange(rows.shape[1])[None, :] <= pos[:, None]
+        scores = jnp.where(seen[:, None, :], scores, -jnp.inf)
+        o_c = mm("snl,slr->snr", jax.nn.softmax(scores, -1),
+                 rows[..., :rank])
+        o = mm("snr,rnd->snd", o_c, wkvb[..., nope:])
+        o = (o * gate[..., None]).reshape(o.shape[0], n * vd)
+        return mm("sc,ch->sh", o, w["wo"])
+
+
+# ------------------------------------------------------------- experts
+
+COUNTERS = ("moe_pairs_total", "moe_pairs_local", "moe_experts_hit",
+            "moe_expert_max")
+
+
+def route(x, w, cfg):
+    """Sigmoid scores over all routed experts in float32; the choice on
+    score + bias, group-limited; weights the chosen scores normalised, times
+    the scaling factor. Returns (ids [T, k] global, weights [T, k])."""
+    r, groups = cfg.n_routed_experts, cfg.n_group
+    s = jax.nn.sigmoid(f32mm("th,hr->tr", x.astype(jnp.float32),
+                             w["router"]))
+    biased = s + w["router_bias"]
+    group_score = jnp.sum(jax.lax.top_k(
+        biased.reshape(-1, groups, r // groups), 2)[0], -1)
+    _, keep = jax.lax.top_k(group_score, cfg.topk_group)
+    group_ok = jnp.any(keep[:, :, None] == jnp.arange(groups), 1)
+    masked = jnp.where(jnp.repeat(group_ok, r // groups, axis=1), biased,
+                       -jnp.inf)
+    _, ids = jax.lax.top_k(masked, cfg.experts_per_tok)
+    picked = jnp.take_along_axis(s, ids, -1)
+    return ids, picked / jnp.sum(picked, -1, keepdims=True) \
+        * cfg.routed_scaling_factor
+
+
+def moe_share(x, w, cfg, live):
+    """The part of the expert layer this chip gives for tokens `x` [T, H]:
+    it routes over all `n_routed_experts`, computes the `num_experts` it
+    holds (global ids from `experts_first`) for the tokens that chose them,
+    and adds the shared expert. What the absent experts would add is left
+    out; nothing stands in for them. `live` [T] bool marks the rows that are
+    someone's token (counters only). Returns (y [T, H], counters int32 [4]
+    in the order of COUNTERS)."""
+    with jax.named_scope("moe_experts"):
+        e = cfg.num_experts
+        ids, weights = route(x, w, cfg)
+        local_id = ids - cfg.experts_first
+        local = (local_id >= 0) & (local_id < e)
+        t = x.shape[0]
+        # every held expert over every token, weighted by the picks
+        dense_w = jnp.zeros((t, e + 1), jnp.float32).at[
+            jnp.arange(t)[:, None], jnp.where(local, local_id, e)
+        ].add(weights)[:, :e]
+        hid = jax.nn.silu(mm("th,ehf->etf", x, w["we_gate"])) \
+            * mm("th,ehf->etf", x, w["we_up"])
+        routed = mm("etf,efh->th", hid * dense_w.T[:, :, None],
+                    w["we_down"])
+        y = routed + swiglu(x, w["ws_gate"], w["ws_up"], w["ws_down"])
+        counted = local & live[:, None]
+        per_expert = jnp.zeros((e + 1,), jnp.int32).at[
+            jnp.where(counted, local_id, e).reshape(-1)].add(1)[:e]
+        counters = jnp.stack([
+            jnp.sum(live) * cfg.experts_per_tok, jnp.sum(counted),
+            jnp.sum(per_expert > 0), jnp.max(per_expert)]).astype(jnp.int32)
+        return y, counters
