@@ -187,7 +187,8 @@ def phase_kernels(sz):
     from paddle_tpu.ops.flash_attention import (_pallas_flash_bhsd,
                                                 _ref_attention_bhsd,
                                                 flash_blocks)
-    from paddle_tpu.ops.pallas.flash_attention import _auto_block
+    from paddle_tpu.ops.pallas.flash_attention import (default_block,
+                                                       flash_plan)
     from paddle_tpu.serving import blocks
 
     out = {}
@@ -197,9 +198,13 @@ def phase_kernels(sz):
     B, H, S, D = f["B"], f["H"], f["S"], f["D"]
     scale = 1.0 / D ** 0.5
     bq, bk = flash_blocks(B, H, S, D, True)
-    used = (bq or _auto_block(S), bk or _auto_block(S))
+    used = (bq or default_block(S), bk or default_block(S))
+    plan = flash_plan(S, D, *used, True)
     log(f"flash: shape {(B, H, S, D)} {f['dtype']} causal, blocks {used} "
-        f"({'tuned row' if bq else 'kernel default'})")
+        f"({'tuned row' if bq else 'kernel default'}); plan: keys resident "
+        f"{plan.major_k}, queries resident {plan.major_q}, chunks run "
+        f"{plan.chunks_run}/{plan.chunks_total} a head, masked "
+        f"{plan.chunks_masked}/{plan.chunks_run}")
     ks = jax.random.split(jax.random.key(7), 4)
     q, k, v, do = (jax.random.normal(kk, (B, H, S, D), f["dtype"]) * 0.5
                    for kk in ks)

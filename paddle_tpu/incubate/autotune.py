@@ -228,11 +228,11 @@ def commit_shipped_table(entries, backend="tpu", path=None, kernel="flash"):
                              f"of 8 (TPU sublane alignment)")
         if S % bq or S % bk:
             raise ValueError(f"blocks {blocks} do not tile S={S}")
-        if causal and bq != bk:
-            # the kernel requires square blocks under causal masking;
-            # committing a non-square pair would ship an entry the
-            # runtime guard silently ignores — reject it here instead
-            raise ValueError(f"causal entries need square blocks, got "
+        if max(bq, bk) % min(bq, bk):
+            # the kernels walk a group of max(bq, bk) rows at a time;
+            # committing a pair that cannot would ship an entry the
+            # runtime guard ignores — reject it here instead
+            raise ValueError(f"one block must divide the other, got "
                              f"{blocks}")
         merged[(backend, int(H), int(S), int(D), bool(causal))] = (bq, bk)
     with open(path, "w") as f:
@@ -245,10 +245,12 @@ def commit_shipped_table(entries, backend="tpu", path=None, kernel="flash"):
 
 def autotune_flash_blocks(B, H, S, D, causal=True, dtype="bfloat16",
                           candidates=(128, 256, 512), n_iters=3):
-    """Measure each candidate square block on the live backend and cache the
-    fastest. Returns (block_q, block_k). Candidates that don't divide S or
-    fail to compile are skipped; measurement uses a host fetch of a
-    result element as the sync (it cannot complete before the work)."""
+    """Measure every (block_q, block_k) pair of the candidates on the live
+    backend and cache the fastest; the two need not be equal, causal or
+    not (`ops.pallas.flash_attention.FlashPlan`). Returns (block_q,
+    block_k). Candidates that don't divide S or fail to compile are
+    skipped; measurement uses a host fetch of a result element as the sync
+    (it cannot complete before the work)."""
     import jax
     import jax.numpy as jnp
 
@@ -258,20 +260,19 @@ def autotune_flash_blocks(B, H, S, D, causal=True, dtype="bfloat16",
     if hit is not None:
         return hit
     if not kernel_tuning_enabled():
-        from ..ops.pallas.flash_attention import _auto_block
-        b = _auto_block(S)
+        from ..ops.pallas.flash_attention import default_block
+        b = default_block(S)
         return (b, b)
 
     q = (jax.random.normal(jax.random.key(0), (B, H, S, D)) * 0.1) \
         .astype(dtype)
     interpret = jax.default_backend() != "tpu"
     best, best_dt = None, float("inf")
-    for b in candidates:
-        if S % b or b > S:
-            continue
+    fits = [b for b in candidates if b <= S and S % b == 0]
+    for bq, bk in [(a, b) for a in fits for b in fits]:
         try:
-            f = jax.jit(lambda q, b=b: flash_attention(
-                q, q, q, causal=causal, block_q=b, block_k=b,
+            f = jax.jit(lambda q, bq=bq, bk=bk: flash_attention(
+                q, q, q, causal=causal, block_q=bq, block_k=bk,
                 interpret=interpret))
             float(jnp.ravel(f(q))[0].astype(jnp.float32))    # compile+warm
             t0 = time.perf_counter()
@@ -281,11 +282,11 @@ def autotune_flash_blocks(B, H, S, D, causal=True, dtype="bfloat16",
         except Exception:                                    # noqa: BLE001
             continue
         if dt < best_dt:
-            best, best_dt = (b, b), dt
+            best, best_dt = (bq, bk), dt
     fallback = best is None
     if fallback:
-        from ..ops.pallas.flash_attention import _auto_block
-        b = _auto_block(S)           # always divides S (never poisons cache)
+        from ..ops.pallas.flash_attention import default_block
+        b = default_block(S)           # always divides S (never poisons cache)
         best = (b, b)
     # fallbacks stay in-memory only: a persisted fallback would override the
     # shipped tuned table for this geometry on every future load (ADVICE r4)

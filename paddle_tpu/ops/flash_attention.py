@@ -62,19 +62,30 @@ def _use_pallas(q, k):
 
 
 def flash_blocks(B, H, S, D, causal):
-    """(block_q, block_k) the Pallas kernel will run with for this
+    """(block_q, block_k) the Pallas kernels will run with for this
     geometry, or (None, None) for the kernel's own default: the autotune
     cache's row (incubate.autotune — the phi AlgorithmsCache role) when
-    it tiles the call. A row that does not — it fails to divide S, or
-    breaks the causal square-block requirement — is reported and
-    ignored; it must not raise mid-forward, and it must not be taken
-    without a word either."""
+    it tiles the call.
+
+    What the pair means (`ops.pallas.flash_attention.FlashPlan`): the
+    (block_q, block_k) score tile of all three kernels. Forward and dq
+    take a grid step per block_q queries and loop over the keys, resident
+    in VMEM, in chunks of block_k; dkv takes a grid step per block_k keys
+    and loops over the queries in chunks of block_q. Under `causal` the
+    two need not be equal since PR 29, but one must divide the other.
+
+    A row that does not tile the call — it fails to divide S, or neither
+    block divides the other — is reported and ignored; it must not raise
+    mid-forward, and it must not be taken without a word either."""
     from ..incubate.autotune import lookup_flash_blocks
     hit = lookup_flash_blocks(B, H, S, D, causal)
     if not hit:
         return None, None
+    from .pallas.flash_attention import flash_plan
     bq, bk = int(hit[0]), int(hit[1])
-    if S % bq or S % bk or (causal and bq != bk):
+    try:
+        flash_plan(S, D, bq, bk, causal)
+    except (ValueError, ZeroDivisionError):
         import warnings
         warnings.warn(
             f"flash autotune row {(bq, bk)} for (H={H}, S={S}, D={D}, "

@@ -38,10 +38,12 @@ def test_autotune_picks_a_valid_block_and_caches(tmp_path, monkeypatch):
 
 def test_tuned_blocks_feed_the_flash_entry(monkeypatch):
     """ops.flash_attention consults the cache: a valid tuned entry is
-    passed through to the kernel, while a poisoned entry (stale disk
-    table: blocks that don't divide S, or a non-square causal pair)
-    falls back to the kernel default instead of raising mid-forward
-    (ISSUE 6 satellite: the block-table fix)."""
+    passed through to the kernel — an unequal causal pair included, since
+    PR 29 moved the key loop into the kernels — while a poisoned entry
+    (stale disk table: blocks that don't divide S, or a pair of which
+    neither divides the other) falls back to the kernel default, with a
+    warning, instead of raising mid-forward (ISSUE 6 satellite: the
+    block-table fix)."""
     import importlib
 
     import jax
@@ -67,10 +69,36 @@ def test_tuned_blocks_feed_the_flash_entry(monkeypatch):
     assert seen["blocks"] == (128, 128)
 
     autotune._block_cache[key] = (96, 96)       # poisoned: 256 % 96 != 0
-    fa_mod._pallas_flash_bhsd(q, q, q, True, 0.125)
+    with pytest.warns(UserWarning, match="does not tile"):
+        fa_mod._pallas_flash_bhsd(q, q, q, True, 0.125)
     assert seen["blocks"] == (None, None)       # fell back, no raise
 
-    autotune._block_cache[key] = (128, 256)     # causal needs square blocks
+    autotune._block_cache[key] = (128, 256)     # causal and unequal: honoured
     fa_mod._pallas_flash_bhsd(q, q, q, True, 0.125)
+    assert seen["blocks"] == (128, 256)
+
+    key = (jax.default_backend(), 2, 384, 64, True)
+    q = jnp.ones((1, 2, 384, 64), jnp.float32)
+    autotune._block_cache[key] = (192, 128)     # neither divides the other
+    with pytest.warns(UserWarning, match="does not tile"):
+        fa_mod._pallas_flash_bhsd(q, q, q, True, 0.125)
     assert seen["blocks"] == (None, None)
     autotune._block_cache.clear()
+
+
+def test_an_unequal_causal_pair_runs_through_the_real_kernel():
+    """What the old kernels refused ("causal masking requires block_q ==
+    block_k") the public entry now runs, and matches the XLA reference."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.flash_attention import _ref_attention_bhsd
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention
+    q, k, v = (jax.random.normal(jax.random.key(i), (1, 2, 256, 64))
+               for i in range(3))
+    out = flash_attention(q, k, v, causal=True, block_q=256, block_k=128,
+                          interpret=True)
+    want = _ref_attention_bhsd(q, k, v, True, 0.125)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               atol=2e-3, rtol=2e-3)
+    assert jnp.isfinite(out).all()

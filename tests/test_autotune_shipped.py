@@ -126,3 +126,21 @@ def test_shipped_table_is_committed_or_reported():
             continue
         bq, bkv = blocks
         assert bq > 0 and bkv > 0 and bq % 8 == 0 and bkv % 8 == 0
+
+
+def test_commit_accepts_unequal_causal_pairs_and_refuses_untileable_ones(
+        clean_cache):
+    """Until PR 29 a causal row had to be square (the kernels skipped whole
+    (block, block) tiles); now one block only has to divide the other."""
+    backend = jax.default_backend()
+    autotune.commit_shipped_table({(16, 1024, 64, True): (256, 128)},
+                                  backend=backend)
+    assert autotune.lookup_flash_blocks(8, 16, 1024, 64, True) == (256, 128)
+    from paddle_tpu.ops.flash_attention import flash_blocks
+    assert flash_blocks(8, 16, 1024, 64, True) == (256, 128)
+    with pytest.raises(ValueError, match="must divide the other"):
+        autotune.commit_shipped_table({(16, 768, 64, True): (384, 256)},
+                                      backend=backend)
+    with pytest.raises(ValueError, match="do not tile"):
+        autotune.commit_shipped_table({(16, 1024, 64, True): (384, 128)},
+                                      backend=backend)
