@@ -1,4 +1,4 @@
-"""BASELINE row 2: BERT-base pretraining, dygraph data parallelism.
+"""Reference configuration 2: BERT-base pretraining, dygraph data parallelism.
 
 Reference UX: paddle.DataParallel + fleet DP (python/paddle/fluid/dygraph/
 parallel.py); here DP comes from a `dp` mesh axis — `Model.fit` (or the

@@ -1,4 +1,4 @@
-"""BASELINE row 4: ERNIE-3.0-Base with mp+pp hybrid via `Model.fit`.
+"""Reference configuration 4: ERNIE-3.0-Base with mp+pp hybrid via `Model.fit`.
 
 Reference UX: fleet hybrid_configs {mp_degree, pp_degree} + hapi
 (python/paddle/hapi/model.py:591-599 routes any fleet strategy). Here the

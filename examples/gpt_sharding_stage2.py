@@ -1,4 +1,4 @@
-"""BASELINE row 3: GPT-3 1.3B with sharding stage-2 (ZeRO-2).
+"""Reference configuration 3: GPT-3 1.3B with sharding stage-2 (ZeRO-2).
 
 Reference UX: fleet DistributedStrategy sharding_degree / stage=2
 (python/paddle/distributed/fleet/meta_optimizers/sharding_optimizer.py).

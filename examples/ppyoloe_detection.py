@@ -1,4 +1,4 @@
-"""BASELINE row 5: PP-YOLOE detection training (conv/bn/SiLU + SyncBN).
+"""Reference configuration 5: PP-YOLOE detection training (conv/bn/SiLU + SyncBN).
 
 Reference UX: PaddleDetection's PP-YOLOE (the reference repo carries its
 kernel stack: conv + sync_batch_norm ops). Here SyncBatchNorm reduces

@@ -1,4 +1,4 @@
-"""BASELINE row 1: ResNet / CIFAR-10 via `Model.fit` on one TPU chip.
+"""Reference configuration 1: ResNet / CIFAR-10 via `Model.fit` on one TPU chip.
 
 Reference UX: python/paddle/hapi/model.py Model.fit + vision zoo
 (python/paddle/vision/models/resnet.py). Run:

@@ -8,7 +8,6 @@ paddle.distributed, paddle.vision, paddle.Model, ...
 
 __version__ = "0.1.0"
 
-from . import _jax_compat  # noqa: F401  (deviceprof finds it via sys.modules)
 from .core import (  # noqa: F401
     Tensor, no_grad, enable_grad, set_grad_enabled, is_grad_enabled,
     seed, get_rng_state, set_rng_state,

@@ -87,8 +87,8 @@ moves never hits.
 Observability: `compile_cache_hits_total` / `compile_cache_misses_total`
 counters (the hits/misses rate-rule in tools/metrics_report.py gates a
 hit-rate drop as a failure-class regression), per-executable compile and
-load seconds histograms, and per-instance `stats` dicts the cold-start
-bench rung reports.
+load seconds histograms, and per-instance `stats` dicts
+(tests/test_compile_cache.py reads them across a restart).
 """
 import hashlib
 import json

@@ -67,8 +67,8 @@ def _cache_path():
 
 
 # Tuned blocks shipped with the framework (the phi role of the bundled
-# cuDNN-heuristics tables): winners measured on real TPU by
-# tools/profile_step.py's sweep get committed here so every process —
+# cuDNN-heuristics tables): winners of an on-chip block sweep (PERF.md §6,
+# PR 29) get committed here so every process —
 # including ones with no PADDLE_TPU_AUTOTUNE_CACHE env — starts from
 # chip-measured tilings. The env-path cache (per-user/runtime) overrides.
 _SHIPPED_PATH = os.path.join(os.path.dirname(__file__), os.pardir, "ops",
@@ -175,7 +175,7 @@ def lookup_paged_blocks(H, padded_len, D, block_size):
 
 def record_flash_blocks(H, S, D, causal, blocks, persist=True):
     """Record an externally-measured (block_q, block_k) winner for a
-    geometry (tools/profile_step.py's sweep) and persist it to the env-path
+    geometry (an on-chip sweep) and persist it to the env-path
     cache if configured. persist=False keeps the entry in-memory only —
     used for static FALLBACK results, which must never shadow shipped
     tuned entries at the next load (ADVICE r4)."""
@@ -192,7 +192,7 @@ def record_flash_blocks(H, S, D, causal, blocks, persist=True):
 def commit_shipped_table(entries, backend="tpu", path=None, kernel="flash"):
     """Commit measured winners into the SHIPPED table
     (`ops/pallas/flash_blocks_tuned.json`) — the path on-chip sweep
-    results (tools/profile_step.py) take into the tree, using the exact
+    results take into the tree, using the exact
     cache serialization the lookups read back.
 
     kernel="flash": entries {(H, S, D, causal): (block_q, block_k)}.

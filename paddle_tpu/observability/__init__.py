@@ -24,14 +24,6 @@ one substrate they all report through:
                        raise/delay/drop/truncate with seeded triggers;
                        every fired fault is a metric + a span
                        (docs/robustness.md).
-  xplane.py          — stdlib XSpace (.xplane.pb) wire decoder: the
-                       device-side capture bytes, readable without jax.
-  deviceprof.py      — the device half of the profiler (ISSUE 9):
-                       capture API over jax.profiler.trace, typed
-                       parser to deviceprof.v1 JSONL, the join against
-                       host spans + the analytical cost model, and the
-                       one-shot healthy-window capture orchestration
-                       (bench --xplane).
   fleet.py           — the LIVE fleet plane (ISSUE 12): metrics
                        federation (merge N per-process metrics.v1
                        snapshots into one worker_id/role-labeled fleet
@@ -74,25 +66,21 @@ collector), and live/peak device bytes (collector below).
 
 Every submodule is stdlib-only at import time: importable before (or
 without) jax. A chip belongs to ONE process, so whatever must run beside
-the process that holds it — bench.py's --cold-start parent writing a
-postmortem, the offline tools parsing a device capture or replaying a
-ledger — has to work without initialising jax (deviceprof's capture
-entry points import jax lazily, only when a trace is actually started).
+the process that holds it — a supervisor writing a postmortem, the
+offline tools replaying a ledger — has to work without initialising jax.
 """
 import sys
 
-from . import deviceprof  # noqa: F401
 from . import faults, fleet, flight_recorder, metrics  # noqa: F401
 from . import kvledger, numerics, reqtimeline  # noqa: F401
-from . import tracecontext, xplane  # noqa: F401
+from . import tracecontext  # noqa: F401
 from .flight_recorder import dump_postmortem  # noqa: F401
 from .metrics import registry  # noqa: F401
 from .tracecontext import merge_chrome_traces, trace_scope  # noqa: F401
 
 __all__ = ["metrics", "tracecontext", "flight_recorder", "faults",
-           "deviceprof", "xplane", "fleet", "reqtimeline", "kvledger",
-           "numerics", "registry", "dump_postmortem", "trace_scope",
-           "merge_chrome_traces"]
+           "fleet", "reqtimeline", "kvledger", "numerics", "registry",
+           "dump_postmortem", "trace_scope", "merge_chrome_traces"]
 
 
 def _collect_live_bytes(reg):

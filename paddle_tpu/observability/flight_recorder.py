@@ -19,10 +19,10 @@ postmortem JSON artifact containing:
   - a full metrics snapshot plus counter deltas since enable().
 
 Deliberately stdlib-only with NO paddle_tpu imports at module level:
-bench.py loads this file standalone (importlib, bypassing the package)
-so a postmortem can be written from a process that must not initialise
-jax — a chip belongs to one process, and bench.py's --cold-start parent
-stays off it so its children can have it — or whose own import hung.
+the file can be loaded standalone (importlib, bypassing the package) so
+a postmortem can be written from a process that must not initialise
+jax — a chip belongs to one process, and a supervisor stays off it so
+its children can have it — or whose own import hung.
 Tracer and registry are discovered through sys.modules — never imported
 — so a standalone load can neither claim the chip nor trigger the hang
 it is documenting.
@@ -232,9 +232,9 @@ class FlightRecorder:
 
     def annotate(self, key, value):
         """Attach/overwrite a named state note that rides every future
-        postmortem dump — how in-flight orchestration (e.g. an armed
-        deviceprof capture) stays visible when the run wedges before it
-        completes."""
+        postmortem dump — how in-flight state (e.g. a KV-ledger
+        divergence) stays visible when the run wedges before it is
+        reported."""
         with self._lock:
             self.annotations[key] = _json_safe(value)
 

@@ -333,8 +333,8 @@ class ShadowPool:
 def replay_events(events, num_blocks):
     """Replay a serialized kvledger.v1 stream (e.g. parsed back from a
     serving JSONL) into a fresh ShadowPool — the offline half of the
-    reconciler, and what bench's end-of-run audit reconstructs the pool
-    from."""
+    reconciler, and what an end-of-run audit reconstructs the pool
+    from (tests/test_kvledger.py)."""
     shadow = ShadowPool(num_blocks)
     for ev in events:
         shadow.apply(ev)

@@ -24,8 +24,7 @@ queue -> prefill|adopt -> decode (-> queue again on preemption);
 marks and joins the worker scheduler's trail from the terminal POLL
 reply as `worker_phases`. Consumers: `tools/serve_report.py` (timeline
 view + tail attribution), `tools/load_harness.py` (per-phase TTFT
-breakdown gauges), `tests/test_perf_pipeline.py` (CI schema gate over
-the `bench.py --serve-dist` artifacts).
+breakdown gauges).
 
 Stdlib-only, like every observability submodule.
 """

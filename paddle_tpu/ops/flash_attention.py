@@ -51,7 +51,6 @@ def _use_pallas(q, k):
     import os
     if os.environ.get("PADDLE_TPU_DISABLE_PALLAS_FLASH") == "1":
         # operator/profiling escape hatch: forces the pure-XLA attention
-        # (tools/profile_step.py uses it for the whole-model A/B row)
         return False
     if jax.default_backend() != "tpu":
         return False

@@ -3,8 +3,8 @@ re-designed TPU-first — SURVEY §2.10).
 
 The compute path here is raw-jax functional (no eager tape): one
 jit-compiled train step per configuration, shard_map'd over a Mesh with
-explicit XLA collectives. This is the performance path used by bench.py and
-__graft_entry__.dryrun_multichip.
+explicit XLA collectives. This is the performance path used by
+benchmark/loops/train_job.py and __graft_entry__.dryrun_multichip.
 """
 from .gpt_spmd import (  # noqa: F401
     GPTSpmdConfig, MeshPlan, init_gpt_params, make_train_step, make_forward_fn,

@@ -58,7 +58,7 @@ class GPTSpmdConfig:
     init_std: float = 0.02
     # lax.scan unroll over the layer stack: >1 lets XLA software-pipeline
     # adjacent blocks (weight prefetch overlapping compute) at the cost of
-    # program size; values measured via tools/profile_step.py
+    # program size
     scan_unroll: int = 1
     # >1 enables the chunked fused linear-CE LM head (ops/fused_ce.py):
     # logits never materialize, saving ~2.5GB peak f32 at the bench shape

@@ -35,7 +35,7 @@ turn a compiled decoder into a serving engine:
                     (`paddle_tpu.serving.distributed`) — single-process
                     serving never pays for the fabric.
 
-`inference.Predictor.generate`, `bench.py --decode/--serve-load` and
+`inference.Predictor.generate`, `benchmark/loops/closed_loop.py` and
 `tools/load_harness.py` ride the same engines. See docs/serving.md.
 """
 from . import blocks, kv_cache, prefix_cache, sampling, spec_decode  # noqa: F401,E501

@@ -37,7 +37,7 @@ un-descoping PARITY §2.7's multi-host row with three composable layers:
                    suspect hosts, and `rolling_drain` — a zero-drop
                    rolling-restart primitive (docs/robustness.md §5).
   worker_main.py — `python -m paddle_tpu.serving.distributed.worker_main`
-                   process entry (tests, bench --serve-dist, deploys).
+                   process entry (tests, deploys).
 
 Deliberately NOT imported by `paddle_tpu.serving` at import time: the
 multi-host tier pulls in the RPC fabric and mesh machinery, which

@@ -881,8 +881,8 @@ class PipelineParallelSpeculativeEngine(_spec.SpeculativeEngine,
                                  jax.device_put(l.v, st.replicated))
                      for l in layers)
 
-    def _draft_feed(self, tokens):
-        return jax.device_put(tokens, self._stages[0].replicated)
+    def _draft_feed(self, vec):
+        return jax.device_put(vec, self._stages[0].replicated)
 
     def _build_draft_decode_params(self):
         """Draft-on-first-stage: params AND buffers device_put
@@ -1063,7 +1063,7 @@ class PipelineParallelSpeculativeEngine(_spec.SpeculativeEngine,
         dv = [l.v for l in self._draft_kv]
         dpos = jnp.asarray(self._draft_pos)
         out["draft_decode"] = self._draft_decode.warm(
-            self._draft_decode_params, dk, dv, dpos,
+            self._draft_decode_params, dk, dv, self._draft_feed(dpos),
             self._draft_feed(jnp.zeros((c.slots,), jnp.int32)))
         for b in c.prefill_buckets:
             if b not in self._draft_prefill:

@@ -259,10 +259,12 @@ class SpeculativeEngine(PagedGenerationEngine):
         on the first stage's mesh (draft-on-first-stage)."""
         return layers
 
-    def _draft_feed(self, tokens):
-        """Placement of the round's t0 token vector before it enters the
-        draft decode executable."""
-        return tokens
+    def _draft_feed(self, vec):
+        """Placement of a per-slot host vector (the round's t0 tokens,
+        the draft positions) before it enters the draft decode
+        executable: every call must see it where the executable's own
+        outputs live, or the first call traces a second program."""
+        return vec
 
     # -- draft functional forward -------------------------------------------
     def _run_draft(self, params, lk, lv, pos, ids):
@@ -416,7 +418,7 @@ class SpeculativeEngine(PagedGenerationEngine):
         and pipeline-parallel verify paths."""
         dk = [l.k for l in self._draft_kv]
         dv = [l.v for l in self._draft_kv]
-        dpos = jnp.asarray(self._draft_pos)
+        dpos = self._draft_feed(jnp.asarray(self._draft_pos))
         feed = self._draft_feed(jnp.asarray(self._last_tokens))
         # the window stays ON DEVICE: fetching each proposal to host
         # would serialize the γ draft dispatches on a round-trip sync

@@ -1,5 +1,5 @@
-"""BERT encoder (reference capability: BERT-base pretraining config in
-BASELINE.md; built from paddle_tpu.nn.TransformerEncoder)."""
+"""BERT encoder (reference capability: the BERT-base pretraining
+configuration of examples/README.md; built from paddle_tpu.nn.TransformerEncoder)."""
 from dataclasses import dataclass
 
 from ...nn import (Dropout, Embedding, Layer, LayerNorm, Linear, Tanh,

@@ -1,4 +1,4 @@
-"""ERNIE 3.0 encoder family (BASELINE.md driver config: "ERNIE-3.0-Base,
+"""ERNIE 3.0 encoder family (reference configuration, examples/README.md: "ERNIE-3.0-Base,
 mp+pp hybrid").
 
 Reference lineage: ERNIE is the PaddlePaddle flagship encoder — a BERT-style

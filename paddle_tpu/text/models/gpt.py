@@ -1,7 +1,8 @@
 """GPT model family — the flagship decoder LM.
 
 Reference capability: PaddleNLP-style GPT trained via fleet hybrid parallel
-(BASELINE.md GPT-3 1.3B/6.7B configs). TPU-native: pre-LN transformer with
+(the GPT-3 1.3B/6.7B reference configurations, examples/README.md).
+TPU-native: pre-LN transformer with
 the Pallas flash-attention path (ops/flash_attention.py), TP-annotated
 parameters (split_axis) so the fleet/jit runner can shard over 'mp', and a
 single jit-compiled train step (see paddle_tpu.parallel.gpt_train).

@@ -1,4 +1,4 @@
-"""PP-YOLOE detection model (BASELINE.md driver config: "PP-YOLOE detection
+"""PP-YOLOE detection model (reference configuration, examples/README.md: "PP-YOLOE detection
 (conv/bn/SiLU + SyncBatchNorm allreduce) trains end-to-end").
 
 Reference lineage: PaddleDetection's PP-YOLOE (the reference repo provides

@@ -11,6 +11,8 @@ The load-bearing properties:
     per-tenant summary decomposes TTFT per tenant, and
     `serving_slo_burn{slo,window,tenant}` gauges exist in a fleet-merged
     snapshot — the ROADMAP item-5 isolation substrate;
+  - the audit log is complete: each shed request and each preemption is
+    named by exactly one decision record;
   - tenant labels are OBSERVABILITY-ONLY: a labeled run's greedy token
     streams and engine trace counts are bit-identical to an unlabeled
     run over the same engine config (zero compile-count changes).
@@ -99,13 +101,11 @@ def test_validator_catches_tampered_records():
 
 # ------------------------------- the two-tenant burst acceptance (ISSUE 15)
 
-def test_two_tenant_burst_decisions_and_per_tenant_burn(tiny, tmp_path):
-    """THE acceptance run: tenant `spike` bursts 8x into a small pool
-    behind tenant `steady`. Sheds and preemptions happen; every one is
-    reproducible from its decisions.v1 record after a JSON round trip;
-    the per-tenant summary decomposes TTFT per tenant; and the
-    per-tenant burn gauges land in a fleet-merged snapshot."""
-    jsonl = str(tmp_path / "serve.jsonl")
+@pytest.fixture(scope="module")
+def burst_run(tiny, tmp_path_factory):
+    """THE acceptance run, made once: tenant `spike` bursts 8x into a
+    small pool behind tenant `steady`; sheds and preemptions happen."""
+    jsonl = str(tmp_path_factory.mktemp("burst") / "serve.jsonl")
     traffic = load_harness.TrafficConfig(
         users=6, requests=24, prefix_len=8, max_new_tokens=4, seed=3,
         tenants={"steady": 100.0, "spike": 100.0},
@@ -116,15 +116,24 @@ def test_two_tenant_burst_decisions_and_per_tenant_burn(tiny, tmp_path):
         num_blocks=10, prefix_cache=False, max_queue=64,
         shed_watermark=3, virtual_step_s=0.01,
         serve_jsonl=jsonl, decision_sink=decisions,
-        metrics_out=str(tmp_path / "metrics.jsonl"))
+        metrics_out=os.path.join(os.path.dirname(jsonl), "metrics.jsonl"))
+    recs = [json.loads(line) for line in open(jsonl) if line.strip()]
+    return summary, decisions, recs
+
+
+def test_two_tenant_burst_decisions_and_per_tenant_burn(burst_run):
+    """Every shed and preemption of the acceptance run is reproducible
+    from its decisions.v1 record after a JSON round trip; the per-tenant
+    summary decomposes TTFT per tenant; and the per-tenant burn gauges
+    land in a fleet-merged snapshot."""
+    summary, decisions, recs = burst_run
     # the mix actually stressed the scheduler
     sheds = [d for d in decisions if d["action"] == "shed"]
     preempts = [d for d in decisions if d["action"] == "preempt"]
     assert summary["shed"] > 0 and sheds
     assert summary["preempted"] > 0 and preempts
-    # reproducibility through the artifact: parse the JSONL back and
-    # replay every decision from its recorded inputs
-    recs = [json.loads(line) for line in open(jsonl) if line.strip()]
+    # reproducibility through the artifact: the JSONL parsed back, every
+    # decision replayed from its recorded inputs
     assert serve_report.validate_records(recs) == []
     disk_decs = [r for r in recs if r["kind"] == "decision"]
     assert len(disk_decs) == len(decisions)
@@ -165,6 +174,29 @@ def test_two_tenant_burst_decisions_and_per_tenant_burn(tiny, tmp_path):
     text = serve_report.render(serve_report.summarize(recs))
     assert "decision audit log" in text
     assert "preemption-victim attribution" in text
+
+
+def test_decision_log_names_every_shed_and_preemption_once(burst_run):
+    """The audit log is COMPLETE, not only replay-valid: every request
+    that ended SHED is named by exactly one shed decision, and every
+    request's preemption count equals the number of preempt decisions
+    naming it as victim."""
+    summary, _, recs = burst_run
+    assert summary["shed"] > 0 and summary["preempted"] > 0
+    sheds, preempts = {}, {}
+    for d in (r for r in recs if r["kind"] == "decision"):
+        if d["action"] == "shed":
+            rid = d.get("request_id")
+            sheds[rid] = sheds.get(rid, 0) + 1
+        elif d["action"] == "preempt":
+            rid = d["outcome"].get("victim_request_id")
+            preempts[rid] = preempts.get(rid, 0) + 1
+    requests = [r for r in recs if r["kind"] == "request"]
+    assert len(requests) == summary["requests"]
+    for r in requests:
+        rid = r["request_id"]
+        assert sheds.get(rid, 0) == (1 if r["status"] == "SHED" else 0), r
+        assert preempts.get(rid, 0) == r["preempted"], r
 
 
 def test_tenant_labels_are_observability_only(tiny):

@@ -109,7 +109,7 @@ def test_shipped_table_is_committed_or_reported():
         pytest.skip(
             "ops/pallas/flash_blocks_tuned.json is NOT committed yet — "
             "docs/PERF_NOTES.md promises a shipped flash-block table once "
-            "an on-chip sweep runs (tools/profile_step.py); the shipped "
+            "an on-chip sweep runs; the shipped "
             "autotune tier is serving nothing")
     with open(path) as f:
         data = json.load(f)

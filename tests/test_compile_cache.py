@@ -5,8 +5,9 @@ The load-bearing properties:
   - a process (or engine) restarted against a warm cache performs ZERO
     fresh compilations for the serving executable set — proven by the
     engine trace counters staying 0 (they tick only when jax traces)
-    plus compile_cache hits, and by `bench.py --cold-start` reporting a
-    warm process strictly faster to serving-ready than a cold one;
+    plus compile_cache hits (test_engine_restart_zero_compiles,
+    test_cold_predictor_serves_warm_with_zero_compiles,
+    test_spec_engine_restart_zero_compiles);
   - cache corruption in every flavor (torn write via fault injection,
     SIGKILL inside the commit window, post-commit truncation, version
     skew) degrades to a miss-and-recompile — never a crash, never a
@@ -421,54 +422,6 @@ def test_gencfg_records_executable_set(tmp_path):
     # precompile without an engine_config is a loud error
     with pytest.raises(ValueError, match="engine_config"):
         save_for_generation(m, path, precompile=True)
-
-
-def test_bench_cold_start_rung(tmp_path):
-    """`bench.py --cold-start` emits the driver schema, the warm child
-    beats the cold child to serving-ready, and the rung's own
-    zero-compile assertions held (it would have failed otherwise). The
-    parent never initialises jax (it asserts so itself: one process per
-    chip), and every cache file of the measured children is under the
-    rung's one cache directory."""
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
-               BENCH_COLDSTART_DIR=str(tmp_path / "rung"))
-    out = subprocess.run(
-        [sys.executable, os.path.join(_ROOT, "bench.py"), "--cold-start"],
-        capture_output=True, text=True, timeout=560, env=env, cwd=_ROOT)
-    assert out.returncode == 0, out.stderr[-2000:]
-    rec = json.loads(out.stdout.strip().splitlines()[-1])
-    assert rec["metric"] == "gpt_cold_start_warm_ready_s"
-    assert "error" not in rec, rec
-    assert rec["device"]["platform"] == "cpu"
-    extra = rec["extra"]
-    assert extra["warm_beats_cold"] is True
-    assert rec["vs_baseline"] > 1.0
-    assert extra["warm"]["compile_cache"]["misses"] == 0
-    assert extra["warm"]["trace_counts"]["decode"] == 0
-    assert extra["cold"]["compile_cache"]["misses"] >= 2
-    assert extra["warm"]["first_token"] == extra["cold"]["first_token"]
-    cache_dir = str(tmp_path / "rung" / "cache")
-    assert extra["cache_dir"] == cache_dir
-    for child in ("cold", "warm"):
-        assert extra[child]["compile_cache_dir"] == \
-            os.path.join(cache_dir, "executables")
-    assert os.listdir(os.path.join(cache_dir, "executables"))
-
-
-def test_bench_failure_exits_nonzero(tmp_path):
-    """Every failure path of bench.py prints the failure record AND
-    exits non-zero — here the default train rung without a chip."""
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
-               PADDLE_TPU_POSTMORTEM_DIR=str(tmp_path / "pm"))
-    env.pop("BENCH_B", None)
-    env.pop("BENCH_REMAT", None)
-    out = subprocess.run(
-        [sys.executable, os.path.join(_ROOT, "bench.py")],
-        capture_output=True, text=True, timeout=240, env=env, cwd=_ROOT)
-    assert out.returncode != 0
-    rec = json.loads(out.stdout.strip().splitlines()[-1])
-    assert rec["value"] == 0.0 and "no TPU" in rec["error"]
-    assert rec["device"]["platform"] == "cpu"
 
 
 def test_retention_cap_evicts_lru_by_mtime(tmp_path):

@@ -72,9 +72,10 @@ def test_roofline_regimes():
     assert c2.bytes / dev.hbm_bw > c2.flops / dev.peak_flops
 
 
-def test_gpt_step_flops_match_bench_formula():
+def test_gpt_step_flops_match_model_flop_formula():
     """The analytic total over the real flagship train step must agree
-    with bench.py's 6N+attention FLOP accounting within 15% (tiny dims:
+    with the 6N+attention model-FLOP accounting
+    (benchmark/harness/model_flops.py) within 15% (tiny dims:
     embedding/LN/loss overheads are relatively larger)."""
     from paddle_tpu.parallel import GPTSpmdConfig, MeshPlan, make_train_step
     cfg = GPTSpmdConfig(vocab_size=256, max_seq_len=64, hidden=64,

@@ -1,5 +1,5 @@
 """PP-YOLOE + ERNIE model-zoo tests: forward shapes, loss decreases, PP
-descs integrate with PipelineLayer (BASELINE driver configs)."""
+descs integrate with PipelineLayer (reference driver configs)."""
 import numpy as np
 import pytest
 

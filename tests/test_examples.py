@@ -1,4 +1,4 @@
-"""The examples/ scripts (one per BASELINE row) must run end-to-end in
+"""The examples/ scripts (one per reference configuration) must run end-to-end in
 their tiny smoke configuration — subprocess-executed exactly as a user
 would, on the 8-device virtual mesh."""
 import os

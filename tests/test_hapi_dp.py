@@ -1,4 +1,4 @@
-"""Model.fit auto data parallelism (VERDICT r1 item 7; BASELINE "BERT-base
+"""Model.fit auto data parallelism (VERDICT r1 item 7; the reference's "BERT-base
 DP over 8 cores via the high-level API").
 
 Reference: hapi/model.py:190 wraps the network in DataParallel and feeds a
@@ -78,7 +78,7 @@ def test_model_dp_ragged_batch_falls_back(dp_mesh):
 
 
 def test_bert_tiny_fit_dp8(dp_mesh):
-    """BASELINE row: BERT (tiny config) trains DP x 8 through Model.fit."""
+    """The reference's BERT row (tiny config) trains DP x 8 through Model.fit."""
     paddle.seed(5)
     cfg = BertConfig(vocab_size=128, hidden_size=32, num_hidden_layers=2,
                      num_attention_heads=2, intermediate_size=64,

@@ -457,7 +457,7 @@ def test_row_parallel_input_split_grads(clean_mesh):
 
 
 def test_model_fit_ernie_tiny_pipeline(clean_mesh):
-    """BASELINE 'ERNIE mp+pp' row through the user-facing API: ERNIE-tiny
+    """The reference's 'ERNIE mp+pp' configuration through the user-facing API: ERNIE-tiny
     as a PipelineLayer (tied embeddings across first/last stage) trained by
     Model.fit over a pp=2 x dp=2 mesh, loss matching the unpipelined run."""
     import paddle_tpu.nn.functional as F

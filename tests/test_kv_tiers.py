@@ -19,6 +19,9 @@ Acceptance, mapped:
     (test_quota_spill_ordering_two_pass);
   - the ledger's tier_residency invariant catches out-of-band drops
     (test_ledger_tier_residency_divergence);
+  - a multi-stream replay under pool pressure demotes and promotes
+    chains by itself, compile-once, with the ledger clean
+    (test_replay_under_pressure_cycles_chains_through_host_tier);
   - affinity placement is deterministic and auditable
     (test_affinity_rule_units_and_record_validation);
   - two-host fleet: worker B serves a prompt whose prefix is resident
@@ -42,6 +45,7 @@ from paddle_tpu.text.models import gpt_tiny
 
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(_ROOT, "tools"))
+import load_harness  # noqa: E402
 import serve_report  # noqa: E402
 
 VOCAB = 1024
@@ -446,6 +450,67 @@ def test_ledger_tier_residency_divergence(tiny):
     assert any(msg.startswith("tier_residency:") for msg in found), found
     assert _counter("serving_kv_ledger_divergence_total",
                     invariant="tier_residency") > div0
+
+
+# ------------------------------------------- the hierarchy under load
+
+def _tier_counters():
+    flat = metrics.flatten_snapshot(metrics.registry().snapshot(),
+                                    kinds=("counter",))
+    return {k: v for k, v in flat.items()
+            if k.startswith(("serving_kv_tier_",
+                             "serving_kv_ledger_divergence_total"))}
+
+
+def test_replay_under_pressure_cycles_chains_through_host_tier(tiny,
+                                                              tmp_path):
+    """A multi-stream replay whose prefix working set is wider than the
+    pool can keep resident, on the virtual clock: eviction under that
+    pressure DEMOTES chains and returning prompts PROMOTE them back,
+    with no hand-made eviction. Then the whole working set is demoted
+    and the same mixture replayed through a fresh scheduler (the cold-
+    return wave). Over both, decode and the restore scatter each trace
+    once (one fixed-shape program serves every run length), every
+    request completes, and the ledger's tier_residency invariant stays
+    clean."""
+    block, num_blocks = 8, 16
+    traffic = load_harness.TrafficConfig(
+        users=8, requests=48, rate_rps=4000.0, prefix_pool=4,
+        prefix_len=2 * block, suffix_min=1, suffix_max=2,
+        max_new_tokens=2, seed=0)
+    before = _tier_counters()
+    engines = []
+    summary = load_harness.run_harness(
+        tiny, "paged", traffic, slots=8, max_len=64, block_size=block,
+        num_blocks=num_blocks, virtual_step_s=0.01, engine_sink=engines,
+        tier_kwargs=dict(enable_kv_tiers=True,
+                         host_tier_blocks=4 * num_blocks,
+                         disk_tier_dir=str(tmp_path / "disk"),
+                         disk_tier_blocks=8 * num_blocks))
+    eng = engines[0]
+    in_replay = _tier_counters()
+
+    def grew(now, name):
+        return now.get(name, 0) - before.get(name, 0)
+
+    assert summary["by_status"] == {"DONE": traffic.requests}, summary
+    assert grew(in_replay, "serving_kv_tier_demote_total{tier=host}") > 0
+    assert grew(in_replay, "serving_kv_tier_promote_total{tier=host}") > 0
+    eng.prefix_cache.evict(num_blocks)
+    vclock = load_harness.VirtualClock()
+    load_harness.replay(Scheduler(eng, clock=vclock),
+                        load_harness.synth_trace(traffic, VOCAB),
+                        virtual_clock=vclock)
+    after = _tier_counters()
+    assert grew(after, "serving_kv_tier_promote_total{tier=host}") > \
+        grew(in_replay, "serving_kv_tier_promote_total{tier=host}")
+    assert not [k for k in after
+                if "divergence" in k and grew(after, k)], after
+    assert eng.trace_counts["decode"] == 1
+    assert eng.trace_counts["tier_restore"] == 1
+    assert kvledger.LedgerReconciler(
+        eng.kv_ledger, eng.block_pool, eng.prefix_cache,
+        tier_store=eng.kv_tiers).check() == []
 
 
 # ------------------------------------------------------- fleet prefix cache

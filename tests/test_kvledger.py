@@ -354,7 +354,7 @@ def test_ledger_disabled_streams_bit_identical(tiny, kind):
 
 def test_block_bytes_priced_from_pool_dtype(tiny):
     """serving_kv_bytes prices a block from the engine's pool dtype:
-    the f32/int8 figures must mirror bench's equal-HBM block math."""
+    the f32/int8 figures must follow the equal-HBM block arithmetic."""
     f32 = PagedGenerationEngine(tiny, slots=2, max_len=32, block_size=4,
                                 num_blocks=6, enable_prefix_cache=False)
     cfg = tiny.cfg

@@ -8,9 +8,9 @@ Covers the tentpole + satellites end to end:
   - the flight recorder (ring capture with the profiler CLOSED, watchdog
     dump, SIGTERM dump from a STANDALONE module load — no paddle_tpu,
     no jax),
-  - bench.py's wedge path: a deliberately-hung probe must produce a
-    postmortem artifact (thread stacks + span ring + metrics snapshot)
-    referenced from the BENCH json, never a bare value 0.0,
+  - the wedge path: a child that hangs inside an open span must leave
+    a postmortem artifact (thread stacks + span ring + open spans +
+    metrics snapshot) on disk and exit non-zero,
   - cross-process trace propagation: a real forked PS server process and
     the client export chrome traces that share ONE trace id and merge
     into a single causally-linked timeline (server spans parented under
@@ -391,34 +391,45 @@ time.sleep(30)   # unreachable: the chained default handler kills us
     assert doc["threads"] and doc["metrics"] is None  # no registry loaded
 
 
-# --------------------------------------------------------- bench wedge probe
+# ------------------------------------------------- a child that wedges
 
-def test_bench_wedged_probe_leaves_postmortem_evidence(tmp_path):
-    """ISSUE 4 acceptance: a deliberately-hung bench rung produces a
-    postmortem artifact (thread stacks + span ring + metrics snapshot),
-    the failure record names it in extra — and the process exits
-    NON-ZERO: a run that did not measure never looks like one that
-    measured 0.0."""
+_WEDGED_CHILD = """
+import os, sys, time
+import paddle_tpu
+from paddle_tpu.observability import flight_recorder
+from paddle_tpu.profiler import RecordEvent, TracerEventType
+fr = flight_recorder.enable(dir=sys.argv[1])
+with RecordEvent("child.setup", TracerEventType.UserDefined):
+    pass
+fr.arm(1.0, "injected wedge", on_fire=lambda path: os._exit(3))
+with RecordEvent("child.wedged_probe", TracerEventType.UserDefined):
+    time.sleep(3600)
+"""
+
+
+def test_wedged_child_leaves_postmortem_naming_its_open_spans(tmp_path):
+    """What test_flight_recorder_watchdog_fires_and_dumps (in process)
+    cannot show: a child that hangs inside an open span is ended by its
+    own watchdog, exits non-zero, and the post-mortem it leaves on disk
+    names the span it hung in, the span it had closed, the sleeping
+    stack and the metrics registry."""
     pm_dir = str(tmp_path / "pm")
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
-               BENCH_INJECT_WEDGE_S="2",
-               PADDLE_TPU_POSTMORTEM_DIR=pm_dir)
-    proc = subprocess.run([sys.executable, os.path.join(ROOT, "bench.py")],
-                          capture_output=True, text=True, timeout=420,
-                          cwd=ROOT, env=env)
-    assert proc.returncode != 0, "a hung rung must fail the process"
-    rec = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert rec["value"] == 0.0
-    assert "watchdog" in rec["error"] and "wedge" in rec["error"]
-    extra = rec["extra"]
-    assert os.path.exists(extra["postmortem"])
-    assert "last_metrics_snapshot" in extra
-    doc = json.load(open(extra["postmortem"]))
+    child = tmp_path / "wedged_child.py"    # a file, so stacks show source
+    child.write_text(_WEDGED_CHILD)
+    proc = subprocess.run([sys.executable, str(child), pm_dir],
+                          capture_output=True, text=True, timeout=240,
+                          env=dict(os.environ, JAX_PLATFORMS="cpu",
+                                   PYTHONPATH=ROOT))
+    assert proc.returncode == 3, proc.stderr[-2000:]
+    dumps = os.listdir(pm_dir)
+    assert len(dumps) == 1, dumps
+    doc = json.load(open(os.path.join(pm_dir, dumps[0])))
     assert doc["schema"] == flight_recorder.POSTMORTEM_SCHEMA
+    assert "injected wedge" in doc["reason"]
     stacks = "\n".join("\n".join(t["stack"]) for t in doc["threads"])
     assert "time.sleep" in stacks       # the wedge is visible
-    assert any(s["name"] == "bench.pre_wedge_setup" for s in doc["spans"])
-    assert any(s["name"] == "bench.wedged_probe"
+    assert any(s["name"] == "child.setup" for s in doc["spans"])
+    assert any(s["name"] == "child.wedged_probe"
                for s in doc["open_spans"])
     assert doc["metrics"]["schema"] == metrics.SNAPSHOT_SCHEMA
 

@@ -1,6 +1,7 @@
-"""CI guard for the perf-evidence pipeline: `bench.py --profile --steps 2`
-on CPU must emit a schema-valid step-timeline JSONL + attribution report,
-and tools/perf_report.py must render both — so the artifacts a dead TPU
+"""CI guard for the perf-evidence pipeline: two tiny train steps under
+`paddle_tpu.profiler.Profiler(timeline=...)` on CPU must leave a
+schema-valid step-timeline JSONL + attribution report, and
+tools/perf_report.py must render both — so the artifacts a dead TPU
 grant leaves behind can never silently rot.
 
 ISSUE 4 extends the same guard to the unified metrics registry: the run
@@ -22,38 +23,60 @@ import perf_report  # noqa: E402
 
 
 @pytest.fixture(scope="module")
-def bench_artifacts(tmp_path_factory):
-    out_dir = str(tmp_path_factory.mktemp("benchprof"))
-    env = dict(os.environ,
-               JAX_PLATFORMS="cpu",
-               BENCH_B="2", BENCH_S="64", BENCH_LAYERS="2",
-               BENCH_HIDDEN="64", BENCH_HEADS="4", BENCH_VOCAB="512")
-    proc = subprocess.run(
-        [sys.executable, os.path.join(_ROOT, "bench.py"),
-         "--profile", "--steps", "2", "--profile-dir", out_dir],
-        capture_output=True, text=True, timeout=480, cwd=_ROOT, env=env)
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    line = proc.stdout.strip().splitlines()[-1]
-    return out_dir, json.loads(line)
+def profile_artifacts(tmp_path_factory):
+    """Two tiny GPT train steps under the profiler, in process: the step
+    timeline, the attribution report and the registry's two dumps, each
+    through its producer's own writer."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from paddle_tpu.observability import metrics
+    from paddle_tpu.parallel import GPTSpmdConfig, MeshPlan, make_train_step
+    from paddle_tpu.profiler import Profiler, RecordEvent, TracerEventType
+
+    out_dir = str(tmp_path_factory.mktemp("profile"))
+    cfg = GPTSpmdConfig(vocab_size=512, max_seq_len=64, hidden=64, layers=2,
+                        heads=4, param_dtype="float32",
+                        compute_dtype="float32")
+    step_fn, init_fn, _ = make_train_step(cfg, MeshPlan(), learning_rate=2e-4)
+    params, state = init_fn(jax.random.key(0))
+    rng = np.random.RandomState(0)
+    toks = jnp.asarray(rng.randint(0, cfg.vocab_size, (2, 64)))
+    labs = jnp.asarray(rng.randint(0, cfg.vocab_size, (2, 64)))
+    lr = jnp.float32(2e-4)
+    loss, params, state = step_fn(params, state, toks, labs, lr)   # compile
+    paths = {"timeline": os.path.join(out_dir, "step_timeline.jsonl"),
+             "attribution": os.path.join(out_dir, "attribution.md"),
+             "metrics": os.path.join(out_dir, "metrics.jsonl"),
+             "metrics_prom": os.path.join(out_dir, "metrics.prom")}
+    prof = Profiler(timer_only=True, timeline=paths["timeline"])
+    prof.start()
+    for _ in range(2):
+        with RecordEvent("train.dispatch", TracerEventType.Forward):
+            loss, params, state = step_fn(params, state, toks, labs, lr)
+            float(loss)
+        prof.step(num_samples=2 * 64)
+    prof.stop()
+    with open(paths["attribution"], "w") as f:
+        f.write(prof.analyze().render() + "\n")
+    reg = metrics.registry()
+    reg.write_snapshot(paths["metrics"])
+    with open(paths["metrics_prom"], "w") as f:
+        f.write(reg.dump_prometheus())
+    return out_dir, paths
 
 
-def test_bench_profile_emits_metric_and_artifacts(bench_artifacts):
-    out_dir, rec = bench_artifacts
-    assert "error" not in rec, rec
-    # a CPU run of the pipeline is a dry run under its own name, with no
-    # MFU: a CPU number is never written under a device metric's name
-    assert rec["metric"] == "train_pipeline_dryrun_steps"
-    assert rec["device"]["platform"] == "cpu"
-    assert rec["value"] == 2
-    assert "tokens_per_sec" not in rec["extra"]
-    arts = rec["extra"]["profile_artifacts"]
-    assert os.path.exists(arts["timeline"])
-    assert os.path.exists(arts["attribution"])
-    assert os.path.dirname(arts["timeline"]) == out_dir
+def test_profiler_leaves_timeline_and_attribution_artifacts(
+        profile_artifacts):
+    out_dir, paths = profile_artifacts
+    for path in paths.values():
+        assert os.path.getsize(path) > 0, path
+        assert os.path.dirname(path) == out_dir
 
 
-def test_timeline_jsonl_schema_valid(bench_artifacts):
-    out_dir, rec = bench_artifacts
+def test_timeline_jsonl_schema_valid(profile_artifacts):
+    out_dir, paths = profile_artifacts
     records = perf_report.load_timeline(out_dir)   # raises on any violation
     assert len(records) == 2                       # one record per step
     for r in records:
@@ -63,16 +86,15 @@ def test_timeline_jsonl_schema_valid(bench_artifacts):
         assert r["step_ms"] is None or r["step_ms"] > 0
 
 
-def test_attribution_report_names_phases(bench_artifacts):
-    out_dir, rec = bench_artifacts
+def test_attribution_report_names_phases(profile_artifacts):
+    out_dir, paths = profile_artifacts
     text = open(os.path.join(out_dir, "attribution.md")).read()
     assert "MFU attribution" in text
     assert "Forward" in text
-    assert "config: B=2 S=64" in text
 
 
-def test_perf_report_renders_and_compares(bench_artifacts):
-    out_dir, rec = bench_artifacts
+def test_perf_report_renders_and_compares(profile_artifacts):
+    out_dir, paths = profile_artifacts
     records = perf_report.load_timeline(out_dir)
     md = perf_report.render(records, title="smoke")
     assert "phase breakdown" in md and "avg step" in md
@@ -80,38 +102,35 @@ def test_perf_report_renders_and_compares(bench_artifacts):
     assert "avg step ms" in cmp_md and "+0.0%" in cmp_md
 
 
-def test_metrics_snapshot_artifact_schema_valid(bench_artifacts):
-    """The unified registry's JSONL snapshot rides the --profile artifact
-    set and must stay schema-valid (paddle_tpu.metrics.v1)."""
-    out_dir, rec = bench_artifacts
-    arts = rec["extra"]["profile_artifacts"]
-    assert os.path.exists(arts["metrics"])
-    snaps = metrics_report.load_snapshots(arts["metrics"])  # raises on rot
+def test_metrics_snapshot_artifact_schema_valid(profile_artifacts):
+    """The unified registry's JSONL snapshot rides the artifact set and
+    must stay schema-valid (paddle_tpu.metrics.v1)."""
+    out_dir, paths = profile_artifacts
+    snaps = metrics_report.load_snapshots(paths["metrics"])  # raises on rot
     assert all(metrics_report.validate_snapshot(s) == [] for s in snaps)
     names = {m["name"] for m in snaps[-1]["metrics"]}
-    # the migrated producers register on import — a bench process must
-    # carry at least the op-cache and live-memory families
+    # the migrated producers register on import — a process that has
+    # imported the package carries at least these families
     for expected in ("op_cache_hits", "op_cache_misses",
                      "live_device_bytes", "serving_tokens_total",
                      "dataloader_wait_seconds"):
         assert expected in names, f"{expected} missing from {names}"
 
 
-def test_metrics_prometheus_dump_valid(bench_artifacts):
-    out_dir, rec = bench_artifacts
-    path = rec["extra"]["profile_artifacts"]["metrics_prom"]
-    assert os.path.exists(path)
-    text = open(path).read()
+def test_metrics_prometheus_dump_valid(profile_artifacts):
+    out_dir, paths = profile_artifacts
+    text = open(paths["metrics_prom"]).read()
     errs = metrics_report.validate_prometheus(text)
     assert errs == [], errs
     assert "# TYPE op_cache_hits gauge" in text
 
 
-def test_metrics_report_compare_gates_regressions(bench_artifacts, tmp_path):
+def test_metrics_report_compare_gates_regressions(profile_artifacts,
+                                                  tmp_path):
     """The CI regression gate: --compare of a run against itself passes;
     a failure counter that grew past the threshold exits nonzero."""
-    out_dir, rec = bench_artifacts
-    mpath = rec["extra"]["profile_artifacts"]["metrics"]
+    out_dir, paths = profile_artifacts
+    mpath = paths["metrics"]
     cli = [sys.executable, os.path.join(_ROOT, "tools", "metrics_report.py")]
     ok = subprocess.run(cli + ["--compare", mpath, mpath],
                         capture_output=True, text=True, timeout=60)
@@ -447,48 +466,6 @@ def test_metrics_compare_flags_gray_failure_plane(tmp_path):
     assert "serving_hedge_primary_rate" in bad.stdout
 
 
-def test_bench_train_rung_runs_numerics_armed(bench_artifacts):
-    """ISSUE 19 satellite: the healthy bench train rung runs with the
-    sentinel plane armed, asserts ZERO latched anomalies, and ships the
-    per-site stats in extra — so every committed BENCH record doubles
-    as a numerics-health attestation."""
-    out_dir, rec = bench_artifacts
-    num = rec["extra"]["numerics"]
-    assert num["anomalies"] == 0
-    assert num["counts"] == {}
-    sites = num["sites"]
-    assert "train.param_global_norm" in sites
-    assert "train.loss" in sites
-    for site, st in sites.items():
-        assert st["finite_frac"] == 1.0, (site, st)
-
-
-def test_bench_emits_cost_model_delta(bench_artifacts):
-    """ISSUE 8 satellite (ROADMAP item 1 debt): every bench run carries
-    the analytical predicted-vs-measured block in extra, and the
-    prediction/measurement gauges ride the metrics artifact so
-    --compare can gate the gap."""
-    out_dir, rec = bench_artifacts
-    cm = rec["extra"]["cost_model"]
-    assert "error" not in cm, cm
-    assert cm["predicted_step_ms"] > 0
-    assert cm["measured_step_ms"] > 0
-    assert cm["measured_vs_predicted"] == pytest.approx(
-        cm["measured_step_ms"] / cm["predicted_step_ms"], rel=1e-3)
-    assert cm["per_op"], "per-op prediction table is empty"
-    for row in cm["per_op"].values():
-        assert row["predicted_ms"] >= 0
-        assert "delta_ms" in row and "measured_share_ms" in row
-    # the gauges landed in the registry snapshot artifact
-    snaps = metrics_report.load_snapshots(
-        rec["extra"]["profile_artifacts"]["metrics"])
-    names = {m["name"] for m in snaps[-1]["metrics"]}
-    for g in ("bench_cost_model_predicted_step_ms",
-              "bench_cost_model_measured_step_ms",
-              "bench_cost_model_measured_vs_predicted"):
-        assert g in names, f"{g} missing from snapshot"
-
-
 def _snapshot_with_gauges(counters=None, gauges=None):
     metrics = [
         {"name": n, "type": "counter", "help": "", "labelnames": [],
@@ -538,14 +515,14 @@ def test_metrics_compare_flags_cost_model_gap_growth(tmp_path):
     GROWING past the threshold is failure-class; shrinking (we got
     faster than the model expected) is not."""
     a = _snapshot_with_gauges(
-        gauges={"bench_cost_model_measured_vs_predicted": 2.0,
-                "bench_cost_model_predicted_step_ms": 10.0})
+        gauges={"train_cost_model_measured_vs_predicted": 2.0,
+                "train_cost_model_predicted_step_ms": 10.0})
     b = _snapshot_with_gauges(
-        gauges={"bench_cost_model_measured_vs_predicted": 3.5,
-                "bench_cost_model_predicted_step_ms": 10.0})
+        gauges={"train_cost_model_measured_vs_predicted": 3.5,
+                "train_cost_model_predicted_step_ms": 10.0})
     regs = metrics_report.compare_counters(a, b)
     why = {k: w for k, *_, w in regs}
-    assert why.get("bench_cost_model_measured_vs_predicted") == \
+    assert why.get("train_cost_model_measured_vs_predicted") == \
         "measured/predicted gap widened"
     # improvement or stability: clean
     assert metrics_report.compare_counters(a, a) == []
@@ -583,52 +560,6 @@ def test_metrics_compare_flags_pp_bubble_growth(tmp_path):
                          capture_output=True, text=True, timeout=60)
     assert bad.returncode == 1
     assert "bubble fraction grew" in bad.stdout
-
-
-def test_metrics_compare_flags_deviceprof_regressions(tmp_path):
-    """ISSUE 9 gate: the device-profile gauges are failure classes —
-    total device ms/step GROWING past the threshold (the kernels got
-    slower) and per-op efficiency DROPPING past it (an op moved away
-    from its roofline) both trip --compare; improvement stays clean."""
-    a = _snapshot_with_gauges(
-        gauges={"deviceprof_total_device_ms_per_step": 10.0,
-                "deviceprof_min_op_efficiency": 0.8,
-                "deviceprof_device_wall_ratio": 0.5})
-    b = _snapshot_with_gauges(
-        gauges={"deviceprof_total_device_ms_per_step": 20.0,   # grew 2x
-                "deviceprof_min_op_efficiency": 0.3,           # dropped
-                "deviceprof_device_wall_ratio": 0.5})
-    regs = metrics_report.compare_counters(a, b)
-    why = {k: w for k, *_, w in regs}
-    assert why.get("deviceprof_total_device_ms_per_step") == \
-        "device time per step grew"
-    assert why.get("deviceprof_min_op_efficiency") == \
-        "per-op device efficiency dropped"
-    # labeled per-op efficiency gauges trip the same drop rule
-    a2 = {"schema": metrics_report.SCHEMA, "ts": 1.0, "pid": 1,
-          "metrics": [{"name": "deviceprof_op_efficiency", "type": "gauge",
-                       "help": "", "labelnames": ["op"],
-                       "samples": [{"labels": {"op": "dot"}, "value": 0.9}]}]}
-    b2 = json.loads(json.dumps(a2))
-    b2["metrics"][0]["samples"][0]["value"] = 0.2
-    regs2 = metrics_report.compare_counters(a2, b2)
-    assert any(k.startswith("deviceprof_op_efficiency{op=dot") and
-               w == "per-op device efficiency dropped"
-               for k, *_, w in regs2), regs2
-    # getting FASTER / more efficient is not a regression
-    assert metrics_report.compare_counters(b, a) == []
-    assert metrics_report.compare_counters(a, a) == []
-    # and the CLI gate exits nonzero on the regressed pair
-    pa, pb = str(tmp_path / "dpa.jsonl"), str(tmp_path / "dpb.jsonl")
-    for path, rec in ((pa, a), (pb, b)):
-        with open(path, "w") as f:
-            f.write(json.dumps(rec) + "\n")
-    cli = [sys.executable, os.path.join(_ROOT, "tools", "metrics_report.py")]
-    bad = subprocess.run(cli + ["--compare", pa, pb],
-                         capture_output=True, text=True, timeout=60)
-    assert bad.returncode == 1
-    assert "device time per step grew" in bad.stdout
-    assert "per-op device efficiency dropped" in bad.stdout
 
 
 def test_validate_record_catches_rot():
@@ -880,58 +811,6 @@ def test_metrics_compare_flags_rate_limit_and_ns_eviction_growth(tmp_path):
     assert bad.returncode == 1
     assert "serving_rate_limited_total{tenant=b}" in bad.stdout
     assert "serving_prefix_ns_evicted_total{namespace=ns-a}" in bad.stdout
-
-
-@pytest.mark.slow
-def test_bench_serve_dist_emits_fleet_artifacts(tmp_path):
-    """ISSUE 12 CI: `bench.py --serve-dist` leaves the fleet
-    observability artifact set — a schema-valid `fleet_metrics.jsonl`
-    (merged metrics.v1 stream with worker_id/role-labeled series and
-    _fleet aggregates), ONE merged Prometheus exposition, and a
-    `timelines.jsonl` whose reqtimeline.v1 records validate (phase sums
-    within the 5% gate is part of validation) with one record per
-    completed request."""
-    import serve_report
-
-    obs = str(tmp_path / "obs")
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
-               BENCH_DIST_REQUESTS="6", BENCH_DIST_MAXNEW="4",
-               BENCH_DIST_DECODE_WORKERS="2", BENCH_DIST_OBS_DIR=obs)
-    out = subprocess.run(
-        [sys.executable, os.path.join(_ROOT, "bench.py"), "--serve-dist"],
-        capture_output=True, text=True, timeout=560, env=env, cwd=_ROOT)
-    rec = json.loads(out.stdout.strip().splitlines()[-1])
-    assert "error" not in rec, rec
-    extra = rec["extra"]["dist"]
-    assert extra["fleet_polls"] >= 1
-    assert extra["timeline_phase_means_s"].get("prefill", 0) > 0
-    assert extra["tail_attribution"]["dominant"]
-
-    snaps = metrics_report.load_snapshots(
-        os.path.join(obs, "fleet_metrics.jsonl"))   # raises on rot
-    members = {(s.get("labels") or {}).get("worker_id")
-               for m in snaps[-1]["metrics"] for s in m["samples"]}
-    assert {"decode0", "decode1", "prefill0", "router",
-            "_fleet"} <= members, members
-    prom = open(os.path.join(obs, "fleet_metrics.prom")).read()
-    assert metrics_report.validate_prometheus(prom) == []
-    assert 'worker_id="_fleet"' in prom
-
-    stream = [json.loads(x) for x in
-              open(os.path.join(obs, "timelines.jsonl")) if x.strip()]
-    errs = serve_report.validate_records(stream)
-    assert errs == [], errs[:5]
-    # the stream interleaves decisions.v1 records (ISSUE 15) with the
-    # timelines: one timeline per request, plus replay-valid placement
-    # decisions
-    timelines = [r for r in stream if r["kind"] == "timeline"]
-    assert len(timelines) == rec["extra"]["requests"]
-    assert any(r["kind"] == "decision" and r["action"] == "place"
-               for r in stream)
-    phases = {s["phase"] for t in timelines for s in t["phases"]}
-    assert {"queue", "prefill", "place", "decode"} <= phases, phases
-    assert any(s["phase"] == "kv_handoff"
-               for t in timelines for s in t["phases"])
 
 
 def test_metrics_compare_flags_kv_tier_regressions(tmp_path):

@@ -312,21 +312,3 @@ def test_predictor_generate_cold_load(tiny, tmp_path):
     outs = pred.generate(prompts, max_new_tokens=4, slots=2, max_len=32)
     for p, got in zip(prompts, outs):
         assert got == _reference_tokens(tiny, p, 4)
-
-
-def test_bench_decode_rung_runs():
-    """bench.py --decode emits the schema the driver parses."""
-    import json
-    import subprocess
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
-               BENCH_DECODE_STEPS="2", BENCH_DECODE_SLOTS="2",
-               BENCH_DECODE_MAXLEN="32", BENCH_DECODE_PROMPT="4")
-    out = subprocess.run(
-        [sys.executable, os.path.join(_ROOT, "bench.py"), "--decode"],
-        capture_output=True, text=True, timeout=560, env=env, cwd=_ROOT)
-    line = out.stdout.strip().splitlines()[-1]
-    rec = json.loads(line)
-    assert rec["metric"] == "gpt_decode_tokens_per_s"
-    assert "error" not in rec, rec
-    assert rec["value"] > 0
-    assert rec["extra"]["trace_counts"]["decode"] == 1

@@ -1044,38 +1044,3 @@ def test_chaos_matrix_streams_bit_exact(tiny, mode, cell):
         for w in (pw, d0, d1):
             if w is not None:
                 w.shutdown()
-
-
-@pytest.mark.slow
-def test_bench_serve_dist_rung_runs():
-    """bench.py --serve-dist emits the driver schema: forked prefill +
-    decode pools vs a single process at EQUAL KV budget, with TTFT
-    percentiles and handoff bytes in extra — and the --gray-chaos arm
-    (ISSUE 20) rides along, recording migration latency and the
-    deadline-miss delta vs the healthy arm with streams still
-    identical."""
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
-               BENCH_DIST_REQUESTS="6", BENCH_DIST_MAXNEW="4",
-               BENCH_DIST_DECODE_WORKERS="2")
-    out = subprocess.run(
-        [sys.executable, os.path.join(_ROOT, "bench.py"), "--serve-dist",
-         "--gray-chaos"],
-        capture_output=True, text=True, timeout=560, env=env, cwd=_ROOT)
-    line = out.stdout.strip().splitlines()[-1]
-    rec = json.loads(line)
-    assert rec["metric"] == "gpt_serve_dist_tokens_per_s", rec
-    assert "error" not in rec, rec
-    assert rec["value"] > 0
-    extra = rec["extra"]
-    assert extra["dist"]["kv_memory_tokens"] == \
-        extra["single"]["kv_memory_tokens"]
-    assert extra["dist"]["handoff_bytes"] > 0
-    assert extra["dist"]["requests_done"] == extra["requests"]
-    assert extra["single"]["requests_done"] == extra["requests"]
-    for arm in ("dist", "single"):
-        assert extra[arm]["ttft_p50_s"] is not None
-        assert extra[arm]["ttft_p99_s"] is not None
-    chaos = extra["gray_chaos"]
-    assert chaos["streams_identical"] is True
-    assert chaos["deadline_miss_delta_vs_healthy"] == 0
-    assert chaos["slow_s"] > 0 and chaos["victim"]

@@ -422,33 +422,3 @@ def test_scheduler_jsonl_carries_slo_fields(tiny, tmp_path):
     assert summary["prefix_hit_rate"] == 0.5
     assert summary["by_priority"] == {0: 1, 2: 1}
     assert "priority mix" in serve_report.render(summary)
-
-
-def test_bench_serve_load_rung_runs():
-    """bench.py --serve-load emits the schema the driver parses, with
-    the paged-vs-dense comparison in extra."""
-    import json
-    import subprocess
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
-               BENCH_SERVE_REQUESTS="8", BENCH_SERVE_SLOTS="2",
-               BENCH_SERVE_MAXLEN="64", BENCH_SERVE_PAGED_SLOTS="4")
-    out = subprocess.run(
-        [sys.executable, os.path.join(_ROOT, "bench.py"), "--serve-load"],
-        capture_output=True, text=True, timeout=560, env=env, cwd=_ROOT)
-    line = out.stdout.strip().splitlines()[-1]
-    rec = json.loads(line)
-    assert rec["metric"] == "gpt_serve_load_tokens_per_s"
-    assert "error" not in rec, rec
-    assert rec["value"] > 0
-    extra = rec["extra"]
-    assert extra["paged"]["trace_counts"]["decode"] == 1
-    assert extra["dense"]["trace_counts"]["decode"] == 1
-    assert extra["paged"]["kv_memory_tokens"] == \
-        extra["dense"]["kv_memory_tokens"]
-    assert extra["paged_beats_dense_concurrency"] is True
-    # ISSUE 19 satellite: the int8 arm re-runs armed with numerics taps
-    # and attests zero latched anomalies across the quant tap surfaces
-    num = extra["numerics"]
-    assert num["anomalies"] == 0
-    assert {"decode.logits", "kv.codes", "kv.scale",
-            "weights.q", "weights.scale"} <= set(num["sites"])

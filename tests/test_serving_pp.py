@@ -571,7 +571,7 @@ def test_pp_tokens_per_chip_vs_tp_only_stated_bound(tiny):
         tiny, PipelineParallelEngineConfig(
             pp=2, tp=2, num_blocks=2 * (nb - 1) + 1, **kw))
     # equal per-host HBM, measured: pp per-device bytes never exceed
-    # the TP-only engine's (the bench gate, asserted engine-level)
+    # the TP-only engine's (asserted engine-level)
     assert pp.hbm_accounting()["max_device_total"] <= \
         1.05 * tp.hbm_accounting()["max_device_total"]
     prompts = [_prompt(180 + s, 8) for s in range(4)]
@@ -658,23 +658,3 @@ def test_in_process_sampled_failover_bit_exact(tiny):
         pw.shutdown()
         for w in dws:
             w.shutdown()
-
-
-@pytest.mark.slow
-def test_bench_serve_dist_pp_stages_runs():
-    """bench.py --serve-dist --pp-stages 2: the decode pool runs
-    pipeline-parallel worker GROUPS; streams still match the
-    single-process arm and the schema carries the group shape."""
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
-               BENCH_DIST_REQUESTS="4", BENCH_DIST_MAXNEW="4",
-               BENCH_DIST_DECODE_WORKERS="2")
-    out = subprocess.run(
-        [sys.executable, os.path.join(_ROOT, "bench.py"), "--serve-dist",
-         "--pp-stages", "2"],
-        capture_output=True, text=True, timeout=560, env=env, cwd=_ROOT)
-    rec = json.loads(out.stdout.strip().splitlines()[-1])
-    assert rec["metric"] == "gpt_serve_dist_tokens_per_s", rec
-    assert "error" not in rec, rec
-    assert rec["extra"]["dist"]["engine"] == "pp"
-    assert rec["extra"]["dist"]["pp_stages"] == 2
-    assert rec["extra"]["streams_identical"] is True

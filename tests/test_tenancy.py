@@ -47,6 +47,7 @@ from paddle_tpu.text.models import GPTConfig, GPTForGeneration, gpt_tiny
 
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(_ROOT, "tools"))
+import load_harness  # noqa: E402
 import serve_report  # noqa: E402
 
 import paddle_tpu as paddle  # noqa: E402
@@ -582,6 +583,48 @@ def test_rate_limit_ahead_of_shed_with_replayable_decisions(tiny):
     assert rl[0]["inputs"]["cost"] == 10
     assert decisions.replay_rate_limit(rl[0]["inputs"]) is not None
     assert decisions.validate_records(recs) == []
+
+
+# ------------------------------------------- isolation under a burst
+def test_neighbour_burst_leaves_tenant_ttft_and_namespace_intact(tiny):
+    """Two tenants share ONE paged engine: tenant A carries an adapter,
+    a token bucket sized to its steady rate and a namespace quota;
+    tenant B is the well-behaved neighbour. The same seeded trace is
+    replayed on the virtual clock without and with A bursting 6x. Under
+    the burst B's p99 TTFT stays within 2x its own baseline (floor 0.25
+    virtual seconds), B's namespace loses no block to A's pressure, the
+    limiter (not B) absorbs A's excess, and the mixed-tenant adapter
+    batch still decodes through one executable."""
+    block, num_blocks = 8, 16
+    rate_a, rate_b = 400.0, 100.0
+    mix = dict(users=8, requests=32, prefix_len=2 * block, suffix_max=8,
+               max_new_tokens=4, seed=0,
+               tenants={"tenant_a": rate_a, "tenant_b": rate_b})
+    # A's bucket refills at its steady rate and holds ten requests of
+    # slack: baseline traffic flows, the burst overdraws and is denied
+    cost = mix["prefix_len"] + mix["suffix_max"] + mix["max_new_tokens"]
+    quota = (num_blocks - 1) // 2
+    tenancy = load_harness.build_tenancy(
+        ("tenant_a", "tenant_b"), adapters_arg="tenant_a:4",
+        quotas_arg=f"tenant_a:{quota},tenant_b:{quota}",
+        rates_arg=f"tenant_a:{rate_a * cost:.0f}/{10 * cost:.0f}")
+    arms = {}
+    for arm, burst in (("baseline", None),
+                       ("burst", {"tenant": "tenant_a", "t0": 0.0,
+                                  "dur_s": 0.05, "mult": 6.0})):
+        arms[arm] = load_harness.run_harness(
+            tiny, "paged", load_harness.TrafficConfig(burst=burst, **mix),
+            slots=4, max_len=64, block_size=block, num_blocks=num_blocks,
+            virtual_step_s=0.01, tenancy=tenancy)
+    base_b = arms["baseline"]["tenants"]["tenant_b"]
+    burst_b = arms["burst"]["tenants"]["tenant_b"]
+    burst_a = arms["burst"]["tenants"]["tenant_a"]
+    assert burst_b["requests"] > 0 and burst_b["ttft_p99_s"] is not None
+    gate_s = max(0.25, 2.0 * (base_b["ttft_p99_s"] or 0.0))
+    assert burst_b["ttft_p99_s"] <= gate_s, (burst_b, base_b)
+    assert burst_b.get("ns_blocks_evicted", 0) == 0, burst_b
+    assert burst_a.get("rate_limited", 0) > 0, burst_a
+    assert arms["burst"]["trace_counts"]["decode"] == 1
 
 
 # ------------------------------------------------- serve_report plane

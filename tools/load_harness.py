@@ -29,8 +29,7 @@ Determinism: the TRACE is fully seeded (numpy RandomState). With
 monotonic counter the harness advances by a fixed amount per step, so
 arrivals, shedding, preemption and peak concurrency are bit-reproducible
 across hosts (the tier-1 paged-vs-dense win assertion runs this mode).
-Without it, the wall clock drives arrivals — the honest-throughput mode
-`bench.py --serve-load` uses.
+Without it, the wall clock drives arrivals.
 
 Usage:
   python tools/load_harness.py --engine paged --users 8 --requests 32
@@ -357,7 +356,7 @@ def _phase_means(timeline_records):
 
 def _ttft_phase_breakdown(sched):
     """The replay-wide attribution (queue wait vs prefill vs
-    handoff/adopt vs first decode step) — a bench rung carries WHY, not
+    handoff/adopt vs first decode step) — the summary carries WHY, not
     just the TTFT total."""
     return _phase_means(sched.timeline_records())
 
@@ -586,11 +585,11 @@ def run_harness(model, kind, traffic, slots, max_len, block_size=8,
     (annotated with the engine's KV budget and compile counters).
     `engine_sink`: optional list the built (now-warmed) engine is
     appended to, so a caller can keep driving its compiled executables
-    — bench's steady-state probe, which must not pay a second build.
+    or audit its pool and ledger after the replay.
     `serve_jsonl` (ISSUE 15): write the scheduler's serving JSONL
     (step/request/timeline AND decisions.v1 records) to this path;
     `decision_sink`: optional list extended with the scheduler's
-    decision records after the replay — what bench's audit asserts
+    decision records after the replay — what an audit asserts
     over. A multi-tenant traffic config additionally judges per-tenant
     SLO burn (fleet.per_tenant_slos) across the replay and reports it
     under summary["tenant_slo_burn"].
@@ -675,8 +674,8 @@ def run_harness(model, kind, traffic, slots, max_len, block_size=8,
         summary["spec_accepted"] = m.get("spec_accepted", 0)
         summary["spec_acceptance_rate"] = m.get("spec_acceptance_rate")
         summary["gamma"] = engine.config.gamma
-    # measured per-device HBM (ISSUE 13): what the equal-per-host-HBM
-    # bench arms equalize/gate on — never dtype-width arithmetic
+    # measured per-device HBM (ISSUE 13): what equal-per-host-HBM
+    # comparisons equalize on — never dtype-width arithmetic
     summary["hbm_max_device_bytes"] = \
         engine.hbm_accounting()["max_device_total"]
     if kind in ("tp", "pp", "spec_pp"):

@@ -2,7 +2,7 @@
 
 The metrics registry (paddle_tpu.observability.metrics) writes
 schema-versioned JSONL snapshots (`paddle_tpu.metrics.v1`) and Prometheus
-text dumps — `bench.py --profile` leaves both next to its step timeline.
+text dumps (`registry().write_snapshot(path)`, `dump_prometheus()`).
 This tool is the offline half: it renders a snapshot as a table, schema-
 validates files (the CI guard in tests/test_perf_pipeline.py), and diffs
 two runs with a REGRESSION mode for CI:
@@ -28,13 +28,9 @@ threshold. Direction matters and is decided per counter name:
   - rate pairs (X_hits/X_misses incl. the persistent compile cache,
     spec accepted/proposed): the RATIO dropping past the threshold is
     failure-class even when the numerator grew with traffic,
-  - gap gauges (bench_cost_model_measured_vs_predicted): the measured/
+  - gap gauges (`*cost_model_measured_vs_predicted`): a measured/
     analytically-predicted step-time ratio GROWING past the threshold
     is failure-class — the hardware regressed or the model lost contact,
-  - device-profile gauges (ISSUE 9): `deviceprof_total_device_ms_per_step`
-    GROWING is failure-class (the kernels themselves slowed down), and
-    `deviceprof_op_efficiency{op=...}` / `deviceprof_min_op_efficiency`
-    DROPPING is failure-class (an op moved away from its roofline),
   - quantized-serving quality gauges (ISSUE 11):
     `serving_quant_greedy_match` (token agreement vs the f32 oracle)
     DROPPING and `serving_quant_logit_kl` GROWING are failure-class —
@@ -165,18 +161,12 @@ _RATE_RULES = (
 )
 
 # GAUGE rules: gauges whose GROWTH past the threshold is failure-class.
-# bench_cost_model_measured_vs_predicted is the analytical-delta gate
-# (ROADMAP item 1 debt): the bench publishes measured/predicted step
-# time every run — the ratio growing means the step got slower relative
-# to what the roofline says the hardware can do.
-# deviceprof_total_device_ms_per_step (ISSUE 9) is the device-side
-# equivalent: the XPlane capture's per-step device op time growing means
-# the kernels themselves got slower, independent of host overhead.
+# *cost_model_measured_vs_predicted is the analytical-delta gate: a
+# measured/predicted step-time ratio growing means the step got slower
+# relative to what the roofline says the hardware can do.
 _GAUGE_GROW_RULES = (
     (re.compile(r"cost_model_measured_vs_predicted(\{.*\})?$"),
      "measured/predicted gap widened"),
-    (re.compile(r"deviceprof_total_device_ms_per_step(\{.*\})?$"),
-     "device time per step grew"),
     # ISSUE 11: the quantized tier's logit divergence vs the f32 oracle
     # growing means the int8 path is drifting (scale corruption, requant
     # rot) even while tokens still mostly match
@@ -207,13 +197,7 @@ _GAUGE_FLIP_RULES = (
 )
 
 # GAUGE rules: gauges whose DROP past the threshold is failure-class.
-# deviceprof_op_efficiency{op=...} / deviceprof_min_op_efficiency
-# (ISSUE 9) carry the per-op predicted-roofline/measured-device ratio
-# from the last capture: a drop means an op moved AWAY from its roofline
-# (kernel regression, layout rot) even if the total still fits budget.
 _GAUGE_DROP_RULES = (
-    (re.compile(r"deviceprof_(?:op|min_op)_efficiency(\{.*\})?$"),
-     "per-op device efficiency dropped"),
     # ISSUE 11 quality gate: greedy-match rate vs the f32 oracle is THE
     # quantized-serving correctness headline — a drop past the threshold
     # is failure-class no matter how fast the int8 path got

@@ -1,7 +1,7 @@
 """Render a perf attribution report from saved step-timeline artifacts.
 
-The profiler's JSONL step timeline (paddle_tpu.profiler Profiler(timeline=…)
-or `bench.py --profile`) is the durable perf evidence: one record per train
+The profiler's JSONL step timeline (paddle_tpu.profiler Profiler(timeline=…))
+is the durable perf evidence: one record per train
 step with phase durations, a per-op digest, eager-cache stats, and the
 memory peak. This tool re-renders the attribution report from those files
 alone — no live backend needed — so a run's decomposition survives the TPU
@@ -20,7 +20,6 @@ import os
 import sys
 
 SCHEMA = "paddle_tpu.step_timeline.v1"
-DEVICEPROF_SCHEMA = "paddle_tpu.deviceprof.v1"
 
 # field -> (types, required)
 _FIELDS = {
@@ -83,134 +82,6 @@ def load_timeline(path):
     if not records:
         raise ValueError(f"{path}: empty timeline")
     return records
-
-
-# ------------------------------------------------- deviceprof (ISSUE 9)
-
-_DEVICEPROF_FIELDS = {
-    "schema": str, "xplane": str, "decoder": str, "plane": str,
-    "line": str, "total_device_ms": (int, float), "n_events": int,
-    "ops": list,
-}
-_DEVICEPROF_OP_FIELDS = ("op", "calls", "device_ms", "frac")
-
-
-def validate_deviceprof_record(rec):
-    """Schema violations of one paddle_tpu.deviceprof.v1 record ([] ==
-    valid). Independent of the producer (observability/deviceprof.py) on
-    purpose — the same cross-validation stance metrics_report takes."""
-    errs = []
-    if not isinstance(rec, dict):
-        return [f"record is {type(rec).__name__}, not dict"]
-    if rec.get("schema") != DEVICEPROF_SCHEMA:
-        errs.append(f"schema={rec.get('schema')!r}, "
-                    f"want {DEVICEPROF_SCHEMA!r}")
-    for field, types in _DEVICEPROF_FIELDS.items():
-        if not isinstance(rec.get(field), types):
-            errs.append(f"{field}={rec.get(field)!r} invalid")
-    for op in rec.get("ops") or []:
-        missing = [k for k in _DEVICEPROF_OP_FIELDS if k not in op]
-        if missing:
-            errs.append(f"op row {op!r} missing {missing}")
-    join = rec.get("join")
-    if join is not None and not isinstance(join, dict):
-        errs.append(f"join={join!r} not a dict")
-    if isinstance(join, dict):
-        for k in ("steps", "device_ms_per_step", "reconciles", "per_op"):
-            if k not in join:
-                errs.append(f"join missing {k!r}")
-    return errs
-
-
-def load_deviceprof(path):
-    """Parse + validate a deviceprof JSONL (or a run dir holding
-    deviceprof.jsonl); ValueError on any rot."""
-    if os.path.isdir(path):
-        path = os.path.join(path, "deviceprof.jsonl")
-    records = []
-    with open(path) as f:
-        for i, line in enumerate(f):
-            line = line.strip()
-            if not line:
-                continue
-            try:
-                rec = json.loads(line)
-            except json.JSONDecodeError as e:
-                raise ValueError(f"{path}:{i + 1}: not JSON: {e}") from None
-            errs = validate_deviceprof_record(rec)
-            if errs:
-                raise ValueError(f"{path}:{i + 1}: " + "; ".join(errs))
-            records.append(rec)
-    if not records:
-        raise ValueError(f"{path}: empty deviceprof stream")
-    return records
-
-
-def render_deviceprof(records, top=10, title="device profile"):
-    """Markdown for the LAST capture record: per-op device table + the
-    cost-model join rows when present."""
-    rec = records[-1]
-    join = rec.get("join") or {}
-    lines = [f"## {title}: {rec['plane']}",
-             f"captures: {len(records)}  ·  decoder {rec['decoder']}  ·  "
-             f"line rule {rec.get('line_rule', '?')}",
-             f"total device time {rec['total_device_ms']:.3f} ms"
-             + (f" over {join['steps']} step(s) — "
-                f"{join['device_ms_per_step']:.3f} ms/step, "
-                f"device/wall ratio "
-                f"{join.get('device_wall_ratio')} "
-                f"({'reconciles' if join.get('reconciles') else 'DOES NOT reconcile'})"
-                if join else ""),
-             "", "| op | calls | device ms | % | predicted ms | eff |",
-             "|---|---|---|---|---|---|"]
-    pred = {r["op"]: r for r in join.get("per_op", [])}
-    for op in rec["ops"][:top]:
-        j = pred.get(op["op"], {})
-        p = j.get("predicted_ms")
-        e = j.get("efficiency")
-        lines.append(
-            f"| {op['op'][:50]} | {op['calls']} | {op['device_ms']:.3f} | "
-            f"{100 * op['frac']:.1f} | "
-            f"{'-' if p is None else format(p, '.4f')} | "
-            f"{'-' if e is None else format(e, '.3f')} |")
-    return "\n".join(lines)
-
-
-def render_deviceprof_compare(a_recs, b_recs, a_name, b_name, top=10):
-    """Per-op device-time + efficiency deltas between two captures'
-    last records."""
-    a, b = a_recs[-1], b_recs[-1]
-
-    def per_step(rec):
-        join = rec.get("join") or {}
-        steps = max(join.get("steps", 1), 1)
-        ops = {o["op"]: o["device_ms"] / steps for o in rec["ops"]}
-        effs = {r["op"]: r.get("efficiency")
-                for r in join.get("per_op", [])}
-        return ops, effs, (join.get("device_ms_per_step")
-                           or rec["total_device_ms"] / steps)
-
-    a_ops, a_eff, a_tot = per_step(a)
-    b_ops, b_eff, b_tot = per_step(b)
-    d = f"{100.0 * (b_tot - a_tot) / a_tot:+.1f}%" if a_tot else "-"
-    lines = [f"# device-profile comparison: {a_name} vs {b_name}", "",
-             f"total device ms/step: {a_tot:.3f} -> {b_tot:.3f} ({d})", "",
-             "| op | A ms/step | B ms/step | delta | A eff | B eff |",
-             "|---|---|---|---|---|---|"]
-    keys = sorted(set(a_ops) | set(b_ops),
-                  key=lambda k: -(b_ops.get(k, a_ops.get(k, 0.0))))
-    for k in keys[:top]:
-        va, vb = a_ops.get(k), b_ops.get(k)
-        delta = (f"{100.0 * (vb - va) / va:+.1f}%"
-                 if va and vb is not None else "-")
-        ea, eb = a_eff.get(k), b_eff.get(k)
-        lines.append(
-            f"| {k[:50]} | "
-            f"{'-' if va is None else format(va, '.4f')} | "
-            f"{'-' if vb is None else format(vb, '.4f')} | {delta} | "
-            f"{'-' if ea is None else format(ea, '.3f')} | "
-            f"{'-' if eb is None else format(eb, '.3f')} |")
-    return "\n".join(lines)
 
 
 # ------------------------------------------------------------- aggregation
@@ -309,68 +180,22 @@ def render_compare(a_recs, b_recs, a_name, b_name):
     return "\n".join(lines)
 
 
-def _deviceprof_path(run):
-    """deviceprof.jsonl riding a run: the file itself, DIR/deviceprof.jsonl,
-    or DIR/xplane/deviceprof.jsonl (bench --xplane's default layout)."""
-    if os.path.isfile(run) and run.endswith("deviceprof.jsonl"):
-        return run
-    run_dir = run if os.path.isdir(run) else os.path.dirname(run)
-    for cand in (os.path.join(run_dir, "deviceprof.jsonl"),
-                 os.path.join(run_dir, "xplane", "deviceprof.jsonl")):
-        if os.path.exists(cand):
-            return cand
-    return None
-
-
 def main(argv=None):
     p = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    p.add_argument("run", help="step-timeline .jsonl (or its directory); "
-                               "a deviceprof.jsonl renders the device "
-                               "table alone")
+    p.add_argument("run", help="step-timeline .jsonl (or its directory)")
     p.add_argument("--compare", default=None,
                    help="second timeline to diff against")
-    p.add_argument("--deviceprof", action="store_true",
-                   help="render/compare only the device-profile capture "
-                        "(deviceprof.v1) of the run(s)")
     p.add_argument("--top", type=int, default=10)
     args = p.parse_args(argv)
-    dp_only = args.deviceprof or (os.path.isfile(args.run)
-                                  and args.run.endswith("deviceprof.jsonl"))
-    if dp_only:
-        dp_path = _deviceprof_path(args.run)
-        if dp_path is None:
-            p.error(f"no deviceprof.jsonl under {args.run}")
-        dp_recs = load_deviceprof(dp_path)
-        if args.compare:
-            other_path = _deviceprof_path(args.compare)
-            if other_path is None:
-                p.error(f"no deviceprof.jsonl under {args.compare}")
-            print(render_deviceprof_compare(
-                dp_recs, load_deviceprof(other_path),
-                args.run, args.compare, top=args.top))
-        else:
-            print(render_deviceprof(dp_recs, top=args.top))
-        return 0
     records = load_timeline(args.run)
     if args.compare:
         other = load_timeline(args.compare)
         print(render_compare(records, other, args.run, args.compare))
-        a_dp, b_dp = _deviceprof_path(args.run), \
-            _deviceprof_path(args.compare)
-        if a_dp and b_dp:
-            print()
-            print(render_deviceprof_compare(
-                load_deviceprof(a_dp), load_deviceprof(b_dp),
-                args.run, args.compare, top=args.top))
     else:
         print(render(records, top=args.top, title=f"perf report: {args.run}"))
-        dp_path = _deviceprof_path(args.run)
-        if dp_path:
-            print()
-            print(render_deviceprof(load_deviceprof(dp_path),
-                                    top=args.top))
-        # an attribution.md written by bench --profile rides along; point
-        # the reader at it rather than re-deriving roofline joins here
+        # an attribution.md written beside the timeline (the profiler's
+        # analyze().render()) rides along; point the reader at it rather
+        # than re-deriving roofline joins here
         run_dir = args.run if os.path.isdir(args.run) \
             else os.path.dirname(args.run)
         attrib = os.path.join(run_dir, "attribution.md")
