@@ -281,6 +281,34 @@ def phase_kernels(sz):
             out[f"gather_{name}_T{T}_rel_err"] = round(
                 rel_err(run_d(*args), want), 7)
             assert err < tol, f"paged {name} T={T}: rel err {err} >= {tol}"
+    # the decode kernel (T = 1 over float pools: one call walks every
+    # slot's block table and fetches only the blocks it holds) at ragged
+    # positions, table entries past a slot's blocks naming a garbage block
+    # full of inf/NaN. The CPU tests interpret it; only this compiles the
+    # DMA path.
+    qq = jnp.asarray(rng.randn(slots, 1, H, D).astype(np.float32))
+    pos_np = rng.randint(0, p["max_len"], slots).astype(np.int32)
+    pos_np[0], pos_np[-1] = 0, p["max_len"] - 1
+    live = (pos_np.astype(np.int64) + bs) // bs
+    ragged = jnp.asarray(np.where(np.arange(nb)[None, :] < live[:, None],
+                                  np.asarray(tables), 0).astype(np.int32))
+    args = (qq, kp.at[0].set(jnp.nan), vp.at[0].set(jnp.inf), ragged,
+            jnp.asarray(pos_np))
+    run_k, c1 = timed_compile(blocks.attend_kernel, *args)
+    with jax.default_matmul_precision("highest"):
+        run_o, c2 = timed_compile(blocks.attend, *args)
+    compile_s += c1 + c2
+    if not sz.tiny:
+        assert "paged_attn_decode" in run_k.as_text(), \
+            "T=1 over float pools did not reach the decode kernel"
+    got = run_k(*args)
+    assert bool(jnp.isfinite(got).all()), \
+        "decode kernel: the garbage block leaked into the output"
+    err = rel_err(got, run_o(*args))
+    out["paged_decode_rel_err"] = round(err, 7)
+    out["paged_decode_blocks_read_share"] = round(
+        float(live.sum()) / (slots * nb), 4)
+    assert err < tol, f"paged decode kernel: rel err {err} >= {tol}"
     out["paged_tol"] = tol
     log(f"paged ok: { {k: v for k, v in out.items() if 'flash' not in k} }")
     return out, compile_s

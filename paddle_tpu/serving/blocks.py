@@ -48,7 +48,7 @@ __all__ = ["BlockAllocError", "BlockPool", "PagedLayerKV",
            "alloc_quant_pools", "write", "quant_write", "gather",
            "gather_quant", "dequant", "attend", "attend_quant",
            "attend_kernel", "attend_kernel_quant", "attention_impl",
-           "attention_scope",
+           "attention_scope", "kernel_attends",
            "current_attention_impl", "blocks_for_tokens", "GARBAGE_BLOCK",
            "QMAX"]
 
@@ -369,14 +369,21 @@ def attend_quant(q, k_pool, v_pool, k_scale, v_scale, tables, pos,
 
 @_scoped
 def attend_kernel(q, k_pool, v_pool, tables, pos, scale=None):
-    """Block-table attention via the Pallas paged-attention kernel: the
-    block table is walked IN-kernel (scalar-prefetch index maps), so the
-    dense per-slot view is never materialized — same masking semantics
-    as `attend`, online-softmax numerics (float-equal, not bit-equal;
-    tile caps served through `incubate.autotune.lookup_paged_blocks`).
-    Runs in interpret mode off-TPU, so CPU tier-1 can assert exactness
-    against the gather path."""
-    from ..ops.pallas.paged_attention import paged_attention
+    """Block-table attention via the Pallas paged-attention kernels: the
+    block table is walked IN-kernel, so the dense per-slot view is never
+    materialized — same masking semantics as `attend`, online-softmax
+    numerics (float-equal, not bit-equal). One query a slot (the decode
+    step) goes to the decode kernel, which loops over the blocks a slot
+    holds and fetches no other; T > 1 (prefill, speculative verify) goes
+    to the grid-per-block kernel (tile caps served through
+    `incubate.autotune.lookup_paged_blocks`). Both run in interpret mode
+    off-TPU, so CPU tier-1 can assert exactness against the gather
+    path."""
+    from ..ops.pallas.paged_attention import (
+        paged_attention, paged_decode_attention)
+    if _decode_kernel_takes(q, k_pool):
+        return paged_decode_attention(q, k_pool, v_pool, tables, pos,
+                                      scale=scale)
     return paged_attention(q, k_pool, v_pool, tables, pos, scale=scale)
 
 
@@ -395,11 +402,15 @@ def attend_kernel_quant(q, k_pool, v_pool, k_scale, v_scale, tables, pos,
 
 
 # Which attend implementation GPTAttention traces for paged caches:
-# "gather" (the bit-exact dense-view oracle) or "kernel" (the in-kernel
-# block-table walk). A module-level flag read at TRACE time: the engines
-# wrap every executable call in `attention_impl(...)` so each engine's
-# executables bake in its configured impl, and the two impls are distinct
+# "gather" (the bit-exact dense-view oracle), "kernel" (the in-kernel
+# block-table walk at every T) or "decode_kernel" (the kernel where there
+# is one query a slot, the gather for prefill and verify windows: what an
+# engine resolves its unset `attention_impl` to on a TPU; not a value a
+# configuration can spell). A module-level flag read at TRACE time: the
+# engines wrap every executable call in `attention_impl(...)` so each
+# engine's executables bake in its resolved impl, and the arms are distinct
 # function objects so the eager op-cache can never replay the wrong one.
+_ATTEND_IMPLS = ("gather", "kernel", "decode_kernel")
 _ATTEND_IMPL = "gather"
 
 
@@ -407,13 +418,29 @@ def current_attention_impl():
     return _ATTEND_IMPL
 
 
+def _decode_kernel_takes(q, k_pool):
+    from ..ops.pallas.paged_attention import decode_kernel_takes
+    return q.shape[1] == 1 and k_pool.dtype != jnp.int8 \
+        and decode_kernel_takes(q.shape[2], q.shape[3], k_pool.dtype)
+
+
+def kernel_attends(q, k_pool):
+    """Trace time: whether the paged attention of `q` [S, T, h, d] over
+    `k_pool` takes the kernel arm under the scoped implementation.
+    "decode_kernel" means the decode kernel or the gather, never the
+    grid-per-block kernel: a decode shape it cannot be compiled for (a
+    head size under a lane's width) gathers."""
+    return _ATTEND_IMPL == "kernel" or (
+        _ATTEND_IMPL == "decode_kernel" and _decode_kernel_takes(q, k_pool))
+
+
 @contextlib.contextmanager
 def attention_impl(impl):
     """Scope the paged-attend implementation for code traced inside."""
     global _ATTEND_IMPL
-    if impl not in ("gather", "kernel"):
+    if impl not in _ATTEND_IMPLS:
         raise ValueError(f"unknown paged attention impl {impl!r} "
-                         f"(want 'gather' or 'kernel')")
+                         f"(want one of {_ATTEND_IMPLS})")
     prev = _ATTEND_IMPL
     _ATTEND_IMPL = impl
     try:

@@ -534,8 +534,8 @@ class PipelineParallelPagedEngine(PagedGenerationEngine):
                          {"slots": c.slots, "paged": True, "pp": c.pp,
                           "tp": c.tp, "microbatches": M,
                           "kv_dtype": c.kv_dtype,
-                          "attend": c.attention_impl}), \
-                blocks.attention_impl(c.attention_impl):
+                          "attend": self.attention_impl}), \
+                blocks.attention_impl(self.attention_impl):
             out_nxt = self._ride_ring(self._decode_tbl, M, stage_call)
         for sink in sinks:
             self._ingest_numerics(sink)
@@ -748,7 +748,7 @@ class PipelineParallelPagedEngine(PagedGenerationEngine):
         H = self._model.cfg.hidden_size
         key = self._warm_key()
         out = {}
-        with blocks.attention_impl(c.attention_impl):
+        with blocks.attention_impl(self.attention_impl):
             for s, st in enumerate(self._stages):
                 mb_tables = jnp.asarray(self._tables[:mbs])
                 mb_pos = jnp.asarray(self._pos[:mbs])
@@ -1018,8 +1018,8 @@ class PipelineParallelSpeculativeEngine(_spec.SpeculativeEngine,
                          TracerEventType.UserDefined,
                          {"window": W, "slots": c.slots, "pp": c.pp,
                           "microbatches": M,
-                          "attend": c.attention_impl}), \
-                blocks.attention_impl(c.attention_impl):
+                          "attend": self.attention_impl}), \
+                blocks.attention_impl(self.attention_impl):
             out = self._ride_ring(self._verify_tbl, M, stage_call)
         for sink in sinks:
             self._ingest_numerics(sink)
@@ -1072,7 +1072,7 @@ class PipelineParallelSpeculativeEngine(_spec.SpeculativeEngine,
                 self._draft_params, dk, dv, dpos,
                 jnp.asarray(0, jnp.int32), jnp.zeros((b,), jnp.int32),
                 jnp.asarray(1, jnp.int32))
-        with blocks.attention_impl(c.attention_impl):
+        with blocks.attention_impl(self.attention_impl):
             for s, st in enumerate(self._stages):
                 mb_tables = jnp.asarray(self._tables[:mbs])
                 mb_pos = jnp.asarray(self._pos[:mbs])

@@ -946,7 +946,7 @@ class PagedEngineConfig(EngineConfig):
     preempt."""
 
     def __init__(self, block_size=16, num_blocks=None,
-                 enable_prefix_cache=True, attention_impl="gather",
+                 enable_prefix_cache=True, attention_impl=None,
                  kv_dtype="float32", weight_dtype="float32",
                  capture_logits=False, enable_kv_tiers=False,
                  host_tier_blocks=64, host_tier_dtype="float32",
@@ -963,10 +963,14 @@ class PagedEngineConfig(EngineConfig):
         self.enable_prefix_cache = bool(enable_prefix_cache)
         # "gather" = dense-view oracle; "kernel" = Pallas in-kernel
         # block-table walk (ops/pallas/paged_attention.py) — validated
-        # here so a typo fails at config time, not mid-trace
-        if attention_impl not in ("gather", "kernel"):
+        # here so a typo fails at config time, not mid-trace. None (the
+        # default) leaves the choice to the engine, which resolves it at
+        # construction from the model, the pools and the platform
+        # (`PagedGenerationEngine.attention_impl`)
+        if attention_impl not in (None, "gather", "kernel"):
             raise ValueError(f"attention_impl must be 'gather' or "
-                             f"'kernel', got {attention_impl!r}")
+                             f"'kernel' (or None: the engine's choice), "
+                             f"got {attention_impl!r}")
         self.attention_impl = attention_impl
         # quantized serving (ISSUE 11): kv_dtype="int8" stores the KV
         # pools as int8 codes + per-block per-head scales (2x the token
@@ -1045,12 +1049,39 @@ class PagedGenerationEngine(GenerationEngine):
         self._counter_names = tuple(getattr(model, "serving_counters", ()))
         self.last_counters = {}
         self.state_store = None
+        # what the executables are traced with: the configured value, or
+        # the engine's own choice where the configuration leaves it open.
+        # This, not the spelled value, is what the executables' cache keys
+        # and the spans' `attend` carry
+        self.attention_impl = config.attention_impl \
+            or self._default_attention_impl(config)
         super().__init__(model, config)
         # KV-adopt executables (multi-host handoff sink, ISSUE 10): one
         # per prefill bucket, compiled on first use and counted like
         # every other executable
         self.trace_counts["adopt"] = {}
         self._adopt = {}
+
+    def _default_attention_impl(self, config):
+        """The paged attention an engine built without `attention_impl`
+        traces: on a TPU, for a model that caches K and V in float pools,
+        "decode_kernel" (`blocks.attention_impl`: one query a slot walks
+        the block table in the Pallas decode kernel, prefill and verify
+        windows gather); "gather" everywhere else: off the TPU the kernel
+        would be interpreted, a model with its own cache layout does not
+        attend through `blocks.attend`, int8 pools have no decode kernel,
+        and pools sharded over a mesh (`tp` > 1) meet a kernel that is one
+        device's program."""
+        if self._layout is not None or config.kv_dtype == "int8" \
+                or getattr(config, "tp", 1) > 1 \
+                or jax.default_backend() != "tpu":
+            return "gather"
+        return "decode_kernel"
+
+    def _compile_signature(self):
+        sig = super()._compile_signature()
+        sig["config"]["attention_impl"] = self.attention_impl
+        return sig
 
     @property
     def _decode_donate(self):
@@ -1125,11 +1156,12 @@ class PagedGenerationEngine(GenerationEngine):
         yet raises here, at construction, and not in the middle of a
         request (docs/serving.md lists them)."""
         c = self.config
-        bad = [f"{k}={getattr(c, k)!r}" for k, ok in (
-            ("kv_dtype", ("float32", "bfloat16")),
-            ("weight_dtype", ("float32", "bfloat16")),
-            ("attention_impl", ("gather",)), ("enable_kv_tiers", (False,)),
-            ("numerics_taps", (False,))) if getattr(c, k) not in ok]
+        bad = [f"{k}={v!r}" for k, v, ok in (
+            ("kv_dtype", c.kv_dtype, ("float32", "bfloat16")),
+            ("weight_dtype", c.weight_dtype, ("float32", "bfloat16")),
+            ("attention_impl", self.attention_impl, ("gather",)),
+            ("enable_kv_tiers", c.enable_kv_tiers, (False,)),
+            ("numerics_taps", c.numerics_taps, (False,))) if v not in ok]
         if bad:
             raise ValueError(
                 f"{type(self._model).__name__} declares its own cache "
@@ -1468,7 +1500,7 @@ class PagedGenerationEngine(GenerationEngine):
         pos = jnp.asarray(self._pos)
         key = self._warm_key()
         out = {}
-        with blocks.attention_impl(self.config.attention_impl):
+        with blocks.attention_impl(self.attention_impl):
             out["decode"] = self._decode.warm(
                 self._decode_params, self._pool, tables, pos,
                 jnp.zeros((self.config.slots,), jnp.int32), key,
@@ -1689,8 +1721,8 @@ class PagedGenerationEngine(GenerationEngine):
                          {"bucket": bucket, "length": plen,
                           "slot": slot, "prefix_hit_tokens": nshared,
                           "paged": True, "kv_dtype": self.config.kv_dtype,
-                          "attend": self.config.attention_impl}), \
-                blocks.attention_impl(self.config.attention_impl):
+                          "attend": self.attention_impl}), \
+                blocks.attention_impl(self.attention_impl):
             first = self._prefill_execute(slot, padded, int(suffix.size),
                                           nshared, bucket)
         self._slot_gen[slot] += 1
@@ -1753,8 +1785,8 @@ class PagedGenerationEngine(GenerationEngine):
                           "active": int(self._slot_active.sum()),
                           "paged": True,
                           "kv_dtype": self.config.kv_dtype,
-                          "attend": self.config.attention_impl}), \
-                blocks.attention_impl(self.config.attention_impl):
+                          "attend": self.attention_impl}), \
+                blocks.attention_impl(self.attention_impl):
             # the three host phases of a decode step, each a child span:
             # tables, positions, tokens and keys go up; the executable is
             # enqueued; the host blocks until positions and tokens are
@@ -1774,6 +1806,14 @@ class PagedGenerationEngine(GenerationEngine):
             if self._numerics_armed:
                 self._last_decode_args = args    # the localizer's replay
             wait = {"pool_donated": self._pool_donated(args[1])}
+            if self.attention_impl != "gather":
+                # what the kernel arm fetched of the dense view the gather
+                # arm would have built, from the host's own positions
+                c = self.config
+                wait["attn_blocks_read"] = int(
+                    ((self._pos[self._slot_active] + c.block_size)
+                     // c.block_size).sum())
+                wait["attn_blocks_table"] = c.slots * c.max_blocks_per_slot
             with _span("serving::decode.wait", wait):
                 pos = np.array(res[2], np.int32)         # owned, writable
                 out = np.asarray(res[0], np.int32)
@@ -1798,7 +1838,7 @@ class PagedGenerationEngine(GenerationEngine):
         return out
 
     def _probe_context(self):
-        return blocks.attention_impl(self.config.attention_impl)
+        return blocks.attention_impl(self.attention_impl)
 
     def _fire_kv_quant_chaos(self):
         """The `serving.kv_quant` chaos site (truncate mode, like the
@@ -1949,7 +1989,7 @@ class PagedGenerationEngine(GenerationEngine):
                              TracerEventType.UserDefined,
                              {"slot": slot, "tokens": plen,
                               "bucket": bucket, "blocks": n}), \
-                    blocks.attention_impl(self.config.attention_impl):
+                    blocks.attention_impl(self.attention_impl):
                 self._adopt_scatter(slot, bucket, pad_ks, pad_vs)
         except Exception:
             self.reset_slot(slot)           # never strand the blocks

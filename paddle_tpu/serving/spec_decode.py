@@ -362,7 +362,7 @@ class SpeculativeEngine(PagedGenerationEngine):
         out["draft_decode"] = self._draft_decode.warm(
             self._draft_decode_params, dk, dv, dpos,
             jnp.zeros((c.slots,), jnp.int32))
-        with blocks.attention_impl(c.attention_impl):
+        with blocks.attention_impl(self.attention_impl):
             out["spec_verify"] = self._spec_verify.warm(
                 self._decode_params, self._pool,
                 jnp.asarray(self._tables), jnp.asarray(self._pos),
@@ -457,8 +457,8 @@ class SpeculativeEngine(PagedGenerationEngine):
         with RecordEvent("serving::spec_verify",
                          TracerEventType.UserDefined,
                          {"window": gamma + 1, "slots": c.slots,
-                          "attend": c.attention_impl}), \
-                blocks.attention_impl(c.attention_impl):
+                          "attend": self.attention_impl}), \
+                blocks.attention_impl(self.attention_impl):
             vres = self._spec_verify(
                 self._decode_params, self._pool,
                 jnp.asarray(self._tables), jnp.asarray(self._pos), window,

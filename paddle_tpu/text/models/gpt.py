@@ -83,7 +83,7 @@ class GPTAttention(Layer):
         q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]  # B,S,h,d
         if cache is not None and tables is not None:
             from ...serving import blocks as _blk
-            kernel = _blk.current_attention_impl() == "kernel"
+            kernel = _blk.kernel_attends(q._data, cache.k._data)
             if hasattr(cache, "k_scale"):
                 # QUANTIZED pool (serving.blocks.QuantPagedLayerKV): the
                 # write requantizes the touched blocks (abs-max per block
@@ -106,7 +106,8 @@ class GPTAttention(Layer):
             v_pool = apply_op(_blk.write, cache.v, v, tables, pos)
             # trace-time dispatch (serving.blocks.attention_impl):
             # "gather" rebuilds the dense view (bit-exact oracle),
-            # "kernel" walks the block table inside the Pallas kernel —
+            # "kernel" walks the block table inside a Pallas kernel,
+            # "decode_kernel" does so for one query a slot only —
             # distinct function objects, so executables can never mix
             attend = _blk.attend_kernel if kernel else _blk.attend
             out = apply_op(attend, q, k_pool, v_pool, tables, pos)

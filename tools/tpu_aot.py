@@ -57,7 +57,8 @@ def kernel_cases():
 
     from paddle_tpu.incubate import autotune
     from paddle_tpu.ops.pallas.flash_attention import flash_attention
-    from paddle_tpu.ops.pallas.paged_attention import paged_attention
+    from paddle_tpu.ops.pallas.paged_attention import (
+        paged_attention, paged_decode_attention)
 
     S = jax.ShapeDtypeStruct
     table = autotune._read_cache_file(autotune._SHIPPED_PATH)
@@ -115,6 +116,25 @@ def kernel_cases():
             continue
         _, _, H, L, D, bs = key
         paged_case("row", H, L, D, bs, tuple(caps))
+
+    def decode_case(H, L, D, bs, dtype, slots=8):
+        nb = L // bs
+        pool = S((slots * nb + 1, bs, H, D), dtype)
+
+        def fn(q, k, v, tables, pos):
+            return paged_decode_attention(q, k, v, tables, pos,
+                                          interpret=False)
+        cases.append((
+            f"paged_decode H{H} L{L} D{D} bs{bs} {jnp.dtype(dtype).name}",
+            fn, (S((slots, 1, H, D), jnp.float32), pool, pool,
+                 S((slots, nb), jnp.int32), S((slots,), jnp.int32))))
+
+    # the decode kernel at the serve cell's shape, over bfloat16 pools, at
+    # block size 8, and at twice the heads and head size
+    decode_case(16, 1024, 128, 16, jnp.float32)
+    decode_case(16, 1024, 128, 16, jnp.bfloat16)
+    decode_case(16, 1024, 128, 8, jnp.float32)
+    decode_case(32, 2048, 256, 16, jnp.bfloat16, slots=4)
     return cases
 
 
