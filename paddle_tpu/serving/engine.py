@@ -1049,6 +1049,8 @@ class PagedGenerationEngine(GenerationEngine):
         self._counter_names = tuple(getattr(model, "serving_counters", ()))
         self.last_counters = {}
         self.state_store = None
+        self._latent_layers = sum(isinstance(spec, blocks.LatentSpec)
+                                  for spec in self._layout or ())
         # what the executables are traced with: the configured value, or
         # the engine's own choice where the configuration leaves it open.
         # This, not the spelled value, is what the executables' cache keys
@@ -1192,12 +1194,14 @@ class PagedGenerationEngine(GenerationEngine):
         # so mid-decode block growth evicts under the same requester
         self._slot_namespace = {}
         self.block_pool = blocks.BlockPool(c.num_blocks, c.block_size)
-        # block-identity prefix reuse is wrong for a model with per-slot
-        # state (the state at the end of a shared prefix is not in any
-        # block): its cache reports no hit and counts the lookups
+        # block-identity prefix reuse is wrong for a model with its own
+        # cache layout, state or no state: the layout prefill runs a
+        # request's tokens from position 0 (it attends over its own tokens
+        # only, and a per-slot state at the end of a shared prefix is in no
+        # block). Its cache reports no hit and counts the lookups
         self.prefix_cache = PrefixCache(
             self.block_pool, c.block_size,
-            bypass=self.state_store is not None) \
+            bypass=self._layout is not None) \
             if c.enable_prefix_cache else None
         # KV attribution ledger (observability.kvledger): because every
         # engine kind — paged, spec, tp, pp, spec_pp — funnels through
@@ -1706,8 +1710,9 @@ class PagedGenerationEngine(GenerationEngine):
         self._tables[slot] = row
         self._slot_active[slot] = True
         self._slot_namespace[slot] = namespace
+        assert not (nshared and self._layout is not None), \
+            "the layout prefill starts at 0"
         if self.state_store is not None:
-            assert nshared == 0, "a stateful model's prefill starts at 0"
             self.state_store.acquire(slot)
         seed, gen = rng if rng is not None \
             else (self._default_slot_seed(), 0)
@@ -1814,6 +1819,17 @@ class PagedGenerationEngine(GenerationEngine):
                     ((self._pos[self._slot_active] + c.block_size)
                      // c.block_size).sum())
                 wait["attn_blocks_table"] = c.slots * c.max_blocks_per_slot
+            if self._latent_layers:
+                # the latent rows resident for the active slots, the new
+                # token's included, and those the arm that ran read of
+                # them: the gather arm builds the dense view of every
+                # slot's whole table, a layer
+                c = self.config
+                wait["latent_rows_held"] = self._latent_layers * int(
+                    (self._pos[self._slot_active] + 1).sum())
+                if self.attention_impl == "gather":
+                    wait["latent_rows_read"] = self._latent_layers \
+                        * c.slots * c.max_blocks_per_slot * c.block_size
             with _span("serving::decode.wait", wait):
                 pos = np.array(res[2], np.int32)         # owned, writable
                 out = np.asarray(res[0], np.int32)
@@ -2236,6 +2252,21 @@ class PagedGenerationEngine(GenerationEngine):
 
     def slot_positions(self):
         return self._pos.copy()
+
+    def layout_gauges(self):
+        """What `serving::step` says of a model with its own cache layout
+        as a step ends: both kinds of cache in bytes (a model without
+        per-slot state holds 0 of it) and the prefix lookups that were
+        refused. None for a model that caches K and V."""
+        if self._layout is None:
+            return None
+        store, cache = self.state_store, self.prefix_cache
+        return {
+            "state_slots_in_use": 0 if store is None else store.in_use,
+            "state_bytes": 0 if store is None else store.bytes_in_use,
+            "latent_bytes_in_use":
+                self.block_pool.in_use * self.kv_block_bytes,
+            "prefix_cache_bypassed": 0 if cache is None else cache.bypassed}
 
 
 def _engine_kind(config):

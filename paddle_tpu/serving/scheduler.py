@@ -873,17 +873,11 @@ class Scheduler:
         attrs["kv_tokens_held"] = sum(
             int(pos[slot]) for slot, req in enumerate(self._slots)
             if req is not None)
-        store = getattr(self.engine, "state_store", None)
-        if store is not None:
-            # a model with per-slot state beside its paged rows: both kinds
-            # of cache in bytes, and the prefix lookups that were refused
-            attrs["state_slots_in_use"] = store.in_use
-            attrs["state_bytes"] = store.bytes_in_use
-            attrs["latent_bytes_in_use"] = \
-                pool.in_use * self.engine.kv_block_bytes
-            cache = self.engine.prefix_cache
-            attrs["prefix_cache_bypassed"] = \
-                cache.bypassed if cache is not None else 0
+        gauges = getattr(self.engine, "layout_gauges", None)
+        if gauges is not None:
+            # a model with its own cache layout: per-slot state and paged
+            # rows in bytes, and the prefix lookups that were refused
+            attrs.update(gauges() or {})
 
     def _step(self):
         self.apply_pending_swap()

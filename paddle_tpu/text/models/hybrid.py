@@ -3,11 +3,16 @@
 Mixers: `kda` (linear attention: the gated delta rule with a per-channel
 decay, a short causal convolution and SiLU; its cache is a per-slot state
 matrix and convolution tail) and `mla` (softmax attention over a shared
-latent: its cache is one paged row a token). Feed-forwards: `swiglu` (dense)
-and `moe` (sigmoid-scored, group-limited top-k experts plus a shared expert,
-of which this chip may hold a share: `num_experts` of `n_routed_experts`,
-global ids from `experts_first`). RMSNorm, partial rotary on the MLA layers
-only, untied head. `hybrid_ops.py` has the mathematics.
+latent: its cache is one paged row a token; queries straight from the
+hidden state or through a bottleneck `q_lora_rank` wide, a head-wise output
+gate or none, plain or YaRN-scaled rotary: what the configuration
+declares). Feed-forwards: `swiglu` (dense) and `moe` (sigmoid-scored,
+group-limited top-k experts plus a shared expert, of which this chip may
+hold a share: `num_experts` of `n_routed_experts`, global ids from
+`experts_first`). The mixers come from the configuration's `mixers` list
+(one name a layer) or, without one, from the rule "every
+`layer_group_size`-th layer is MLA, the others KDA". RMSNorm, partial rotary
+on the MLA layers only, untied head. `hybrid_ops.py` has the mathematics.
 
 The model serves through `serving.PagedGenerationEngine` like GPT does; what
 differs is that it tells the engine what each layer caches
@@ -26,7 +31,8 @@ from . import hybrid_ops as ops
 
 # parameters that stay float32 whatever the weights' type
 FLOAT32_LEAVES = ("norm1", "norm2", "norm_f", "a_log", "bf", "onorm",
-                  "cnorm", "router", "router_bias")
+                  "cnorm", "qnorm", "router", "router_bias")
+_NORM_LEAVES = ("norm1", "norm2", "norm_f", "onorm", "cnorm", "qnorm")
 _RESIDUAL_LEAVES = ("wo", "w_down", "we_down", "ws_down")
 
 
@@ -38,17 +44,21 @@ class HybridConfig:
     num_heads: int = 2
     head_dim: int = 16                   # KDA key and value size per head
     layer_group_size: int = 6            # every group's last layer is MLA
+    mixers: tuple = None                 # one mixer a layer; None: that rule
     first_k_dense: int = 1               # leading layers with a dense FFN
     intermediate_size: int = 128
     max_position_embeddings: int = 256
     rms_norm_eps: float = 1e-6
     conv_kernel: int = 4
     kda_lower_bound: float = -5.0
+    q_lora_rank: int = None              # MLA's query bottleneck, or none
     kv_lora_rank: int = 32
     qk_nope_head_dim: int = 16
     qk_rope_head_dim: int = 8
     v_head_dim: int = 16
+    mla_gate: bool = True                # MLA's head-wise output gate
     rope_theta: float = 6e6
+    rope_scaling: dict = None            # YaRN's numbers, or plain rotary
     n_routed_experts: int = 16           # the router's width
     num_experts: int = 16                # held here
     experts_first: int = 0               # global id of the first held
@@ -68,11 +78,23 @@ class HybridConfig:
         if not 0 <= self.experts_first <= \
                 self.n_routed_experts - self.num_experts:
             raise ValueError("the held experts lie outside the routed ones")
+        if self.mixers is not None:
+            self.mixers = tuple(self.mixers)
+            if len(self.mixers) != self.num_layers \
+                    or set(self.mixers) - {"kda", "mla"}:
+                raise ValueError(
+                    f"mixers must name 'kda' or 'mla' for each of the "
+                    f"{self.num_layers} layers, got {self.mixers!r}")
+        if self.rope_scaling and self.rope_scaling.get("type") != "yarn":
+            raise ValueError(f"rope_scaling: only YaRN is built, got "
+                             f"{self.rope_scaling!r}")
 
     def layer_kinds(self):
-        return [("mla" if (i + 1) % self.layer_group_size == 0 else "kda",
-                 "swiglu" if i < self.first_k_dense else "moe")
-                for i in range(self.num_layers)]
+        mixers = self.mixers or [
+            "mla" if (i + 1) % self.layer_group_size == 0 else "kda"
+            for i in range(self.num_layers)]
+        return [(mixer, "swiglu" if i < self.first_k_dense else "moe")
+                for i, mixer in enumerate(mixers)]
 
 
 def leaf_shapes(cfg, kinds):
@@ -88,11 +110,18 @@ def leaf_shapes(cfg, kinds):
                     "wg": (h, c), "onorm": (cfg.head_dim,), "wo": (c, h)})
     else:
         nope, rope = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
-        out.update({"wq": (h, n * (nope + rope)),
-                    "wa": (h, cfg.kv_lora_rank + rope),
+        if cfg.q_lora_rank:
+            out.update({"wq_a": (h, cfg.q_lora_rank),
+                        "qnorm": (cfg.q_lora_rank,),
+                        "wq_b": (cfg.q_lora_rank, n * (nope + rope))})
+        else:
+            out["wq"] = (h, n * (nope + rope))
+        out.update({"wa": (h, cfg.kv_lora_rank + rope),
                     "cnorm": (cfg.kv_lora_rank,),
-                    "wkvb": (cfg.kv_lora_rank, n * (nope + cfg.v_head_dim)),
-                    "wgate": (h, n), "wo": (n * cfg.v_head_dim, h)})
+                    "wkvb": (cfg.kv_lora_rank, n * (nope + cfg.v_head_dim))})
+        if cfg.mla_gate:
+            out["wgate"] = (h, n)
+        out["wo"] = (n * cfg.v_head_dim, h)
     if ffn == "swiglu":
         f = cfg.intermediate_size
         out.update({"w_gate": (h, f), "w_up": (h, f), "w_down": (f, h)})
@@ -120,7 +149,7 @@ class _Leaves(Layer):
                 else jnp.dtype(cfg.param_dtype)
             if not cfg.init_weights:
                 data = jnp.zeros((), dtype)
-            elif leaf.startswith("norm") or leaf in ("onorm", "cnorm"):
+            elif leaf in _NORM_LEAVES:
                 data = jnp.ones(shape, dtype)
             elif leaf in ("a_log", "bf", "router_bias"):
                 data = jnp.zeros(shape, dtype)
@@ -269,7 +298,7 @@ class HybridDecoder(Layer):
                 new_pool.append(blocks.LatentLayer(rows))
                 h = h + ops.mla_decode(
                     q_n[:, 0], q_r[:, 0], blocks.gather_rows(rows, tables),
-                    pos, gate[:, 0], w, cfg)
+                    pos, None if gate is None else gate[:, 0], w, cfg)
             h, counters = self._ffn(h, w, ffn, live, counters)
         logits, counters = self._finish(params, h, counters)
         return logits[:, None], tuple(new_pool), counters

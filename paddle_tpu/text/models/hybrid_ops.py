@@ -8,6 +8,8 @@ Plain XLA; `text/models/hybrid.py` wires them into a model and
 Weights are bfloat16 (matmuls accumulate in float32), the residual stream,
 the norms, the router and the recurrent state are float32.
 """
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -184,14 +186,61 @@ def kda_chunked(q, k, v, g, beta, valid):
 
 # ------------------------------------------------------------------- MLA
 
-def rotary(x, positions, theta):
+def _yarn_mscale(scaling, a):
+    """YaRN's m(a) = 0.1 a ln(factor) + 1 (1 where nothing is stretched)."""
+    return 0.1 * a * math.log(scaling["factor"]) + 1.0 \
+        if scaling["factor"] > 1 else 1.0
+
+
+def mla_scale(cfg):
+    """The softmax scale of the MLA layers: (nope + rope)^-0.5, times
+    m(mscale_all_dim)^2 under YaRN."""
+    scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    s = cfg.rope_scaling
+    if s and s.get("mscale_all_dim", 0):
+        scale *= _yarn_mscale(s, s["mscale_all_dim"]) ** 2
+    return scale
+
+
+def rope_frequencies(cfg):
+    """(the R/2 rotary frequencies, float32; the factor on cos and sin).
+    Plain rotary unless the configuration declares `rope_scaling` (YaRN:
+    factor s, original length L, beta_fast, beta_slow, mscale,
+    mscale_all_dim): the pairs that turn fewer than beta_slow times over L
+    positions are slowed by s, those that turn more than beta_fast times
+    keep their frequency, a linear ramp between; cos and sin carry
+    m(mscale) / m(mscale_all_dim)."""
+    rope, theta = cfg.qk_rope_head_dim, cfg.rope_theta
+    half = rope // 2
+    inv = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    s = cfg.rope_scaling
+    if not s:
+        return inv, 1.0
+
+    def pair_of(turns):
+        return rope * math.log(s["original_max_position_embeddings"]
+                               / (2 * math.pi * turns)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(pair_of(s["beta_fast"])), 0)
+    high = min(math.ceil(pair_of(s["beta_slow"])), rope - 1)
+    ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low)
+                    / max(high - low, 0.001), 0.0, 1.0)
+    inv = inv * (1.0 - ramp) + inv / s["factor"] * ramp
+    return inv, _yarn_mscale(s, s.get("mscale", 1)) \
+        / _yarn_mscale(s, s.get("mscale_all_dim", 0))
+
+
+def rotary(x, positions, cfg):
     """x [..., T, (heads,) R] at `positions` [..., T], half-split pairs."""
     half = x.shape[-1] // 2
-    inv = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    inv, factor = rope_frequencies(cfg)
     ang = positions.astype(jnp.float32)[..., None] * inv
     if x.ndim == ang.ndim + 1:                                # a heads axis
         ang = ang[..., None, :]
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
     a, b = x[..., :half], x[..., half:]
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], -1)
 
@@ -199,40 +248,76 @@ def rotary(x, positions, theta):
 def mla_project(x, w, cfg, positions):
     """(q_n [..., T, n, nope], rotated q_r [..., T, n, rope], the latent row
     [..., T, rank + rope] = [RMSNorm(c), rotated k_r] as cached, the
-    head-wise gate [..., T, n])."""
+    head-wise gate [..., T, n] or None). Queries come through the
+    bottleneck `W_qb RMSNorm(W_qa x)` where the configuration declares
+    `q_lora_rank`, else straight from `W_q x`; the gate is there unless
+    `mla_gate` is off."""
     n = cfg.num_heads
     nope, rope, rank = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, \
         cfg.kv_lora_rank
-    q = mm("...h,hc->...c", x, w["wq"]).reshape(x.shape[:-1]
-                                                + (n, nope + rope))
-    q_n, q_r = q[..., :nope], rotary(q[..., nope:], positions,
-                                     cfg.rope_theta)
+    if cfg.q_lora_rank:
+        q = mm("...c,cd->...d",
+               rms_norm(mm("...h,hc->...c", x, w["wq_a"]), w["qnorm"],
+                        cfg.rms_norm_eps), w["wq_b"])
+    else:
+        q = mm("...h,hc->...c", x, w["wq"])
+    q = q.reshape(x.shape[:-1] + (n, nope + rope))
+    q_n, q_r = q[..., :nope], rotary(q[..., nope:], positions, cfg)
     a = mm("...h,hc->...c", x, w["wa"])
     latent = jnp.concatenate(
         [rms_norm(a[..., :rank], w["cnorm"], cfg.rms_norm_eps),
-         rotary(a[..., rank:], positions, cfg.rope_theta)], -1)
-    gate = jax.nn.sigmoid(mm("...h,hn->...n", x, w["wgate"]))
+         rotary(a[..., rank:], positions, cfg)], -1)
+    gate = jax.nn.sigmoid(mm("...h,hn->...n", x, w["wgate"])) \
+        if cfg.mla_gate else None
     return q_n, q_r, latent.astype(jnp.bfloat16), gate
+
+
+# the float32 scores one block of prefill queries may take: [n, block, keys]
+MLA_SCORE_BYTES = 1 << 29
+
+
+def mla_prefill_block(n, t):
+    """Queries a block of the prefill attention takes, so that its scores
+    against up to `t` keys stay within MLA_SCORE_BYTES: `t` (one block, the
+    whole square) where that fits, else a multiple of 128."""
+    rows = MLA_SCORE_BYTES // (4 * n * t)
+    return t if rows >= t else max(128, rows // 128 * 128)
 
 
 def mla_prefill(q_n, q_r, latent, gate, w, cfg):
     """Expanded form over one request's own tokens [T] (a request starts at
     position 0: no earlier rows to read). Padding sits after the real
-    tokens, so causality keeps it out of every row that is read."""
+    tokens, so causality keeps it out of every row that is read. Queries go
+    a block at a time (`mla_prefill_block`) against the keys up to the
+    block's last row: the triangle's work, and scores that fit whatever
+    `n * T * T` is."""
     with jax.named_scope("mla_attn"):
         n, nope, vd = cfg.num_heads, cfg.qk_nope_head_dim, cfg.v_head_dim
         rank = cfg.kv_lora_rank
         t = latent.shape[0]
         kv = mm("tr,rc->tc", latent[:, :rank], w["wkvb"]) \
             .reshape(t, n, nope + vd)
-        scores = (mm("qnd,knd->nqk", q_n, kv[..., :nope])
-                  + mm("qnd,kd->nqk", q_r, latent[:, rank:])) \
-            * ((nope + cfg.qk_rope_head_dim) ** -0.5)
-        scores = jnp.where(jnp.tril(jnp.ones((t, t), bool)), scores,
-                           -jnp.inf)
-        o = mm("nqk,knd->qnd", jax.nn.softmax(scores, -1), kv[..., nope:])
-        o = (o * gate[..., None]).reshape(t, n * vd)
-        return mm("tc,ch->th", o, w["wo"])
+        scale = mla_scale(cfg)
+        block = mla_prefill_block(n, t)
+
+        def attend(lo, hi):
+            """Queries [lo, hi) against the keys before `hi`."""
+            scores = (mm("qnd,knd->nqk", q_n[lo:hi], kv[:hi, :, :nope])
+                      + mm("qnd,kd->nqk", q_r[lo:hi], latent[:hi, rank:])) \
+                * scale
+            # row i is position lo + i; a first block's mask is spelled as
+            # it always was, so the one-block program is the same program
+            seen = jnp.ones((hi - lo, hi), bool)
+            scores = jnp.where(jnp.tril(seen, lo) if lo else jnp.tril(seen),
+                               scores, -jnp.inf)
+            return mm("nqk,knd->qnd", jax.nn.softmax(scores, -1),
+                      kv[:hi, :, nope:])
+
+        o = [attend(lo, min(lo + block, t)) for lo in range(0, t, block)]
+        o = o[0] if len(o) == 1 else jnp.concatenate(o)
+        if gate is not None:
+            o = o * gate[..., None]
+        return mm("tc,ch->th", o.reshape(t, n * vd), w["wo"])
 
 
 def mla_decode(q_n, q_r, rows, pos, gate, w, cfg):
@@ -247,14 +332,15 @@ def mla_decode(q_n, q_r, rows, pos, gate, w, cfg):
         q_c = mm("snd,rnd->snr", q_n, wkvb[..., :nope])
         scores = (mm("snr,slr->snl", q_c, rows[..., :rank])
                   + mm("snd,sld->snl", q_r, rows[..., rank:])) \
-            * ((nope + cfg.qk_rope_head_dim) ** -0.5)
+            * mla_scale(cfg)
         seen = jnp.arange(rows.shape[1])[None, :] <= pos[:, None]
         scores = jnp.where(seen[:, None, :], scores, -jnp.inf)
         o_c = mm("snl,slr->snr", jax.nn.softmax(scores, -1),
                  rows[..., :rank])
         o = mm("snr,rnd->snd", o_c, wkvb[..., nope:])
-        o = (o * gate[..., None]).reshape(o.shape[0], n * vd)
-        return mm("sc,ch->sh", o, w["wo"])
+        if gate is not None:
+            o = o * gate[..., None]
+        return mm("sc,ch->sh", o.reshape(o.shape[0], n * vd), w["wo"])
 
 
 # ------------------------------------------------------------- experts

@@ -260,7 +260,7 @@ def test_injected_leak_caught_within_one_step_and_gates_compare(
     name the leaked block, dump one postmortem — and the divergence
     counter must gate `metrics_report --compare` as failure-class from
     a clean zero baseline."""
-    flight_recorder.enable(dir=str(tmp_path / "pm"))
+    recorder = flight_recorder.enable(dir=str(tmp_path / "pm"))
     engine = PagedGenerationEngine(tiny, slots=2, max_len=32,
                                    block_size=4, num_blocks=12,
                                    enable_prefix_cache=False)
@@ -295,6 +295,9 @@ def test_injected_leak_caught_within_one_step_and_gates_compare(
         metrics.registry().write_snapshot(after)
     finally:
         faults.disarm("serving.kv_ledger_leak")
+        # detach from the host tracer: a recorder left attached fails
+        # whichever test file the same worker runs next and expects none
+        recorder.disable()
     # the CI gate: divergence growth from the primed-zero baseline is a
     # failure-class regression
     rc = metrics_report.main(["--compare", baseline, after])
