@@ -42,7 +42,7 @@ from ..profiler import _tracer as _TRACER
 from . import blocks
 from . import kv_cache as kvc
 from . import sampling
-from .prefix_cache import PrefixCache, prefix_key
+from .prefix_cache import PrefixCache
 
 
 @functools.partial(jax.jit, static_argnums=(1,))
@@ -1681,47 +1681,59 @@ class PagedGenerationEngine(GenerationEngine):
             raise ValueError(
                 f"prompt length {prompt.size} leaves no decode headroom "
                 f"(max_len={self.config.max_len})")
-        if self._slot_active[slot]:
-            self.reset_slot(slot)
         plen = int(prompt.size)
         bs = self.config.block_size
-        toks = [int(t) for t in prompt]
-        # record=False: the hit/miss counters tick only when this prefill
-        # STICKS — a BlockAllocError below means the scheduler will retry
-        # and a per-attempt count would inflate the gated hit rate
-        # reserve = this prompt's total block need: tier promotion may
-        # alloc to restore cold chain blocks, but never below the
-        # headroom the suffix prefill is about to claim (ISSUE 18)
-        shared_ids, nshared = ([], 0) if self.prefix_cache is None \
-            else self.prefix_cache.match(
-                toks, record=False, namespace=namespace,
-                reserve=blocks.blocks_for_tokens(plen, bs))
-        n_priv = blocks.blocks_for_tokens(plen, bs) - nshared // bs
-        try:
-            priv = self._alloc_blocks(n_priv, requester=namespace) \
-                if n_priv else []
-        except blocks.BlockAllocError:
-            for b in shared_ids:          # give back the matched refs
-                self.block_pool.unref(b)
-            raise
-        row = np.zeros((self.config.max_blocks_per_slot,), np.int32)
-        row[:len(shared_ids)] = shared_ids
-        row[len(shared_ids):len(shared_ids) + n_priv] = priv
-        self._tables[slot] = row
-        self._slot_active[slot] = True
-        self._slot_namespace[slot] = namespace
-        assert not (nshared and self._layout is not None), \
-            "the layout prefill starts at 0"
-        if self.state_store is not None:
-            self.state_store.acquire(slot)
-        seed, gen = rng if rng is not None \
-            else (self._default_slot_seed(), 0)
-        self.set_slot_rng(slot, seed, gen)
+        cache = self.prefix_cache
+        # the host's work around the executable is two siblings of
+        # `serving::prefill`, which keeps its extent (the call and the
+        # fetch): `admit` before it, `publish` after it
+        with _span("serving::prefill.admit"):
+            if self._slot_active[slot]:
+                self.reset_slot(slot)
+            cost = self._prefix_cost()
+            # the prompt's chain keys, hashed once: `match` stops at its
+            # first miss and `insert` goes on from there
+            chain = None if cache is None \
+                else cache.chain(prompt, namespace)
+            # record=False: the hit/miss counters tick only when this
+            # prefill STICKS — a BlockAllocError below means the scheduler
+            # will retry and a per-attempt count would inflate the gated
+            # hit rate
+            # reserve = this prompt's total block need: tier promotion may
+            # alloc to restore cold chain blocks, but never below the
+            # headroom the suffix prefill is about to claim (ISSUE 18)
+            shared_ids, nshared = ([], 0) if cache is None \
+                else cache.match(
+                    prompt, record=False, namespace=namespace,
+                    reserve=blocks.blocks_for_tokens(plen, bs), chain=chain)
+            n_priv = blocks.blocks_for_tokens(plen, bs) - nshared // bs
+            try:
+                priv = self._alloc_blocks(n_priv, requester=namespace) \
+                    if n_priv else []
+            except blocks.BlockAllocError:
+                for b in shared_ids:          # give back the matched refs
+                    self.block_pool.unref(b)
+                raise
+            finally:
+                self._note_prefix_cost(cost, evict=True)
+            row = np.zeros((self.config.max_blocks_per_slot,), np.int32)
+            row[:len(shared_ids)] = shared_ids
+            row[len(shared_ids):len(shared_ids) + n_priv] = priv
+            self._tables[slot] = row
+            self._slot_active[slot] = True
+            self._slot_namespace[slot] = namespace
+            assert not (nshared and self._layout is not None), \
+                "the layout prefill starts at 0"
+            if self.state_store is not None:
+                self.state_store.acquire(slot)
+            seed, gen = rng if rng is not None \
+                else (self._default_slot_seed(), 0)
+            self.set_slot_rng(slot, seed, gen)
 
-        suffix = prompt[nshared:]
-        bucket = self.bucket_for(suffix.size)
-        padded = np.zeros((bucket,), np.int32)
-        padded[:suffix.size] = suffix
+            suffix = prompt[nshared:]
+            bucket = self.bucket_for(suffix.size)
+            padded = np.zeros((bucket,), np.int32)
+            padded[:suffix.size] = suffix
         with RecordEvent("serving::prefill", TracerEventType.UserDefined,
                          {"bucket": bucket, "length": plen,
                           "slot": slot, "prefix_hit_tokens": nshared,
@@ -1730,24 +1742,41 @@ class PagedGenerationEngine(GenerationEngine):
                 blocks.attention_impl(self.attention_impl):
             first = self._prefill_execute(slot, padded, int(suffix.size),
                                           nshared, bucket)
-        self._slot_gen[slot] += 1
-        if self.prefix_cache is not None:
-            # the prompt's fully-written blocks become shareable; the
-            # matched prefix chain is already registered (touch only)
-            self.prefix_cache.insert(toks, row, (plen // bs) * bs,
-                                     namespace=namespace)
-            self.prefix_cache.record_lookup(nshared > 0)
-        tier_stats = self.prefix_cache.last_tier_stats \
-            if self.prefix_cache is not None \
-            else {"promoted_blocks": 0, "restore_s": 0.0}
-        self.last_prefill_stats = {
-            "prefix_hit_tokens": nshared, "blocks_allocated": n_priv,
-            "suffix_bucket": bucket,
-            "tier_promoted_blocks": tier_stats["promoted_blocks"],
-            "tier_restore_s": tier_stats["restore_s"]}
-        first = int(first)
-        self._last_tokens[slot] = np.int32(first)
+        with _span("serving::prefill.publish"):
+            self._slot_gen[slot] += 1
+            cost = self._prefix_cost()
+            if cache is not None:
+                # the prompt's fully-written blocks become shareable; the
+                # matched prefix chain is already registered (touch only)
+                cache.insert(prompt, row, (plen // bs) * bs,
+                             namespace=namespace, chain=chain)
+                cache.record_lookup(nshared > 0)
+            self._note_prefix_cost(cost)
+            tier_stats = cache.last_tier_stats if cache is not None \
+                else {"promoted_blocks": 0, "restore_s": 0.0}
+            self.last_prefill_stats = {
+                "prefix_hit_tokens": nshared, "blocks_allocated": n_priv,
+                "suffix_bucket": bucket,
+                "tier_promoted_blocks": tier_stats["promoted_blocks"],
+                "tier_restore_s": tier_stats["restore_s"]}
+            first = int(first)
+            self._last_tokens[slot] = np.int32(first)
         return first
+
+    def _prefix_cost(self):
+        """What the prefix cache's bookkeeping has cost so far: (token
+        updates fed to sha1, entries an eviction looked at)."""
+        cache = self.prefix_cache
+        return (0, 0) if cache is None \
+            else (cache.hashed_tokens, cache.evict_visited)
+
+    def _note_prefix_cost(self, since, evict=False):
+        """Note on the open span what the bookkeeping cost since
+        `since`, the way `pool_donated` is noted."""
+        hashed, visited = self._prefix_cost()
+        _TRACER.note("prefix_hashed_tokens", hashed - since[0])
+        if evict:
+            _TRACER.note("prefix_evict_visited", visited - since[1])
 
     def _prefill_execute(self, slot, padded, length, start, bucket):
         """Run the suffix through the bucket executable and commit the
@@ -2076,8 +2105,7 @@ class PagedGenerationEngine(GenerationEngine):
         the DistFrontend's affinity sweep calls it on every shard."""
         if self.prefix_cache is None:
             return 0
-        toks = [int(t) for t in
-                np.asarray(prompt_ids, np.int64).reshape(-1)]
+        toks = np.asarray(prompt_ids, np.int64).reshape(-1)
         return int(self.prefix_cache.probe(toks, namespace))
 
     def extract_prefix_kv(self, prompt_ids, namespace=None):
@@ -2091,16 +2119,16 @@ class PagedGenerationEngine(GenerationEngine):
         self._require_kv_layout("extract_prefix_kv")
         if self.prefix_cache is None:
             return [], [], 0
-        toks = [int(t) for t in
-                np.asarray(prompt_ids, np.int64).reshape(-1)]
+        toks = np.asarray(prompt_ids, np.int64).reshape(-1)
         bs = self.config.block_size
         cache = self.prefix_cache
+        chain = cache.chain(toks, namespace)
         nl = len(self._pool)
         parts_k = [[] for _ in range(nl)]
         parts_v = [[] for _ in range(nl)]
         n = 0
         for k in range((len(toks) - 1) // bs):
-            key = prefix_key(toks[:(k + 1) * bs], namespace)
+            key = chain.key(k)
             blk = cache._entries.get(key)
             if blk is not None:
                 for li, layer in enumerate(self._pool):
@@ -2178,15 +2206,15 @@ class PagedGenerationEngine(GenerationEngine):
                 raise ValueError(
                     f"restore layer shape {tuple(np.asarray(arr).shape)} "
                     f"!= {(int(plen),) + head_shape}")
-        toks = [int(t) for t in
-                np.asarray(prompt_ids, np.int64).reshape(-1)]
+        toks = np.asarray(prompt_ids, np.int64).reshape(-1)
         bs = self.config.block_size
         n = min(int(plen) // bs, (len(toks) - 1) // bs)
         cache = self.prefix_cache
+        chain = cache.chain(toks, namespace)
         prev_key = None
         restored = 0
         for k in range(n):
-            key = prefix_key(toks[:(k + 1) * bs], namespace)
+            key = chain.key(k)
             if key in cache._entries:
                 cache._touch(key)
                 prev_key = key

@@ -28,6 +28,13 @@ Sharing protocol (the copy-on-write invariant):
     the pool — called by the engine when an allocation comes up short,
     before the scheduler resorts to preemption.
 
+What the bookkeeping costs (ISSUE 33): a prompt's chain keys come from
+one running sha1 (`chain_keys`), lazily, and one `chain()` serves the
+`match` and the `insert` of a prefill, so a prefill hashes its prompt
+once; an eviction works from the set of leaf entries, so it costs the
+leaves resident and the blocks it frees. `hashed_tokens` and
+`evict_visited` count both.
+
 Hit/miss counters (per prefill lookup) and the resident-block gauge feed
 the unified metrics registry; `tools/metrics_report.py --compare` treats
 a prefix-hit-rate drop as a failure-class regression.
@@ -57,13 +64,16 @@ pressure can never evict a paying tenant's system prompt. Requests with
 no namespace (and caches with no quotas) behave exactly as before.
 """
 import hashlib
+import heapq
 import time
+
+import numpy as np
 
 from ..observability import kvledger as _kvl
 from ..observability import metrics as _metrics
 from .blocks import GARBAGE_BLOCK, BlockAllocError
 
-__all__ = ["PrefixCache", "prefix_key", "DEFAULT_NAMESPACE"]
+__all__ = ["PrefixCache", "prefix_key", "chain_keys", "DEFAULT_NAMESPACE"]
 
 _M_HITS = _metrics.counter(
     "serving_prefix_cache_hits_total",
@@ -100,6 +110,42 @@ def prefix_key(tokens, namespace=None):
     return h.hexdigest()
 
 
+def chain_keys(tokens, block_size, namespace=None):
+    """The chain keys of `tokens`' full blocks, in order: key k is byte
+    for byte `prefix_key(tokens[:(k + 1) * block_size], namespace)`,
+    from ONE running sha1 that is fed a block at a time and copied for
+    each digest, so a chain costs its tokens once and not once a block.
+    A generator: a caller that stops at its first miss has hashed only
+    what it looked up."""
+    step = 8 * int(block_size)
+    buf = memoryview(np.asarray(tokens, dtype="<i8").tobytes())
+    h = hashlib.sha1()
+    if namespace is not None:
+        h.update(str(namespace).encode("utf-8") + b"\x00")
+    for k in range(len(buf) // step):
+        h.update(buf[k * step:(k + 1) * step])
+        yield h.copy().hexdigest()
+
+
+class _Chain:
+    """One prompt's chain keys, hashed lazily and once: `key(k)` runs
+    `chain_keys` as far as block k and keeps what it yielded, so `match`
+    stops hashing at its first miss and `insert` goes on from there."""
+    __slots__ = ("_cache", "_gen", "_keys")
+
+    def __init__(self, cache, tokens, namespace):
+        self._cache = cache
+        self._gen = chain_keys(tokens, cache.block_size, namespace)
+        self._keys = []
+
+    def key(self, k):
+        keys = self._keys
+        while len(keys) <= k:
+            keys.append(next(self._gen))
+            self._cache.hashed_tokens += self._cache.block_size
+        return keys[k]
+
+
 class PrefixCache:
     def __init__(self, pool, block_size, bypass=False):
         self.pool = pool
@@ -116,7 +162,14 @@ class PrefixCache:
         self._lru = {}            # key -> last-use sequence number
         self._parent = {}         # key -> chain-parent key (None at k=0)
         self._children = {}       # key -> cached direct children count
+        self._leaves = set()      # entries with no cached child: the
+        #                           only ones an eviction can take
         self._seq = 0
+        # what the bookkeeping cost, since construction: token updates
+        # fed to sha1, and entries an eviction looked at (the engine
+        # notes a prefill's share of each on its spans)
+        self.hashed_tokens = 0
+        self.evict_visited = 0
         # per-namespace bookkeeping (ISSUE 17): entry ownership, resident
         # counts, and quotas (resident <= quota protects a namespace from
         # FOREIGN eviction pressure)
@@ -193,8 +246,15 @@ class PrefixCache:
         self._seq += 1
         self._lru[key] = self._seq
 
+    def chain(self, prompt, namespace=None):
+        """`prompt`'s chain keys under `namespace`, for the `chain=` of
+        `match` and `insert`: a prefill that hands both the same one
+        hashes its prompt once."""
+        return _Chain(self, prompt, namespace)
+
     # -- lookup --------------------------------------------------------------
-    def match(self, prompt, record=True, namespace=None, reserve=0):
+    def match(self, prompt, record=True, namespace=None, reserve=0,
+              chain=None):
         """Longest cached block chain covering a strict prefix of
         `prompt`. Returns (block_ids, n_tokens) with one pool reference
         taken per returned block (owned by the caller's table row).
@@ -223,10 +283,12 @@ class PrefixCache:
             return [], 0
         bs = self.block_size
         usable = (len(prompt) - 1) // bs      # full blocks, 1 token spared
+        if chain is None:
+            chain = self.chain(prompt, namespace)
         ids = []
         prev_key = None
         for k in range(usable):
-            key = prefix_key(prompt[:(k + 1) * bs], namespace)
+            key = chain.key(k)
             blk = self._entries.get(key)
             if blk is None:
                 break
@@ -239,7 +301,7 @@ class PrefixCache:
             # always a contiguous SUFFIX of the HBM walk — promote the
             # whole run in one batched device write
             t0 = time.perf_counter()
-            promoted = self._promote_run(prompt, len(ids), usable,
+            promoted = self._promote_run(chain, len(ids), usable,
                                          namespace, prev_key, reserve)
             for key, blk in promoted:
                 ids.append(blk)
@@ -266,16 +328,18 @@ class PrefixCache:
 
     def probe(self, prompt, namespace=None):
         """Longest servable prefix in TOKENS, side-effect-free: no pool
-        refs, no LRU touches, no promotion, no counters — counts HBM
+        refs, no LRU touches, no promotion, no hit/miss counters (what
+        it hashed is in `hashed_tokens`, as everyone's) — counts HBM
         entries AND tiered continuations. The `OP_PREFIX_LOOKUP` fabric
         verb answers from this (readonly verbs must not mutate)."""
         if self.bypass:
             return 0
         bs = self.block_size
         usable = (len(prompt) - 1) // bs
+        chain = self.chain(prompt, namespace)
         n = 0
         for k in range(usable):
-            key = prefix_key(prompt[:(k + 1) * bs], namespace)
+            key = chain.key(k)
             if key in self._entries or \
                     (self._tier is not None and key in self._tier):
                 n += 1
@@ -283,10 +347,10 @@ class PrefixCache:
                 break
         return n * bs
 
-    def _promote_run(self, prompt, k0, usable, namespace, parent,
+    def _promote_run(self, chain, k0, usable, namespace, parent,
                      reserve):
-        """Promote the contiguous tiered continuation of `prompt`'s
-        chain (blocks k0..usable) back into HBM in ONE batched device
+        """Promote the contiguous tiered continuation of the prompt's
+        `chain` (blocks k0..usable) back into HBM in ONE batched device
         write. The sequential headroom rule is precomputed: promoting
         block k is allowed only while the pool's availability, net of
         the run's earlier promotes, stays >= max(reserve - k, 1) — a
@@ -297,11 +361,10 @@ class PrefixCache:
         ledger as a cache_insert so the shadow model's cached set and
         evictable() stay exact. Returns [(key, block_id)] in chain
         order."""
-        bs = self.block_size
         store = self._tier
         keys = []
         for k in range(k0, usable):
-            key = prefix_key(prompt[:(k + 1) * bs], namespace)
+            key = chain.key(k)
             if key not in store:
                 break
             keys.append(key)
@@ -337,17 +400,24 @@ class PrefixCache:
         row owns. The allocation's own reference becomes the cache's."""
         if self._ledger is not None:
             self._ledger.cache_insert((int(blk),))
-        self._entries[key] = int(blk)
+        self._add_entry(key, int(blk), namespace, parent)
+        _M_BLOCKS.set(len(self._entries))
+
+    def _add_entry(self, key, blk, namespace, parent):
+        """The books of one new entry: a leaf, its parent no longer."""
+        self._entries[key] = blk
         self._ns[key] = namespace
         self._resident[namespace] = self._resident.get(namespace, 0) + 1
         self._parent[key] = parent
         if parent is not None:
             self._children[parent] = self._children.get(parent, 0) + 1
+            self._leaves.discard(parent)
+        self._leaves.add(key)
         self._touch(key)
-        _M_BLOCKS.set(len(self._entries))
 
     # -- registration --------------------------------------------------------
-    def insert(self, prompt, table_row, upto_tokens, namespace=None):
+    def insert(self, prompt, table_row, upto_tokens, namespace=None,
+               chain=None):
         """Register the fully-written blocks of `prompt` (logical blocks
         whose every position < upto_tokens) from the request's table row.
         Already-cached chains keep their existing block (the duplicate
@@ -355,13 +425,14 @@ class PrefixCache:
         reference."""
         if self.bypass:
             return
-        bs = self.block_size
+        if chain is None:
+            chain = self.chain(prompt, namespace)
         prev_key = None
-        for k in range(int(upto_tokens) // bs):
+        for k in range(int(upto_tokens) // self.block_size):
             blk = int(table_row[k])
             if blk == GARBAGE_BLOCK:
                 continue
-            key = prefix_key(prompt[:(k + 1) * bs], namespace)
+            key = chain.key(k)
             if key in self._entries:
                 self._touch(key)
                 prev_key = key
@@ -372,14 +443,7 @@ class PrefixCache:
                 self._ledger.cache_insert((blk,))
             else:
                 self.pool.ref(blk)
-            self._entries[key] = blk
-            self._ns[key] = namespace
-            self._resident[namespace] = self._resident.get(namespace, 0) + 1
-            self._parent[key] = prev_key
-            if prev_key is not None:
-                self._children[prev_key] = \
-                    self._children.get(prev_key, 0) + 1
-            self._touch(key)
+            self._add_entry(key, blk, namespace, prev_key)
             prev_key = key
         _M_BLOCKS.set(len(self._entries))
 
@@ -411,50 +475,65 @@ class PrefixCache:
         return freed
 
     def _evict_pass(self, n_blocks, eligible):
-        """One LRU leaf-first sweep over entries whose namespace passes
-        `eligible` (re-evaluated per eviction — resident counts move)."""
+        """One LRU leaf-first pass over entries whose namespace passes
+        `eligible` (re-evaluated per eviction — resident counts move).
+
+        The victims and their order are those of sweeping ALL entries
+        by last use, freeing the leaves met, and starting over while a
+        sweep freed something: a leaf freed in sweep s exposes its
+        parent to the same sweep where the parent was used after it,
+        to sweep s + 1 where before. Only leaves can go, so the heap
+        holds (sweep, last use, key) of the leaves alone, and a pass
+        costs the leaves it starts from and what it frees, not sweeps x
+        entries. A candidate turned down stays turned down for the
+        pass: no other block's refcount moves under an eviction, and a
+        namespace's protection only sets in as it drains."""
+        heap = [(0, self._lru[key], key) for key in self._leaves]
+        heapq.heapify(heap)
+        self.evict_visited += len(heap)
         freed = 0
-        progress = True
-        while freed < n_blocks and progress:
-            progress = False
-            for key in sorted(self._lru, key=self._lru.get):
-                if freed >= n_blocks:
-                    break
-                ns = self._ns.get(key)
-                if not eligible(ns):
-                    continue
-                blk = self._entries.get(key)
-                if blk is None or self.pool.refcount(blk) != 1 \
-                        or self._children.get(key, 0) > 0:
-                    continue
-                if self._tier is not None:
-                    # demote-instead-of-free (ISSUE 18): capture the
-                    # block's KV into the cold tiers while it is still
-                    # allocated; the eviction below then releases the
-                    # HBM copy exactly as before. A torn spill simply
-                    # skips the capture — lost, never corrupt.
-                    self._tier.demote(key, ns, self._parent.get(key), blk)
-                if self._ledger is not None:
-                    # cache_evict BEFORE the unref so a replay never
-                    # sees the cache holding a freed block
-                    self._ledger.cache_evict((blk,))
-                    with _kvl.origin_scope("prefix_cache.evict"):
-                        self.pool.unref(blk)
-                else:
+        while heap and freed < n_blocks:
+            sweep, seq, key = heapq.heappop(heap)
+            ns = self._ns.get(key)
+            if not eligible(ns):
+                continue
+            blk = self._entries[key]
+            if self.pool.refcount(blk) != 1:
+                continue
+            if self._tier is not None:
+                # demote-instead-of-free (ISSUE 18): capture the
+                # block's KV into the cold tiers while it is still
+                # allocated; the eviction below then releases the
+                # HBM copy exactly as before. A torn spill simply
+                # skips the capture — lost, never corrupt.
+                self._tier.demote(key, ns, self._parent.get(key), blk)
+            if self._ledger is not None:
+                # cache_evict BEFORE the unref so a replay never
+                # sees the cache holding a freed block
+                self._ledger.cache_evict((blk,))
+                with _kvl.origin_scope("prefix_cache.evict"):
                     self.pool.unref(blk)
-                parent = self._parent.pop(key, None)
-                if parent is not None and parent in self._children:
-                    self._children[parent] -= 1
-                    if self._children[parent] <= 0:
-                        del self._children[parent]
-                self._children.pop(key, None)
-                del self._entries[key]
-                del self._lru[key]
-                self._ns.pop(key, None)
-                self._resident[ns] = self._resident.get(ns, 1) - 1
-                label = ns if ns is not None else DEFAULT_NAMESPACE
-                self._ns_evicted[label] = self._ns_evicted.get(label, 0) + 1
-                _M_NS_EVICTED.labels(namespace=label).inc()
-                freed += 1
-                progress = True     # a freed leaf may expose its parent
+            else:
+                self.pool.unref(blk)
+            parent = self._parent.pop(key, None)
+            if parent is not None and parent in self._children:
+                self._children[parent] -= 1
+                if self._children[parent] <= 0:
+                    del self._children[parent]
+                    if parent in self._entries:
+                        # the freed leaf exposed its parent
+                        self._leaves.add(parent)
+                        pseq = self._lru[parent]
+                        heapq.heappush(
+                            heap, (sweep + (pseq < seq), pseq, parent))
+                        self.evict_visited += 1
+            self._leaves.discard(key)
+            del self._entries[key]
+            del self._lru[key]
+            self._ns.pop(key, None)
+            self._resident[ns] = self._resident.get(ns, 1) - 1
+            label = ns if ns is not None else DEFAULT_NAMESPACE
+            self._ns_evicted[label] = self._ns_evicted.get(label, 0) + 1
+            _M_NS_EVICTED.labels(namespace=label).inc()
+            freed += 1
         return freed
