@@ -43,8 +43,8 @@ from . import kv_cache as kvc
 
 __all__ = ["BlockAllocError", "BlockPool", "PagedLayerKV",
            "QuantPagedLayerKV", "PagedDecodeCache", "LatentSpec",
-           "StateSpec", "LatentLayer", "StateLayer", "SlotStateStore",
-           "alloc_layers", "gather_rows", "alloc_pools",
+           "StateSpec", "NoCache", "LatentLayer", "StateLayer",
+           "SlotStateStore", "alloc_layers", "gather_rows", "alloc_pools",
            "alloc_quant_pools", "write", "quant_write", "gather",
            "gather_quant", "dequant", "attend", "attend_quant",
            "attend_kernel", "attend_kernel_quant", "attention_impl",
@@ -103,7 +103,7 @@ PagedDecodeCache = collections.namedtuple(
     defaults=(None, None))
 
 # What a layer of a model declares it caches (`model.cache_layout()`, one
-# spec a layer), and the arrays the engine allocates for each. Two kinds
+# spec a layer), and the arrays the engine allocates for each. The kinds
 # live side by side in one pool tuple, under one block table and one
 # allocator, and are donated together:
 #   LatentSpec(width)   one row of `width` values a token, paged like K/V:
@@ -112,9 +112,15 @@ PagedDecodeCache = collections.namedtuple(
 #                       (a linear-attention layer's matrix and the last
 #                       inputs of its short convolution): StateLayer(
 #                       state [slots, *state] float32, tail [slots, *tail])
+#   NoCache()           nothing: a layer that is a feed-forward alone. It
+#                       is its own (empty) layer in the pool tuple, so the
+#                       pool keeps one entry a layer
+# Softmax attention with few key/value heads needs no third kind: a token's
+# keys and values of all its key/value heads are one LatentSpec row.
 # A model without `cache_layout` (GPT) caches K and V per layer, as above.
 LatentSpec = collections.namedtuple("LatentSpec", ["width"])
 StateSpec = collections.namedtuple("StateSpec", ["state", "tail"])
+NoCache = collections.namedtuple("NoCache", [])
 LatentLayer = collections.namedtuple("LatentLayer", ["rows"])
 StateLayer = collections.namedtuple("StateLayer", ["state", "tail"])
 
@@ -135,8 +141,9 @@ def alloc_pools(num_layers, num_blocks, block_size, num_heads, head_dim,
 
 def alloc_layers(layout, num_blocks, block_size, slots, dtype):
     """Zeroed cache arrays for a model that declares its layers' caches:
-    one LatentLayer or StateLayer per spec of `layout`. Latent rows and
-    convolution tails take `dtype`; the recurrent state is float32."""
+    one LatentLayer, StateLayer or (for a layer that caches nothing)
+    NoCache per spec of `layout`. Latent rows and convolution tails take
+    `dtype`; the recurrent state is float32."""
     out = []
     for spec in layout:
         if isinstance(spec, LatentSpec):
@@ -146,6 +153,8 @@ def alloc_layers(layout, num_blocks, block_size, slots, dtype):
             out.append(StateLayer(
                 jnp.zeros((slots,) + tuple(spec.state), jnp.float32),
                 jnp.zeros((slots,) + tuple(spec.tail), dtype)))
+        elif isinstance(spec, NoCache):
+            out.append(spec)
         else:
             raise TypeError(f"unknown cache spec {spec!r}")
     return tuple(out)

@@ -1042,15 +1042,17 @@ class PagedGenerationEngine(GenerationEngine):
     def __init__(self, model, config=None, **kwargs):
         config = config or PagedEngineConfig(**kwargs)
         # what each layer caches: None for GPT (K and V per layer), else
-        # the model's own declaration (blocks.LatentSpec / StateSpec);
+        # the model's own declaration (blocks.LatentSpec / StateSpec /
+        # NoCache);
         # and the counters its forward returns with the logits
         self._layout = model.cache_layout() \
             if hasattr(model, "cache_layout") else None
         self._counter_names = tuple(getattr(model, "serving_counters", ()))
         self.last_counters = {}
         self.state_store = None
-        self._latent_layers = sum(isinstance(spec, blocks.LatentSpec)
-                                  for spec in self._layout or ())
+        self._latent_layers, self._state_layers = (
+            sum(isinstance(spec, kind) for spec in self._layout or ())
+            for kind in (blocks.LatentSpec, blocks.StateSpec))
         # what the executables are traced with: the configured value, or
         # the engine's own choice where the configuration leaves it open.
         # This, not the spelled value, is what the executables' cache keys
@@ -1794,6 +1796,11 @@ class PagedGenerationEngine(GenerationEngine):
         # the pool that went in is gone: rebind before anything can raise
         first, self._pool, pos = out[:3]
         _TRACER.note("pool_donated", self._pool_donated(pool_in))
+        if self._state_layers:
+            # positions the state layers' chunked scan ran (the bucket, in
+            # every one of them) and those that were a real token
+            _TRACER.note("ssm_tokens_scanned", self._state_layers * bucket)
+            _TRACER.note("ssm_tokens_valid", self._state_layers * length)
         if self._numerics_armed:
             self._ingest_numerics(out[3])
         self._pos = np.array(pos, np.int32)   # owned, writable copy

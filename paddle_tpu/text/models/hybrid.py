@@ -1,16 +1,23 @@
-"""A decoder built from a per-layer list of (mixer, feed-forward) kinds.
+"""A decoder built from a per-layer list of (mixer, feed-forward) kinds, of
+which a layer has both (each behind its own norm) or one alone.
 
 Mixers: `kda` (linear attention: the gated delta rule with a per-channel
 decay, a short causal convolution and SiLU; its cache is a per-slot state
-matrix and convolution tail) and `mla` (softmax attention over a shared
-latent: its cache is one paged row a token; queries straight from the
-hidden state or through a bottleneck `q_lora_rank` wide, a head-wise output
-gate or none, plain or YaRN-scaled rotary: what the configuration
-declares). Feed-forwards: `swiglu` (dense) and `moe` (sigmoid-scored,
-group-limited top-k experts plus a shared expert, of which this chip may
-hold a share: `num_experts` of `n_routed_experts`, global ids from
-`experts_first`). The mixers come from the configuration's `mixers` list
-(one name a layer) or, without one, from the rule "every
+matrix and convolution tail), `mamba2` (a selective state-space layer: a
+scalar decay a head, B and C shared by a group of heads, a convolution with
+bias, a gated grouped norm; the same kind of cache), `mla` (softmax
+attention over a shared latent: its cache is one paged row a token; queries
+straight from the hidden state or through a bottleneck `q_lora_rank` wide, a
+head-wise output gate or none, plain or YaRN-scaled rotary: what the
+configuration declares) and `gqa` (softmax attention of `num_heads` query
+heads over `num_kv_heads` key/value heads, no rotary; a token's keys and
+values are one paged row). Feed-forwards: `swiglu` (dense) and `moe`
+(sigmoid-scored, group-limited top-k experts plus a shared expert, SwiGLU or
+`relu2` as `moe_act` declares, of which this chip may hold a share:
+`num_experts` of `n_routed_experts`, global ids from `experts_first`). The
+layers come from the configuration: `blocks` (one part a layer: a mixer or a
+feed-forward alone, behind ONE norm), or `mixers` (one mixer a layer, each
+followed by a feed-forward) or, without either, the rule "every
 `layer_group_size`-th layer is MLA, the others KDA". RMSNorm, partial rotary
 on the MLA layers only, untied head. `hybrid_ops.py` has the mathematics.
 
@@ -31,9 +38,13 @@ from . import hybrid_ops as ops
 
 # parameters that stay float32 whatever the weights' type
 FLOAT32_LEAVES = ("norm1", "norm2", "norm_f", "a_log", "bf", "onorm",
-                  "cnorm", "qnorm", "router", "router_bias")
-_NORM_LEAVES = ("norm1", "norm2", "norm_f", "onorm", "cnorm", "qnorm")
-_RESIDUAL_LEAVES = ("wo", "w_down", "we_down", "ws_down")
+                  "cnorm", "qnorm", "router", "router_bias", "dt_bias",
+                  "d_skip", "ssm_norm")
+_ONES_LEAVES = ("norm1", "norm2", "norm_f", "onorm", "cnorm", "qnorm",
+                "d_skip", "ssm_norm")
+_RESIDUAL_LEAVES = ("wo", "w_down", "we_down", "ws_down", "w_out")
+MIXERS = ("kda", "mla", "mamba2", "gqa")
+FFNS = ("swiglu", "moe")
 
 
 @dataclass
@@ -42,15 +53,22 @@ class HybridConfig:
     hidden_size: int = 64
     num_layers: int = 7
     num_heads: int = 2
-    head_dim: int = 16                   # KDA key and value size per head
+    head_dim: int = 16                   # KDA, GQA key and value head size
+    num_kv_heads: int = None             # GQA's key/value heads
     layer_group_size: int = 6            # every group's last layer is MLA
     mixers: tuple = None                 # one mixer a layer; None: that rule
+    blocks: tuple = None                 # one part a layer, nothing paired
     first_k_dense: int = 1               # leading layers with a dense FFN
     intermediate_size: int = 128
     max_position_embeddings: int = 256
     rms_norm_eps: float = 1e-6
     conv_kernel: int = 4
     kda_lower_bound: float = -5.0
+    ssm_heads: int = 4                   # Mamba-2: heads of ssm_head_dim,
+    ssm_head_dim: int = 8                # B and C shared by a group,
+    ssm_groups: int = 2                  # the state [heads, head_dim, size]
+    ssm_state_size: int = 16
+    ssm_chunk: int = 128                 # tokens a chunk of its prefill
     q_lora_rank: int = None              # MLA's query bottleneck, or none
     kv_lora_rank: int = 32
     qk_nope_head_dim: int = 16
@@ -68,6 +86,7 @@ class HybridConfig:
     routed_scaling_factor: float = 2.5
     moe_intermediate_size: int = 32
     shared_intermediate_size: int = 32
+    moe_act: str = "swiglu"              # or "relu2": no gate matrix
     param_dtype: str = "float32"
     initializer_range: float = 0.02
     init_weights: bool = True            # False: shapes only, load later
@@ -78,18 +97,38 @@ class HybridConfig:
         if not 0 <= self.experts_first <= \
                 self.n_routed_experts - self.num_experts:
             raise ValueError("the held experts lie outside the routed ones")
-        if self.mixers is not None:
-            self.mixers = tuple(self.mixers)
-            if len(self.mixers) != self.num_layers \
-                    or set(self.mixers) - {"kda", "mla"}:
+        for name, parts in (("mixers", MIXERS), ("blocks", MIXERS + FFNS)):
+            given = getattr(self, name)
+            if given is None:
+                continue
+            given = tuple(given)
+            setattr(self, name, given)
+            if len(given) != self.num_layers or set(given) - set(parts):
                 raise ValueError(
-                    f"mixers must name 'kda' or 'mla' for each of the "
-                    f"{self.num_layers} layers, got {self.mixers!r}")
+                    f"{name} must name one of {parts} for each of the "
+                    f"{self.num_layers} layers, got {given!r}")
+        if self.mixers is not None and self.blocks is not None:
+            raise ValueError("a layer list is `mixers` (each followed by a "
+                             "feed-forward) or `blocks` (one part a layer),"
+                             " not both")
+        kinds = set(self.blocks or self.mixers or ())
+        if "gqa" in kinds and (not self.num_kv_heads
+                               or self.num_heads % self.num_kv_heads):
+            raise ValueError("gqa: num_kv_heads must divide num_heads")
+        if "mamba2" in kinds and self.ssm_heads % self.ssm_groups:
+            raise ValueError("mamba2: ssm_groups must divide ssm_heads")
+        if self.moe_act not in ("swiglu", "relu2"):
+            raise ValueError(f"moe_act: 'swiglu' or 'relu2', got "
+                             f"{self.moe_act!r}")
         if self.rope_scaling and self.rope_scaling.get("type") != "yarn":
             raise ValueError(f"rope_scaling: only YaRN is built, got "
                              f"{self.rope_scaling!r}")
 
     def layer_kinds(self):
+        """[(mixer | None, feed-forward | None)] of each layer."""
+        if self.blocks is not None:
+            return [(b, None) if b in MIXERS else (None, b)
+                    for b in self.blocks]
         mixers = self.mixers or [
             "mla" if (i + 1) % self.layer_group_size == 0 else "kda"
             for i in range(self.num_layers)]
@@ -98,17 +137,31 @@ class HybridConfig:
 
 
 def leaf_shapes(cfg, kinds):
-    """{leaf: shape} of one layer of kinds (mixer, feed-forward)."""
+    """{leaf: shape} of one layer of kinds (mixer, feed-forward); a part
+    that is None brings neither its leaves nor its norm."""
     mixer, ffn = kinds
     h, n = cfg.hidden_size, cfg.num_heads
-    out = {"norm1": (h,), "norm2": (h,)}
+    out = {name: (h,) for name, part in (("norm1", mixer), ("norm2", ffn))
+           if part is not None}
     if mixer == "kda":
         c, k = n * cfg.head_dim, cfg.conv_kernel
         out.update({"wq": (h, c), "wk": (h, c), "wv": (h, c),
                     "conv_q": (k, c), "conv_k": (k, c), "conv_v": (k, c),
                     "a_log": (n,), "wf": (h, c), "bf": (c,), "wb": (h, n),
                     "wg": (h, c), "onorm": (cfg.head_dim,), "wo": (c, h)})
-    else:
+    elif mixer == "mamba2":
+        heads, inner = cfg.ssm_heads, cfg.ssm_heads * cfg.ssm_head_dim
+        chan = inner + 2 * cfg.ssm_groups * cfg.ssm_state_size
+        out.update({"w_in": (h, inner + chan + heads),
+                    "conv_w": (cfg.conv_kernel, chan), "conv_b": (chan,),
+                    "dt_bias": (heads,), "a_log": (heads,),
+                    "d_skip": (heads,), "ssm_norm": (inner,),
+                    "w_out": (inner, h)})
+    elif mixer == "gqa":
+        d = cfg.head_dim
+        out.update({"wq": (h, n * d), "wk": (h, cfg.num_kv_heads * d),
+                    "wv": (h, cfg.num_kv_heads * d), "wo": (n * d, h)})
+    elif mixer == "mla":
         nope, rope = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
         if cfg.q_lora_rank:
             out.update({"wq_a": (h, cfg.q_lora_rank),
@@ -125,7 +178,7 @@ def leaf_shapes(cfg, kinds):
     if ffn == "swiglu":
         f = cfg.intermediate_size
         out.update({"w_gate": (h, f), "w_up": (h, f), "w_down": (f, h)})
-    else:
+    elif ffn == "moe":
         e, f = cfg.num_experts, cfg.moe_intermediate_size
         fs = cfg.shared_intermediate_size
         out.update({"router": (h, cfg.n_routed_experts),
@@ -133,6 +186,8 @@ def leaf_shapes(cfg, kinds):
                     "we_gate": (e, h, f), "we_up": (e, h, f),
                     "we_down": (e, f, h), "ws_gate": (h, fs),
                     "ws_up": (h, fs), "ws_down": (fs, h)})
+        if cfg.moe_act == "relu2":
+            del out["we_gate"], out["ws_gate"]
     return out
 
 
@@ -149,9 +204,10 @@ class _Leaves(Layer):
                 else jnp.dtype(cfg.param_dtype)
             if not cfg.init_weights:
                 data = jnp.zeros((), dtype)
-            elif leaf in _NORM_LEAVES:
+            elif leaf in _ONES_LEAVES:
                 data = jnp.ones(shape, dtype)
-            elif leaf in ("a_log", "bf", "router_bias"):
+            elif leaf in ("a_log", "bf", "router_bias", "dt_bias",
+                          "conv_b"):
                 data = jnp.zeros(shape, dtype)
             else:
                 std = cfg.initializer_range
@@ -214,12 +270,24 @@ class HybridDecoder(Layer):
     def cache_layout(self):
         from ...serving import blocks
         cfg = self.cfg
-        n, d = cfg.num_heads, cfg.head_dim
-        return tuple(
-            blocks.StateSpec((n, d, d), (cfg.conv_kernel - 1, 3 * n * d))
-            if mixer == "kda"
-            else blocks.LatentSpec(cfg.kv_lora_rank + cfg.qk_rope_head_dim)
-            for mixer, _ in self.kinds)
+        n, d, tail = cfg.num_heads, cfg.head_dim, cfg.conv_kernel - 1
+
+        def spec(mixer):
+            if mixer == "kda":
+                return blocks.StateSpec((n, d, d), (tail, 3 * n * d))
+            if mixer == "mamba2":
+                inner = cfg.ssm_heads * cfg.ssm_head_dim
+                return blocks.StateSpec(
+                    (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state_size),
+                    (tail, inner + 2 * cfg.ssm_groups * cfg.ssm_state_size))
+            if mixer == "mla":
+                return blocks.LatentSpec(cfg.kv_lora_rank
+                                         + cfg.qk_rope_head_dim)
+            if mixer == "gqa":   # a token's keys and values: one row
+                return blocks.LatentSpec(2 * cfg.num_kv_heads * d)
+            return blocks.NoCache()      # a feed-forward alone
+
+        return tuple(spec(mixer) for mixer, _ in self.kinds)
 
     # -- forward ----------------------------------------------------------
     def forward(self, input_ids, cache):
@@ -258,6 +326,8 @@ class HybridDecoder(Layer):
                                 jnp.maximum(counters[3:], new[3:])])
 
     def _ffn(self, h, w, ffn, live, counters):
+        if ffn is None:
+            return h, counters
         x = ops.rms_norm(h, w["norm2"], self.cfg.rms_norm_eps)
         if ffn == "swiglu":
             return h + ops.swiglu(x, w["w_gate"], w["w_up"],
@@ -271,6 +341,44 @@ class HybridDecoder(Layer):
             counters = jnp.zeros((len(self.serving_counters),), jnp.int32)
         return ops.mm("...h,hv->...v", x, params["top.head"]), counters
 
+    # One token a slot through a mixer: x [S, H] normed, `cached` the
+    # layer's cache -> (what the mixer adds to h, the layer's new cache)
+    def _mix_decode(self, mixer, x, w, cached, tables, pos):
+        from ...serving import blocks
+        cfg = self.cfg
+        if mixer == "kda":
+            conv_in = ops.kda_conv_in(x, w, cached.tail.dtype)[:, None]
+            q, k, v, g, beta, gate = ops.kda_inputs(
+                x[:, None], w, cfg, conv_in, cached.tail)
+            state, o = ops.kda_recurrent_step(
+                cached.state, q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+                beta[:, 0])
+            tail = jnp.concatenate([cached.tail[:, 1:], conv_in], 1)
+            return ops.kda_output(o, gate[:, 0], w, cfg), \
+                blocks.StateLayer(state, tail)
+        if mixer == "mamba2":
+            z, xbc, dt = ops.mamba2_project(x[:, None], w, cfg,
+                                            cached.tail.dtype)
+            xs, b, c, dt, a = ops.mamba2_inputs(xbc, dt, w, cfg,
+                                                cached.tail)
+            state, y = ops.mamba2_recurrent_step(
+                cached.state, xs[:, 0], b[:, 0], c[:, 0], dt[:, 0], a)
+            tail = jnp.concatenate([cached.tail[:, 1:], xbc], 1)
+            return ops.mamba2_output(y, xs[:, 0], z[:, 0], w, cfg), \
+                blocks.StateLayer(state, tail)
+        if mixer == "gqa":
+            q, row = ops.gqa_project(x[:, None], w, cfg)
+            rows = blocks.write(cached.rows, row, tables, pos)
+            return ops.gqa_decode(q[:, 0], blocks.gather_rows(rows, tables),
+                                  pos, w, cfg), blocks.LatentLayer(rows)
+        q_n, q_r, latent, gate = ops.mla_project(
+            x[:, None], w, cfg, pos[:, None])
+        rows = blocks.write(cached.rows, latent, tables, pos)
+        return ops.mla_decode(
+            q_n[:, 0], q_r[:, 0], blocks.gather_rows(rows, tables),
+            pos, None if gate is None else gate[:, 0], w, cfg), \
+            blocks.LatentLayer(rows)
+
     def _decode(self, params, pool, tables, pos, ids):
         from ...serving import blocks
         cfg = self.cfg
@@ -280,64 +388,80 @@ class HybridDecoder(Layer):
         new_pool, counters = [], None
         for i, ((mixer, ffn), cached) in enumerate(zip(self.kinds, pool)):
             w = self._layer_params(params, i)
-            x = ops.rms_norm(h, w["norm1"], cfg.rms_norm_eps)
-            if mixer == "kda":
-                conv_in = ops.kda_conv_in(x, w, cached.tail.dtype)[:, None]
-                q, k, v, g, beta, gate = ops.kda_inputs(
-                    x[:, None], w, cfg, conv_in, cached.tail)
-                state, o = ops.kda_recurrent_step(
-                    cached.state, q[:, 0], k[:, 0], v[:, 0], g[:, 0],
-                    beta[:, 0])
-                tail = jnp.concatenate([cached.tail[:, 1:], conv_in], 1)
-                new_pool.append(blocks.StateLayer(state, tail))
-                h = h + ops.kda_output(o, gate[:, 0], w, cfg)
-            else:
-                q_n, q_r, latent, gate = ops.mla_project(
-                    x[:, None], w, cfg, pos[:, None])
-                rows = blocks.write(cached.rows, latent, tables, pos)
-                new_pool.append(blocks.LatentLayer(rows))
-                h = h + ops.mla_decode(
-                    q_n[:, 0], q_r[:, 0], blocks.gather_rows(rows, tables),
-                    pos, None if gate is None else gate[:, 0], w, cfg)
+            if mixer is not None:
+                x = ops.rms_norm(h, w["norm1"], cfg.rms_norm_eps)
+                y, cached = self._mix_decode(mixer, x, w, cached, tables,
+                                             pos)
+                h = h + y
+            new_pool.append(cached)
             h, counters = self._ffn(h, w, ffn, live, counters)
         logits, counters = self._finish(params, h, counters)
         return logits[:, None], tuple(new_pool), counters
 
-    def _prefill(self, params, pool, tables, pos, ids, length, slot):
+    # One request's (bucket-padded) tokens from position 0 through a mixer:
+    # x [T, H] normed, `valid` [T] the real ones, `length` their count
+    def _mix_prefill(self, mixer, x, w, cached, tables, pos, valid, length,
+                     slot):
         from ...serving import blocks
         cfg = self.cfg
-        t = ids.shape[1]
-        valid = jnp.arange(t) < length
+
+        def no_history(conv_in):
+            return jnp.zeros((cfg.conv_kernel - 1, conv_in.shape[1]),
+                             conv_in.dtype)
+
+        def stored(state, history, conv_in):
+            # the tail: the rows before position `length`, padding left out
+            tail = jax.lax.dynamic_slice_in_dim(
+                jnp.concatenate([history, conv_in]), length,
+                cfg.conv_kernel - 1)
+            return blocks.StateLayer(
+                jax.lax.dynamic_update_index_in_dim(
+                    cached.state, state, slot, 0),
+                jax.lax.dynamic_update_index_in_dim(
+                    cached.tail, tail, slot, 0))
+
+        if mixer == "kda":
+            conv_in = ops.kda_conv_in(x, w, cached.tail.dtype)    # [T, C]
+            history = no_history(conv_in)
+            q, k, v, g, beta, gate = ops.kda_inputs(
+                x, w, cfg, conv_in, history)
+            o, state = ops.kda_chunked(q, k, v, g, beta, valid)
+            new = stored(state, history, conv_in)
+            return ops.kda_output(o, gate, w, cfg), new
+        if mixer == "mamba2":
+            z, xbc, dt = ops.mamba2_project(x, w, cfg, cached.tail.dtype)
+            history = no_history(xbc)
+            xs, b, c, dt, a = ops.mamba2_inputs(xbc, dt, w, cfg, history)
+            y, state = ops.mamba2_chunked(xs, b, c, dt, a, valid,
+                                          cfg.ssm_chunk)
+            new = stored(state, history, xbc)
+            return ops.mamba2_output(y, xs, z, w, cfg), new
+        # padded rows land beyond `length` in the slot's last block or in
+        # the garbage block: masked by position, overwritten
+        if mixer == "gqa":
+            q, row = ops.gqa_project(x, w, cfg)
+            new = blocks.LatentLayer(
+                blocks.write(cached.rows, row[None], tables, pos))
+            return ops.gqa_prefill(q, row, w, cfg), new
+        q_n, q_r, latent, gate = ops.mla_project(
+            x, w, cfg, jnp.arange(x.shape[0]))
+        new = blocks.LatentLayer(
+            blocks.write(cached.rows, latent[None], tables, pos))
+        return ops.mla_prefill(q_n, q_r, latent, gate, w, cfg), new
+
+    def _prefill(self, params, pool, tables, pos, ids, length, slot):
+        cfg = self.cfg
+        valid = jnp.arange(ids.shape[1]) < length
         h = params["top.embed"][ids[0]].astype(jnp.float32)       # [T, H]
         new_pool, counters = [], None
         for i, ((mixer, ffn), cached) in enumerate(zip(self.kinds, pool)):
             w = self._layer_params(params, i)
-            x = ops.rms_norm(h, w["norm1"], cfg.rms_norm_eps)
-            if mixer == "kda":
-                conv_in = ops.kda_conv_in(x, w, cached.tail.dtype)  # [T, C]
-                history = jnp.zeros((cfg.conv_kernel - 1,
-                                     conv_in.shape[1]), conv_in.dtype)
-                q, k, v, g, beta, gate = ops.kda_inputs(
-                    x, w, cfg, conv_in, history)
-                o, state = ops.kda_chunked(q, k, v, g, beta, valid)
-                # the rows before position `length`: padding stays out
-                tail = jax.lax.dynamic_slice_in_dim(
-                    jnp.concatenate([history, conv_in]), length,
-                    cfg.conv_kernel - 1)
-                new_pool.append(blocks.StateLayer(
-                    jax.lax.dynamic_update_index_in_dim(
-                        cached.state, state, slot, 0),
-                    jax.lax.dynamic_update_index_in_dim(
-                        cached.tail, tail, slot, 0)))
-                h = h + ops.kda_output(o, gate, w, cfg)
-            else:
-                q_n, q_r, latent, gate = ops.mla_project(
-                    x, w, cfg, jnp.arange(t))
-                # padded rows land beyond `length` in the slot's last block
-                # or in the garbage block: masked by position, overwritten
-                new_pool.append(blocks.LatentLayer(blocks.write(
-                    cached.rows, latent[None], tables, pos)))
-                h = h + ops.mla_prefill(q_n, q_r, latent, gate, w, cfg)
+            if mixer is not None:
+                x = ops.rms_norm(h, w["norm1"], cfg.rms_norm_eps)
+                y, cached = self._mix_prefill(mixer, x, w, cached, tables,
+                                              pos, valid, length, slot)
+                h = h + y
+            new_pool.append(cached)
             h, counters = self._ffn(h, w, ffn, valid, counters)
         logits, counters = self._finish(params, h, counters)
         return logits[None], tuple(new_pool), counters

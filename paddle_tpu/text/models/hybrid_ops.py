@@ -1,8 +1,9 @@
 """The mechanisms of the hybrid decoder as pure functions over raw arrays:
-KDA linear attention (a chunked form for prefill, the one-step recurrence
-for decode), MLA over a latent cache (expanded for prefill, absorbed for
-decode), and the expert layer of a chip that holds a share of the experts.
-Plain XLA; `text/models/hybrid.py` wires them into a model and
+KDA linear attention and the Mamba-2 state-space mixer (each a chunked form
+for prefill and the one-step recurrence for decode), MLA over a latent cache
+(expanded for prefill, absorbed for decode), grouped-query attention over
+paged K/V rows, and the expert layer of a chip that holds a share of the
+experts. Plain XLA; `text/models/hybrid.py` wires them into a model and
 `serving/blocks.py` holds the two kinds of cache they read and write.
 
 Weights are bfloat16 (matmuls accumulate in float32), the residual stream,
@@ -44,6 +45,21 @@ def swiglu(x, gate, up, down):
               * mm("...h,hf->...f", x, up), down)
 
 
+def relu2_mlp(x, up, down):
+    return mm("...f,fh->...h",
+              jnp.square(jax.nn.relu(mm("...h,hf->...f", x, up))), down)
+
+
+def short_conv(history, conv_in, taps):
+    """The causal depthwise convolution of `conv_in` [..., T, C] behind
+    `history` [..., K-1, C] with `taps` [K, C] float32 (tap K-1 on the
+    current row), in float32."""
+    seq = jnp.concatenate([history, conv_in], -2).astype(jnp.float32)
+    t = conv_in.shape[-2]
+    return sum(taps[j] * jax.lax.slice_in_dim(seq, j, j + t, axis=-2)
+               for j in range(taps.shape[0]))
+
+
 # ------------------------------------------------------------------- KDA
 
 def kda_inputs(x, w, cfg, conv_in, history):
@@ -54,13 +70,9 @@ def kda_inputs(x, w, cfg, conv_in, history):
     [..., K-1, 3nd] the ones before them (zeros at the start of a request):
     the cache keeps the last K-1 rows of their concatenation."""
     n, d = cfg.num_heads, cfg.head_dim
-    k = cfg.conv_kernel
     taps = jnp.concatenate([w["conv_q"], w["conv_k"], w["conv_v"]], -1) \
         .astype(jnp.float32)
-    seq = jnp.concatenate([history, conv_in], -2).astype(jnp.float32)
-    t = conv_in.shape[-2]
-    conv = sum(taps[j] * jax.lax.slice_in_dim(seq, j, j + t, axis=-2)
-               for j in range(k))
+    conv = short_conv(history, conv_in, taps)
     q, kk, v = (part.reshape(part.shape[:-1] + (n, d))
                 for part in jnp.split(jax.nn.silu(conv), 3, -1))
     q = l2norm(q) * (d ** -0.5)
@@ -343,6 +355,160 @@ def mla_decode(q_n, q_r, rows, pos, gate, w, cfg):
         return mm("sc,ch->sh", o.reshape(o.shape[0], n * vd), w["wo"])
 
 
+# --------------------------------------------------------------- Mamba-2
+
+def mamba2_project(x, w, cfg, dtype):
+    """`W_in x` of the normed input `x` [..., T, H], split: the gate z
+    [..., T, I], the convolution's input xBC [..., T, I + 2GN] in the type
+    the cache keeps its last rows in, the step's input dt [..., T, heads]."""
+    inner = cfg.ssm_heads * cfg.ssm_head_dim
+    chan = inner + 2 * cfg.ssm_groups * cfg.ssm_state_size
+    z, xbc, dt = jnp.split(mm("...h,hc->...c", x, w["w_in"]),
+                           [inner, inner + chan], axis=-1)
+    return z, xbc.astype(dtype), dt
+
+
+def mamba2_inputs(xbc, dt, w, cfg, history):
+    """What the recurrence consumes: x [..., T, heads, P], B and C
+    [..., T, G, N] out of `SiLU(conv(xBC) + b)`, the step `softplus(dt +
+    dt_bias)` [..., T, heads] and the decay's rate a = -exp(A_log) [heads].
+    `history` [..., K-1, C] are the rows of xBC before these (zeros at the
+    start of a request): the cache keeps the last K-1 rows of their
+    concatenation."""
+    heads, p = cfg.ssm_heads, cfg.ssm_head_dim
+    g, n = cfg.ssm_groups, cfg.ssm_state_size
+    conv = jax.nn.silu(
+        short_conv(history, xbc, w["conv_w"].astype(jnp.float32))
+        + w["conv_b"].astype(jnp.float32))
+    xs, b, c = jnp.split(conv, [heads * p, heads * p + g * n], axis=-1)
+    lead = conv.shape[:-1]
+    return (xs.reshape(lead + (heads, p)), b.reshape(lead + (g, n)),
+            c.reshape(lead + (g, n)),
+            jax.nn.softplus(dt + w["dt_bias"]), -jnp.exp(w["a_log"]))
+
+
+def mamba2_output(y, xs, z, w, cfg):
+    """The skip `D x`, the gate before the grouped RMSNorm (G groups of
+    I/G, one weight of I), W_out. y, xs [..., heads, P], z [..., I]."""
+    g = cfg.ssm_groups
+    y = (y + w["d_skip"][:, None] * xs).reshape(z.shape) * jax.nn.silu(z)
+    y = rms_norm(y.reshape(z.shape[:-1] + (g, -1)), 1.0, cfg.rms_norm_eps) \
+        .reshape(z.shape) * w["ssm_norm"]
+    return mm("...c,ch->...h", y, w["w_out"])
+
+
+def mamba2_recurrent_step(state, xs, b, c, dt, a):
+    """One token for every slot: state [S, heads, P, N] float32; xs
+    [S, heads, P]; b, c [S, G, N]; dt [S, heads]; a [heads]. Returns (new
+    state, y [S, heads, P])."""
+    with jax.named_scope("ssm_state"):
+        rep = state.shape[1] // b.shape[1]
+        b, c = jnp.repeat(b, rep, axis=1), jnp.repeat(c, rep, axis=1)
+        state = jnp.exp(dt * a)[..., None, None] * state \
+            + (dt[..., None] * xs)[..., None] * b[:, :, None, :]
+        return state, f32mm("shpn,shn->shp", state, c)
+
+
+def mamba2_chunked(xs, b, c, dt, a, valid, chunk):
+    """The same recurrence over one sequence from a zero state, `chunk`
+    tokens at a time (the SSD form): xs [T, heads, P], b, c [T, G, N], dt
+    [T, heads], a [heads], `valid` [T] bool (bucket padding is False: its
+    step is 0, so it neither decays nor writes). Returns (y [T, heads, P],
+    final state [heads, P, N]).
+
+    With L_t the cumulative log-decay `sum dt a` inside a chunk:
+    `y_t = sum_{s<=t} e^{L_t - L_s} (C_t.B_s) dt_s x_s + e^{L_t} S0 C_t` and
+    `S_end = e^{L_end} S0 + sum_s e^{L_end - L_s} dt_s x_s (outer) B_s`;
+    only `S0 -> S_end` runs chunk after chunk. Every exponent is a
+    difference `L_t - L_s <= 0` taken before `exp`, never a product of
+    `e^{L_t}` and `e^{-L_s}`: a chunk of fast heads decays past what
+    float32 holds."""
+    t, heads, p = xs.shape
+    g, n = b.shape[1:]
+    size = min(chunk, t)
+    pad = -t % size
+    dt = jnp.where(valid[:, None], dt, 0.0)
+    if pad:
+        xs, b, c, dt = (jnp.pad(v, ((0, pad),) + ((0, 0),) * (v.ndim - 1))
+                        for v in (xs, b, c, dt))
+    nc = (t + pad) // size
+
+    def chunks(v):                       # [T, ...] -> [N, C, ...]
+        return v.reshape((nc, size) + v.shape[1:])
+
+    with jax.named_scope("ssm_state"):
+        xs, b, c, dt = map(chunks, (xs, b, c, dt))
+        cum = jnp.cumsum(dt * a, axis=1)                     # [N, C, heads]
+        xdt = xs * dt[..., None]
+        # within a chunk: (C_t.B_s) e^{L_t - L_s} on and below the diagonal
+        seen = jnp.tril(jnp.ones((size, size), bool))
+        decay = jnp.exp(jnp.where(
+            seen[None, :, :, None],
+            cum[:, :, None, :] - cum[:, None, :, :], -jnp.inf))
+        cb = f32mm("ntgk,nsgk->ntsg", c, b)
+        weight = jnp.repeat(cb, heads // g, axis=-1) * decay  # [N,C,C,heads]
+        y = f32mm("ntsh,nshp->nthp", weight, xdt)
+        # what a chunk adds to the state it hands on, and its whole decay
+        to_end = jnp.exp(cum[:, -1:, :] - cum)               # [N, C, heads]
+        b_h = jnp.repeat(b, heads // g, axis=2)
+        added = f32mm("nshp,nshk->nhpk", xdt * to_end[..., None], b_h)
+        total = jnp.exp(cum[:, -1, :])                       # [N, heads]
+
+        def carry(state, xs_c):
+            add_c, total_c = xs_c
+            return total_c[:, None, None] * state + add_c, state
+
+        state, starts = jax.lax.scan(
+            carry, jnp.zeros((heads, p, n), jnp.float32), (added, total))
+        c_h = jnp.repeat(c, heads // g, axis=2)
+        y = y + f32mm("nthk,nhpk->nthp", c_h * jnp.exp(cum)[..., None],
+                      starts)
+        return y.reshape(t + pad, heads, p)[:t], state
+
+
+# ------------------------------------------------- grouped-query attention
+
+def gqa_project(x, w, cfg):
+    """(q [..., T, n, d], the paged row [..., T, 2 kv d] = [k, v] of the
+    kv key/value heads as cached). No rotary: positions come from the
+    state-space blocks."""
+    q = mm("...h,hc->...c", x, w["wq"])
+    row = jnp.concatenate([mm("...h,hc->...c", x, w["wk"]),
+                           mm("...h,hc->...c", x, w["wv"])], -1)
+    return q.reshape(x.shape[:-1] + (cfg.num_heads, cfg.head_dim)), \
+        row.astype(jnp.bfloat16)
+
+
+def _gqa_attend(q, row, seen, w, cfg):
+    """q [B, Q, n, d] over rows [B, L, 2 kv d] where `seen` [B, Q, L]:
+    query head j reads key/value head j // (n / kv)."""
+    with jax.named_scope("gqa_attn"):
+        n, kv, d = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+        lead = q.shape[:2]
+        k, v = (part.reshape(row.shape[:2] + (kv, d))
+                for part in jnp.split(row, 2, axis=-1))
+        q = q.reshape(lead + (kv, n // kv, d))
+        scores = mm("bqkgd,blkd->bkgql", q, k) * d ** -0.5
+        scores = jnp.where(seen[:, None, None], scores, -jnp.inf)
+        o = mm("bkgql,blkd->bqkgd", jax.nn.softmax(scores, -1), v)
+        return mm("...c,ch->...h", o.reshape(lead + (n * d,)), w["wo"])
+
+
+def gqa_prefill(q, row, w, cfg):
+    """One request's own tokens [T] from position 0: the causal softmax.
+    Padding sits after the real tokens, so causality keeps it out."""
+    t = q.shape[0]
+    return _gqa_attend(q[None], row[None],
+                       jnp.tril(jnp.ones((t, t), bool))[None], w, cfg)[0]
+
+
+def gqa_decode(q, rows, pos, w, cfg):
+    """One token a slot: q [S, n, d], `rows` [S, L, 2 kv d] the slot's
+    rows (its own new row written), `pos` [S] the new token's position."""
+    seen = jnp.arange(rows.shape[1])[None, :] <= pos[:, None]
+    return _gqa_attend(q[:, None], rows, seen[:, None], w, cfg)[:, 0]
+
+
 # ------------------------------------------------------------- experts
 
 COUNTERS = ("moe_pairs_total", "moe_pairs_local", "moe_experts_hit",
@@ -375,8 +541,10 @@ def moe_share(x, w, cfg, live):
     holds (global ids from `experts_first`) for the tokens that chose them,
     and adds the shared expert. What the absent experts would add is left
     out; nothing stands in for them. `live` [T] bool marks the rows that are
-    someone's token (counters only). Returns (y [T, H], counters int32 [4]
-    in the order of COUNTERS)."""
+    someone's token (counters only). An expert is SwiGLU or, where the
+    configuration declares `moe_act` "relu2", `W_down relu(W_up x)^2`,
+    routed and shared alike. Returns (y [T, H], counters int32 [4] in the
+    order of COUNTERS)."""
     with jax.named_scope("moe_experts"):
         e = cfg.num_experts
         ids, weights = route(x, w, cfg)
@@ -387,11 +555,14 @@ def moe_share(x, w, cfg, live):
         dense_w = jnp.zeros((t, e + 1), jnp.float32).at[
             jnp.arange(t)[:, None], jnp.where(local, local_id, e)
         ].add(weights)[:, :e]
-        hid = jax.nn.silu(mm("th,ehf->etf", x, w["we_gate"])) \
+        relu2 = cfg.moe_act == "relu2"   # W_down relu(W_up x)^2, no gate
+        hid = jnp.square(jax.nn.relu(mm("th,ehf->etf", x, w["we_up"]))) \
+            if relu2 else jax.nn.silu(mm("th,ehf->etf", x, w["we_gate"])) \
             * mm("th,ehf->etf", x, w["we_up"])
         routed = mm("etf,efh->th", hid * dense_w.T[:, :, None],
                     w["we_down"])
-        y = routed + swiglu(x, w["ws_gate"], w["ws_up"], w["ws_down"])
+        y = routed + (relu2_mlp(x, w["ws_up"], w["ws_down"]) if relu2
+                      else swiglu(x, w["ws_gate"], w["ws_up"], w["ws_down"]))
         counted = local & live[:, None]
         per_expert = jnp.zeros((e + 1,), jnp.int32).at[
             jnp.where(counted, local_id, e).reshape(-1)].add(1)[:e]
