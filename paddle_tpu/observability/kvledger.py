@@ -66,6 +66,21 @@ event-stream self-consistency. Any divergence latches
 `serving_kv_ledger_divergence_total{invariant}`, a flight-recorder
 annotation, and (once) a postmortem bundle.
 
+What a check costs (ISSUE 35): the step's events, not the pool. The
+shadow keeps its aggregates as events apply (distinct blocks per
+(tenant, kind) and per tenant, the free list in the pool's own order,
+the cache-only count, the tier map, the (tenant, kind) keys whose
+count moved), and every invariant is still a WHOLE-pool comparison at
+every step boundary, made by the interpreter's own primitives (a list
+against a list, a set against a dict's keys): no Python-level
+iteration per block, cached entry or holder while nothing diverged.
+The per-block walks remain as the describer of a divergence once one
+is found and as the from-scratch oracle (`free_set`,
+`scan_tenant_kind_blocks`, `scan_tenant_resident_totals`,
+`scan_cache_only`) the tests hold the kept aggregates to.
+`LedgerReconciler.last_check` says what the last check visited one by
+one (`ledger_blocks_walked`).
+
 Zero-cost when disabled: the pool/cache hot paths pay one `is None`
 check; `disable()` (or PTN_KV_LEDGER=0) keeps engines from attaching a
 ledger at construction, and the streams are bit-identical either way —
@@ -192,6 +207,12 @@ class ShadowPool:
     are recorded in `errors` instead of raising: the shadow must keep
     tracking a diverged pool so the reconciler can describe the damage.
 
+    The aggregates the step boundary reads are kept as events apply
+    (ISSUE 35), so reading them costs nothing per block: the distinct
+    blocks per (tenant, kind) and per tenant, `free_list`, `cache_only`,
+    `tier_of`, and `dirty`. The `scan_*` methods recount each from the
+    per-block state; the two always agree (tests/test_kvledger.py).
+
     Stdlib-only on purpose (plain-list refcounts): the package contract
     is that every observability submodule imports before/without the
     accelerator stack, so offline tools can replay a ledger stream
@@ -208,21 +229,74 @@ class ShadowPool:
         self.tiered = {}             # chain key -> (owner tenant, tier)
         self.errors = []             # event-stream self-inconsistencies
         self.applied = 0
+        # the free list as the pool keeps it, a stack (alloc pops, the
+        # last unref pushes): a clean pool's list equals it element for
+        # element. A pool that orders its list otherwise loses only
+        # that short cut; the set of it is `free_set()` either way
+        self.free_list = list(range(self.num_blocks - 1, 0, -1))
+        self.cache_only = 0          # cached blocks with refcount 1
+        self.tier_of = {}            # chain key -> tier
+        self.dirty = set()           # (tenant, kind) whose count moved
+        #                              since `export_gauges` last took it
+        self._kind_blocks = {}       # (tenant, kind) -> distinct blocks
+        self._tenant_blocks = {}     # tenant -> distinct resident blocks
 
     def _err(self, msg):
         if len(self.errors) < self._MAX_ERRORS:
             self.errors.append(msg)
 
+    # -- the kept aggregates ------------------------------------------------
+    def _count(self, tenant, kind, d):
+        """Move (tenant, kind)'s distinct-block count by d; a count of
+        zero is no entry, as the from-scratch view has none."""
+        tk = (tenant, kind)
+        n = self._kind_blocks.get(tk, 0) + d
+        if n:
+            self._kind_blocks[tk] = n
+        else:
+            self._kind_blocks.pop(tk, None)
+        self.dirty.add(tk)
+
+    def _count_tenant(self, tenant, d):
+        n = self._tenant_blocks.get(tenant, 0) + d
+        if n:
+            self._tenant_blocks[tenant] = n
+        else:
+            self._tenant_blocks.pop(tenant, None)
+
+    def _holder_moved(self, hs, holder, d):
+        """`holder` joined (d=+1, not yet in `hs`) or left (d=-1,
+        already out of `hs`) a block's holders: the block counts once
+        for a (tenant, kind) pair and once for a tenant however many of
+        its holders carry them, so only the first to come and the last
+        to go move a count."""
+        tenant, kind = holder[0], holder[1]
+        same_tenant = False
+        for h in hs:
+            if h[0] == tenant:
+                if h[1] == kind:
+                    return
+                same_tenant = True
+        self._count(tenant, kind, d)
+        if not same_tenant:
+            self._count_tenant(tenant, d)
+
+    def _forget_holders(self, b):
+        """Block `b` lost all its holders at once (freed, or allocated
+        over): each distinct pair and tenant among them counts one
+        block fewer."""
+        hs = self.holders.pop(b, None)
+        if not hs:
+            return
+        for tenant, kind in {(h[0], h[1]) for h in hs}:
+            self._count(tenant, kind, -1)
+        for tenant in {h[0] for h in hs}:
+            self._count_tenant(tenant, -1)
+
     def _drop_holder(self, b, tenant, rid, origin):
         hs = self.holders.get(b)
         if not hs:
             return
-        if origin == "prefix_cache.evict":
-            # the cache's own reference, whoever inserted it
-            for i, h in enumerate(hs):
-                if h[1] == "cached":
-                    hs.pop(i)
-                    return
         preds = (
             lambda h: rid is not None and h[2] == rid
             and h[1] != "cached",
@@ -230,11 +304,28 @@ class ShadowPool:
             lambda h: h[0] == tenant and h[1] == "private",
             lambda h: True,
         )
+        if origin == "prefix_cache.evict":
+            # the cache's own reference, whoever inserted it
+            preds = (lambda h: h[1] == "cached",) + preds
         for pred in preds:
             for i, h in enumerate(hs):
                 if pred(h):
-                    hs.pop(i)
+                    self._holder_moved(hs, hs.pop(i), -1)
                     return
+
+    def _tier_moved(self, key, entry):
+        """Chain `key`'s cold copy is now `entry` ((owner, tier), or
+        None when it left the tiers): one entry == one block-sized
+        record under `serving_kv_blocks{tenant,kind=host|disk}`."""
+        old = self.tiered.pop(key, None)
+        self.tier_of.pop(key, None)
+        if old is not None and old[1] in ("host", "disk"):
+            self._count(old[0] or DEFAULT_TENANT, old[1], -1)
+        if entry is not None:
+            self.tiered[key] = entry
+            self.tier_of[key] = entry[1]
+            if entry[1] in ("host", "disk"):
+                self._count(entry[0] or DEFAULT_TENANT, entry[1], 1)
 
     def apply(self, ev):
         kind = ev["event"]
@@ -249,85 +340,113 @@ class ShadowPool:
             if key is None:
                 self._err(f"seq {ev.get('seq')}: {kind} without a key")
             elif kind == "tier_demote":
-                self.tiered[key] = (ev.get("owner") or tenant,
-                                    ev.get("tier"))
+                self._tier_moved(key, (ev.get("owner") or tenant,
+                                       ev.get("tier")))
             else:
                 if key not in self.tiered:
                     self._err(f"seq {ev.get('seq')}: {kind} of "
                               f"untiered key {key}")
-                self.tiered.pop(key, None)
+                self._tier_moved(key, None)
             self.applied += 1
             return
+        refs, cached = self.refs, self.cached
         for b in ev.get("blocks", ()):
             b = int(b)
             if not 0 < b < self.num_blocks:
                 self._err(f"seq {ev.get('seq')}: block {b} out of "
                           f"range for pool of {self.num_blocks}")
                 continue
+            cache_only = b in cached and refs[b] == 1
             if kind == "alloc":
                 if b in self.allocated:
                     self._err(f"seq {ev.get('seq')}: double alloc of "
                               f"block {b}")
+                elif self.free_list[-1] == b:
+                    self.free_list.pop()
+                else:
+                    self.free_list.remove(b)
+                self._forget_holders(b)     # a diverged stream's leftovers
                 self.allocated.add(b)
-                self.refs[b] = 1
-                self.holders[b] = [(tenant, "private", rid)]
+                refs[b] = 1
+                holder = (tenant, "private", rid)
+                self.holders[b] = [holder]
+                self._holder_moved((), holder, 1)
             elif kind == "ref":
-                if b not in self.allocated or self.refs[b] < 1:
+                if b not in self.allocated or refs[b] < 1:
                     self._err(f"seq {ev.get('seq')}: ref of free "
                               f"block {b}")
-                self.refs[b] += 1
-                self.holders.setdefault(b, []).append(
-                    (tenant, _holder_kind(origin), rid))
+                refs[b] += 1
+                holder = (tenant, _holder_kind(origin), rid)
+                hs = self.holders.setdefault(b, [])
+                self._holder_moved(hs, holder, 1)
+                hs.append(holder)
             elif kind == "unref":
-                if self.refs[b] < 1:
+                if refs[b] < 1:
                     self._err(f"seq {ev.get('seq')}: unref of free "
                               f"block {b}")
                 else:
-                    self.refs[b] -= 1
+                    refs[b] -= 1
                 self._drop_holder(b, tenant, rid, origin)
             elif kind == "free":
-                if self.refs[b] != 0:
+                if refs[b] != 0:
                     self._err(f"seq {ev.get('seq')}: free of block {b} "
-                              f"with {int(self.refs[b])} refs")
-                self.allocated.discard(b)
-                self.holders.pop(b, None)
+                              f"with {int(refs[b])} refs")
+                if b in self.allocated:
+                    self.allocated.discard(b)
+                    self.free_list.append(b)
+                self._forget_holders(b)
             elif kind == "cache_insert":
-                self.cached[b] = tenant
+                cached[b] = tenant
             elif kind == "cache_evict":
-                self.cached.pop(b, None)
+                cached.pop(b, None)
             # share: attribution metadata only — its refs ride alongside
+            self.cache_only += (b in cached and refs[b] == 1) - cache_only
         self.applied += 1
 
     # -- aggregation views --------------------------------------------------
+    def tenant_kind_blocks(self):
+        """{(tenant, kind): distinct resident blocks} — a block counts
+        once per (tenant, kind) pair holding it, so two same-tenant
+        sharers of one block read as one shared block; a chain entry on
+        a cold tier counts once under its owner and kind host|disk
+        (ISSUE 18). Kept as events apply: a copy, at no cost a block."""
+        return dict(self._kind_blocks)
+
+    def tenant_resident_totals(self):
+        """{tenant: distinct resident blocks of any kind} — the load
+        harness's per-step residency sample. Kept as events apply."""
+        return dict(self._tenant_blocks)
+
+    # -- the same, recounted from the per-block state -----------------------
+    # What the views above must equal at every moment, and what an
+    # offline audit may trust without trusting the bookkeeping.
     def free_set(self):
         """Block ids the shadow believes sit on the free list."""
         return {b for b in range(1, self.num_blocks)
                 if b not in self.allocated}
 
-    def tenant_kind_blocks(self):
-        """{(tenant, kind): distinct resident blocks} — a block counts
-        once per (tenant, kind) pair holding it, so two same-tenant
-        sharers of one block read as one shared block."""
+    def scan_tenant_kind_blocks(self):
         out = {}
         for b, hs in self.holders.items():
             for tk in {(h[0], h[1]) for h in hs}:
                 out[tk] = out.get(tk, 0) + 1
-        # cold tiers (ISSUE 18): one entry == one block-sized record, so
-        # serving_kv_blocks{tenant,kind=host|disk} counts demoted blocks
         for owner, tier in self.tiered.values():
             if tier in ("host", "disk"):
                 tk = (owner or DEFAULT_TENANT, tier)
                 out[tk] = out.get(tk, 0) + 1
         return out
 
-    def tenant_resident_totals(self):
-        """{tenant: distinct resident blocks of any kind} — the load
-        harness's per-step residency sample."""
+    def scan_tenant_resident_totals(self):
         out = {}
         for b, hs in self.holders.items():
             for t in {h[0] for h in hs}:
                 out[t] = out.get(t, 0) + 1
         return out
+
+    def scan_cache_only(self):
+        """Cached blocks whose only reference is the cache's: what
+        `PrefixCache.evictable()` must read."""
+        return sum(1 for b in self.cached if self.refs[b] == 1)
 
 
 def replay_events(events, num_blocks):
@@ -360,7 +479,6 @@ class KVLedger:
         self.events = []
         self.shadow = ShadowPool(self.num_blocks)
         self._seq = 0
-        self._exported = set()       # (tenant, kind) keys last exported
 
     def __len__(self):
         return len(self.events)
@@ -428,17 +546,21 @@ class KVLedger:
         self.events = []
 
     def export_gauges(self):
-        """Publish serving_kv_blocks/bytes{tenant,kind} from the shadow,
-        zeroing (tenant, kind) series that went non-resident so a stale
-        child can never read as live HBM."""
-        counts = self.shadow.tenant_kind_blocks()
-        for t, k in self._exported - set(counts):
-            _G_BLOCKS.labels(tenant=t, kind=k).set(0)
-            _G_BYTES.labels(tenant=t, kind=k).set(0)
-        for (t, k), n in counts.items():
+        """Publish serving_kv_blocks/bytes{tenant,kind} from the shadow:
+        only the (tenant, kind) series whose count moved since the last
+        export are set, a series that went non-resident to zero, so a
+        stale child can never read as live HBM. Returns how many series
+        it set."""
+        if not _metrics.registry().enabled:
+            return 0        # a set() would be dropped: the series stay owed
+        shadow = self.shadow
+        dirty, shadow.dirty = shadow.dirty, set()
+        counts = shadow._kind_blocks
+        for t, k in dirty:
+            n = counts.get((t, k), 0)
             _G_BLOCKS.labels(tenant=t, kind=k).set(n)
             _G_BYTES.labels(tenant=t, kind=k).set(n * self.block_bytes)
-        self._exported = set(counts)
+        return len(dirty)
 
 
 # -------------------------------------------------------- the reconciler
@@ -446,10 +568,16 @@ class KVLedger:
 class LedgerReconciler:
     """Continuous invariant checker: at every scheduler-step boundary,
     compare the ledger's shadow model against the REAL free list,
-    refcounts, and prefix-cache structure. A clean pool passes every
-    check for free; any divergence is latched (counter + flight-recorder
-    annotation + one postmortem bundle) and keeps being counted each
-    step it persists — a leak does not heal by being old."""
+    refcounts, and prefix-cache structure. Every invariant is a
+    whole-pool comparison every time, so damage done behind the
+    ledger's back (a refcount or a free-list entry changed with no
+    event) is caught within one step too; a clean pool passes each in
+    a constant number of the interpreter's own list/set/dict
+    operations, and only a comparison that fails walks the pool block
+    by block, to describe what it found. Any divergence is latched
+    (counter + flight-recorder annotation + one postmortem bundle) and
+    keeps being counted each step it persists — a leak does not heal by
+    being old."""
 
     def __init__(self, ledger, pool, cache=None, tier_store=None):
         self.ledger = ledger
@@ -459,6 +587,13 @@ class LedgerReconciler:
         self.divergences = []        # latched messages, newest-last
         self._dumped = False
         self.last_postmortem = None
+        self._applied = ledger.shadow.applied
+        # what the last check() cost (the scheduler notes it on its
+        # `serving::bookkeeping` span): events applied since the check
+        # before, blocks / cached entries / holders / tier entries /
+        # gauge series visited one by one in Python, the pool's blocks
+        self.last_check = {"ledger_events": 0, "ledger_blocks_walked": 0,
+                           "ledger_pool_blocks": ledger.num_blocks}
         # prime every invariant's series at zero so a later increment is
         # a DELTA from a clean baseline, not a first sight that
         # metrics_report --compare could mistake for schema churn
@@ -466,8 +601,10 @@ class LedgerReconciler:
             _C_DIVERGENCE.labels(invariant=inv).inc(0)
 
     def _diffs(self):
-        """[(invariant, message)] — one entry per violated invariant."""
-        out = []
+        """([(invariant, message)], walked) — one entry per violated
+        invariant, and how many blocks, cached entries and tier entries
+        the describing of them visited one by one (0 on a clean pool)."""
+        out, walked = [], 0
         shadow = self.ledger.shadow
         pool = self.pool
         if shadow.errors:
@@ -475,16 +612,18 @@ class LedgerReconciler:
                         f"{len(shadow.errors)} impossible transitions "
                         f"in the event stream; first: "
                         f"{shadow.errors[0]}"))
-        real_refs = [int(r) for r in pool._refs]
+        real_refs = pool._refs.tolist()
         if shadow.refs != real_refs:
+            walked += shadow.num_blocks
             bad = [b for b in range(shadow.num_blocks)
                    if shadow.refs[b] != real_refs[b]][:8]
             out.append(("refcounts", "refcount mismatch at blocks " +
                         ", ".join(f"{b} (ledger {shadow.refs[b]} vs "
                                   f"pool {real_refs[b]})" for b in bad)))
-        real_free = set(int(b) for b in pool._free)
-        shadow_free = shadow.free_set()
-        if real_free != shadow_free:
+        if pool._free != shadow.free_list:
+            walked += shadow.num_blocks + len(pool._free)
+            real_free = set(int(b) for b in pool._free)
+            shadow_free = shadow.free_set()
             leaked = sorted(shadow_free - real_free)
             phantom = sorted(real_free - shadow_free)
             parts = []
@@ -496,59 +635,84 @@ class LedgerReconciler:
                 parts.append(f"blocks {phantom[:8]} on the free list "
                              f"the ledger still sees allocated "
                              f"(double free)")
-            out.append(("free_list", "; ".join(parts)))
+            if len(real_free) != len(pool._free):
+                parts.append(f"{len(pool._free) - len(real_free)} "
+                             f"entries on the free list twice "
+                             f"(double free)")
+            if parts:           # the same set in another order is none
+                out.append(("free_list", "; ".join(parts)))
         cache = self.cache
         if cache is not None:
-            real_cached = set(int(b) for b in cache._entries.values())
-            led_cached = set(shadow.cached)
-            if real_cached != led_cached:
-                out.append(("cached_set",
-                            f"cache holds blocks "
-                            f"{sorted(real_cached - led_cached)[:8]} the"
-                            f" ledger missed; ledger holds "
-                            f"{sorted(led_cached - real_cached)[:8]} "
-                            f"the cache dropped"))
-            orphans = [k for k, parent in cache._parent.items()
-                       if parent is not None
-                       and parent not in cache._entries]
-            if orphans:
+            if set(cache._entries.values()) != shadow.cached.keys():
+                walked += len(cache._entries) + len(shadow.cached)
+                real_cached = set(int(b) for b in cache._entries.values())
+                led_cached = set(shadow.cached)
+                if real_cached != led_cached:
+                    out.append(
+                        ("cached_set",
+                         f"cache holds blocks "
+                         f"{sorted(real_cached - led_cached)[:8]} the "
+                         f"ledger missed; ledger holds "
+                         f"{sorted(led_cached - real_cached)[:8]} the "
+                         f"cache dropped"))
+            parents = set(cache._parent.values())
+            parents.discard(None)
+            if not cache._entries.keys() >= parents:
+                walked += len(cache._parent)
+                orphans = [k for k, parent in cache._parent.items()
+                           if parent is not None
+                           and parent not in cache._entries]
                 out.append(("orphan_chain",
                             f"{len(orphans)} cached entries whose chain "
                             f"parent was evicted (unmatchable tails)"))
-            want = sum(1 for b in led_cached if shadow.refs[b] == 1)
+            want = shadow.cache_only
             got = cache.evictable()
             if want != got:
-                out.append(("evictable",
-                            f"cache.evictable()={got} but the ledger "
-                            f"counts {want} cache-only blocks"))
+                walked += len(shadow.cached)
+                want = shadow.scan_cache_only()
+                if want != got:
+                    out.append(("evictable",
+                                f"cache.evictable()={got} but the ledger "
+                                f"counts {want} cache-only blocks"))
         store = self.tier_store
         if store is not None:
             # ISSUE 18: the shadow's {key: tier} map must equal the live
             # tier store's residency — a demote the ledger missed (or a
             # dropped entry it still counts) is a cross-tier leak
-            real_tiers = {str(k): str(t)
-                          for k, t in store.residency().items()}
-            led_tiers = {str(k): str(t)
-                         for k, (_own, t) in shadow.tiered.items()}
-            if real_tiers != led_tiers:
-                ghost = sorted(set(led_tiers) - set(real_tiers))
-                unseen = sorted(set(real_tiers) - set(led_tiers))
-                moved = sorted(k for k in set(led_tiers) & set(real_tiers)
-                               if led_tiers[k] != real_tiers[k])
-                out.append(("tier_residency",
-                            f"{len(ghost)} ledger-only tier entries "
-                            f"(dropped without tier_drop), {len(unseen)} "
-                            f"store-only (demoted without tier_demote), "
-                            f"{len(moved)} on the wrong tier"))
-        return out
+            residency = store.residency()
+            if residency != shadow.tier_of:
+                walked += len(residency) + len(shadow.tiered)
+                real_tiers = {str(k): str(t)
+                              for k, t in residency.items()}
+                led_tiers = {str(k): str(t)
+                             for k, (_own, t) in shadow.tiered.items()}
+                if real_tiers != led_tiers:
+                    ghost = sorted(set(led_tiers) - set(real_tiers))
+                    unseen = sorted(set(real_tiers) - set(led_tiers))
+                    moved = sorted(
+                        k for k in set(led_tiers) & set(real_tiers)
+                        if led_tiers[k] != real_tiers[k])
+                    out.append(
+                        ("tier_residency",
+                         f"{len(ghost)} ledger-only tier entries "
+                         f"(dropped without tier_drop), {len(unseen)} "
+                         f"store-only (demoted without tier_demote), "
+                         f"{len(moved)} on the wrong tier"))
+        return out, walked
 
     def check(self):
         """Run every invariant; returns the (possibly empty) list of
         divergence messages found THIS call. Also refreshes the
         per-tenant residency gauges — the reconciler is the step-boundary
         hook, so the gauges track live occupancy at step granularity."""
-        diffs = self._diffs()
-        self.ledger.export_gauges()
+        diffs, walked = self._diffs()
+        shadow = self.ledger.shadow
+        self.last_check = {
+            "ledger_events": shadow.applied - self._applied,
+            "ledger_blocks_walked":
+                walked + self.ledger.export_gauges(),
+            "ledger_pool_blocks": shadow.num_blocks}
+        self._applied = shadow.applied
         if not diffs:
             return []
         msgs = [f"{inv}: {msg}" for inv, msg in diffs]

@@ -527,6 +527,12 @@ class BlockPool:
     def refcount(self, block_id):
         return int(self._refs[block_id])
 
+    def refcounts(self, block_ids):
+        """The refcounts of many blocks in one indexing (an int32 array,
+        in the order given): what a count over every cached block costs
+        as one operation and not a `refcount()` call a block."""
+        return self._refs[np.fromiter(block_ids, np.int64)]
+
     def _export(self):
         _M_POOL_TOTAL.set(self.capacity)
         _M_POOL_IN_USE.set(self.in_use)

@@ -117,10 +117,9 @@ class TieredBlockStore:
     def residency(self):
         """{key: "host"|"disk"} — what the ledger reconciler's
         tier_residency invariant compares the shadow model against."""
-        out = {key: "disk" for key in
-               (self.disk.keys() if self.disk is not None else ())}
-        for key in self.host.keys():
-            out[key] = "host"
+        out = dict.fromkeys(
+            self.disk.keys() if self.disk is not None else (), "disk")
+        out.update(dict.fromkeys(self.host.keys(), "host"))
         return out
 
     # -- demote (PrefixCache eviction hook) ----------------------------------

@@ -239,8 +239,8 @@ class PrefixCache:
         shed_pool_free watermark — must treat these as free, else a warm
         cache reads as a full pool and sheds traffic an eviction would
         trivially serve."""
-        return sum(1 for blk in self._entries.values()
-                   if self.pool.refcount(blk) == 1)
+        refs = self.pool.refcounts(self._entries.values())
+        return int(np.count_nonzero(refs == 1))
 
     def _touch(self, key):
         self._seq += 1

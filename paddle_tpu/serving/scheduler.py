@@ -922,7 +922,8 @@ class Scheduler:
                 # hot-swap leaves probation — it did not poison decode)
                 self._quarantined.clear()
                 self._swap_probation = False
-        with _span("serving::bookkeeping"):
+        bookkeeping = {}
+        with _span("serving::bookkeeping", bookkeeping):
             self._steps += 1
             _M_QUEUE_DEPTH.set(len(self._queue))
             _M_OCCUPANCY.set(self.active_slots() / max(self.engine.slots, 1))
@@ -932,6 +933,7 @@ class Scheduler:
             # the JSONL ahead of the step record that closed them
             if self._kv_reconciler is not None:
                 self._kv_reconciler.check()
+                bookkeeping.update(self._kv_reconciler.last_check)
                 self._write_kvledger_records()
             self._write_step_record(now, len(active))
         return bool(self._queue or any(s is not None for s in self._slots))

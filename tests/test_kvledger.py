@@ -378,3 +378,416 @@ def test_fleet_priming_creates_kv_children_at_zero():
             f"serving_kv_blocks{{kind={kind},tenant=primed_t}}"] == 0
         assert flat[
             f"serving_kv_bytes{{kind={kind},tenant=primed_t}}"] == 0
+
+
+# ---------------- ISSUE 35: the aggregates kept as events apply, the checks
+# ---------------- made whole at every boundary without a walk of the pool
+
+_TENANTS = ("eq-a", "eq-b", "eq-c", None)
+_ORIGINS = ("prefill", "grow", "retire", "prefix_cache.match",
+            "prefix_cache.insert", "prefix_cache.evict",
+            "prefix_cache.promote")
+
+
+def _gauge(name, tenant, kind):
+    return getattr(kvledger, name).labels(tenant=tenant, kind=kind).value
+
+
+def _views_agree(shadow):
+    """The kept aggregates against the per-block recount."""
+    assert shadow.tenant_kind_blocks() == shadow.scan_tenant_kind_blocks()
+    assert shadow.tenant_resident_totals() == \
+        shadow.scan_tenant_resident_totals()
+    assert shadow.cache_only == shadow.scan_cache_only()
+    free = shadow.free_set()
+    assert len(free) == shadow.num_blocks - 1 - len(shadow.allocated)
+    assert free.isdisjoint(shadow.allocated)
+    assert len(shadow.free_list) == len(free)
+    assert set(shadow.free_list) == free
+    assert shadow.tier_of == {k: t for k, (_o, t) in shadow.tiered.items()}
+
+
+def _random_batch(led, rng, refs, n_events, big):
+    """`n_events` ledger events under changing attribution. `refs` is
+    the driver's own {block: refcount}: most transitions are the ones a
+    pool could make, a few are not (a diverged stream must keep its
+    aggregates equal to its own per-block state too)."""
+    nb = led.num_blocks
+    for _ in range(n_events):
+        tenant = _TENANTS[rng.randint(len(_TENANTS))]
+        rid = int(rng.randint(1, 40)) if rng.rand() < 0.8 else None
+        held = list(refs)
+        op = rng.choice(["alloc", "ref", "unref", "unref", "cache",
+                         "evict", "share", "tier", "junk"],
+                        p=[.2, .17, .2, .1, .1, .08, .03, .1, .02])
+        with kvledger.attribution(request_id=rid, tenant=tenant,
+                                  origin=_ORIGINS[rng.randint(3)]):
+            if op == "alloc":
+                free = [b for b in rng.randint(1, nb, 3 * (90 if big else 3))
+                        if b not in refs]
+                free = list(dict.fromkeys(int(b) for b in free))
+                if free:
+                    led.pool_alloc(free)
+                    refs.update(dict.fromkeys(free, 1))
+            elif op == "ref" and held:
+                b = held[rng.randint(len(held))]
+                with kvledger.origin_scope(_ORIGINS[rng.randint(7)]):
+                    led.pool_ref(b)
+                refs[b] += 1
+            elif op == "unref" and held:
+                for b in rng.choice(held, min(len(held),
+                                              40 if big else 2), False):
+                    b = int(b)
+                    if rng.rand() < 0.3:
+                        with kvledger.origin_scope("prefix_cache.evict"):
+                            led.pool_unref(b)
+                    else:
+                        led.pool_unref(b)
+                    refs[b] -= 1
+                    if not refs[b]:
+                        del refs[b]
+                        led.pool_free(b)
+            elif op == "cache" and held:
+                b = held[rng.randint(len(held))]
+                with kvledger.origin_scope("prefix_cache.insert"):
+                    led.pool_ref(b)
+                refs[b] += 1
+                led.cache_insert((b,))
+            elif op == "evict" and led.shadow.cached:
+                cached = list(led.shadow.cached)
+                led.cache_evict((cached[rng.randint(len(cached))],))
+            elif op == "share" and held:
+                led.cache_share(held[:2], tokens=8)
+            elif op == "tier":
+                key = f"chain{rng.randint(12)}"
+                what = rng.randint(4)
+                if what == 0:
+                    led.tier_demote((), key, "host", tenant or "default")
+                elif what == 1:
+                    led.tier_demote((), key, "disk", "eq-b")
+                elif what == 2:
+                    led.tier_promote((1,), key, "host", "eq-a")
+                else:
+                    led.tier_drop(key, "disk", "eq-a", reason="capacity")
+            elif op == "junk":
+                # transitions no pool makes: the shadow records them in
+                # `errors` and keeps tracking
+                led._emit(["alloc", "ref", "unref", "free"][rng.randint(4)],
+                          (int(rng.randint(0, nb + 2)),))
+
+
+@pytest.mark.parametrize("num_blocks", [64, 12289])
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_kept_aggregates_equal_the_recount_and_the_replay(seed, num_blocks):
+    """Random alloc / ref / unref / free / cache_insert / cache_evict /
+    share / tier streams under changing attribution: after every batch
+    the views kept as events apply equal the ones recounted from the
+    per-block state, the gauges read the recount, and at the end a
+    ShadowPool replayed from the serialized stream equals the live one,
+    aggregates included."""
+    rng = np.random.RandomState(seed)
+    big = num_blocks > 1000
+    led = kvledger.KVLedger(num_blocks, block_bytes=48)
+    refs, seen = {}, set()
+    for _ in range(12):
+        _random_batch(led, rng, refs, 120 if big else 60, big)
+        shadow = led.shadow
+        _views_agree(shadow)
+        led.export_gauges()
+        counts = shadow.scan_tenant_kind_blocks()
+        seen |= set(counts)
+        for t, k in seen:
+            assert _gauge("_G_BLOCKS", t, k) == counts.get((t, k), 0)
+            assert _gauge("_G_BYTES", t, k) == 48 * counts.get((t, k), 0)
+        assert not shadow.dirty
+    assert big is False or len(led.shadow.allocated) > 1000
+    assert led.shadow.errors                   # the junk was seen
+    stream = json.loads(json.dumps(led.events))
+    replayed = kvledger.replay_events(stream, num_blocks)
+    live = led.shadow
+    for field in ("refs", "allocated", "holders", "cached", "tiered",
+                  "errors", "applied", "free_list", "cache_only",
+                  "tier_of"):
+        got = getattr(replayed, field)
+        if field == "holders":      # tuples come back from JSON as such
+            got = {b: [tuple(h) for h in hs] for b, hs in got.items()}
+        assert got == getattr(live, field), field
+    assert replayed.tenant_kind_blocks() == live.tenant_kind_blocks()
+    assert replayed.tenant_resident_totals() == \
+        live.tenant_resident_totals()
+    assert replayed.free_set() == live.free_set()
+    _views_agree(replayed)
+
+
+def _attached_12k():
+    """A 12 289-block pool, 95 % allocated by 128 requests of two
+    tenants, each with its prompt's first eight blocks in an attached
+    prefix cache, a tier store behind the cache holding a few evicted
+    chains — and the reconciler over all of it, clean."""
+    from paddle_tpu.serving.kv_tiers import TieredBlockStore
+    nb, bs, per = 12289, 4, 91
+    pool = BlockPool(nb, bs)
+    ledger = kvledger.KVLedger(nb, block_bytes=64)
+    pool.attach_ledger(ledger)
+    cache = PrefixCache(pool, bs)
+    cache.attach_ledger(ledger)
+    store = TieredBlockStore(
+        lambda blk: {"quant": False,
+                     "arrays": {"k0": np.full((2,), blk, np.float32)}},
+        lambda blk, arrays: None, host_blocks=64)
+    store.attach_ledger(ledger)
+    cache.attach_tier(store)
+    rng = np.random.RandomState(35)
+    rows = {}
+
+    def admit(rid):
+        prompt = rng.randint(0, 50000, 8 * bs + 1).tolist()
+        with kvledger.attribution(request_id=rid, tenant=f"wb-{rid % 2}",
+                                  origin="prefill"):
+            rows[rid] = pool.alloc(per)
+            cache.insert(prompt, rows[rid], 8 * bs)
+
+    def retire(rid):
+        with kvledger.attribution(request_id=rid, tenant=f"wb-{rid % 2}",
+                                  origin="retire"):
+            for b in rows.pop(rid):
+                pool.unref(b)
+
+    for rid in range(128):
+        admit(rid)
+    for rid in (3, 4):
+        retire(rid)                  # their cached blocks: cache-only now
+    assert cache.evict(6) == 6       # six chains' tails go to the host tier
+    for rid in (200, 201):
+        admit(rid)
+    recon = kvledger.LedgerReconciler(ledger, pool, cache, tier_store=store)
+    assert recon.check() == []
+    assert pool.in_use > 0.94 * pool.capacity
+    assert len(cache._entries) > 1000 and cache.evictable() == 10
+    assert len(store.residency()) == 6
+    return {"pool": pool, "ledger": ledger, "cache": cache, "store": store,
+            "recon": recon, "rows": rows, "admit": admit, "retire": retire}
+
+
+def _leak_one_block(w):
+    spec = faults.arm("serving.kv_ledger_leak", "truncate", nth=1,
+                      max_fires=1)
+    try:
+        with kvledger.attribution(request_id=7, tenant="wb-1",
+                                  origin="retire"):
+            w["pool"].unref(w["rows"][7].pop())
+    finally:
+        faults.disarm("serving.kv_ledger_leak")
+    assert spec.fires == 1
+
+
+def _ref_a_free_block(w):
+    w["ledger"].pool_ref(w["pool"]._free[0])
+
+
+def _poke_refcount(w):
+    w["pool"]._refs[w["rows"][9][20]] += 1
+
+
+def _drop_a_free_entry(w):
+    w["pool"]._free.pop()
+
+
+def _free_an_allocated_block(w):
+    w["pool"]._free.append(w["rows"][9][20])
+
+
+def _free_a_block_twice(w):
+    w["pool"]._free.append(w["pool"]._free[0])
+
+
+def _swap_in_a_block_out_of_range(w):
+    w["pool"]._free[0] = w["pool"].num_blocks + 5
+
+
+def _drop_a_cache_entry(w):
+    leaf = next(iter(w["cache"]._leaves))
+    del w["cache"]._entries[leaf]
+
+
+def _repoint_a_cache_entry(w):
+    key = next(iter(w["cache"]._entries))
+    w["cache"]._entries[key] = w["rows"][9][20]
+
+
+def _orphan_a_chain(w):
+    key = next(k for k, p in w["cache"]._parent.items() if p is not None)
+    w["cache"]._parent[key] = "no-such-entry"
+
+
+def _cache_only_behind_the_ledger(w):
+    # a cached block a request still co-owns, made to look cache-only
+    w["pool"]._refs[w["rows"][9][0]] = 1
+
+
+def _evictable_lies(w):
+    real = w["cache"].evictable
+    w["cache"].evictable = lambda: real() + 1
+
+
+def _drop_a_tier_entry(w):
+    w["store"].host.drop(next(iter(w["store"].residency())))
+
+
+def _demote_behind_the_ledger(w):
+    w["store"].host.put("unseen", {"ns": None, "parent": None,
+                                   "quant": False, "arrays": {}})
+
+
+@pytest.mark.parametrize("invariant,damage", [
+    ("event_stream", _ref_a_free_block),
+    ("refcounts", _poke_refcount),
+    ("free_list", _leak_one_block),
+    ("free_list", _drop_a_free_entry),
+    ("free_list", _free_an_allocated_block),
+    ("free_list", _free_a_block_twice),
+    ("free_list", _swap_in_a_block_out_of_range),
+    ("cached_set", _drop_a_cache_entry),
+    ("cached_set", _repoint_a_cache_entry),
+    ("orphan_chain", _orphan_a_chain),
+    ("evictable", _cache_only_behind_the_ledger),
+    ("evictable", _evictable_lies),
+    ("tier_residency", _drop_a_tier_entry),
+    ("tier_residency", _demote_behind_the_ledger),
+], ids=lambda v: v if isinstance(v, str) else v.__name__.strip("_"))
+def test_each_invariant_fires_in_the_first_check_after_its_damage(
+        invariant, damage):
+    """Every entry of INVARIANTS, damage done through the fault site and
+    behind the ledger's back (a poke at the pool's, the cache's or the
+    tier store's own state, no event): the very next check() names it,
+    and goes on naming it while it lasts."""
+    w = _attached_12k()
+    div0 = _divergence_total()
+    damage(w)
+    found = w["recon"].check()
+    assert any(m.startswith(invariant + ":") for m in found), found
+    assert _divergence_total() >= div0 + 1
+    again = w["recon"].check()
+    assert any(m.startswith(invariant + ":") for m in again), again
+
+
+def test_every_invariant_has_a_detection_case():
+    marks = test_each_invariant_fires_in_the_first_check_after_its_damage \
+        .pytestmark
+    cases = next(m for m in marks if m.name == "parametrize").args[1]
+    assert {inv for inv, _ in cases} == set(kvledger.INVARIANTS)
+
+
+@pytest.mark.parametrize("events", ["none", "a decode step", "a retirement"])
+def test_a_clean_check_walks_what_the_step_changed_not_the_pool(
+        events, monkeypatch):
+    """The work bound, on the counter and never on a clock: a clean
+    check() after k events visits at most k things one by one (the
+    gauge series whose count moved), calls none of the per-block
+    recounts, and says so in `last_check` — what the scheduler puts on
+    its `serving::bookkeeping` span."""
+    w = _attached_12k()
+    shadow = w["ledger"].shadow
+    for name in ("free_set", "scan_tenant_kind_blocks",
+                 "scan_tenant_resident_totals", "scan_cache_only"):
+        monkeypatch.setattr(
+            shadow, name, lambda name=name: pytest.fail(
+                f"a clean check() called {name}()"), raising=True)
+    applied = shadow.applied
+    if events == "a decode step":        # eight slots cross a block edge
+        for rid in range(10, 18):
+            with kvledger.attribution(request_id=rid,
+                                      tenant=f"wb-{rid % 2}",
+                                      origin="grow"):
+                w["rows"][rid].extend(w["pool"].alloc(1))
+    elif events == "a retirement":       # 182 blocks back, 91 out again
+        w["retire"](20)
+        w["retire"](21)
+        w["admit"](300)
+    k = shadow.applied - applied
+    assert w["recon"].check() == []
+    last = w["recon"].last_check
+    assert last["ledger_events"] == k
+    assert last["ledger_pool_blocks"] == 12289
+    assert last["ledger_blocks_walked"] <= k
+    assert last["ledger_blocks_walked"] < 0.05 * 12289
+    # and a second check with nothing in between walks nothing at all
+    assert w["recon"].check() == []
+    assert w["recon"].last_check["ledger_blocks_walked"] == 0
+    assert w["recon"].last_check["ledger_events"] == 0
+
+
+def test_a_step_that_finds_a_divergence_may_walk_everything():
+    w = _attached_12k()
+    _drop_a_free_entry(w)
+    assert w["recon"].check()
+    assert w["recon"].last_check["ledger_blocks_walked"] >= 12289
+
+
+def test_a_free_list_in_another_order_is_no_divergence():
+    """The shadow keeps the free list in the pool's own order for the
+    one-comparison short cut only: the invariant is the SET, as it
+    always was."""
+    w = _attached_12k()
+    assert w["pool"]._free == w["ledger"].shadow.free_list
+    w["pool"]._free.reverse()
+    assert w["recon"].check() == []
+    assert w["recon"].last_check["ledger_blocks_walked"] >= 12289
+    with kvledger.attribution(request_id=5, tenant="wb-1", origin="grow"):
+        w["rows"][5].extend(w["pool"].alloc(3))      # not the shadow's top
+    w["retire"](5)
+    assert w["recon"].check() == [] and not w["ledger"].shadow.errors
+    assert set(w["pool"]._free) == w["ledger"].shadow.free_set()
+
+
+def test_bookkeeping_span_carries_the_ledgers_counters(tiny):
+    """`serving::bookkeeping` says what the reconcile cost on every
+    step: events applied, things walked one by one, the pool's size."""
+    from paddle_tpu import profiler
+    from paddle_tpu.observability.flight_recorder import SpanLog
+    engine = PagedGenerationEngine(tiny, slots=2, max_len=32,
+                                   block_size=4, num_blocks=12,
+                                   enable_prefix_cache=True)
+    sched = Scheduler(engine, max_queue=8)
+    before = profiler.span_log().appended
+    rng = np.random.RandomState(9)
+    for _ in range(3):
+        sched.submit(rng.randint(0, 1000, 9).tolist(), max_new_tokens=4)
+    sched.run_until_idle()
+    spans = [dict(zip(SpanLog.FIELDS, r))
+             for r in profiler.span_log().spans()][
+                 before - profiler.span_log().appended:]
+    kept = [s["attrs"] for s in spans
+            if s["name"] == "serving::bookkeeping"]
+    assert len(kept) == sched._steps > 3
+    assert all(a["ledger_pool_blocks"] == 12 for a in kept)
+    assert sum(a["ledger_events"] for a in kept) == \
+        len(engine.kv_ledger.events)
+    assert all(a["ledger_blocks_walked"] <= max(a["ledger_events"], 0)
+               for a in kept)
+    assert not sched._kv_reconciler.divergences
+
+
+def test_kvledger_imports_and_replays_with_no_numpy_and_no_jax():
+    """The offline half: `kvledger.py` loads beside a process that holds
+    the chip, with the stdlib alone."""
+    import subprocess
+    obs = os.path.join(_ROOT, "paddle_tpu", "observability")
+    code = f"""
+import importlib, sys, types
+pkg = types.ModuleType("obs"); pkg.__path__ = [{obs!r}]
+sys.modules["obs"] = pkg
+k = importlib.import_module("obs.kvledger")
+assert not [m for m in sys.modules
+            if m.split(".")[0] in ("numpy", "jax", "paddle_tpu")]
+sh = k.replay_events([
+    {{"seq": 0, "event": "alloc", "blocks": [1, 2], "tenant": "a"}},
+    {{"seq": 1, "event": "unref", "blocks": [2], "tenant": "a"}},
+    {{"seq": 2, "event": "free", "blocks": [2], "tenant": "a"}}], 4)
+assert sh.tenant_kind_blocks() == {{("a", "private"): 1}} \\
+    == sh.scan_tenant_kind_blocks()
+assert sh.free_set() == {{2, 3}} and not sh.errors
+"""
+    proc = subprocess.run([sys.executable, "-c", code], timeout=60,
+                          capture_output=True, text=True)
+    assert proc.returncode == 0, proc.stderr[-2000:]

@@ -183,6 +183,9 @@ class FakePool:
     def refcount(self, b):
         return self._refs.get(b, 0)
 
+    def refcounts(self, blocks):
+        return np.array([self.refcount(b) for b in blocks], np.int32)
+
     def alloc(self, n):
         assert n <= len(self._free)
         out = [self._free.pop() for _ in range(n)]
