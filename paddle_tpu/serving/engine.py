@@ -1786,13 +1786,21 @@ class PagedGenerationEngine(GenerationEngine):
         the pipeline-parallel engine can stream the suffix through its
         stages in chunks instead. Returns the first token (host int)."""
         if bucket not in self._prefill:
+            # a cold bucket compiles here, in `serving::prefill`'s own
+            # time, ahead of the three children
             self._prefill[bucket] = self._make_prefill(bucket)
-        pool_in = self._pool
-        out = self._prefill[bucket](
-            self._params, pool_in, jnp.asarray(self._tables),
-            jnp.asarray(self._pos), jnp.asarray(slot, jnp.int32),
-            jnp.asarray(padded), jnp.asarray(length, jnp.int32),
-            jnp.asarray(start, jnp.int32), self._slot_key(slot))
+        # the three host phases of a prefill, as decode has them: the
+        # arguments go up; the bucket executable is enqueued; the host
+        # blocks until every result it reads is back
+        with _span("serving::prefill.upload"):
+            pool_in = self._pool
+            args = (
+                self._params, pool_in, jnp.asarray(self._tables),
+                jnp.asarray(self._pos), jnp.asarray(slot, jnp.int32),
+                jnp.asarray(padded), jnp.asarray(length, jnp.int32),
+                jnp.asarray(start, jnp.int32), self._slot_key(slot))
+        with _span("serving::prefill.dispatch"):
+            out = self._prefill[bucket](*args)
         # the pool that went in is gone: rebind before anything can raise
         first, self._pool, pos = out[:3]
         _TRACER.note("pool_donated", self._pool_donated(pool_in))
@@ -1801,32 +1809,37 @@ class PagedGenerationEngine(GenerationEngine):
             # every one of them) and those that were a real token
             _TRACER.note("ssm_tokens_scanned", self._state_layers * bucket)
             _TRACER.note("ssm_tokens_valid", self._state_layers * length)
-        if self._numerics_armed:
-            self._ingest_numerics(out[3])
-        self._pos = np.array(pos, np.int32)   # owned, writable copy
-        if self._counter_names:
-            first = np.asarray(first, np.int32)
-            for name, n in zip(self._counter_names, first[1:]):
-                _TRACER.note(name, int(n))
-            first = first[0]
-        return int(first)
+        with _span("serving::prefill.wait"):
+            if self._numerics_armed:
+                self._ingest_numerics(out[3])
+            self._pos = np.array(pos, np.int32)   # owned, writable copy
+            # the model's counters ride behind the first token
+            first = np.asarray(first, np.int32).reshape(-1)
+        # noted on `serving::prefill`, where the readers look for them
+        for name, n in zip(self._counter_names, first[1:]):
+            _TRACER.note(name, int(n))
+        return int(first[0])
 
     def decode(self):
         """Advance every slot one token; returns np.int32 [slots]. Active
         slots are guaranteed a writable block first (BlockAllocError
         under pressure — callers driving the engine directly see it; the
         scheduler pre-grows per slot so it can preempt instead)."""
-        _faults.fire("serving.decode_step")
-        self._fire_kv_quant_chaos()
-        self._fire_numerics_chaos()
-        self.ensure_decode_capacity()
+        # two siblings around `serving::decode_step`, which keeps its
+        # extent (as `prefill.admit` and `.publish` are of
+        # `serving::prefill`): `prepare` before it, `commit` after it
+        with _span("serving::decode.prepare"):
+            _faults.fire("serving.decode_step")
+            self._fire_kv_quant_chaos()
+            self._fire_numerics_chaos()
+            self.ensure_decode_capacity()
+            attrs = {"slots": self.config.slots,
+                     "active": int(self._slot_active.sum()),
+                     "paged": True,
+                     "kv_dtype": self.config.kv_dtype,
+                     "attend": self.attention_impl}
         with RecordEvent("serving::decode_step",
-                         TracerEventType.UserDefined,
-                         {"slots": self.config.slots,
-                          "active": int(self._slot_active.sum()),
-                          "paged": True,
-                          "kv_dtype": self.config.kv_dtype,
-                          "attend": self.attention_impl}), \
+                         TracerEventType.UserDefined, attrs), \
                 blocks.attention_impl(self.attention_impl):
             # the three host phases of a decode step, each a child span:
             # tables, positions, tokens and keys go up; the executable is
@@ -1875,18 +1888,22 @@ class PagedGenerationEngine(GenerationEngine):
                     self.last_counters = dict(zip(
                         self._counter_names, map(int, counts)))
                     wait.update(self.last_counters)
-        # positions advance only once the tokens are on the host too: a
-        # step whose fetch failed is run again at the same positions and
-        # writes the same K/V
-        self._pos = pos
-        if self._numerics_armed:
-            sink = res[-1]
-            res = res[:-1]
-            self._ingest_numerics(sink)
-        if self.config.capture_logits:
-            self.last_logits = np.asarray(res[3], np.float32)
-        self._slot_gen += 1
-        self._last_tokens = out.copy()
+        with _span("serving::decode.commit"):
+            # positions advance only once the tokens are on the host too:
+            # a step whose fetch failed is run again at the same positions
+            # and writes the same K/V
+            self._pos = pos
+            if self._numerics_armed:
+                sink = res[-1]
+                res = res[:-1]
+                self._ingest_numerics(sink)
+            if self.config.capture_logits:
+                self.last_logits = np.asarray(res[3], np.float32)
+            self._slot_gen += 1
+            self._last_tokens = out.copy()
+            # the call's arguments and results are let go here, not as
+            # the frame unwinds in the caller's time
+            del args, res
         return out
 
     def _probe_context(self):

@@ -46,8 +46,8 @@ slots, tokens emitted) and every request completion appends a summary
 hit) PLUS a `paddle_tpu.reqtimeline.v1` timeline record (ISSUE 12):
 contiguous queue/prefill|adopt/decode phase segments whose durations sum
 exactly to the request's end-to-end latency, re-entering `queue` on
-every preemption; the same figures feed profiler spans and the `native`
-stat counters, and `tools/serve_report.py` renders the file. The step loop is
+every preemption; the same figures feed profiler spans and the metrics
+registry, and `tools/serve_report.py` renders the file. The step loop is
 synchronous by design — the engine's decode is one executable replay, so
 a thread adds latency, not throughput.
 
@@ -69,7 +69,6 @@ import time
 
 import numpy as np
 
-from .. import native
 from ..observability import decisions as _dec
 from ..observability import kvledger as _kvl
 from ..observability import metrics as _metrics
@@ -97,9 +96,9 @@ SHED = "SHED"
 # worst-class-first, most-deadline-slack-first within a class.
 PRIORITIES = {"interactive": 0, "standard": 1, "batch": 2}
 
-# DEPRECATED counter surface: the per-instance `Scheduler.counts` dict and
-# the free-standing `native.stat_*` names below are kept for callers that
-# already read them, but the source of truth is now the unified metrics
+# DEPRECATED counter surface: the per-instance `Scheduler.counts` dict under
+# the names below is kept for callers that already read it (`step()` among
+# them), but the source of truth is now the unified metrics
 # registry (paddle_tpu.observability.metrics) — the families registered
 # here, exported via registry().snapshot()/dump_prometheus() and rendered
 # by tools/metrics_report.py.
@@ -847,15 +846,19 @@ class Scheduler:
         """One scheduling iteration. Returns True while work remains.
 
         One `serving::step` span covers it, with a child per phase
-        (retire, refill -> prefill, grow, decode_step, emit,
-        bookkeeping); the counts in its attrs are taken as the step
-        ends (docs/observability.md, "What a serving operator gets")."""
+        (retire, refill -> prefill, grow, decode.prepare, decode_step,
+        decode.commit, emit, bookkeeping, step.counts); the counts in its
+        attrs are
+        taken as the step ends, inside `step.counts`
+        (docs/observability.md, "What a serving operator gets")."""
         attrs = {"step": self._steps}
         preempted = self.counts["serving.preempted"]
         with _span("serving::step", attrs):
             more = self._step()
-            attrs["preempted"] = self.counts["serving.preempted"] - preempted
-            self._boundary_counts(attrs)
+            with _span("serving::step.counts"):
+                attrs["preempted"] = \
+                    self.counts["serving.preempted"] - preempted
+                self._boundary_counts(attrs)
         return more
 
     def _boundary_counts(self, attrs):
@@ -1518,7 +1521,7 @@ class Scheduler:
 
     def _count(self, name, req=None):
         # registry first (the unified surface), then the deprecated
-        # per-instance dict + native stat mirror for existing readers.
+        # per-instance dict for existing readers (`step()` among them).
         # Every per-request family carries the request's tenant label
         # (ISSUE 15); counts with no request context label "default".
         tenant = getattr(req, "tenant", None) or _dec.DEFAULT_TENANT
@@ -1530,7 +1533,6 @@ class Scheduler:
             _M_REQUESTS.labels(status=name.split(".", 1)[1],
                                tenant=tenant).inc()
         self.counts[name] += 1
-        native.stat_add(name, 1)
 
     # -- metrics ---------------------------------------------------------------
     def metrics(self):

@@ -376,7 +376,10 @@ def test_prefill_span_is_what_it_was(served):
         assert set(p["attrs"]) == {
             "attend", "bucket", "kv_dtype", "length", "paged",
             "pool_donated", "prefix_hit_tokens", "request_id", "slot"}
-        assert not [s for s in spans if s["parent"] == p["span_id"]]
+        # ISSUE 36: the host's three phases inside it, and nothing else
+        assert [s["name"] for s in spans if s["parent"] == p["span_id"]] \
+            == [P + "prefill.upload", P + "prefill.dispatch",
+                P + "prefill.wait"]
         parent = next(s for s in spans if s["span_id"] == p["parent"])
         assert parent["name"] == P + "refill"
 

@@ -81,14 +81,15 @@ def test_the_log_fills_with_no_profiler_and_no_flight_recorder(served):
 
 
 @pytest.mark.parametrize("child", ["retire", "refill", "grow",
-                                   "bookkeeping"])
+                                   "bookkeeping", "step.counts"])
 def test_every_step_holds_its_phase(served, child):
     for step in served["steps"]:
         names = [k["name"] for k in served["children"][step["span_id"]]]
         assert names.count(P + child) == 1
 
 
-@pytest.mark.parametrize("child", ["decode_step", "emit"])
+@pytest.mark.parametrize("child", ["decode.prepare", "decode_step",
+                                   "decode.commit", "emit"])
 def test_a_step_that_decoded_holds_decode_and_emit(served, child):
     decoded = 0
     for step in served["steps"]:
@@ -116,6 +117,149 @@ def test_decode_step_holds_upload_dispatch_wait(served):
             P + "decode.upload", P + "decode.dispatch", P + "decode.wait"]
         assert sum(k["dur"] for k in kids) <= d["dur"]
         assert 1 <= d["attrs"]["active"] <= d["attrs"]["slots"] == 2
+
+
+# ------------------------------- the host's phases of a call (ISSUE 36)
+
+def tiny_hybrid():
+    """The benchmark's Nemotron configuration at its tiny sizes, built as
+    its model tests build it: state-space, attention and expert blocks, and
+    the counters that ride behind the tokens."""
+    from test_nemotron_h_model import build, tiny_config
+    config = tiny_config()
+    return build(config), PagedEngineConfig(
+        **config["program"]["paged_engine_config"])
+
+
+@pytest.fixture(scope="module", params=["gpt_tiny", "tiny hybrid"])
+def one_call_each(request, model):
+    """One request of two tokens through a scheduler: one prefill, one
+    decode. The log's spans, and the engine."""
+    if request.param == "gpt_tiny":
+        sched, engine = paged_scheduler(model)
+    else:
+        hybrid, config = tiny_hybrid()
+        engine = PagedGenerationEngine(hybrid, config)
+        sched = Scheduler(engine, ServingConfig(max_queue=16))
+    mark = profiler.span_log().appended
+    handle = sched.submit(list(range(1, 12)), max_new_tokens=2)
+    while sched.step():
+        pass
+    assert handle.status == "DONE"
+    spans = logged(mark)
+    return {"spans": spans, "children": by_parent(spans), "engine": engine}
+
+
+def in_turn(parent, kids):
+    """`kids` lie inside `parent`, one after the other."""
+    edges = [parent["ts"]]
+    for k in kids:
+        edges += [k["ts"], k["ts"] + k["dur"]]
+    edges.append(parent["ts"] + parent["dur"])
+    return edges == sorted(edges)
+
+
+def test_prefill_holds_upload_dispatch_wait_and_keeps_its_attrs(
+        one_call_each):
+    spans, engine = one_call_each["spans"], one_call_each["engine"]
+    prefill, = [s for s in spans if s["name"] == P + "prefill"]
+    kids = one_call_each["children"][prefill["span_id"]]
+    assert [k["name"] for k in kids] == [
+        P + "prefill.upload", P + "prefill.dispatch", P + "prefill.wait"]
+    assert in_turn(prefill, kids)
+    # what the fetches brought is noted on `serving::prefill`, as before
+    assert all(set(k["attrs"]) == {"request_id"} for k in kids)
+    extra = set(engine._counter_names)
+    if engine._state_layers:
+        extra |= {"ssm_tokens_scanned", "ssm_tokens_valid"}
+    assert set(prefill["attrs"]) == extra | {
+        "attend", "bucket", "kv_dtype", "length", "paged", "pool_donated",
+        "prefix_hit_tokens", "request_id", "slot"}
+    assert prefill["attrs"]["pool_donated"] == 1
+    around = [s["name"] for s in
+              one_call_each["children"][prefill["parent"]]]
+    assert around == [P + "prefill.admit", P + "prefill",
+                      P + "prefill.publish"]
+
+
+def test_prepare_and_commit_stand_around_the_decode_step(one_call_each):
+    spans = one_call_each["spans"]
+    decode, = [s for s in spans if s["name"] == P + "decode_step"]
+    prepare, = [s for s in spans if s["name"] == P + "decode.prepare"]
+    commit, = [s for s in spans if s["name"] == P + "decode.commit"]
+    step = next(s for s in spans if s["span_id"] == decode["parent"])
+    assert step["name"] == P + "step"
+    assert prepare["parent"] == commit["parent"] == step["span_id"]
+    names = [k["name"] for k in one_call_each["children"][step["span_id"]]]
+    at = names.index(P + "decode_step")
+    assert names[at - 2:at + 3] == [
+        P + "grow", P + "decode.prepare", P + "decode_step",
+        P + "decode.commit", P + "emit"]
+    # siblings, one after the other: decode_step keeps its own extent
+    assert prepare["ts"] + prepare["dur"] <= decode["ts"]
+    assert decode["ts"] + decode["dur"] <= commit["ts"]
+    assert not prepare["attrs"] and not commit["attrs"]
+    assert {"slots", "active", "paged", "kv_dtype", "attend"} \
+        == set(decode["attrs"])
+    assert in_turn(decode, one_call_each["children"][decode["span_id"]])
+
+
+def test_counts_closes_the_step_and_the_attrs_stay_on_the_step(
+        one_call_each):
+    steps = [s for s in one_call_each["spans"] if s["name"] == P + "step"]
+    assert steps
+    for step in steps:
+        kids = one_call_each["children"][step["span_id"]]
+        assert [k["name"] for k in kids[-2:]] == [
+            P + "bookkeeping", P + "step.counts"]
+        assert not kids[-1]["attrs"]
+        assert {"step", "preempted", "queue_depth", "active_slots", "slots",
+                "kv_blocks_in_use", "kv_blocks_total",
+                "kv_tokens_held"} <= set(step["attrs"])
+        assert in_turn(step, kids)
+
+
+def test_the_log_alone_says_when_nothing_was_in_flight(one_call_each):
+    """The benchmark's reader over the program's own spans: the four
+    groups and the in-flight time are the window, to the nanosecond."""
+    from benchmark.harness import host_gaps
+    spans = one_call_each["spans"]
+    steps = [s for s in spans if s["name"] == P + "step"]
+    got = host_gaps.split(spans, steps[0]["ts"], steps[-1]["ts"] + 1)
+    lo, hi = got["window_ns"]
+    assert (lo, hi) == (steps[0]["ts"], steps[-1]["ts"] + steps[-1]["dur"])
+    assert got["steps"] == len(steps)
+    assert sum(got["starved_ns"].values()) + got["in_flight_ns"] == hi - lo
+    assert all(ns >= 0 for ns in got["starved_ns"].values())
+    calls = [s for s in spans
+             if s["name"] in (P + "prefill", P + "decode_step")]
+    kids = one_call_each["children"]
+    assert got["in_flight_ns"] == sum(
+        kids[c["span_id"]][2]["ts"] + kids[c["span_id"]][2]["dur"]
+        - kids[c["span_id"]][1]["ts"] for c in calls)
+    assert len(got["prefill_host_ns"]) == 1
+    # every phase of the two calls that is not in flight found its group
+    assert {"prefill.admit", "prefill.upload", "prefill.publish",
+            "decode.prepare", "decode.upload",
+            "decode.commit"} <= set(got["starved_by_span"])
+    assert not {"prefill.wait", "decode.wait", "prefill.dispatch",
+                "decode.dispatch"} & set(got["starved_by_span"])
+
+
+def test_an_engine_without_the_children_leaves_the_reader_silent(model):
+    """The dense engine opens no `prefill.dispatch` / `.wait`: the reader
+    says nothing rather than half of it."""
+    from benchmark.harness import host_gaps
+    sched = Scheduler(GenerationEngine(model, slots=2, max_len=48),
+                      max_queue=4)
+    mark = profiler.span_log().appended
+    sched.submit([1, 2, 3], max_new_tokens=2)
+    sched.run_until_idle()
+    spans = logged(mark)
+    assert P + "prefill" in {s["name"] for s in spans}
+    assert P + "prefill.dispatch" not in {s["name"] for s in spans}
+    starts = [s["ts"] for s in spans if s["name"] == P + "step"]
+    assert host_gaps.split(spans, min(starts), max(starts) + 1) is None
 
 
 def test_self_times_add_up_to_the_step(served):
