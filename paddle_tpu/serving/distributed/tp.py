@@ -13,7 +13,8 @@ Megatron mp_layers):
     ([num_blocks, block_size, heads/mp, head_dim] per device), so a
     tp-degree mesh holds a tp-times-larger pool at the same per-device
     memory — the serving-side win;
-  - block tables, positions and tokens stay replicated (tiny int32).
+  - block tables, positions and tokens stay replicated (tiny int32): a
+    call's one packed upload (`_put`) goes to every device of the mesh.
 
 Decode AND prefill are the SAME traced programs as the single-device
 paged engine (`functional_call` over the same Layer forward — token
@@ -129,6 +130,9 @@ class TensorParallelPagedEngine(PagedGenerationEngine):
         # follow the head split, so dequant stays shard-local
         self._scale_sharding = NamedSharding(self._mesh, P(None, "mp"))
         self._replicated = NamedSharding(self._mesh, P())
+        # a call's packed upload replicates over the mesh, as the block
+        # tables, positions and tokens it holds always did
+        self._upload_sharding = self._replicated
         super().__init__(model, config)
 
     # -- placement -----------------------------------------------------------

@@ -70,6 +70,46 @@ def _span(name, attrs=None):
     """A `serving::*` span (the scheduler's phases use it too)."""
     return RecordEvent(name, TracerEventType.UserDefined, attrs)
 
+
+class _CallPack:
+    """Where each host-owned input of one executable lies in the call's ONE
+    int32 upload: `fields` [(name, shape)] back to back. The layout is a
+    function of the engine's configuration (and the bucket), so the host
+    writes and the trace slices at the same static offsets; a `uint32`
+    seed rides as its bits and is bit-cast back in the trace."""
+
+    def __init__(self, fields):
+        self.fields = {}
+        off = 0
+        for name, shape in fields:
+            n = int(np.prod(shape, dtype=np.int64))
+            self.fields[name] = (off, off + n, tuple(shape))
+            off += n
+        self.size = off
+
+    def pack(self, **values):
+        """The host half: one fresh int32 buffer holding every field."""
+        buf = np.empty((self.size,), np.int32)
+        for name, (lo, hi, _) in self.fields.items():
+            v = np.asarray(values[name])
+            if v.dtype == np.uint32:
+                v = v.view(np.int32)
+            buf[lo:hi] = v.reshape(-1)
+        return buf
+
+    def unpack(self, packed):
+        """The trace half: {name: static slice of `packed`, reshaped}. An
+        argument that is not this layout's array (an older calling
+        convention's tables, say) fails here, before anything compiles."""
+        if packed.shape != (self.size,) or packed.dtype != jnp.int32:
+            raise TypeError(
+                f"expected the call's packed int32[{self.size}] upload "
+                f"({', '.join(self.fields)}), got "
+                f"{packed.dtype}{list(packed.shape)}")
+        return {name: packed[lo:hi].reshape(shape)
+                for name, (lo, hi, shape) in self.fields.items()}
+
+
 DEFAULT_BUCKETS = (32, 64, 128, 256, 512, 1024)
 GENCFG_SUFFIX = ".gencfg"
 
@@ -1037,7 +1077,21 @@ class PagedGenerationEngine(GenerationEngine):
     to the result right after each dispatch, and nothing may keep a
     pool tuple across a call: the one that went in is deleted. Each
     step's span says whether it engaged (`pool_donated`,
-    docs/serving.md)."""
+    docs/serving.md).
+
+    A call is ONE upload, one enqueue and one fetch. Everything the
+    host owns that an executable reads (decode: tables, positions, last
+    tokens, with adapters the per-slot ids, under sampling the per-slot
+    seeds and generation counters; prefill: the slot's table row, the
+    slot, the suffix's length and start, the padded suffix, under
+    sampling the slot's seed and generation index) goes up as one int32
+    array (`_CallPack`, `_put`) and is sliced apart in the trace;
+    weights, pool and adapter tree are resident and stay arguments of
+    their own. Only the tokens come back (`_fetch`), the model's
+    counters behind them where it counts: positions are the host's to
+    advance, once the tokens are here. No eager device program runs on
+    the path: a greedy executable takes no key, a sampling one derives
+    `fold_in(key(seed), gen)` in the trace."""
 
     def __init__(self, model, config=None, **kwargs):
         config = config or PagedEngineConfig(**kwargs)
@@ -1059,6 +1113,12 @@ class PagedGenerationEngine(GenerationEngine):
         # and the spans' `attend` carry
         self.attention_impl = config.attention_impl \
             or self._default_attention_impl(config)
+        self._packs = {}     # executable -> the layout of its one upload
+        # puts and fetches issued through `_put` / `_fetch`, the call
+        # path's only two: its upload and wait spans note how many fell
+        # inside them
+        self._transfers = 0
+        self._fetches = 0
         super().__init__(model, config)
         # KV-adopt executables (multi-host handoff sink, ISSUE 10): one
         # per prefill bucket, compiled on first use and counted like
@@ -1186,9 +1246,10 @@ class PagedGenerationEngine(GenerationEngine):
         per-STAGE device pools) reuses it verbatim — block tables and
         the allocator are shared across stages by construction."""
         c = self.config
-        # pos lives host-side (np): the block math (ensure_slot_capacity,
-        # once per slot per decode step) must not pay a device fetch each
-        # read — ONE transfer per decode/prefill return refreshes it
+        # pos lives host-side (np) and only there: the block math
+        # (ensure_slot_capacity, once per slot per decode step) reads it,
+        # every call sends it up inside its one upload, and the host
+        # advances it itself once the call's tokens are back
         self._pos = np.zeros((c.slots,), np.int32)
         self._tables = np.zeros((c.slots, c.max_blocks_per_slot), np.int32)
         self._slot_active = np.zeros((c.slots,), bool)
@@ -1502,24 +1563,93 @@ class PagedGenerationEngine(GenerationEngine):
         wrap the warms exactly as it wraps the live calls — a kernel-
         config engine warmed outside the context would compile (and
         commit under the kernel key) the gather program."""
-        tables = jnp.asarray(self._tables)
-        pos = jnp.asarray(self._pos)
-        key = self._warm_key()
         out = {}
         with blocks.attention_impl(self.attention_impl):
-            out["decode"] = self._decode.warm(
-                self._decode_params, self._pool, tables, pos,
-                jnp.zeros((self.config.slots,), jnp.int32), key,
-                *self._adapter_args(), *self._rng_args())
+            out["decode"] = self._decode.warm(*self._decode_args())
             for b in self.config.prefill_buckets:
                 if b not in self._prefill:
                     self._prefill[b] = self._make_prefill(b)
                 out[f"prefill[{b}]"] = self._prefill[b].warm(
-                    self._params, self._pool, tables, pos,
-                    jnp.asarray(0, jnp.int32), jnp.zeros((b,), jnp.int32),
-                    jnp.asarray(1, jnp.int32), jnp.asarray(0, jnp.int32),
-                    key)
+                    *self._prefill_args(b, 0, np.zeros((b,), np.int32),
+                                        1, 0))
         return out
+
+    # -- the call's one upload and one fetch ----------------------------------
+    # where `_put` places a call's upload: the default device here; the
+    # tensor-parallel engine replicates it over its mesh
+    _upload_sharding = None
+
+    def _put(self, buf):
+        """THE host-to-device transfer of a call (counted, so the upload
+        span's `transfers` is what was issued, not what was meant)."""
+        self._transfers += 1
+        return jax.device_put(buf, self._upload_sharding)
+
+    def _fetch(self, arr):
+        """THE device-to-host fetch of a call: blocks until the
+        executable is done; counted like `_put`."""
+        self._fetches += 1
+        return np.asarray(arr, np.int32)
+
+    def _pack(self, bucket=None):
+        """The layout of decode's upload (`bucket` None) or of the
+        bucket's prefill: what the engine can see decides the fields
+        (slots, blocks a slot, the bucket, sampling or greedy, adapters
+        attached or not)."""
+        key = (bucket, self._adapter_bank is not None)
+        pack = self._packs.get(key)
+        if pack is not None:
+            return pack
+        c = self.config
+        per_slot = (c.slots,)
+        if bucket is None:
+            fields = [("tables", (c.slots, c.max_blocks_per_slot)),
+                      ("pos", per_slot), ("tokens", per_slot)]
+            if self._adapter_bank is not None:
+                fields.append(("adapter", per_slot))
+            if self._sampling:
+                fields += [("seeds", per_slot), ("gen", per_slot)]
+        else:
+            fields = [("row", (1, c.max_blocks_per_slot)), ("slot", ()),
+                      ("length", ()), ("start", ())]
+            if self._sampling:
+                fields += [("seed", ()), ("gen", ())]
+            fields.append(("ids", (bucket,)))
+        pack = self._packs[key] = _CallPack(fields)
+        return pack
+
+    def _decode_args(self):
+        """The decode executable's arguments, the host's part of them put
+        on the device in one transfer."""
+        host = {"tables": self._tables, "pos": self._pos,
+                "tokens": self._last_tokens}
+        if self._adapter_bank is not None:
+            host["adapter"] = self._slot_adapter
+        if self._sampling:
+            host["seeds"], host["gen"] = self._slot_seeds, self._slot_gen
+        args = (self._decode_params, self._pool,
+                self._put(self._pack().pack(**host)))
+        if self._adapter_bank is not None:
+            args += (self._adapter_tree,)
+        return args
+
+    def _prefill_args(self, bucket, slot, padded, length, start):
+        """The bucket executable's arguments for `slot`: its table row
+        drives both the scatter of the new suffix K/V and the gather over
+        the (possibly shared) prefix blocks; `start` = tokens already
+        resident (prefix hit)."""
+        host = {"row": self._tables[slot], "slot": slot, "length": length,
+                "start": start, "ids": padded}
+        if self._sampling:
+            host["seed"] = self._slot_seeds[slot]
+            host["gen"] = self._slot_gen[slot]
+        return (self._params, self._pool,
+                self._put(self._pack(bucket).pack(**host)))
+
+    def _unpack_rng(self, seeds, gen):
+        """The packed sampler state as `_select_slots` takes it: the
+        seeds' bits read as the `uint32` they are."""
+        return jax.lax.bitcast_convert_type(seeds, jnp.uint32), gen
 
     # -- functional forward (paged) -----------------------------------------
     def _run_model_paged(self, params, pool, tables, pos, ids, valid=None,
@@ -1561,28 +1691,36 @@ class PagedGenerationEngine(GenerationEngine):
                 tuple(type(l)(*(x._data for x in l))
                       for l in new_cache.layers), counters._data)
 
-    def _layout_decode_fn(self, params, pool, tables, pos, tokens, key,
-                          *rng):
+    def _layout_decode_fn(self, params, pool, tables, pos, tokens, *rng):
         """`_decode_fn` for a model with its own cache layout: the tokens
         come back with the model's counters behind them in ONE int32
         array, so the step's single fetch brings both."""
         logits, npool, counters = self._run_layout_model(
             params, pool, tables, pos, tokens[:, None])
-        nxt = self._select_slots(logits[:, 0, :], key, *rng)
+        nxt = self._select_slots(logits[:, 0, :], None, *rng)
         out = (jnp.concatenate([nxt.astype(jnp.int32), counters]),
-               self._constrain_pools(npool),
-               jnp.minimum(pos + 1, self.config.max_len - 1))
+               self._constrain_pools(npool))
         if self.config.capture_logits:
             out = out + (logits[:, 0, :],)
         return out
 
     # -- decode: ONE executable ---------------------------------------------
-    def _decode_fn(self, params, pool, tables, pos, tokens, key, *extra):
+    def _decode_fn(self, params, pool, packed, adapter_tree=None):
         self._bump_decode_trace()            # trace-time only
+        if (adapter_tree is None) != (self._adapter_bank is None):
+            raise TypeError("the adapter tree rides the decode call exactly "
+                            "when a bank is attached")
+        host = self._pack().unpack(packed)
+        tables, pos, tokens = host["tables"], host["pos"], host["tokens"]
+        # greedy takes no key at all; sampling derives each row's in the
+        # trace from its packed (seed, gen)
+        rng = self._unpack_rng(host["seeds"], host["gen"]) \
+            if self._sampling else ()
         if self._layout is not None:
             return self._layout_decode_fn(params, pool, tables, pos, tokens,
-                                          key, *extra)
-        adapters, rng = self._split_extra(extra)
+                                          *rng)
+        adapters = None if adapter_tree is None else \
+            {"slot": host["adapter"], "layers": adapter_tree["layers"]}
         with self._numerics_scope() as sink:
             if self.kv_quantized:
                 # fused health of the WHOLE quantized pool: scale
@@ -1605,13 +1743,12 @@ class PagedGenerationEngine(GenerationEngine):
                 logits, npool = self._run_model_paged(
                     self._dequant_params(params), pool, tables, pos,
                     tokens[:, None], adapters=adapters)
-            nxt = self._select_slots(logits[:, 0, :], key, *rng)
+            nxt = self._select_slots(logits[:, 0, :], None, *rng)
             _numerics.tap("decode.logits", logits[:, 0, :])
             if adapters is not None:
                 _numerics.tap_tree("adapter.delta", adapters["layers"])
-        npool = self._constrain_pools(npool)
-        new_pos = jnp.minimum(pos + 1, self.config.max_len - 1)
-        out = (nxt, npool, new_pos)
+        # the positions are not returned: they are the host's to advance
+        out = (nxt, self._constrain_pools(npool))
         if self.config.capture_logits:
             out = out + (logits[:, 0, :],)
         if sink is not None:
@@ -1620,45 +1757,44 @@ class PagedGenerationEngine(GenerationEngine):
 
     # -- prefill: one executable per SUFFIX bucket ---------------------------
     def _make_prefill(self, bucket):
-        nb = self.config.max_blocks_per_slot
-
-        def prefill_fn(params, pool, tables, pos, slot, ids, length,
-                       start, key):
+        def prefill_fn(params, pool, packed):
             self.trace_counts["prefill"][bucket] = \
                 self.trace_counts["prefill"].get(bucket, 0) + 1
-            slot = slot.astype(jnp.int32)
-            # the slot's table row drives both the scatter of the new
-            # suffix K/V and the gather over the (possibly shared) prefix
-            # blocks; `start` = tokens already resident (prefix hit)
-            row = jax.lax.dynamic_slice(tables, (slot, 0), (1, nb))
+            host = self._pack(bucket).unpack(packed)
+            row, ids = host["row"], host["ids"]
+            slot, length, start = host["slot"], host["length"], host["start"]
+            # the key of the slot's next token, the expression
+            # `_select_slots` computes in the decode trace: prefill (a
+            # restart) and decode (the original) sample generation index
+            # n identically. Greedy reads no key
+            key = None
+            if self._sampling:
+                seed, gen = self._unpack_rng(host["seed"], host["gen"])
+                key = jax.random.fold_in(jax.random.key(seed), gen)
             if self._layout is not None:
                 # always from position 0 (the prefix cache is bypassed);
                 # first token and counters in one int32 array
                 logits, npool, counters = self._run_layout_model(
                     params, pool, row, start[None], ids[None, :],
                     valid=length[None], slot=slot)
-                pos = jax.lax.dynamic_update_slice(
-                    pos, length[None].astype(pos.dtype), (slot,))
                 last = jax.lax.dynamic_index_in_dim(logits[0], length - 1,
                                                     keepdims=False)
                 first = self._select(last[None, :], key).astype(jnp.int32)
                 return (jnp.concatenate([first, counters]),
-                        self._constrain_pools(npool), pos)
+                        self._constrain_pools(npool))
             with self._numerics_scope() as sink:
                 with blocks.attention_scope("prefill_attn"):
                     logits, npool = self._run_model_paged(
                         params, pool, row, start[None], ids[None, :],
                         valid=length[None])
-                pos = jax.lax.dynamic_update_slice(
-                    pos, (start + length)[None].astype(pos.dtype), (slot,))
                 last = jax.lax.dynamic_index_in_dim(logits[0], length - 1,
                                                     keepdims=False)
                 first_token = self._select(last[None, :], key)[0]
                 _numerics.tap("prefill.logits", last[None, :])
             npool = self._constrain_pools(npool)
             if sink is None:
-                return first_token, npool, pos
-            return first_token, npool, pos, sink
+                return first_token, npool
+            return first_token, npool, sink
         return self._cached(prefill_fn, f"prefill[{bucket}]",
                             donate_argnums=(1,))
 
@@ -1790,19 +1926,17 @@ class PagedGenerationEngine(GenerationEngine):
             # time, ahead of the three children
             self._prefill[bucket] = self._make_prefill(bucket)
         # the three host phases of a prefill, as decode has them: the
-        # arguments go up; the bucket executable is enqueued; the host
-        # blocks until every result it reads is back
+        # host's arguments go up in one transfer; the bucket executable
+        # is enqueued; the host blocks until the first token is back
         with _span("serving::prefill.upload"):
+            before = self._transfers
             pool_in = self._pool
-            args = (
-                self._params, pool_in, jnp.asarray(self._tables),
-                jnp.asarray(self._pos), jnp.asarray(slot, jnp.int32),
-                jnp.asarray(padded), jnp.asarray(length, jnp.int32),
-                jnp.asarray(start, jnp.int32), self._slot_key(slot))
+            args = self._prefill_args(bucket, slot, padded, length, start)
+            _TRACER.note("transfers", self._transfers - before)
         with _span("serving::prefill.dispatch"):
             out = self._prefill[bucket](*args)
         # the pool that went in is gone: rebind before anything can raise
-        first, self._pool, pos = out[:3]
+        first, self._pool = out[:2]
         _TRACER.note("pool_donated", self._pool_donated(pool_in))
         if self._state_layers:
             # positions the state layers' chunked scan ran (the bucket, in
@@ -1810,11 +1944,15 @@ class PagedGenerationEngine(GenerationEngine):
             _TRACER.note("ssm_tokens_scanned", self._state_layers * bucket)
             _TRACER.note("ssm_tokens_valid", self._state_layers * length)
         with _span("serving::prefill.wait"):
-            if self._numerics_armed:
-                self._ingest_numerics(out[3])
-            self._pos = np.array(pos, np.int32)   # owned, writable copy
+            before = self._fetches
             # the model's counters ride behind the first token
-            first = np.asarray(first, np.int32).reshape(-1)
+            first = self._fetch(first).reshape(-1)
+            _TRACER.note("fetches", self._fetches - before)
+        # the one position that changed is the host's to write, once the
+        # token is here
+        self._pos[slot] = start + length
+        if self._numerics_armed:
+            self._ingest_numerics(out[2])
         # noted on `serving::prefill`, where the readers look for them
         for name, n in zip(self._counter_names, first[1:]):
             _TRACER.note(name, int(n))
@@ -1842,16 +1980,13 @@ class PagedGenerationEngine(GenerationEngine):
                          TracerEventType.UserDefined, attrs), \
                 blocks.attention_impl(self.attention_impl):
             # the three host phases of a decode step, each a child span:
-            # tables, positions, tokens and keys go up; the executable is
-            # enqueued; the host blocks until positions and tokens are
-            # back (the device's time and the copy)
+            # tables, positions and tokens go up in one transfer; the
+            # executable is enqueued; the host blocks until the tokens
+            # are back (the device's time and the copy)
             with _span("serving::decode.upload"):
-                tokens = self._last_tokens
-                args = (
-                    self._decode_params, self._pool,
-                    jnp.asarray(self._tables), jnp.asarray(self._pos),
-                    jnp.asarray(tokens), self._next_key(),
-                    *self._adapter_args(), *self._rng_args())
+                before = self._transfers
+                args = self._decode_args()
+                _TRACER.note("transfers", self._transfers - before)
             with _span("serving::decode.dispatch"):
                 res = self._decode(*args)
             # the pool that went in is gone: rebind before the fetches,
@@ -1880,8 +2015,9 @@ class PagedGenerationEngine(GenerationEngine):
                     wait["latent_rows_read"] = self._latent_layers \
                         * c.slots * c.max_blocks_per_slot * c.block_size
             with _span("serving::decode.wait", wait):
-                pos = np.array(res[2], np.int32)         # owned, writable
-                out = np.asarray(res[0], np.int32)
+                before = self._fetches
+                out = self._fetch(res[0])
+                wait["fetches"] = self._fetches - before
                 if self._counter_names:
                     # the model's counters ride behind the tokens
                     out, counts = np.split(out, [self.config.slots])
@@ -1889,16 +2025,19 @@ class PagedGenerationEngine(GenerationEngine):
                         self._counter_names, map(int, counts)))
                     wait.update(self.last_counters)
         with _span("serving::decode.commit"):
-            # positions advance only once the tokens are on the host too:
-            # a step whose fetch failed is run again at the same positions
-            # and writes the same K/V
-            self._pos = pos
+            # positions are the host's, and advance only once the tokens
+            # are here: a step whose fetch failed is run again at the same
+            # positions and writes the same K/V. Free slots keep decoding
+            # garbage harmlessly; the clamp keeps their position (and the
+            # wpe lookup) in bounds forever
+            self._pos = np.minimum(self._pos + 1, self.config.max_len - 1,
+                                   dtype=np.int32)
             if self._numerics_armed:
                 sink = res[-1]
                 res = res[:-1]
                 self._ingest_numerics(sink)
             if self.config.capture_logits:
-                self.last_logits = np.asarray(res[3], np.float32)
+                self.last_logits = np.asarray(res[2], np.float32)
             self._slot_gen += 1
             self._last_tokens = out.copy()
             # the call's arguments and results are let go here, not as

@@ -522,25 +522,28 @@ def test_what_the_model_cannot_be_combined_with_raises_at_construction():
 # ------------------------------------ the siblings' programs did not move
 
 # sha256 of the lowered text of each executable of the tiny Ling and
-# DeepSeek-V3 engines, taken on the parent of the PR that brought the
+# DeepSeek-V3 engines. Taken first on the parent of the PR that brought the
 # single-part blocks (2152d6a): the shared code (`HybridDecoder`,
-# `hybrid_ops.moe_share`) was extended, and their programs are the parent's
-# byte for byte. A PR that means to change them takes new hashes.
+# `hybrid_ops.moe_share`) was extended, and their programs were the parent's
+# byte for byte. Taken anew in PR 37, which meant to change every paged
+# executable's signature (one packed int32 upload in, no key, no positions
+# out; the mathematics between is the same). A PR that means to change
+# them takes new hashes.
 SIBLING_PROGRAMS = {
     "ling3_flash_ep4_share": {
-        "decode": "52aa972dcc550b4cc05cd1f2af4170102c97989d874ff6cd"
-                  "708135e0ce36a3b6",
-        "prefill[32]": "e13eb8b998243eee52af83b8573ed7125c66a18971f0"
-                       "ea096b1dbde3300d8a86",
-        "prefill[64]": "5e8345dade55fae341e7184ea9ce6845136455eb2f8e"
-                       "d69498aa3258a16fc1aa"},
+        "decode": "d1046c1dd5dd3ed4cdd24ad8d66f75dedb25765e2e19adfd"
+                  "abfd5f2343981c5c",
+        "prefill[32]": "e377f3f9fed6a5089023034f9d525ef82480fa325829"
+                       "d2d74716fd31ec16f419",
+        "prefill[64]": "72c0e45f9ae7fc6b79e1056a696fec4eb930afa3db1e"
+                       "e986fd3fbbfb5622838f"},
     "deepseek_v3_ep16_share": {
-        "decode": "5b887ea563a4664fea05ee5c4cd5ce7e6e79bd49640254b2"
-                  "7206fcd1da906594",
-        "prefill[32]": "d2302d0c054a1418e2d5849b0b26b5e768aa5f2a89ba"
-                       "81a1e0f83232bb4a0311",
-        "prefill[64]": "48c2c0009331c5094b57e284e0f07ab09c34c9bfae4d"
-                       "9e10faffc5371410c2ca"}}
+        "decode": "c876e142525c23e61ab459cf5981e7df72341fc9713b5402"
+                  "36d645e4b23d50aa",
+        "prefill[32]": "bd370268824ad12fd23fd5674b9ed65902647910342c"
+                       "841e0a3dcf7e4cd75b62",
+        "prefill[64]": "4a18a719e2fd39aca597b5a279d144e4e7610affb5d6"
+                       "76946763926806bef6e6"}}
 
 
 def lowered_programs(name):
@@ -554,21 +557,15 @@ def lowered_programs(name):
     model.load_arrays(weights.named(config, 5, config["dtype"]["param"]))
     eng = PagedGenerationEngine(model, PagedEngineConfig(
         **config["program"]["paged_engine_config"]))
-    tables, pos = jnp.asarray(eng._tables), jnp.asarray(eng._pos)
-    key = eng._warm_key()
     sha = lambda lowered: hashlib.sha256(
         lowered.as_text().encode()).hexdigest()
     with blocks.attention_impl(eng.attention_impl):
         out = {"decode": sha(jax.jit(eng._decode_fn).lower(
-            eng._decode_params, eng._pool, tables, pos,
-            jnp.zeros((eng.config.slots,), jnp.int32), key,
-            *eng._rng_args()))}
+            *eng._decode_args()))}
         for b in eng.config.prefill_buckets:
             out[f"prefill[{b}]"] = sha(jax.jit(
-                eng._make_prefill(b)._fn).lower(
-                eng._params, eng._pool, tables, pos,
-                jnp.asarray(0, jnp.int32), jnp.zeros((b,), jnp.int32),
-                jnp.asarray(1, jnp.int32), jnp.asarray(0, jnp.int32), key))
+                eng._make_prefill(b)._fn).lower(*eng._prefill_args(
+                    b, 0, np.zeros((b,), np.int32), 1, 0)))
     return out
 
 

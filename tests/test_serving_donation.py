@@ -174,7 +174,7 @@ def test_an_armed_engine_keeps_its_replayable_decode(tiny, copying):
     waits = [r for r in profiler.span_log().spans()
              if r[0] == "serving::decode.wait"]
     assert len(waits) == STEPS
-    assert all(r[5] == {"pool_donated": 0} for r in waits)
+    assert all(r[5] == {"pool_donated": 0, "fetches": 1} for r in waits)
 
 
 def test_a_failed_fetch_leaves_the_engine_on_live_buffers(tiny, copying):

@@ -167,8 +167,13 @@ def test_prefill_holds_upload_dispatch_wait_and_keeps_its_attrs(
     assert [k["name"] for k in kids] == [
         P + "prefill.upload", P + "prefill.dispatch", P + "prefill.wait"]
     assert in_turn(prefill, kids)
-    # what the fetches brought is noted on `serving::prefill`, as before
-    assert all(set(k["attrs"]) == {"request_id"} for k in kids)
+    # what the fetch brought is noted on `serving::prefill`, as before;
+    # the upload says how many transfers it made, the wait how many
+    # fetches: one each (ISSUE 37)
+    assert [k["attrs"] for k in kids] == [
+        {"request_id": prefill["attrs"]["request_id"], "transfers": 1},
+        {"request_id": prefill["attrs"]["request_id"]},
+        {"request_id": prefill["attrs"]["request_id"], "fetches": 1}]
     extra = set(engine._counter_names)
     if engine._state_layers:
         extra |= {"ssm_tokens_scanned", "ssm_tokens_valid"}
