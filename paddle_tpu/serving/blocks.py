@@ -43,8 +43,9 @@ from . import kv_cache as kvc
 
 __all__ = ["BlockAllocError", "BlockPool", "PagedLayerKV",
            "QuantPagedLayerKV", "PagedDecodeCache", "LatentSpec",
-           "StateSpec", "NoCache", "LatentLayer", "StateLayer",
-           "SlotStateStore", "alloc_layers", "gather_rows", "alloc_pools",
+           "StateSpec", "NoCache", "WindowSpec", "LatentLayer",
+           "StateLayer", "SlotStateStore", "alloc_layers", "gather_rows",
+           "window_of", "window_write", "alloc_pools",
            "alloc_quant_pools", "write", "quant_write", "gather",
            "gather_quant", "dequant", "attend", "attend_quant",
            "attend_kernel", "attend_kernel_quant", "attention_impl",
@@ -115,6 +116,8 @@ PagedDecodeCache = collections.namedtuple(
 #   NoCache()           nothing: a layer that is a feed-forward alone. It
 #                       is its own (empty) layer in the pool tuple, so the
 #                       pool keeps one entry a layer
+#   WindowSpec(width, window, chunk)  rows of `width` values of two
+#                       lifetimes under the one table (below): a LatentLayer
 # Softmax attention with few key/value heads needs no third kind: a token's
 # keys and values of all its key/value heads are one LatentSpec row.
 # A model without `cache_layout` (GPT) caches K and V per layer, as above.
@@ -123,6 +126,100 @@ StateSpec = collections.namedtuple("StateSpec", ["state", "tail"])
 NoCache = collections.namedtuple("NoCache", [])
 LatentLayer = collections.namedtuple("LatentLayer", ["rows"])
 StateLayer = collections.namedtuple("StateLayer", ["state", "tail"])
+
+
+class WindowSpec(collections.namedtuple("WindowSpec",
+                                        ["width", "window", "chunk"])):
+    """A layer that keeps a token's row only while the token's window of
+    `window` positions is open, and one summary row per `chunk` positions
+    for as long as the request lives (windows are aligned blocks: position
+    p lies in window p // window). Rows of both kinds are `width` wide and
+    live in one LatentLayer; the layout, not the engine, says how many
+    blocks a slot of n tokens needs, where a position writes and what a
+    query sees:
+
+      table entries [0, window / block_size): a RING of token blocks.
+        Position p writes entry (p % window) // block_size, row
+        p % block_size; the entries are allocated as the first window grows
+        and written again by every later window. Visibility is by
+        position, so nothing is cleared.
+      the entries behind them: summaries. Chunk c writes entry
+        window / block_size + c // block_size, row c % block_size.
+
+    So the dense view of a slot (`gather_rows`) is `window` ring rows, then
+    one row a chunk, and a query at p sees ring rows [0, p % window] and the
+    summaries of every chunk of every CLOSED window."""
+    __slots__ = ()
+
+    def ring_blocks(self, block_size):
+        return self.window // block_size
+
+    def table_blocks(self, max_len, block_size):
+        """Table entries a slot of up to `max_len` positions may use."""
+        chunks = -(-int(max_len) // self.chunk)
+        return self.ring_blocks(block_size) + -(-chunks // block_size)
+
+    def blocks_for(self, n_tokens, block_size):
+        """Blocks a slot of `n_tokens` tokens holds: the ring as far as
+        the first window has grown, and a summary row a chunk begun."""
+        n = int(n_tokens)
+        chunks = -(-n // self.chunk)
+        return min(-(-n // block_size), self.ring_blocks(block_size)) \
+            + -(-chunks // block_size)
+
+    def entries(self, first, last, block_size):
+        """Table entries that tokens at positions first..last write: their
+        ring blocks and their chunks' summary blocks."""
+        first, last, ring = int(first), int(last), \
+            self.ring_blocks(block_size)
+        a, b = (p % self.window // block_size for p in (first, last))
+        if last - first >= self.window - 1:
+            held = range(ring)                       # a whole window's
+        elif first // self.window == last // self.window:
+            held = range(a, b + 1)
+        else:                                   # across a window's edge
+            held = sorted(set(range(a, ring)) | set(range(b + 1)))
+        return [*held, *range(ring + first // self.chunk // block_size,
+                              ring + last // self.chunk // block_size + 1)]
+
+    def visible_rows(self, pos):
+        """(ring rows, summary rows) a query at position `pos` (an int or
+        an array of them) scores, its own row among the first."""
+        pos = np.asarray(pos)
+        return pos % self.window + 1, \
+            pos // self.window * (self.window // self.chunk)
+
+    def prefill_pairs(self, n_tokens):
+        """(query, visible row) pairs of a prompt of `n_tokens` tokens from
+        position 0: `sum(visible_rows(p))` over it, in closed form."""
+        n, w = int(n_tokens), self.window
+        full, rest = divmod(n, w)
+        ring = full * w * (w + 1) // 2 + rest * (rest + 1) // 2
+        return ring + (w // self.chunk) * (w * full * (full - 1) // 2
+                                           + rest * full)
+
+
+def window_of(layout, block_size=None):
+    """The WindowSpec of a model's `cache_layout()`, or None where no layer
+    declares one (or there is no layout at all). One table a slot serves
+    every paged layer, so every paged layer must then declare the SAME
+    geometry: window layers beside one-row-a-token layers, or windows of two
+    sizes, would need a table each (ROADMAP B4's other half)."""
+    paged = {s for s in layout or () if isinstance(s, (LatentSpec,
+                                                       WindowSpec))}
+    spec = next((s for s in paged if isinstance(s, WindowSpec)), None)
+    if spec is None:
+        return None
+    if len({(type(s), *s[1:]) for s in paged}) != 1:
+        raise ValueError(
+            f"one block table a slot: every paged layer must declare the "
+            f"same window geometry, got {sorted(map(repr, paged))}")
+    if spec.window % spec.chunk or (block_size and (
+            spec.window % block_size or spec.chunk > spec.window)):
+        raise ValueError(
+            f"{spec!r}: the window must be whole chunks and whole blocks "
+            f"of {block_size}")
+    return spec
 
 
 def blocks_for_tokens(n_tokens, block_size):
@@ -146,7 +243,7 @@ def alloc_layers(layout, num_blocks, block_size, slots, dtype):
     `dtype`; the recurrent state is float32."""
     out = []
     for spec in layout:
-        if isinstance(spec, LatentSpec):
+        if isinstance(spec, (LatentSpec, WindowSpec)):
             out.append(LatentLayer(jnp.zeros(
                 (num_blocks, block_size, spec.width), dtype)))
         elif isinstance(spec, StateSpec):
@@ -165,7 +262,7 @@ def layout_bytes(layout, block_size, dtype):
     state pins over the state layers)."""
     item = np.dtype(dtype).itemsize
     block = sum(block_size * s.width * item for s in layout
-                if isinstance(s, LatentSpec))
+                if isinstance(s, (LatentSpec, WindowSpec)))
     slot = sum(4 * int(np.prod(s.state)) + item * int(np.prod(s.tail))
                for s in layout if isinstance(s, StateSpec))
     return block, slot
@@ -204,6 +301,20 @@ def write(pool, new, tables, pos):
                                jnp.minimum(lb, nb - 1), axis=1)
     phys = jnp.where(lb < nb, phys, GARBAGE_BLOCK)
     return pool.at[phys, off].set(new.astype(pool.dtype))
+
+
+def window_write(pool, new, tables, entry, row, live):
+    """Scatter `new` [S, T, width] into the latent `pool` [N, block_size,
+    width] at table entry `entry` [S, T], row `row` [S, T] of each slot's
+    `tables` [S, max_blocks]: the write of a layer whose layout, not the
+    position alone, says where a row goes (`WindowSpec`). Rows that are not
+    `live` [S, T] land in the garbage block."""
+    nb = tables.shape[1]
+    entry = entry.astype(jnp.int32)
+    phys = jnp.take_along_axis(tables.astype(jnp.int32),
+                               jnp.minimum(entry, nb - 1), axis=1)
+    phys = jnp.where(live & (entry < nb), phys, GARBAGE_BLOCK)
+    return pool.at[phys, row.astype(jnp.int32)].set(new.astype(pool.dtype))
 
 
 def dequant(codes, scale):

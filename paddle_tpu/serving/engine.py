@@ -1106,7 +1106,19 @@ class PagedGenerationEngine(GenerationEngine):
         self.state_store = None
         self._latent_layers, self._state_layers = (
             sum(isinstance(spec, kind) for spec in self._layout or ())
-            for kind in (blocks.LatentSpec, blocks.StateSpec))
+            for kind in ((blocks.LatentSpec, blocks.WindowSpec),
+                         blocks.StateSpec))
+        # a layout whose rows live one window and whose summaries live on
+        # (blocks.WindowSpec) sizes a slot itself: its table is as wide as
+        # ITS blocks for max_len, and full provisioning is that a slot
+        self._window = blocks.window_of(self._layout, config.block_size)
+        if self._window is not None:
+            per_slot = self._window.table_blocks(config.max_len,
+                                                 config.block_size)
+            if config.num_blocks == \
+                    1 + config.slots * config.max_blocks_per_slot:
+                config.num_blocks = 1 + config.slots * per_slot
+            config.max_blocks_per_slot = per_slot
         # what the executables are traced with: the configured value, or
         # the engine's own choice where the configuration leaves it open.
         # This, not the spelled value, is what the executables' cache keys
@@ -1495,6 +1507,34 @@ class PagedGenerationEngine(GenerationEngine):
                 for n, v in params.items()}
 
     # -- block accounting ----------------------------------------------------
+    def _blocks_for(self, n_tokens):
+        """Blocks a slot of `n_tokens` tokens holds: one row a token, or
+        what the model's layout declares."""
+        bs = self.config.block_size
+        if self._window is not None:
+            return self._window.blocks_for(n_tokens, bs)
+        return blocks.blocks_for_tokens(n_tokens, bs)
+
+    def _table_entries(self, first, last):
+        """The table entries that tokens at positions first..last write,
+        none beyond the table."""
+        bs = self.config.block_size
+        if self._window is not None:
+            return self._window.entries(first, last, bs)
+        return range(first // bs, min(last // bs,
+                                      self.config.max_blocks_per_slot - 1)
+                     + 1)
+
+    def _rows_visible(self, back=0):
+        """(ring rows, summary rows) the active slots' queries at `_pos -
+        back` score, a layer's worth: what a window layout keeps of the
+        positions `kv_tokens_held` counts. None without such a layout."""
+        if self._window is None:
+            return None
+        ring, chunks = self._window.visible_rows(
+            np.maximum(self._pos[self._slot_active] - back, 0))
+        return int(ring.sum()), int(chunks.sum())
+
     def _alloc_blocks(self, n, requester=None):
         """Pool alloc with prefix-cache eviction as the pressure valve:
         only when eviction cannot cover the shortfall does
@@ -1526,11 +1566,9 @@ class PagedGenerationEngine(GenerationEngine):
             return
         if tokens is None:
             tokens = self.decode_write_tokens
-        bs = self.config.block_size
-        first = int(self._pos[slot]) // bs
-        last = (int(self._pos[slot]) + int(tokens) - 1) // bs
-        last = min(last, self.config.max_blocks_per_slot - 1)
-        need = [lb for lb in range(first, last + 1)
+        first = int(self._pos[slot])
+        need = [lb for lb in self._table_entries(first,
+                                                 first + int(tokens) - 1)
                 if self._tables[slot, lb] == blocks.GARBAGE_BLOCK]
         if need:
             requester = self._slot_namespace.get(slot)
@@ -1843,8 +1881,8 @@ class PagedGenerationEngine(GenerationEngine):
             shared_ids, nshared = ([], 0) if cache is None \
                 else cache.match(
                     prompt, record=False, namespace=namespace,
-                    reserve=blocks.blocks_for_tokens(plen, bs), chain=chain)
-            n_priv = blocks.blocks_for_tokens(plen, bs) - nshared // bs
+                    reserve=self._blocks_for(plen), chain=chain)
+            n_priv = self._blocks_for(plen) - nshared // bs
             try:
                 priv = self._alloc_blocks(n_priv, requester=namespace) \
                     if n_priv else []
@@ -1856,7 +1894,10 @@ class PagedGenerationEngine(GenerationEngine):
                 self._note_prefix_cost(cost, evict=True)
             row = np.zeros((self.config.max_blocks_per_slot,), np.int32)
             row[:len(shared_ids)] = shared_ids
-            row[len(shared_ids):len(shared_ids) + n_priv] = priv
+            # the private ones where the layout puts the other positions:
+            # behind the shared blocks, or a window layout's ring and
+            # summary entries
+            row[list(self._table_entries(nshared, plen - 1))] = priv
             self._tables[slot] = row
             self._slot_active[slot] = True
             self._slot_namespace[slot] = namespace
@@ -1943,6 +1984,14 @@ class PagedGenerationEngine(GenerationEngine):
             # every one of them) and those that were a real token
             _TRACER.note("ssm_tokens_scanned", self._state_layers * bucket)
             _TRACER.note("ssm_tokens_valid", self._state_layers * length)
+        if self._window is not None:
+            # what the window layout made of the prompt, a layer's worth:
+            # windows it spans, chunk summaries written, and the (query,
+            # visible row) pairs of its real tokens
+            spec = self._window
+            _TRACER.note("eva_windows", -(-length // spec.window))
+            _TRACER.note("eva_chunks_summarised", -(-length // spec.chunk))
+            _TRACER.note("eva_pairs", spec.prefill_pairs(length))
         with _span("serving::prefill.wait"):
             before = self._fetches
             # the model's counters ride behind the first token
@@ -2009,8 +2058,10 @@ class PagedGenerationEngine(GenerationEngine):
                 # them: the gather arm builds the dense view of every
                 # slot's whole table, a layer
                 c = self.config
-                wait["latent_rows_held"] = self._latent_layers * int(
-                    (self._pos[self._slot_active] + 1).sum())
+                visible = self._rows_visible()
+                wait["latent_rows_held"] = self._latent_layers * (
+                    int((self._pos[self._slot_active] + 1).sum())
+                    if visible is None else sum(visible))
                 if self.attention_impl == "gather":
                     wait["latent_rows_read"] = self._latent_layers \
                         * c.slots * c.max_blocks_per_slot * c.block_size
@@ -2104,7 +2155,7 @@ class PagedGenerationEngine(GenerationEngine):
         plen = int(self._pos[slot])
         if plen < 1:
             raise ValueError(f"slot {slot} has no resident tokens")
-        nb = blocks.blocks_for_tokens(plen, self.config.block_size)
+        nb = self._blocks_for(plen)
         return jnp.asarray(self._tables[slot][:nb], jnp.int32), plen, nb
 
     def _strip_padding(self, arr, nb, plen):
@@ -2176,7 +2227,7 @@ class PagedGenerationEngine(GenerationEngine):
         if self._slot_active[slot]:
             self.reset_slot(slot)
         bs = self.config.block_size
-        n = blocks.blocks_for_tokens(plen, bs)
+        n = self._blocks_for(plen)
         priv = self._alloc_blocks(n)        # all-or-nothing; may raise
         row = np.zeros((self.config.max_blocks_per_slot,), np.int32)
         row[:n] = priv
@@ -2452,7 +2503,11 @@ class PagedGenerationEngine(GenerationEngine):
         if self._layout is None:
             return None
         store, cache = self.state_store, self.prefix_cache
+        visible = self._rows_visible(back=1)   # of the last token written
         return {
+            **({} if visible is None else {
+                "window_rows_held": visible[0],
+                "summary_rows_held": visible[1]}),
             "state_slots_in_use": 0 if store is None else store.in_use,
             "state_bytes": 0 if store is None else store.bytes_in_use,
             "latent_bytes_in_use":

@@ -9,17 +9,24 @@ bias, a gated grouped norm; the same kind of cache), `mla` (softmax
 attention over a shared latent: its cache is one paged row a token; queries
 straight from the hidden state or through a bottleneck `q_lora_rank` wide, a
 head-wise output gate or none, plain or YaRN-scaled rotary: what the
-configuration declares) and `gqa` (softmax attention of `num_heads` query
+configuration declares), `gqa` (softmax attention of `num_heads` query
 heads over `num_kv_heads` key/value heads, no rotary; a token's keys and
-values are one paged row). Feed-forwards: `swiglu` (dense) and `moe`
+values are one paged row) and `eva` (EVA attention, `num_heads` heads with
+rotary over the whole head: exact softmax inside the aligned window of
+`eva_window` positions a token lies in, and of every window closed before it
+one learned summary row per `eva_chunk` positions, under one softmax; a
+token's row lives one window, a summary as long as the request:
+`blocks.WindowSpec`). Feed-forwards: `swiglu` (dense) and `moe`
 (sigmoid-scored, group-limited top-k experts plus a shared expert, SwiGLU or
 `relu2` as `moe_act` declares, of which this chip may hold a share:
 `num_experts` of `n_routed_experts`, global ids from `experts_first`). The
 layers come from the configuration: `blocks` (one part a layer: a mixer or a
 feed-forward alone, behind ONE norm), or `mixers` (one mixer a layer, each
 followed by a feed-forward) or, without either, the rule "every
-`layer_group_size`-th layer is MLA, the others KDA". RMSNorm, partial rotary
-on the MLA layers only, untied head. `hybrid_ops.py` has the mathematics.
+`layer_group_size`-th layer is MLA, the others KDA". RMSNorm (scales `w`, or
+`1 + w` under `norm_unit_offset`), partial rotary on the MLA layers, untied
+head: `num_pred_heads` prediction heads of `vocab_size` side by side, of which
+the served step computes the first. `hybrid_ops.py` has the mathematics.
 
 The model serves through `serving.PagedGenerationEngine` like GPT does; what
 differs is that it tells the engine what each layer caches
@@ -39,11 +46,12 @@ from . import hybrid_ops as ops
 # parameters that stay float32 whatever the weights' type
 FLOAT32_LEAVES = ("norm1", "norm2", "norm_f", "a_log", "bf", "onorm",
                   "cnorm", "qnorm", "router", "router_bias", "dt_bias",
-                  "d_skip", "ssm_norm")
+                  "d_skip", "ssm_norm", "phi", "mu")
 _ONES_LEAVES = ("norm1", "norm2", "norm_f", "onorm", "cnorm", "qnorm",
                 "d_skip", "ssm_norm")
 _RESIDUAL_LEAVES = ("wo", "w_down", "we_down", "ws_down", "w_out")
-MIXERS = ("kda", "mla", "mamba2", "gqa")
+_NORM_LEAVES = ("norm1", "norm2", "norm_f")
+MIXERS = ("kda", "mla", "mamba2", "gqa", "eva")
 FFNS = ("swiglu", "moe")
 
 
@@ -53,8 +61,12 @@ class HybridConfig:
     hidden_size: int = 64
     num_layers: int = 7
     num_heads: int = 2
-    head_dim: int = 16                   # KDA, GQA key and value head size
+    head_dim: int = 16                   # KDA, GQA, EVA key and value head
     num_kv_heads: int = None             # GQA's key/value heads
+    eva_window: int = 2048               # EVA: positions a window, and
+    eva_chunk: int = 16                  # positions a summary row
+    num_pred_heads: int = 1              # heads of vocab_size in `head`
+    norm_unit_offset: bool = False       # RMSNorm scales are 1 + w
     layer_group_size: int = 6            # every group's last layer is MLA
     mixers: tuple = None                 # one mixer a layer; None: that rule
     blocks: tuple = None                 # one part a layer, nothing paired
@@ -115,6 +127,13 @@ class HybridConfig:
         if "gqa" in kinds and (not self.num_kv_heads
                                or self.num_heads % self.num_kv_heads):
             raise ValueError("gqa: num_kv_heads must divide num_heads")
+        if "eva" in kinds and (self.eva_chunk < 1
+                               or self.eva_window % self.eva_chunk
+                               or self.head_dim % 2):
+            raise ValueError("eva: eva_window must be whole chunks of "
+                             "eva_chunk, and head_dim even (rotary)")
+        if self.num_pred_heads < 1:
+            raise ValueError("num_pred_heads must be at least 1")
         if "mamba2" in kinds and self.ssm_heads % self.ssm_groups:
             raise ValueError("mamba2: ssm_groups must divide ssm_heads")
         if self.moe_act not in ("swiglu", "relu2"):
@@ -161,6 +180,10 @@ def leaf_shapes(cfg, kinds):
         d = cfg.head_dim
         out.update({"wq": (h, n * d), "wk": (h, cfg.num_kv_heads * d),
                     "wv": (h, cfg.num_kv_heads * d), "wo": (n * d, h)})
+    elif mixer == "eva":
+        d = cfg.head_dim
+        out.update({"wq": (h, n * d), "wk": (h, n * d), "wv": (h, n * d),
+                    "phi": (n, d), "mu": (n, d), "wo": (n * d, h)})
     elif mixer == "mla":
         nope, rope = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
         if cfg.q_lora_rank:
@@ -204,6 +227,8 @@ class _Leaves(Layer):
                 else jnp.dtype(cfg.param_dtype)
             if not cfg.init_weights:
                 data = jnp.zeros((), dtype)
+            elif leaf in _NORM_LEAVES and cfg.norm_unit_offset:
+                data = jnp.zeros(shape, dtype)
             elif leaf in _ONES_LEAVES:
                 data = jnp.ones(shape, dtype)
             elif leaf in ("a_log", "bf", "router_bias", "dt_bias",
@@ -211,6 +236,8 @@ class _Leaves(Layer):
                 data = jnp.zeros(shape, dtype)
             else:
                 std = cfg.initializer_range
+                if leaf in ("phi", "mu"):    # neither uniform nor one-hot
+                    std = cfg.head_dim ** -0.5
                 if leaf in _RESIDUAL_LEAVES:
                     std /= (2 * cfg.num_layers) ** 0.5
                 data = (std * jax.random.normal(
@@ -231,7 +258,7 @@ class HybridDecoder(Layer):
         key = jax.random.key(0)
         h, v = cfg.hidden_size, cfg.vocab_size
         self.top = _Leaves(cfg, {"embed": (v, h), "norm_f": (h,),
-                                 "head": (h, v)}, key)
+                                 "head": (h, cfg.num_pred_heads * v)}, key)
         self.layers = LayerList([
             _Leaves(cfg, leaf_shapes(cfg, k), jax.random.fold_in(key, i + 1))
             for i, k in enumerate(self.kinds)])
@@ -285,18 +312,23 @@ class HybridDecoder(Layer):
                                          + cfg.qk_rope_head_dim)
             if mixer == "gqa":   # a token's keys and values: one row
                 return blocks.LatentSpec(2 * cfg.num_kv_heads * d)
+            if mixer == "eva":   # [k, v] of a token, [kbar, vbar] of a chunk
+                return blocks.WindowSpec(2 * n * d, cfg.eva_window,
+                                         cfg.eva_chunk)
             return blocks.NoCache()      # a feed-forward alone
 
         return tuple(spec(mixer) for mixer, _ in self.kinds)
 
     # -- forward ----------------------------------------------------------
-    def forward(self, input_ids, cache):
+    def forward(self, input_ids, cache, all_heads=False):
         """`input_ids` [S, T] against `cache` (serving.blocks
         .PagedDecodeCache over `cache_layout()`'s layers). Decode: S slots,
         T = 1, `cache.slot` None. Prefill: S = 1, the request's T (bucket-
         padded) tokens from position 0, `cache.valid` [1] its real length,
         `cache.slot` the slot whose state rows it fills. Returns (logits
-        [S, T, V] float32, the new cache, counters int32 [4])."""
+        [S, T, V] float32, the new cache, counters int32 [4]); the logits
+        are the first prediction head's, as the served step reads them, or
+        with `all_heads` every head's, [S, T, num_pred_heads, V]."""
         from ...serving import blocks
         ids = input_ids._data
         pool = tuple(type(l)(*(x._data for x in l)) for l in cache.layers)
@@ -306,7 +338,7 @@ class HybridDecoder(Layer):
         logits, new_pool, counters = run(
             {n: p._data for n, p in self.named_parameters()}, pool, tables,
             pos, ids, *((cache.valid._data[0], cache.slot._data)
-                        if prefill else ()))
+                        if prefill else ()), all_heads=all_heads)
         new_layers = tuple(type(l)(*(Tensor(x) for x in l))
                            for l in new_pool)
         return Tensor(logits), blocks.PagedDecodeCache(
@@ -325,21 +357,33 @@ class HybridDecoder(Layer):
         return jnp.concatenate([counters[:3] + new[:3],
                                 jnp.maximum(counters[3:], new[3:])])
 
+    def _norm(self, h, scale):
+        if self.cfg.norm_unit_offset:
+            scale = 1.0 + scale
+        return ops.rms_norm(h, scale, self.cfg.rms_norm_eps)
+
     def _ffn(self, h, w, ffn, live, counters):
         if ffn is None:
             return h, counters
-        x = ops.rms_norm(h, w["norm2"], self.cfg.rms_norm_eps)
+        x = self._norm(h, w["norm2"])
         if ffn == "swiglu":
             return h + ops.swiglu(x, w["w_gate"], w["w_up"],
                                   w["w_down"]), counters
         y, new = ops.moe_share(x, w, self.cfg, live)
         return h + y, self._merge(counters, new)
 
-    def _finish(self, params, h, counters):
-        x = ops.rms_norm(h, params["top.norm_f"], self.cfg.rms_norm_eps)
+    def _finish(self, params, h, counters, all_heads=False):
+        x = self._norm(h, params["top.norm_f"])
         if counters is None:
             counters = jnp.zeros((len(self.serving_counters),), jnp.int32)
-        return ops.mm("...h,hv->...v", x, params["top.head"]), counters
+        head, heads = params["top.head"], self.cfg.num_pred_heads
+        if heads > 1 and not all_heads:
+            # the served step reads the first head: its columns alone
+            head = head[:, :self.cfg.vocab_size]
+        logits = ops.mm("...h,hv->...v", x, head)
+        if all_heads:
+            logits = logits.reshape(logits.shape[:-1] + (heads, -1))
+        return logits, counters
 
     # One token a slot through a mixer: x [S, H] normed, `cached` the
     # layer's cache -> (what the mixer adds to h, the layer's new cache)
@@ -371,6 +415,29 @@ class HybridDecoder(Layer):
             rows = blocks.write(cached.rows, row, tables, pos)
             return ops.gqa_decode(q[:, 0], blocks.gather_rows(rows, tables),
                                   pos, w, cfg), blocks.LatentLayer(rows)
+        if mixer == "eva":
+            bs, win, chunk = cached.rows.shape[1], cfg.eva_window, \
+                cfg.eva_chunk
+            every = jnp.ones((pos.shape[0], 1), bool)
+            q, row = ops.eva_project(x[:, None], w, cfg, pos[:, None],
+                                     cached.rows.dtype)
+            at = (pos % win)[:, None]                  # the ring row
+            rows = blocks.window_write(cached.rows, row, tables, at // bs,
+                                       at % bs, every)
+            view = blocks.gather_rows(rows, tables)
+            y = ops.eva_decode(q[:, 0], view, pos, w, cfg)
+            # the open chunk's summary, from its tokens' rows so far; it is
+            # final when the chunk's last token lands, seen once the window
+            # has closed
+            c = (pos // chunk)[:, None]
+            of_chunk = c * chunk + jnp.arange(chunk)[None, :]     # [S, C]
+            summary = ops.eva_summaries(
+                jnp.take_along_axis(view, (of_chunk % win)[..., None],
+                                    1)[:, None],
+                (of_chunk <= pos[:, None])[:, None], w, cfg)
+            rows = blocks.window_write(rows, summary, tables,
+                                       win // bs + c // bs, c % bs, every)
+            return y, blocks.LatentLayer(rows)
         q_n, q_r, latent, gate = ops.mla_project(
             x[:, None], w, cfg, pos[:, None])
         rows = blocks.write(cached.rows, latent, tables, pos)
@@ -379,7 +446,7 @@ class HybridDecoder(Layer):
             pos, None if gate is None else gate[:, 0], w, cfg), \
             blocks.LatentLayer(rows)
 
-    def _decode(self, params, pool, tables, pos, ids):
+    def _decode(self, params, pool, tables, pos, ids, all_heads=False):
         from ...serving import blocks
         cfg = self.cfg
         tokens = ids[:, 0]
@@ -389,13 +456,13 @@ class HybridDecoder(Layer):
         for i, ((mixer, ffn), cached) in enumerate(zip(self.kinds, pool)):
             w = self._layer_params(params, i)
             if mixer is not None:
-                x = ops.rms_norm(h, w["norm1"], cfg.rms_norm_eps)
+                x = self._norm(h, w["norm1"])
                 y, cached = self._mix_decode(mixer, x, w, cached, tables,
                                              pos)
                 h = h + y
             new_pool.append(cached)
             h, counters = self._ffn(h, w, ffn, live, counters)
-        logits, counters = self._finish(params, h, counters)
+        logits, counters = self._finish(params, h, counters, all_heads)
         return logits[:, None], tuple(new_pool), counters
 
     # One request's (bucket-padded) tokens from position 0 through a mixer:
@@ -443,13 +510,43 @@ class HybridDecoder(Layer):
             new = blocks.LatentLayer(
                 blocks.write(cached.rows, row[None], tables, pos))
             return ops.gqa_prefill(q, row, w, cfg), new
+        if mixer == "eva":
+            bs, win, chunk = cached.rows.shape[1], cfg.eva_window, \
+                cfg.eva_chunk
+            t = x.shape[0]
+            q, row = ops.eva_project(x, w, cfg, jnp.arange(t),
+                                     cached.rows.dtype)
+            m = -(-t // chunk)                  # chunks the bucket begins
+            summaries = ops.eva_summaries(
+                jnp.pad(row, ((0, m * chunk - t), (0, 0)))
+                .reshape(m, chunk, -1),
+                (jnp.arange(m * chunk) < length).reshape(m, chunk), w, cfg)
+            y = ops.eva_prefill(q, row, summaries, w, cfg)
+            # kept: the token rows of the window the last real token lies
+            # in (a span of one window that holds it: rows of the window
+            # before land on ring rows no query sees before they are
+            # written again) and the summary of every chunk begun
+            span = min(win, t)
+            start = jnp.clip((length - 1) // win * win, 0, t - span)
+            at = start + jnp.arange(span)
+            rows = blocks.window_write(
+                cached.rows,
+                jax.lax.dynamic_slice_in_dim(row, start, span)[None],
+                tables, (at % win // bs)[None], (at % bs)[None],
+                (at < length)[None])
+            c = jnp.arange(m)
+            rows = blocks.window_write(
+                rows, summaries[None], tables, (win // bs + c // bs)[None],
+                (c % bs)[None], (c * chunk < length)[None])
+            return y, blocks.LatentLayer(rows)
         q_n, q_r, latent, gate = ops.mla_project(
             x, w, cfg, jnp.arange(x.shape[0]))
         new = blocks.LatentLayer(
             blocks.write(cached.rows, latent[None], tables, pos))
         return ops.mla_prefill(q_n, q_r, latent, gate, w, cfg), new
 
-    def _prefill(self, params, pool, tables, pos, ids, length, slot):
+    def _prefill(self, params, pool, tables, pos, ids, length, slot,
+                 all_heads=False):
         cfg = self.cfg
         valid = jnp.arange(ids.shape[1]) < length
         h = params["top.embed"][ids[0]].astype(jnp.float32)       # [T, H]
@@ -457,11 +554,11 @@ class HybridDecoder(Layer):
         for i, ((mixer, ffn), cached) in enumerate(zip(self.kinds, pool)):
             w = self._layer_params(params, i)
             if mixer is not None:
-                x = ops.rms_norm(h, w["norm1"], cfg.rms_norm_eps)
+                x = self._norm(h, w["norm1"])
                 y, cached = self._mix_prefill(mixer, x, w, cached, tables,
                                               pos, valid, length, slot)
                 h = h + y
             new_pool.append(cached)
             h, counters = self._ffn(h, w, ffn, valid, counters)
-        logits, counters = self._finish(params, h, counters)
+        logits, counters = self._finish(params, h, counters, all_heads)
         return logits[None], tuple(new_pool), counters

@@ -2,7 +2,8 @@
 KDA linear attention and the Mamba-2 state-space mixer (each a chunked form
 for prefill and the one-step recurrence for decode), MLA over a latent cache
 (expanded for prefill, absorbed for decode), grouped-query attention over
-paged K/V rows, and the expert layer of a chip that holds a share of the
+paged K/V rows, EVA attention (exact softmax inside an aligned window, one
+learned summary row a chunk of every closed window), and the expert layer of a chip that holds a share of the
 experts. Plain XLA; `text/models/hybrid.py` wires them into a model and
 `serving/blocks.py` holds the two kinds of cache they read and write.
 
@@ -214,15 +215,16 @@ def mla_scale(cfg):
     return scale
 
 
-def rope_frequencies(cfg):
-    """(the R/2 rotary frequencies, float32; the factor on cos and sin).
+def rope_frequencies(cfg, rope=None):
+    """(the R/2 rotary frequencies, float32; the factor on cos and sin) of
+    a rotated width `rope` (MLA's `qk_rope_head_dim` unless given).
     Plain rotary unless the configuration declares `rope_scaling` (YaRN:
     factor s, original length L, beta_fast, beta_slow, mscale,
     mscale_all_dim): the pairs that turn fewer than beta_slow times over L
     positions are slowed by s, those that turn more than beta_fast times
     keep their frequency, a linear ramp between; cos and sin carry
     m(mscale) / m(mscale_all_dim)."""
-    rope, theta = cfg.qk_rope_head_dim, cfg.rope_theta
+    rope, theta = rope or cfg.qk_rope_head_dim, cfg.rope_theta
     half = rope // 2
     inv = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
     s = cfg.rope_scaling
@@ -246,7 +248,7 @@ def rope_frequencies(cfg):
 def rotary(x, positions, cfg):
     """x [..., T, (heads,) R] at `positions` [..., T], half-split pairs."""
     half = x.shape[-1] // 2
-    inv, factor = rope_frequencies(cfg)
+    inv, factor = rope_frequencies(cfg, x.shape[-1])
     ang = positions.astype(jnp.float32)[..., None] * inv
     if x.ndim == ang.ndim + 1:                                # a heads axis
         ang = ang[..., None, :]
@@ -507,6 +509,132 @@ def gqa_decode(q, rows, pos, w, cfg):
     rows (its own new row written), `pos` [S] the new token's position."""
     seen = jnp.arange(rows.shape[1])[None, :] <= pos[:, None]
     return _gqa_attend(q[:, None], rows, seen[:, None], w, cfg)[:, 0]
+
+
+# ------------------------------------------------------------------- EVA
+
+# the float32 scores one block of prefill queries may take: [n, block, keys]
+EVA_SCORE_BYTES = 1 << 29
+
+
+def eva_project(x, w, cfg, positions, dtype):
+    """(q [..., T, n, d] rotated, the cached row [..., T, 2 n d] = [rotated
+    k, v] of all n heads, in the cache's `dtype`). Rotary over the whole
+    head at the absolute `positions` [..., T]."""
+    n, d = cfg.num_heads, cfg.head_dim
+    q, k, v = (mm("...h,hc->...c", x, w[name]).reshape(
+        x.shape[:-1] + (n, d)) for name in ("wq", "wk", "wv"))
+    q, k = rotary(q, positions, cfg), rotary(k, positions, cfg)
+    row = jnp.concatenate([k, v], -2)                    # [..., T, 2n, d]
+    return q, row.reshape(x.shape[:-1] + (2 * n * d,)).astype(dtype)
+
+
+def eva_summaries(rows, live, w, cfg):
+    """The summary row of each chunk from its tokens' cached rows: `rows`
+    [..., m, C, 2 n d] (C = `eva_chunk`), `live` [..., m, C] bool the tokens
+    that exist -> [..., m, 2 n d] = [kbar, vbar] in the rows' type, with
+    the layer's per-head `phi` and `mu` [n, d] float32: `a = softmax_j(s
+    <k_j, phi>)` over the chunk's live tokens, `kbar = sum a k + mu`,
+    `vbar = sum a v`.
+    A chunk with no live token gives `[mu, 0]`. Float32 throughout: the
+    rows are read as cached, so prefill and decode pool the same numbers."""
+    with jax.named_scope("eva_summary"):
+        n, d = cfg.num_heads, cfg.head_dim
+        kv = rows.astype(jnp.float32).reshape(rows.shape[:-1] + (2, n, d))
+        k, v = kv[..., 0, :, :], kv[..., 1, :, :]        # [..., m, C, n, d]
+        score = jnp.sum(k * w["phi"], -1) * d ** -0.5    # [..., m, C, n]
+        score = jnp.where(live[..., None], score, -1e30)
+        a = jnp.where(live[..., None], jax.nn.softmax(score, -2), 0.0)
+        kbar = jnp.sum(a[..., None] * k, -3) + w["mu"]
+        vbar = jnp.sum(a[..., None] * v, -3)
+        out = jnp.concatenate([kbar, vbar], -2)
+        return out.reshape(out.shape[:-2] + (2 * n * d,)) \
+            .astype(rows.dtype)
+
+
+def _eva_attend(q, rows, seen, w, cfg):
+    """q [B, Q, n, d] over `rows` [B, L, 2 n d] (token rows and summary
+    rows alike: [k, v] or [kbar, vbar]) where `seen` [B, Q, L]: ONE softmax
+    over all of them, float32 scores of bfloat16 operands."""
+    with jax.named_scope("eva_attn"):
+        n, d = cfg.num_heads, cfg.head_dim
+        kv = rows.reshape(rows.shape[:2] + (2, n, d))
+        scores = mm("bqnd,blnd->bnql", q, kv[:, :, 0]) * d ** -0.5
+        scores = jnp.where(seen[:, None], scores, -jnp.inf)
+        o = mm("bnql,blnd->bqnd", jax.nn.softmax(scores, -1), kv[:, :, 1])
+        return mm("...c,ch->...h", o.reshape(q.shape[:2] + (n * d,)),
+                  w["wo"])
+
+
+def eva_prefill_block(n, window, keys):
+    """Queries a block of the prefill attention takes so that its scores
+    against up to `keys` rows stay within EVA_SCORE_BYTES: the window, or
+    the largest halving of it that fits (never under 128 where the window
+    is larger)."""
+    block = window
+    while block > 128 and 4 * n * block * keys > EVA_SCORE_BYTES:
+        block //= 2
+    return block
+
+
+def eva_prefill(q, row, summaries, w, cfg):
+    """One request's own tokens [T] from position 0. Queries go a block of
+    one window (or a halving of it, `eva_prefill_block`) at a time: against
+    the token rows of their own window up to the block's end, causally, and
+    the summaries of every chunk of the windows closed before it; never
+    `T x T`. Padding sits after the real tokens, so causality keeps it out
+    of every row that is read, and a window the real tokens never reach
+    closes on nobody."""
+    t = q.shape[0]
+    win, per = cfg.eva_window, cfg.eva_window // cfg.eva_chunk
+    block = eva_prefill_block(cfg.num_heads, win,
+                              win + (t - 1) // win * per)
+    out = []
+    for lo in range(0, t, block):
+        hi, start = min(lo + block, t), lo // win * win
+        closed = start // cfg.eva_chunk
+        keys = jnp.concatenate([summaries[:closed], row[start:hi]])
+        seen = jnp.concatenate(
+            [jnp.ones((hi - lo, closed), bool),
+             jnp.tril(jnp.ones((hi - lo, hi - start), bool), lo - start)],
+            1)
+        out.append(_eva_attend(q[None, lo:hi], keys[None], seen[None], w,
+                               cfg)[0])
+    return out[0] if len(out) == 1 else jnp.concatenate(out)
+
+
+def eva_decode(q, rows, pos, w, cfg):
+    """One token a slot: q [S, n, d], `rows` [S, L, 2 n d] the slot's dense
+    view (`eva_window` ring rows, its own new row written, then one row a
+    chunk), `pos` [S] the new token's position. Visibility is by position:
+    ring rows up to `pos % window`, the summaries of the closed windows.
+
+    The view is read as it lies, rows of 2 n d values: splitting a row into
+    heads would make the compiler copy the whole view into another tiling
+    first. So the heads stay inside the row and the query and the
+    probabilities are spread instead: scores are `rows @ Q`, with Q [2 n d,
+    n] holding head j's query in column j at head j's key entries and
+    zeros elsewhere, and the weighted rows are `P^T @ rows` [n, 2 n d], of
+    which head j's value entries of row j are kept. Two passes over the
+    view, both matmuls batched over slots alone; the zeros add nothing, so
+    the numbers are `_eva_attend`'s."""
+    with jax.named_scope("eva_attn"):
+        n, d = cfg.num_heads, cfg.head_dim
+        s = q.shape[0]
+        win, per = cfg.eva_window, cfg.eva_window // cfg.eva_chunk
+        ring = jnp.arange(win)[None, :] <= (pos % win)[:, None]
+        chunks = jnp.arange(rows.shape[1] - win)[None, :] \
+            < (pos // win * per)[:, None]
+        seen = jnp.concatenate([ring, chunks], 1)                 # [S, L]
+        own = jnp.eye(n, dtype=q.dtype)                  # head j, column j
+        spread = (q[:, :, :, None] * own[:, None, :]).reshape(s, n * d, n)
+        spread = jnp.concatenate([spread, jnp.zeros_like(spread)], 1)
+        scores = mm("slc,scn->sln", rows, spread) * d ** -0.5
+        scores = jnp.where(seen[..., None], scores, -jnp.inf)
+        every = mm("sln,slc->snc", jax.nn.softmax(scores, 1), rows)
+        o = every[:, :, n * d:].reshape(s, n, n, d)      # [S, j, head, d]
+        o = jnp.sum(o * own[None, :, :, None], 1)        # its own head's
+        return mm("sc,ch->sh", o.reshape(s, n * d), w["wo"])
 
 
 # ------------------------------------------------------------- experts
