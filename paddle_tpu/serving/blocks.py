@@ -43,9 +43,10 @@ from . import kv_cache as kvc
 
 __all__ = ["BlockAllocError", "BlockPool", "PagedLayerKV",
            "QuantPagedLayerKV", "PagedDecodeCache", "LatentSpec",
-           "StateSpec", "NoCache", "WindowSpec", "LatentLayer",
-           "StateLayer", "SlotStateStore", "alloc_layers", "gather_rows",
-           "window_of", "window_write", "alloc_pools",
+           "StateSpec", "NoCache", "WindowSpec", "SelectSpec",
+           "LatentLayer", "StateLayer", "SelectLayer", "SlotStateStore",
+           "alloc_layers", "gather_rows", "rows_at", "write_keys",
+           "gather_keys", "window_of", "window_write", "alloc_pools",
            "alloc_quant_pools", "write", "quant_write", "gather",
            "gather_quant", "dequant", "attend", "attend_quant",
            "attend_kernel", "attend_kernel_quant", "attention_impl",
@@ -118,6 +119,17 @@ PagedDecodeCache = collections.namedtuple(
 #                       pool keeps one entry a layer
 #   WindowSpec(width, window, chunk)  rows of `width` values of two
 #                       lifetimes under the one table (below): a LatentLayer
+#   SelectSpec(width, index_width, topk)  TWO rows a token under the one
+#                       table, paged alike: the row attention reads (`width`)
+#                       and the key an indexer scores to choose which `topk`
+#                       of those rows a query reads (`index_width`), in
+#                       arrays of their own, so that scoring every position
+#                       reads none of the wide rows: SelectLayer(rows
+#                       [num_blocks, block_size, width], keys [num_blocks,
+#                       block_size * index_width]: a block's keys side by
+#                       side in one row, so that a key narrower than the
+#                       chip's 128 lanes pads nothing and needs no layout of
+#                       its own; `write_keys` and `gather_keys`)
 # Softmax attention with few key/value heads needs no third kind: a token's
 # keys and values of all its key/value heads are one LatentSpec row.
 # A model without `cache_layout` (GPT) caches K and V per layer, as above.
@@ -126,6 +138,22 @@ StateSpec = collections.namedtuple("StateSpec", ["state", "tail"])
 NoCache = collections.namedtuple("NoCache", [])
 LatentLayer = collections.namedtuple("LatentLayer", ["rows"])
 StateLayer = collections.namedtuple("StateLayer", ["state", "tail"])
+SelectLayer = collections.namedtuple("SelectLayer", ["rows", "keys"])
+
+
+class SelectSpec(collections.namedtuple("SelectSpec",
+                                        ["width", "index_width", "topk"])):
+    """A layer whose query at position p reads the rows of min(topk, p + 1)
+    positions that an indexer picks, out of every position its slot holds
+    (one `width` row and one `index_width` key a token, paged alike)."""
+    __slots__ = ()
+
+    def selected_pairs(self, n_tokens):
+        """(query, read row) pairs of a prompt of `n_tokens` tokens from
+        position 0: `sum(min(topk, p + 1))` over it, in closed form."""
+        n, k = int(n_tokens), self.topk
+        return n * (n + 1) // 2 if n <= k else k * (k + 1) // 2 \
+            + (n - k) * k
 
 
 class WindowSpec(collections.namedtuple("WindowSpec",
@@ -206,7 +234,8 @@ def window_of(layout, block_size=None):
     geometry: window layers beside one-row-a-token layers, or windows of two
     sizes, would need a table each (ROADMAP B4's other half)."""
     paged = {s for s in layout or () if isinstance(s, (LatentSpec,
-                                                       WindowSpec))}
+                                                       WindowSpec,
+                                                       SelectSpec))}
     spec = next((s for s in paged if isinstance(s, WindowSpec)), None)
     if spec is None:
         return None
@@ -238,9 +267,9 @@ def alloc_pools(num_layers, num_blocks, block_size, num_heads, head_dim,
 
 def alloc_layers(layout, num_blocks, block_size, slots, dtype):
     """Zeroed cache arrays for a model that declares its layers' caches:
-    one LatentLayer, StateLayer or (for a layer that caches nothing)
-    NoCache per spec of `layout`. Latent rows and convolution tails take
-    `dtype`; the recurrent state is float32."""
+    one LatentLayer, StateLayer, SelectLayer or (for a layer that caches
+    nothing) NoCache per spec of `layout`. Latent rows, indexer keys and
+    convolution tails take `dtype`; the recurrent state is float32."""
     out = []
     for spec in layout:
         if isinstance(spec, (LatentSpec, WindowSpec)):
@@ -250,6 +279,11 @@ def alloc_layers(layout, num_blocks, block_size, slots, dtype):
             out.append(StateLayer(
                 jnp.zeros((slots,) + tuple(spec.state), jnp.float32),
                 jnp.zeros((slots,) + tuple(spec.tail), dtype)))
+        elif isinstance(spec, SelectSpec):
+            out.append(SelectLayer(
+                jnp.zeros((num_blocks, block_size, spec.width), dtype),
+                jnp.zeros((num_blocks, block_size * spec.index_width),
+                          dtype)))
         elif isinstance(spec, NoCache):
             out.append(spec)
         else:
@@ -261,8 +295,10 @@ def layout_bytes(layout, block_size, dtype):
     """(bytes one pool block pins over the latent layers, bytes one slot's
     state pins over the state layers)."""
     item = np.dtype(dtype).itemsize
-    block = sum(block_size * s.width * item for s in layout
-                if isinstance(s, (LatentSpec, WindowSpec)))
+    block = sum(block_size * item * (s.width + s.index_width)
+                if isinstance(s, SelectSpec) else block_size * s.width * item
+                for s in layout
+                if isinstance(s, (LatentSpec, WindowSpec, SelectSpec)))
     slot = sum(4 * int(np.prod(s.state)) + item * int(np.prod(s.tail))
                for s in layout if isinstance(s, StateSpec))
     return block, slot
@@ -315,6 +351,49 @@ def window_write(pool, new, tables, entry, row, live):
                                jnp.minimum(entry, nb - 1), axis=1)
     phys = jnp.where(live & (entry < nb), phys, GARBAGE_BLOCK)
     return pool.at[phys, row.astype(jnp.int32)].set(new.astype(pool.dtype))
+
+
+def _phys(tables, positions, bs):
+    """(physical block, row in it) of each logical position [S, T] through
+    `tables` [S, max_blocks]; positions past the table go to the garbage
+    block."""
+    nb = tables.shape[1]
+    lb = positions // bs
+    phys = jnp.take_along_axis(tables.astype(jnp.int32),
+                               jnp.minimum(lb, nb - 1), axis=1)
+    return jnp.where(lb < nb, phys, GARBAGE_BLOCK), positions % bs
+
+
+def write_keys(pool, new, tables, pos):
+    """`write` for a pool of keys side by side, [N, block_size * e]:
+    scatter `new` [S, T, e] at logical positions `pos + 0..T-1` of each
+    slot, a window of e values a key."""
+    s, t, e = new.shape
+    positions = pos.astype(jnp.int32)[:, None] \
+        + jnp.arange(t, dtype=jnp.int32)[None, :]
+    phys, row = _phys(tables, positions, pool.shape[1] // e)
+    at = jnp.stack([phys, row * e], -1).reshape(s * t, 2)
+    return jax.lax.scatter(
+        pool, at, new.reshape(s * t, e).astype(pool.dtype),
+        jax.lax.ScatterDimensionNumbers(
+            update_window_dims=(1,), inserted_window_dims=(0,),
+            scatter_dims_to_operand_dims=(0, 1)),
+        mode=jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS)
+
+
+def gather_keys(pool, tables, e):
+    """Each slot's keys in position order, [S, max_blocks * block_size, e],
+    from a pool of keys side by side [N, block_size * e]."""
+    return pool[tables.astype(jnp.int32)].reshape(tables.shape[0], -1, e)
+
+
+def rows_at(pool, tables, positions):
+    """The rows of a latent `pool` [N, block_size, width] at `positions`
+    [S, K] of each slot, read through its table [S, max_blocks]: [S, K,
+    width], and nothing of the pool besides (a position past the table
+    reads the garbage block)."""
+    phys, row = _phys(tables, positions.astype(jnp.int32), pool.shape[1])
+    return pool[phys, row]
 
 
 def dequant(codes, scale):

@@ -1117,6 +1117,9 @@ class PagedGenerationEngine(GenerationEngine):
             sum(isinstance(spec, kind) for spec in self._layout or ())
             for kind in ((blocks.LatentSpec, blocks.WindowSpec),
                          blocks.StateSpec))
+        # the layers whose queries read only the rows an indexer selects
+        self._select_specs = [spec for spec in self._layout or ()
+                              if isinstance(spec, blocks.SelectSpec)]
         # a layout whose rows live one window and whose summaries live on
         # (blocks.WindowSpec) sizes a slot itself: its table is as wide as
         # ITS blocks for max_len, and full provisioning is that a slot
@@ -1844,8 +1847,12 @@ class PagedGenerationEngine(GenerationEngine):
                 logits, npool, counters = self._run_layout_model(
                     params, pool, row, start[None], ids[None, :],
                     valid=length[None], slot=slot)
-                last = jax.lax.dynamic_index_in_dim(logits[0], length - 1,
-                                                    keepdims=False)
+                # a model may say that a long prompt hands back the last
+                # real token's row alone
+                keeps = getattr(self._model, "prefill_keeps_last_row", None)
+                last = logits[0, 0] if keeps is not None and keeps(bucket) \
+                    else jax.lax.dynamic_index_in_dim(logits[0], length - 1,
+                                                      keepdims=False)
                 first = self._select(last[None, :], key).astype(jnp.int32)
                 return (jnp.concatenate([first, counters]),
                         self._constrain_pools(npool))
@@ -2021,6 +2028,13 @@ class PagedGenerationEngine(GenerationEngine):
             _TRACER.note("eva_windows", -(-length // spec.window))
             _TRACER.note("eva_chunks_summarised", -(-length // spec.chunk))
             _TRACER.note("eva_pairs", spec.prefill_pairs(length))
+        if self._select_specs:
+            # (query, key) pairs the selecting layers' indexer scored over
+            # the prompt's real tokens, and those their attention read
+            _TRACER.note("dsa_index_pairs", len(self._select_specs)
+                         * length * (length + 1) // 2)
+            _TRACER.note("dsa_selected_pairs", sum(
+                s.selected_pairs(length) for s in self._select_specs))
         with _span("serving::prefill.wait"):
             before = self._fetches
             # the model's counters ride behind the first token
@@ -2140,6 +2154,20 @@ class PagedGenerationEngine(GenerationEngine):
         call, self._inflight = self._inflight, None
         return None if call is None else self._fetch_call(call)
 
+    def _select_attrs(self):
+        """What the selecting layers' decode reads this call, summed over
+        them, from the host's positions: the positions the indexer scores
+        (the slots' own, the new token's included) and those attention
+        selects of them (`topk` or all). The K/V rows the program read come
+        back with its counters (`dsa_kv_rows_read`)."""
+        if not self._select_specs:
+            return {}
+        held = self._pos[self._slot_active].astype(np.int64) + 1
+        return {
+            "dsa_rows_indexed": len(self._select_specs) * int(held.sum()),
+            "dsa_rows_selected": sum(int(np.minimum(held, s.topk).sum())
+                                     for s in self._select_specs)}
+
     def _wait_attrs(self, pool_in):
         """What the wait span of a call says of it, from the host's
         positions at its dispatch."""
@@ -2164,6 +2192,10 @@ class PagedGenerationEngine(GenerationEngine):
             if self.attention_impl == "gather":
                 wait["latent_rows_read"] = self._latent_layers \
                     * c.slots * c.max_blocks_per_slot * c.block_size
+        if self._select_specs:
+            # beside the rows the program says it read, on the same span
+            wait["dsa_rows_selected"] = \
+                self._select_attrs()["dsa_rows_selected"]
         return wait
 
     def decode(self):
@@ -2192,6 +2224,7 @@ class PagedGenerationEngine(GenerationEngine):
                      "attend": self.attention_impl,
                      "ahead": int(self._inflight is not None),
                      "call": self._calls}
+            attrs.update(self._select_attrs())
         with RecordEvent("serving::decode_step",
                          TracerEventType.UserDefined, attrs), \
                 blocks.attention_impl(self.attention_impl):

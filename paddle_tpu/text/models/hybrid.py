@@ -10,14 +10,21 @@ attention over a shared latent: its cache is one paged row a token; queries
 straight from the hidden state or through a bottleneck `q_lora_rank` wide, a
 head-wise output gate or none, plain or YaRN-scaled rotary: what the
 configuration declares), `gqa` (softmax attention of `num_heads` query
-heads over `num_kv_heads` key/value heads, no rotary; a token's keys and
-values are one paged row) and `eva` (EVA attention, `num_heads` heads with
-rotary over the whole head: exact softmax inside the aligned window of
-`eva_window` positions a token lies in, and of every window closed before it
-one learned summary row per `eva_chunk` positions, under one softmax; a
-token's row lives one window, a summary as long as the request:
+heads over `num_kv_heads` key/value heads; a token's keys and values are one
+paged row; a per-head q/k RMSNorm (`qk_norm`) and rotary over the whole head
+(`gqa_rope`) where declared; and where `index_topk` is declared, DeepSeek
+Sparse Attention's learned selection: an indexer of `index_heads` heads of
+`index_head_dim` over one key a token, cached beside the row
+(`blocks.SelectSpec`), scores every earlier position and the query attends
+only to the `index_topk` it picks) and `eva` (EVA attention, `num_heads`
+heads with rotary over the whole head: exact softmax inside the aligned
+window of `eva_window` positions a token lies in, and of every window closed
+before it one learned summary row per `eva_chunk` positions, under one
+softmax; a token's row lives one window, a summary as long as the request:
 `blocks.WindowSpec`). Feed-forwards: `swiglu` (dense) and `moe`
-(sigmoid-scored, group-limited top-k experts plus a shared expert, SwiGLU or
+(sigmoid-scored, group-limited top-k experts plus a shared expert, or
+softmax-scored top-k experts with no groups, bias or shared expert as
+`router_score` "softmax" and `shared_intermediate_size` 0 declare; SwiGLU or
 `relu2` as `moe_act` declares, of which this chip may hold a share:
 `num_experts` of `n_routed_experts`, global ids from `experts_first`). The
 layers come from the configuration: `blocks` (one part a layer: a mixer or a
@@ -46,12 +53,16 @@ from . import hybrid_ops as ops
 # parameters that stay float32 whatever the weights' type
 FLOAT32_LEAVES = ("norm1", "norm2", "norm_f", "a_log", "bf", "onorm",
                   "cnorm", "qnorm", "router", "router_bias", "dt_bias",
-                  "d_skip", "ssm_norm", "phi", "mu")
+                  "d_skip", "ssm_norm", "phi", "mu", "q_norm", "k_norm",
+                  "k_idx_norm", "k_idx_bias")
 _ONES_LEAVES = ("norm1", "norm2", "norm_f", "onorm", "cnorm", "qnorm",
-                "d_skip", "ssm_norm")
+                "d_skip", "ssm_norm", "q_norm", "k_norm", "k_idx_norm")
 _RESIDUAL_LEAVES = ("wo", "w_down", "we_down", "ws_down", "w_out")
 _NORM_LEAVES = ("norm1", "norm2", "norm_f")
 MIXERS = ("kda", "mla", "mamba2", "gqa", "eva")
+# the float32 logits [T, V] a prefill may return whole; past it, the last
+# real token's row alone ([1, 1, V])
+PREFILL_LOGIT_BYTES = 1 << 29
 FFNS = ("swiglu", "moe")
 
 
@@ -63,6 +74,12 @@ class HybridConfig:
     num_heads: int = 2
     head_dim: int = 16                   # KDA, GQA, EVA key and value head
     num_kv_heads: int = None             # GQA's key/value heads
+    qk_norm: bool = False                # GQA: per-head RMSNorm of q and k
+    gqa_rope: bool = False               # GQA: rotary over the whole head
+    index_topk: int = None               # GQA: positions a query selects
+    index_heads: int = 16                # the indexer's heads,
+    index_head_dim: int = 64             # their width, and the queries a
+    index_block: int = 512               # prefill block (keys alike)
     eva_window: int = 2048               # EVA: positions a window, and
     eva_chunk: int = 16                  # positions a summary row
     num_pred_heads: int = 1              # heads of vocab_size in `head`
@@ -99,6 +116,7 @@ class HybridConfig:
     moe_intermediate_size: int = 32
     shared_intermediate_size: int = 32
     moe_act: str = "swiglu"              # or "relu2": no gate matrix
+    router_score: str = "sigmoid"        # or "softmax": no groups or bias
     param_dtype: str = "float32"
     initializer_range: float = 0.02
     init_weights: bool = True            # False: shapes only, load later
@@ -139,6 +157,15 @@ class HybridConfig:
         if self.moe_act not in ("swiglu", "relu2"):
             raise ValueError(f"moe_act: 'swiglu' or 'relu2', got "
                              f"{self.moe_act!r}")
+        if self.router_score not in ("sigmoid", "softmax"):
+            raise ValueError(f"router_score: 'sigmoid' or 'softmax', got "
+                             f"{self.router_score!r}")
+        if self.index_topk is not None and (
+                "gqa" not in kinds or self.index_topk < 1
+                or self.index_head_dim % 2 or self.index_block < 1):
+            raise ValueError("index_topk: a positive number of positions "
+                             "for the gqa layers to select, an even "
+                             "index_head_dim (rotary) and an index_block")
         if self.rope_scaling and self.rope_scaling.get("type") != "yarn":
             raise ValueError(f"rope_scaling: only YaRN is built, got "
                              f"{self.rope_scaling!r}")
@@ -180,6 +207,13 @@ def leaf_shapes(cfg, kinds):
         d = cfg.head_dim
         out.update({"wq": (h, n * d), "wk": (h, cfg.num_kv_heads * d),
                     "wv": (h, cfg.num_kv_heads * d), "wo": (n * d, h)})
+        if cfg.qk_norm:
+            out.update({"q_norm": (d,), "k_norm": (d,)})
+        if cfg.index_topk:
+            m, e = cfg.index_heads, cfg.index_head_dim
+            out.update({"wq_idx": (h, m * e), "wk_idx": (h, e),
+                        "k_idx_norm": (e,), "k_idx_bias": (e,),
+                        "w_idx": (h, m)})
     elif mixer == "eva":
         d = cfg.head_dim
         out.update({"wq": (h, n * d), "wk": (h, n * d), "wv": (h, n * d),
@@ -211,6 +245,11 @@ def leaf_shapes(cfg, kinds):
                     "ws_up": (h, fs), "ws_down": (fs, h)})
         if cfg.moe_act == "relu2":
             del out["we_gate"], out["ws_gate"]
+        if cfg.router_score == "softmax":
+            del out["router_bias"]
+        if not fs:
+            for leaf in ("ws_gate", "ws_up", "ws_down"):
+                out.pop(leaf, None)
     return out
 
 
@@ -232,7 +271,7 @@ class _Leaves(Layer):
             elif leaf in _ONES_LEAVES:
                 data = jnp.ones(shape, dtype)
             elif leaf in ("a_log", "bf", "router_bias", "dt_bias",
-                          "conv_b"):
+                          "conv_b", "k_idx_bias"):
                 data = jnp.zeros(shape, dtype)
             else:
                 std = cfg.initializer_range
@@ -247,9 +286,14 @@ class _Leaves(Layer):
 
 
 class HybridDecoder(Layer):
-    # the counters `forward` returns with the logits, in this order; the
-    # first three add up over the expert layers, the last is their maximum
-    serving_counters = ops.COUNTERS
+    @property
+    def serving_counters(self):
+        """The counters `forward` returns with the logits, in this order:
+        the first three add up over the expert layers, the fourth is their
+        maximum; with selecting layers (`index_topk`), last, the K/V rows
+        their attention read, counted from the shapes the program reads."""
+        return ops.COUNTERS + (("dsa_kv_rows_read",)
+                               if self.cfg.index_topk else ())
 
     def __init__(self, cfg: HybridConfig):
         super().__init__()
@@ -310,6 +354,9 @@ class HybridDecoder(Layer):
             if mixer == "mla":
                 return blocks.LatentSpec(cfg.kv_lora_rank
                                          + cfg.qk_rope_head_dim)
+            if mixer == "gqa" and cfg.index_topk:   # and the indexer's key
+                return blocks.SelectSpec(2 * cfg.num_kv_heads * d,
+                                         cfg.index_head_dim, cfg.index_topk)
             if mixer == "gqa":   # a token's keys and values: one row
                 return blocks.LatentSpec(2 * cfg.num_kv_heads * d)
             if mixer == "eva":   # [k, v] of a token, [kbar, vbar] of a chunk
@@ -318,6 +365,12 @@ class HybridDecoder(Layer):
             return blocks.NoCache()      # a feed-forward alone
 
         return tuple(spec(mixer) for mixer, _ in self.kinds)
+
+    def prefill_keeps_last_row(self, tokens):
+        """Whether a prefill of `tokens` (bucket-padded) positions hands
+        back the last real token's row of logits alone, [1, 1, V]: its
+        [T, V] float32 would pass PREFILL_LOGIT_BYTES."""
+        return 4 * tokens * self.cfg.vocab_size > PREFILL_LOGIT_BYTES
 
     # -- forward ----------------------------------------------------------
     def forward(self, input_ids, cache, all_heads=False):
@@ -328,7 +381,9 @@ class HybridDecoder(Layer):
         `cache.slot` the slot whose state rows it fills. Returns (logits
         [S, T, V] float32, the new cache, counters int32 [4]); the logits
         are the first prediction head's, as the served step reads them, or
-        with `all_heads` every head's, [S, T, num_pred_heads, V]."""
+        with `all_heads` every head's, [S, T, num_pred_heads, V]. A prefill
+        whose [T, V] float32 logits would pass PREFILL_LOGIT_BYTES returns
+        the last real token's row alone, [1, 1, V]."""
         from ...serving import blocks
         ids = input_ids._data
         pool = tuple(type(l)(*(x._data for x in l)) for l in cache.layers)
@@ -356,6 +411,18 @@ class HybridDecoder(Layer):
             return new
         return jnp.concatenate([counters[:3] + new[:3],
                                 jnp.maximum(counters[3:], new[3:])])
+
+    @staticmethod
+    def _rows_read(counters, read):
+        """The expert counters with the selecting layers' K/V rows read
+        (`read`, a count a layer) behind them; unchanged without such
+        layers."""
+        if not read:
+            return counters
+        if counters is None:
+            counters = jnp.zeros((len(ops.COUNTERS),), jnp.int32)
+        return jnp.concatenate([counters,
+                                jnp.full((1,), sum(read), jnp.int32)])
 
     def _norm(self, h, scale):
         if self.cfg.norm_unit_offset:
@@ -387,7 +454,7 @@ class HybridDecoder(Layer):
 
     # One token a slot through a mixer: x [S, H] normed, `cached` the
     # layer's cache -> (what the mixer adds to h, the layer's new cache)
-    def _mix_decode(self, mixer, x, w, cached, tables, pos):
+    def _mix_decode(self, mixer, x, w, cached, tables, pos, read):
         from ...serving import blocks
         cfg = self.cfg
         if mixer == "kda":
@@ -410,8 +477,28 @@ class HybridDecoder(Layer):
             tail = jnp.concatenate([cached.tail[:, 1:], xbc], 1)
             return ops.mamba2_output(y, xs[:, 0], z[:, 0], w, cfg), \
                 blocks.StateLayer(state, tail)
+        if mixer == "gqa" and cfg.index_topk:
+            # both rows of the token written, the indexer over every
+            # position's key, then the selected positions' rows alone
+            q, row = ops.gqa_project(x[:, None], w, cfg, pos[:, None],
+                                     cached.rows.dtype)
+            q_i, wts, key = ops.dsa_index_project(x[:, None], w, cfg,
+                                                  pos[:, None],
+                                                  cached.keys.dtype)
+            rows = blocks.write(cached.rows, row, tables, pos)
+            keys = blocks.write_keys(cached.keys, key, tables, pos)
+            picked = ops.dsa_select_decode(
+                q_i[:, 0], wts[:, 0],
+                blocks.gather_keys(keys, tables, cfg.index_head_dim), pos,
+                cfg)
+            with jax.named_scope("dsa_attn"):
+                picked_rows = blocks.rows_at(rows, tables, picked)
+            read.append(picked_rows.shape[0] * picked_rows.shape[1])
+            return ops.dsa_decode(q[:, 0], picked_rows, picked, pos, w,
+                                  cfg), blocks.SelectLayer(rows, keys)
         if mixer == "gqa":
-            q, row = ops.gqa_project(x[:, None], w, cfg)
+            q, row = ops.gqa_project(
+                x[:, None], w, cfg, pos[:, None] if cfg.gqa_rope else None)
             rows = blocks.write(cached.rows, row, tables, pos)
             return ops.gqa_decode(q[:, 0], blocks.gather_rows(rows, tables),
                                   pos, w, cfg), blocks.LatentLayer(rows)
@@ -452,23 +539,24 @@ class HybridDecoder(Layer):
         tokens = ids[:, 0]
         live = tables[:, 0] != blocks.GARBAGE_BLOCK     # a slot with a row
         h = params["top.embed"][tokens].astype(jnp.float32)       # [S, H]
-        new_pool, counters = [], None
+        new_pool, counters, read = [], None, []
         for i, ((mixer, ffn), cached) in enumerate(zip(self.kinds, pool)):
             w = self._layer_params(params, i)
             if mixer is not None:
                 x = self._norm(h, w["norm1"])
                 y, cached = self._mix_decode(mixer, x, w, cached, tables,
-                                             pos)
+                                             pos, read)
                 h = h + y
             new_pool.append(cached)
             h, counters = self._ffn(h, w, ffn, live, counters)
+        counters = self._rows_read(counters, read)
         logits, counters = self._finish(params, h, counters, all_heads)
         return logits[:, None], tuple(new_pool), counters
 
     # One request's (bucket-padded) tokens from position 0 through a mixer:
     # x [T, H] normed, `valid` [T] the real ones, `length` their count
     def _mix_prefill(self, mixer, x, w, cached, tables, pos, valid, length,
-                     slot):
+                     slot, read):
         from ...serving import blocks
         cfg = self.cfg
 
@@ -505,8 +593,21 @@ class HybridDecoder(Layer):
             return ops.mamba2_output(y, xs, z, w, cfg), new
         # padded rows land beyond `length` in the slot's last block or in
         # the garbage block: masked by position, overwritten
+        if mixer == "gqa" and cfg.index_topk:
+            at = jnp.arange(x.shape[0])
+            q, row = ops.gqa_project(x, w, cfg, at, cached.rows.dtype)
+            q_i, wts, key = ops.dsa_index_project(x, w, cfg, at,
+                                                  cached.keys.dtype)
+            new = blocks.SelectLayer(
+                blocks.write(cached.rows, row[None], tables, pos),
+                blocks.write_keys(cached.keys, key[None], tables, pos))
+            # each block of queries reads the key blocks up to its own
+            blocks_of = -(-x.shape[0] // cfg.index_block)
+            read.append(cfg.index_block * blocks_of * (blocks_of + 1) // 2)
+            return ops.dsa_prefill(q, row, q_i, wts, key, w, cfg), new
         if mixer == "gqa":
-            q, row = ops.gqa_project(x, w, cfg)
+            q, row = ops.gqa_project(
+                x, w, cfg, jnp.arange(x.shape[0]) if cfg.gqa_rope else None)
             new = blocks.LatentLayer(
                 blocks.write(cached.rows, row[None], tables, pos))
             return ops.gqa_prefill(q, row, w, cfg), new
@@ -550,15 +651,19 @@ class HybridDecoder(Layer):
         cfg = self.cfg
         valid = jnp.arange(ids.shape[1]) < length
         h = params["top.embed"][ids[0]].astype(jnp.float32)       # [T, H]
-        new_pool, counters = [], None
+        new_pool, counters, read = [], None, []
         for i, ((mixer, ffn), cached) in enumerate(zip(self.kinds, pool)):
             w = self._layer_params(params, i)
             if mixer is not None:
                 x = self._norm(h, w["norm1"])
                 y, cached = self._mix_prefill(mixer, x, w, cached, tables,
-                                              pos, valid, length, slot)
+                                              pos, valid, length, slot, read)
                 h = h + y
             new_pool.append(cached)
             h, counters = self._ffn(h, w, ffn, valid, counters)
+        counters = self._rows_read(counters, read)
+        if not all_heads and self.prefill_keeps_last_row(h.shape[0]):
+            # the one row the engine reads: the last real token's
+            h = jax.lax.dynamic_slice_in_dim(h, length - 1, 1)
         logits, counters = self._finish(params, h, counters, all_heads)
         return logits[None], tuple(new_pool), counters

@@ -2,13 +2,18 @@
 KDA linear attention and the Mamba-2 state-space mixer (each a chunked form
 for prefill and the one-step recurrence for decode), MLA over a latent cache
 (expanded for prefill, absorbed for decode), grouped-query attention over
-paged K/V rows, EVA attention (exact softmax inside an aligned window, one
-learned summary row a chunk of every closed window), and the expert layer of a chip that holds a share of the
-experts. Plain XLA; `text/models/hybrid.py` wires them into a model and
-`serving/blocks.py` holds the two kinds of cache they read and write.
+paged K/V rows (with, where declared, DeepSeek Sparse Attention's learned
+selection in front: an indexer scores every cached position and attention
+reads only the `index_topk` it picks), EVA attention (exact softmax inside an
+aligned window, one learned summary row a chunk of every closed window), and
+the expert layer of a chip that holds a share of the experts (sigmoid,
+group-limited routing, or a plain softmax). Plain XLA;
+`text/models/hybrid.py` wires them into a model and `serving/blocks.py`
+holds the kinds of cache they read and write.
 
 Weights are bfloat16 (matmuls accumulate in float32), the residual stream,
-the norms, the router and the recurrent state are float32.
+the norms, the router, the index scores and the recurrent state are
+float32.
 """
 import math
 
@@ -470,15 +475,29 @@ def mamba2_chunked(xs, b, c, dt, a, valid, chunk):
 
 # ------------------------------------------------- grouped-query attention
 
-def gqa_project(x, w, cfg):
+def gqa_project(x, w, cfg, positions=None, dtype=jnp.bfloat16):
     """(q [..., T, n, d], the paged row [..., T, 2 kv d] = [k, v] of the
-    kv key/value heads as cached). No rotary: positions come from the
-    state-space blocks."""
+    kv key/value heads as cached, in `dtype`). What the configuration
+    declares, in this order: a per-head RMSNorm of q and k (`qk_norm`),
+    rotary over the whole head at `positions` [..., T] (`gqa_rope`). Neither
+    where positions come from elsewhere (the state-space blocks)."""
     q = mm("...h,hc->...c", x, w["wq"])
-    row = jnp.concatenate([mm("...h,hc->...c", x, w["wk"]),
+    if not (cfg.qk_norm or cfg.gqa_rope):
+        row = jnp.concatenate([mm("...h,hc->...c", x, w["wk"]),
+                               mm("...h,hc->...c", x, w["wv"])], -1)
+        return q.reshape(x.shape[:-1] + (cfg.num_heads, cfg.head_dim)), \
+            row.astype(dtype)
+    lead, d = x.shape[:-1], cfg.head_dim
+    q = q.reshape(lead + (cfg.num_heads, d))
+    k = mm("...h,hc->...c", x, w["wk"]).reshape(lead + (cfg.num_kv_heads, d))
+    if cfg.qk_norm:
+        q = rms_norm(q, w["q_norm"], cfg.rms_norm_eps)
+        k = rms_norm(k, w["k_norm"], cfg.rms_norm_eps)
+    if cfg.gqa_rope:
+        q, k = rotary(q, positions, cfg), rotary(k, positions, cfg)
+    row = jnp.concatenate([k.reshape(lead + (-1,)),
                            mm("...h,hc->...c", x, w["wv"])], -1)
-    return q.reshape(x.shape[:-1] + (cfg.num_heads, cfg.head_dim)), \
-        row.astype(jnp.bfloat16)
+    return q, row.astype(dtype)
 
 
 def _gqa_attend(q, row, seen, w, cfg):
@@ -509,6 +528,184 @@ def gqa_decode(q, rows, pos, w, cfg):
     rows (its own new row written), `pos` [S] the new token's position."""
     seen = jnp.arange(rows.shape[1])[None, :] <= pos[:, None]
     return _gqa_attend(q[:, None], rows, seen[:, None], w, cfg)[:, 0]
+
+
+# ------------------------------------------------ learned sparse attention
+#
+# DeepSeek Sparse Attention's selection in front of grouped-query attention:
+# an indexer of m heads of e dims over ONE cached key a token scores every
+# earlier position, `I[t, s] = sum_j w[t, j] relu(q_I[t, j] . k_I[s])`, and
+# the query at t attends only to the `index_topk` positions s <= t of
+# largest I (all of them while there are fewer), ties to the lower
+# position, one set for every query head.
+
+def layer_norm(x, w, b, eps):
+    x = x.astype(jnp.float32)
+    mu = jnp.mean(x, -1, keepdims=True)
+    var = jnp.mean(jnp.square(x - mu), -1, keepdims=True)
+    return (x - mu) * jax.lax.rsqrt(var + eps) * w + b
+
+
+def dsa_index_project(x, w, cfg, positions, dtype):
+    """(q_I [..., T, m, e] float32, the heads' weights [..., T, m] float32
+    with the scale `(m e)^-1/2` in them, k_I [..., T, e] as cached, in
+    `dtype`): queries straight from the normed input, the key through a
+    LayerNorm, rotary on the first half of every indexer head at
+    `positions` [..., T]."""
+    m, e = cfg.index_heads, cfg.index_head_dim
+    half = e // 2
+
+    def rope(a):
+        return jnp.concatenate([rotary(a[..., :half], positions, cfg),
+                                a[..., half:]], -1)
+
+    q = rope(mm("...h,hc->...c", x, w["wq_idx"]).reshape(
+        x.shape[:-1] + (m, e)))
+    k = rope(layer_norm(mm("...h,hc->...c", x, w["wk_idx"]),
+                        w["k_idx_norm"], w["k_idx_bias"], cfg.rms_norm_eps))
+    wts = mm("...h,hm->...m", x, w["w_idx"]) * (m * e) ** -0.5
+    return q, wts, k.astype(dtype)
+
+
+def dsa_index_scores(q, wts, keys):
+    """I [..., Q, L] float32 of queries q [..., Q, m, e] with weights
+    [..., Q, m] against `keys` [..., L, e]: bfloat16 operands, float32
+    products, the sum over the m heads elementwise (a float32 matmul would
+    be one bfloat16 pass on a TPU). A zero is +0, so that one order of the
+    scores serves comparison and bits alike."""
+    with jax.named_scope("dsa_index"):
+        s = mm("...qme,...le->...qml", q, keys)
+        out = jnp.sum(jax.nn.relu(s) * wts[..., None], -2)
+        return jnp.where(out == 0, 0.0, out)
+
+
+def _order_key(x):
+    """float32 -> uint32 in the same order (-inf lowest)."""
+    b = jax.lax.bitcast_convert_type(x, jnp.uint32)
+    return jnp.where(b >> 31 == 1, ~b, b | jnp.uint32(1 << 31))
+
+
+def dsa_select_mask(scores, k):
+    """[..., L] bool: the k entries of largest `scores` [..., L] float32 of
+    each row, ties to the lower index (a row's -inf entries count as the
+    lowest, so a row with fewer than k finite ones keeps all of those and
+    some of the others, for the caller's causal mask to drop). The k-th
+    largest is found exactly by bisection on the scores' bits: 32 counting
+    passes over the row, a bit each (on the chip a [512, 32 768] block takes
+    2.1 ms; two bits a pass 2.2, four 4.2, `lax.top_k` 18.2); positions in
+    order break ties only when a row has more at the k-th value than places
+    left."""
+    with jax.named_scope("dsa_select"):
+        k = min(k, scores.shape[-1])
+        key = _order_key(scores)
+
+        def bit(i, kth):
+            cand = kth | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+            return jnp.where(jnp.sum(key >= cand[..., None], -1) >= k,
+                             cand, kth)
+
+        kth = jax.lax.fori_loop(0, 32, bit,
+                                jnp.zeros(key.shape[:-1], jnp.uint32))
+        above, at = key > kth[..., None], key == kth[..., None]
+        room = k - jnp.sum(above, -1, keepdims=True)
+        return jax.lax.cond(
+            jnp.any(jnp.sum(at, -1, keepdims=True) > room),
+            lambda: above | (at & (jnp.cumsum(at, -1) <= room)),
+            lambda: above | at)
+
+
+def dsa_select_decode(q_idx, wts, keys, pos, cfg):
+    """The positions one token a slot attends to: q_idx [S, m, e], wts
+    [S, m], `keys` [S, L, e] the slot's indexer keys in position order (its
+    own new one written), `pos` [S]. Returns int32 [S, K], K =
+    min(index_topk, L): the selected positions, those past `pos` standing
+    for nothing (a slot with fewer than K positions)."""
+    scores = dsa_index_scores(q_idx[:, None], wts[:, None], keys)[:, 0]
+    seen = jnp.arange(keys.shape[1])[None, :] <= pos[:, None]
+    scores = jnp.where(seen, scores, -jnp.inf)
+    with jax.named_scope("dsa_select"):
+        return jax.lax.top_k(scores, min(cfg.index_topk, keys.shape[1]))[1]
+
+
+def dsa_decode(q, rows, picked, pos, w, cfg):
+    """One token a slot over the K/V rows it selected: q [S, n, d], `rows`
+    [S, K, 2 kv d] the rows at the positions `picked` [S, K]."""
+    with jax.named_scope("dsa_attn"):
+        seen = picked <= pos[:, None]
+        return _gqa_attend(q[:, None], rows, seen[:, None], w, cfg)[:, 0]
+
+
+def dsa_prefill(q, row, q_idx, wts, k_idx, w, cfg):
+    """One request's own tokens [T] from position 0 (q [T, n, d], its rows
+    [T, 2 kv d], the indexer's q_idx [T, m, e], wts [T, m], k_idx [T, e]):
+    queries go `index_block` at a time. For a block, the index scores
+    against the key blocks up to its own (the triangle), the selection
+    (`dsa_select_mask` and causality), then attention over the selected
+    keys, masked dense a key block at a time, each key block read once: the
+    softmax is shifted by the running maximum of the selected scores, and
+    what is already summed is rescaled when a block raises it, so no score
+    lies above its shift and every row's largest term is exactly 1. Padding
+    sits after the real tokens, so causality keeps it out of every row that
+    is read."""
+    n, kv, d = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    t, blk = q.shape[0], cfg.index_block
+    tp = -(-t // blk) * blk
+
+    def pad(a):
+        return jnp.pad(a, ((0, tp - t),) + ((0, 0),) * (a.ndim - 1))
+
+    # q and the heads' outputs are kept as the rows are (bfloat16 as the
+    # matmuls take them): half the bytes of float32 at 32k tokens
+    q = pad(q.astype(row.dtype)).reshape(tp, kv, n // kv, d)
+    k, v = (pad(part).reshape(tp, kv, d) for part in jnp.split(row, 2, -1))
+    q_idx, wts, k_idx = pad(q_idx), pad(wts), pad(k_idx)
+    # the shift before a query's first selected key: finite, so that a
+    # block with nothing selected rescales by exp(0) and adds exp(-inf)
+    floor = jnp.finfo(jnp.float32).min
+
+    def block(i, out):
+        lo = i * blk
+        q_i = jax.lax.dynamic_slice_in_dim(q_idx, lo, blk)
+        w_i = jax.lax.dynamic_slice_in_dim(wts, lo, blk)
+
+        def score(j, acc):
+            s = dsa_index_scores(q_i, w_i, jax.lax.dynamic_slice_in_dim(
+                k_idx, j * blk, blk))
+            return jax.lax.dynamic_update_slice_in_dim(acc, s, j * blk, 1)
+
+        scores = jax.lax.fori_loop(0, i + 1, score, jnp.full(
+            (blk, tp), -jnp.inf, jnp.float32))
+        causal = jnp.arange(tp)[None, :] <= lo + jnp.arange(blk)[:, None]
+        chosen = dsa_select_mask(jnp.where(causal, scores, -jnp.inf),
+                                 cfg.index_topk) & causal
+        q_b = jax.lax.dynamic_slice_in_dim(q, lo, blk)
+
+        def attend(j, carry):
+            top, total, acc = carry
+            k_j, v_j = (jax.lax.dynamic_slice_in_dim(a, j * blk, blk)
+                        for a in (k, v))
+            seen = jax.lax.dynamic_slice_in_dim(chosen, j * blk, blk, 1)
+            s = jnp.where(seen, mm("qkgd,lkd->kgql", q_b, k_j) * d ** -0.5,
+                          -jnp.inf)
+            new = jnp.maximum(top, jnp.max(s, -1))
+            p = jnp.exp(s - new[..., None])
+            scale = jnp.exp(top - new)
+            return new, total * scale + jnp.sum(p, -1), \
+                acc * scale[..., None] + mm("kgql,lkd->kgqd", p, v_j)
+
+        with jax.named_scope("dsa_attn"):
+            heads = (kv, n // kv, blk)
+            _, total, acc = jax.lax.fori_loop(0, i + 1, attend, (
+                jnp.full(heads, floor, jnp.float32),
+                jnp.zeros(heads, jnp.float32),
+                jnp.zeros(heads + (d,), jnp.float32)))
+            o = (acc / total[..., None]).transpose(2, 0, 1, 3)
+        return jax.lax.dynamic_update_slice_in_dim(
+            out, o.reshape(blk, n * d).astype(out.dtype), lo, 0)
+
+    o = jax.lax.fori_loop(0, tp // blk, block,
+                          jnp.zeros((tp, n * d), row.dtype))
+    return mm("tc,ch->th", o[:t], w["wo"])
 
 
 # ------------------------------------------------------------------- EVA
@@ -642,11 +839,32 @@ def eva_decode(q, rows, pos, w, cfg):
 COUNTERS = ("moe_pairs_total", "moe_pairs_local", "moe_experts_hit",
             "moe_expert_max")
 
+# the float32 hidden activations [E, T, f] of the held experts one pass of
+# the expert share may take
+MOE_HIDDEN_BYTES = 1 << 30
+
+
+def moe_token_block(e, t, f):
+    """Tokens a pass of the expert share takes so that E x tokens x f
+    float32 stay within MOE_HIDDEN_BYTES: `t` (one pass) where that fits,
+    else the largest power of two that does."""
+    if 4 * e * t * f <= MOE_HIDDEN_BYTES:
+        return t
+    return 1 << int(math.log2(MOE_HIDDEN_BYTES // (4 * e * f)))
+
 
 def route(x, w, cfg):
     """Sigmoid scores over all routed experts in float32; the choice on
     score + bias, group-limited; weights the chosen scores normalised, times
-    the scaling factor. Returns (ids [T, k] global, weights [T, k])."""
+    the scaling factor. Where the configuration declares `router_score`
+    "softmax": a softmax over all routed experts in float32, the top k, their
+    probabilities normalised to sum 1 (no groups, no bias, no factor).
+    Returns (ids [T, k] global, weights [T, k])."""
+    if cfg.router_score == "softmax":
+        p = jax.nn.softmax(f32mm("th,hr->tr", x.astype(jnp.float32),
+                                 w["router"]), -1)
+        picked, ids = jax.lax.top_k(p, cfg.experts_per_tok)
+        return ids, picked / jnp.sum(picked, -1, keepdims=True)
     r, groups = cfg.n_routed_experts, cfg.n_group
     s = jax.nn.sigmoid(f32mm("th,hr->tr", x.astype(jnp.float32),
                              w["router"]))
@@ -667,30 +885,50 @@ def moe_share(x, w, cfg, live):
     """The part of the expert layer this chip gives for tokens `x` [T, H]:
     it routes over all `n_routed_experts`, computes the `num_experts` it
     holds (global ids from `experts_first`) for the tokens that chose them,
-    and adds the shared expert. What the absent experts would add is left
+    and adds the shared expert where the configuration has one (a
+    `shared_intermediate_size`). What the absent experts would add is left
     out; nothing stands in for them. `live` [T] bool marks the rows that are
     someone's token (counters only). An expert is SwiGLU or, where the
     configuration declares `moe_act` "relu2", `W_down relu(W_up x)^2`,
-    routed and shared alike. Returns (y [T, H], counters int32 [4] in the
-    order of COUNTERS)."""
+    routed and shared alike. Every held expert runs over every token,
+    weighted by the picks; where that would hold more than MOE_HIDDEN_BYTES
+    of one float32 [E, T, f] it runs over the tokens a block at a time
+    (`moe_token_block`). Returns (y [T, H], counters int32 [4] in the order
+    of COUNTERS)."""
     with jax.named_scope("moe_experts"):
         e = cfg.num_experts
         ids, weights = route(x, w, cfg)
         local_id = ids - cfg.experts_first
         local = (local_id >= 0) & (local_id < e)
         t = x.shape[0]
-        # every held expert over every token, weighted by the picks
         dense_w = jnp.zeros((t, e + 1), jnp.float32).at[
             jnp.arange(t)[:, None], jnp.where(local, local_id, e)
         ].add(weights)[:, :e]
         relu2 = cfg.moe_act == "relu2"   # W_down relu(W_up x)^2, no gate
-        hid = jnp.square(jax.nn.relu(mm("th,ehf->etf", x, w["we_up"]))) \
-            if relu2 else jax.nn.silu(mm("th,ehf->etf", x, w["we_gate"])) \
-            * mm("th,ehf->etf", x, w["we_up"])
-        routed = mm("etf,efh->th", hid * dense_w.T[:, :, None],
-                    w["we_down"])
-        y = routed + (relu2_mlp(x, w["ws_up"], w["ws_down"]) if relu2
-                      else swiglu(x, w["ws_gate"], w["ws_up"], w["ws_down"]))
+
+        def held(x, dense_w):
+            hid = jnp.square(jax.nn.relu(mm("th,ehf->etf", x, w["we_up"]))) \
+                if relu2 else \
+                jax.nn.silu(mm("th,ehf->etf", x, w["we_gate"])) \
+                * mm("th,ehf->etf", x, w["we_up"])
+            return mm("etf,efh->th", hid * dense_w.T[:, :, None],
+                      w["we_down"])
+
+        block = moe_token_block(e, t, w["we_up"].shape[-1])
+        if block == t:
+            routed = held(x, dense_w)
+        else:
+            nb = -(-t // block)
+            pad = ((0, nb * block - t), (0, 0))
+            routed = jax.lax.map(
+                lambda a: held(*a),
+                (jnp.pad(x, pad).reshape(nb, block, -1),
+                 jnp.pad(dense_w, pad).reshape(nb, block, e))
+            ).reshape(nb * block, -1)[:t]
+        y = routed
+        if cfg.shared_intermediate_size:
+            y = y + (relu2_mlp(x, w["ws_up"], w["ws_down"]) if relu2 else
+                     swiglu(x, w["ws_gate"], w["ws_up"], w["ws_down"]))
         counted = local & live[:, None]
         per_expert = jnp.zeros((e + 1,), jnp.int32).at[
             jnp.where(counted, local_id, e).reshape(-1)].add(1)[:e]
