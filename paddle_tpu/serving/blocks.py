@@ -129,7 +129,8 @@ PagedDecodeCache = collections.namedtuple(
 #                       block_size * index_width]: a block's keys side by
 #                       side in one row, so that a key narrower than the
 #                       chip's 128 lanes pads nothing and needs no layout of
-#                       its own; `write_keys` and `gather_keys`)
+#                       its own; `write_keys` writes whole such rows and
+#                       `gather_keys` reads them)
 # Softmax attention with few key/value heads needs no third kind: a token's
 # keys and values of all its key/value heads are one LatentSpec row.
 # A model without `cache_layout` (GPT) caches K and V per layer, as above.
@@ -364,21 +365,36 @@ def _phys(tables, positions, bs):
     return jnp.where(lb < nb, phys, GARBAGE_BLOCK), positions % bs
 
 
-def write_keys(pool, new, tables, pos):
-    """`write` for a pool of keys side by side, [N, block_size * e]:
-    scatter `new` [S, T, e] at logical positions `pos + 0..T-1` of each
-    slot, a window of e values a key."""
+def write_keys(pool, new, tables, pos=None):
+    """`write` for a pool of keys side by side, [N, block_size * e], in
+    whole rows of the pool: a scatter of e-wide windows into the wider rows
+    is no operation the TPU has, and its compiler builds it as a loop of
+    one trip a key. `new` [S, T, e] is either
+      a prefill's keys from position 0 (`pos` None): block_size keys are
+        one row, and the rows are written through the table (the last one
+        padded, where T is not whole blocks: padding lies past the real
+        keys, where no query reads it);
+      or one key a slot (T == 1) at `pos` [S]: each slot's block row is
+        read, the key laid over its lanes, and the row written back.
+    Keys past the table land in the garbage block, as with `write`."""
     s, t, e = new.shape
-    positions = pos.astype(jnp.int32)[:, None] \
-        + jnp.arange(t, dtype=jnp.int32)[None, :]
-    phys, row = _phys(tables, positions, pool.shape[1] // e)
-    at = jnp.stack([phys, row * e], -1).reshape(s * t, 2)
-    return jax.lax.scatter(
-        pool, at, new.reshape(s * t, e).astype(pool.dtype),
-        jax.lax.ScatterDimensionNumbers(
-            update_window_dims=(1,), inserted_window_dims=(0,),
-            scatter_dims_to_operand_dims=(0, 1)),
-        mode=jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS)
+    width = pool.shape[1]
+    bs = width // e
+    new = new.astype(pool.dtype)
+    if pos is None:
+        n = blocks_for_tokens(t, bs)
+        first = jnp.arange(n, dtype=jnp.int32) * bs
+        phys, _ = _phys(tables, jnp.broadcast_to(first, (s, n)), bs)
+        rows = jnp.pad(new, ((0, 0), (0, n * bs - t), (0, 0)))
+        return pool.at[phys.reshape(s * n)].set(rows.reshape(s * n, width))
+    if t != 1:
+        raise ValueError(f"write_keys: {t} keys a slot at `pos`; only a "
+                         f"prefill from position 0 writes more than one")
+    phys, row = _phys(tables, pos.astype(jnp.int32)[:, None], bs)
+    phys, row = phys[:, 0], row[:, 0]
+    mine = jnp.arange(width, dtype=jnp.int32)[None, :] // e == row[:, None]
+    rows = jnp.where(mine, jnp.tile(new[:, 0], (1, bs)), pool[phys])
+    return pool.at[phys].set(rows)
 
 
 def gather_keys(pool, tables, e):

@@ -291,8 +291,9 @@ class HybridDecoder(Layer):
         """The counters `forward` returns with the logits, in this order:
         the first three add up over the expert layers, the fourth is their
         maximum; with selecting layers (`index_topk`), last, the K/V rows
-        their attention read, counted from the shapes the program reads."""
-        return ops.COUNTERS + (("dsa_kv_rows_read",)
+        their attention read and the block rows of indexer keys they wrote
+        whole, counted from the shapes the program reads and writes."""
+        return ops.COUNTERS + (("dsa_kv_rows_read", "dsa_key_blocks_written")
                                if self.cfg.index_topk else ())
 
     def __init__(self, cfg: HybridConfig):
@@ -413,16 +414,17 @@ class HybridDecoder(Layer):
                                 jnp.maximum(counters[3:], new[3:])])
 
     @staticmethod
-    def _rows_read(counters, read):
-        """The expert counters with the selecting layers' K/V rows read
-        (`read`, a count a layer) behind them; unchanged without such
-        layers."""
+    def _select_counts(counters, read):
+        """The expert counters with the selecting layers' K/V rows read and
+        key block rows written (`read`, a pair of counts a layer) behind
+        them; unchanged without such layers."""
         if not read:
             return counters
         if counters is None:
             counters = jnp.zeros((len(ops.COUNTERS),), jnp.int32)
         return jnp.concatenate([counters,
-                                jnp.full((1,), sum(read), jnp.int32)])
+                                jnp.array([sum(c) for c in zip(*read)],
+                                          jnp.int32)])
 
     def _norm(self, h, scale):
         if self.cfg.norm_unit_offset:
@@ -493,7 +495,9 @@ class HybridDecoder(Layer):
                 cfg)
             with jax.named_scope("dsa_attn"):
                 picked_rows = blocks.rows_at(rows, tables, picked)
-            read.append(picked_rows.shape[0] * picked_rows.shape[1])
+            # and one key block row a slot written
+            read.append((picked_rows.shape[0] * picked_rows.shape[1],
+                         pos.shape[0]))
             return ops.dsa_decode(q[:, 0], picked_rows, picked, pos, w,
                                   cfg), blocks.SelectLayer(rows, keys)
         if mixer == "gqa":
@@ -549,7 +553,7 @@ class HybridDecoder(Layer):
                 h = h + y
             new_pool.append(cached)
             h, counters = self._ffn(h, w, ffn, live, counters)
-        counters = self._rows_read(counters, read)
+        counters = self._select_counts(counters, read)
         logits, counters = self._finish(params, h, counters, all_heads)
         return logits[:, None], tuple(new_pool), counters
 
@@ -600,10 +604,13 @@ class HybridDecoder(Layer):
                                                   cached.keys.dtype)
             new = blocks.SelectLayer(
                 blocks.write(cached.rows, row[None], tables, pos),
-                blocks.write_keys(cached.keys, key[None], tables, pos))
-            # each block of queries reads the key blocks up to its own
+                blocks.write_keys(cached.keys, key[None], tables))
+            # each block of queries reads the key blocks up to its own; the
+            # keys are written a block row at a time
             blocks_of = -(-x.shape[0] // cfg.index_block)
-            read.append(cfg.index_block * blocks_of * (blocks_of + 1) // 2)
+            read.append((cfg.index_block * blocks_of * (blocks_of + 1) // 2,
+                         blocks.blocks_for_tokens(x.shape[0],
+                                                  cached.rows.shape[1])))
             return ops.dsa_prefill(q, row, q_i, wts, key, w, cfg), new
         if mixer == "gqa":
             q, row = ops.gqa_project(
@@ -661,7 +668,7 @@ class HybridDecoder(Layer):
                 h = h + y
             new_pool.append(cached)
             h, counters = self._ffn(h, w, ffn, valid, counters)
-        counters = self._rows_read(counters, read)
+        counters = self._select_counts(counters, read)
         if not all_heads and self.prefill_keeps_last_row(h.shape[0]):
             # the one row the engine reads: the last real token's
             h = jax.lax.dynamic_slice_in_dim(h, length - 1, 1)

@@ -9,6 +9,7 @@ uncut layer, and the four sibling configurations' programs, which must not
 have moved."""
 import json
 import os
+import re
 import sys
 
 import jax
@@ -20,7 +21,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from test_nemotron_h_model import lowered_programs             # noqa: E402
+from test_nemotron_h_model import (lowered_programs,           # noqa: E402
+                                   lowered_texts)
 
 from benchmark.harness import reference_keye as ref            # noqa: E402
 from benchmark.harness import weights_keye as wk               # noqa: E402
@@ -335,6 +337,99 @@ def test_the_two_rows_of_a_token_share_one_table():
     assert blocks.SelectSpec(6, 3, 8).selected_pairs(5) == 15
 
 
+def write_keys_per_key(pool, new, tables, pos):
+    """The oracle: the key write as one e-wide window a key, scattered at
+    its row's lanes of its block's row (`new` [S, T, e] at positions
+    `pos + 0..T-1`; past the table, the garbage block)."""
+    s, t, e = new.shape
+    bs, nb = pool.shape[1] // e, tables.shape[1]
+    positions = pos.astype(jnp.int32)[:, None] + jnp.arange(t)[None, :]
+    lb = positions // bs
+    phys = jnp.where(lb < nb, jnp.take_along_axis(
+        tables, jnp.minimum(lb, nb - 1), axis=1), blocks.GARBAGE_BLOCK)
+    at = jnp.stack([phys, positions % bs * e], -1).reshape(s * t, 2)
+    return jax.lax.scatter(
+        pool, at, new.reshape(s * t, e).astype(pool.dtype),
+        jax.lax.ScatterDimensionNumbers(
+            update_window_dims=(1,), inserted_window_dims=(0,),
+            scatter_dims_to_operand_dims=(0, 1)),
+        mode=jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS)
+
+
+def key_pool(n, bs, e, seed=0):
+    """A pool of `n` blocks holding distinct values, so a row written where
+    it should not be shows."""
+    return jax.random.normal(jax.random.key(seed), (n, bs * e))
+
+
+def outside_garbage(pool):
+    """What the garbage block holds is any writer's: the rest must match."""
+    return np.asarray(pool)[blocks.GARBAGE_BLOCK + 1:]
+
+
+@pytest.mark.parametrize("row,t", [
+    ([5, 2, 6], 12),             # every block in the table, out of order
+    ([3, 6, 1, 0], 12),          # an unallocated entry behind the keys
+    ([4, 1], 16),                # blocks past the table: the garbage block
+    ([2, 5, 3], 8)])             # fewer keys than the table holds
+def test_a_prefill_writes_its_keys_as_the_per_key_write(row, t):
+    """A prefill's keys from position 0, a block row at a time, are the
+    per-key write bit for bit wherever a slot's table reaches; the real
+    keys are fewer than the bucket (length 9), the padding lands as the
+    per-key write puts it."""
+    bs, e = 4, 3
+    tables = jnp.array([row], jnp.int32)
+    pool = key_pool(7, bs, e)
+    new = jax.random.normal(jax.random.key(1), (1, t, e))
+    new = jnp.where(jnp.arange(t)[None, :, None] < 9, new, 100.0)
+    got = blocks.write_keys(pool, new, tables)
+    want = write_keys_per_key(pool, new, tables, jnp.zeros((1,), jnp.int32))
+    np.testing.assert_array_equal(outside_garbage(got),
+                                  outside_garbage(want))
+    assert not np.array_equal(outside_garbage(got),
+                              outside_garbage(pool))
+
+
+def test_a_prefill_of_part_of_a_block_pads_past_its_keys():
+    """A bucket that is not whole blocks: every key where the per-key write
+    puts it, and the rest of the last row zeros, past every real key."""
+    bs, e, t = 4, 3, 10
+    tables = jnp.array([[5, 2, 6]], jnp.int32)
+    pool = key_pool(7, bs, e)
+    new = jax.random.normal(jax.random.key(1), (1, t, e))
+    got = np.asarray(blocks.write_keys(pool, new, tables))
+    want = np.asarray(write_keys_per_key(pool, new, tables,
+                                         jnp.zeros((1,), jnp.int32)))
+    np.testing.assert_array_equal(got[6, :2 * e], want[6, :2 * e])
+    assert not got[6, 2 * e:].any()
+    np.testing.assert_array_equal(np.delete(got, [0, 6], 0),
+                                  np.delete(want, [0, 6], 0))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_a_decode_writes_one_key_a_slot_as_the_per_key_write(dtype):
+    """Four slots, one key each: the block row read, the key laid over its
+    lanes, the row written back, bit for bit the per-key write; the dead
+    slot (all of its table the garbage block) and a position past the
+    table write the garbage block alone."""
+    bs, e = 4, 3
+    tables = jnp.array([[3, 1, 0], [2, 6, 0], [0, 0, 0], [5, 4, 7]],
+                       jnp.int32)
+    pos = jnp.array([5, 3, 0, 12], jnp.int32)
+    pool = key_pool(8, bs, e).astype(dtype)
+    new = jax.random.normal(jax.random.key(1), (4, 1, e))
+    got = blocks.write_keys(pool, new, tables, pos)
+    want = write_keys_per_key(pool, new, tables, pos)
+    assert got.dtype == dtype
+    np.testing.assert_array_equal(outside_garbage(got),
+                                  outside_garbage(want))
+    changed = np.flatnonzero((np.asarray(got, np.float32)
+                              != np.asarray(pool, np.float32)).any(1))
+    assert set(changed) - {blocks.GARBAGE_BLOCK} == {1, 2}
+    with pytest.raises(ValueError):
+        blocks.write_keys(pool, jnp.concatenate([new, new], 1), tables, pos)
+
+
 # ------------------------------------------------------------ served path
 
 def served_logits(model, prompt, new_tokens, **engine_kw):
@@ -428,6 +523,37 @@ def test_scheduler_serves_and_the_spans_count_the_selection():
     assert all(a["dsa_kv_rows_read"] == 2 * 2 * 8 for a in waits)
     assert prefills[0]["bucket"] == 32
     assert prefills[0]["dsa_kv_rows_read"] == 2 * 4 * (8 * 9 // 2)
+    # and the key block rows it wrote whole: 2 layers x bucket / block
+    # size on a prefill, 2 layers x 2 slots on a decode
+    assert all(a["dsa_key_blocks_written"] == 2 * a["bucket"] // 4
+               for a in prefills)
+    assert all(a["dsa_key_blocks_written"] == 2 * 2 for a in waits)
+
+
+SCATTER = re.compile(
+    r'"stablehlo\.scatter"\(.*?update_window_dims = \[([\d, ]*)\], '
+    r'inserted_window_dims = \[([\d, ]*)\].*?\}\) : '
+    r'\((tensor<[^>]*>), (tensor<[^>]*>), (tensor<[^>]*>)\)', re.S)
+
+
+def test_every_key_write_is_whole_block_rows():
+    """In the tiny engine's decode and prefill programs, every scatter into
+    a selecting layer's key pool [N, block_size * e] writes whole rows: a
+    window of the row's width, never one key's e lanes (which the TPU
+    compiler builds as a loop of one trip a key)."""
+    texts, eng = lowered_texts(NAME)
+    keys = {l.keys.shape for l in eng._pool}
+    assert len(keys) == 1
+    n, width = keys.pop()
+    pool = f"tensor<{n}x{width}x"
+    for name, text in texts.items():
+        into = [m for m in SCATTER.finditer(text)
+                if m.group(3).startswith(pool)]
+        assert len(into) == 2, name               # one a selecting layer
+        for m in into:
+            update, inserted, updates = m.group(1), m.group(2), m.group(5)
+            assert (update, inserted) == ("1", "0"), (name, m.group(0))
+            assert updates.split("x")[1] == str(width), (name, updates)
 
 
 # ------------------------------------ the siblings' programs did not move

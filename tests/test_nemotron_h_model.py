@@ -548,9 +548,10 @@ SIBLING_PROGRAMS = {
                        "76946763926806bef6e6"}}
 
 
-def lowered_programs(name):
-    """{executable: sha256 of its lowered text} of the tiny engine of the
-    configuration `name`, built as the benchmark builds it."""
+def lowered_texts(name):
+    """{executable: its lowered text} of the tiny engine of the
+    configuration `name`, built as the benchmark builds it, and the
+    engine."""
     config = tiny_of(full_config(name))
     model = HybridDecoder(HybridConfig(**config["program"]["model_config"]))
     model.eval()
@@ -559,16 +560,21 @@ def lowered_programs(name):
     model.load_arrays(weights.named(config, 5, config["dtype"]["param"]))
     eng = PagedGenerationEngine(model, PagedEngineConfig(
         **config["program"]["paged_engine_config"]))
-    sha = lambda lowered: hashlib.sha256(
-        lowered.as_text().encode()).hexdigest()
     with blocks.attention_impl(eng.attention_impl):
-        out = {"decode": sha(jax.jit(eng._decode_fn).lower(
-            *eng._decode_args()))}
+        out = {"decode": jax.jit(eng._decode_fn).lower(
+            *eng._decode_args()).as_text()}
         for b in eng.config.prefill_buckets:
-            out[f"prefill[{b}]"] = sha(jax.jit(
+            out[f"prefill[{b}]"] = jax.jit(
                 eng._make_prefill(b)._fn).lower(*eng._prefill_args(
-                    b, 0, np.zeros((b,), np.int32), 1, 0)))
-    return out
+                    b, 0, np.zeros((b,), np.int32), 1, 0)).as_text()
+    return out, eng
+
+
+def lowered_programs(name):
+    """{executable: sha256 of its lowered text} of the tiny engine of the
+    configuration `name`, built as the benchmark builds it."""
+    return {k: hashlib.sha256(text.encode()).hexdigest()
+            for k, text in lowered_texts(name)[0].items()}
 
 
 @pytest.mark.parametrize("name", sorted(SIBLING_PROGRAMS))
