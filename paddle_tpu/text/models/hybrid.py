@@ -292,8 +292,14 @@ class HybridDecoder(Layer):
         the first three add up over the expert layers, the fourth is their
         maximum; with selecting layers (`index_topk`), last, the K/V rows
         their attention read and the block rows of indexer keys they wrote
-        whole, counted from the shapes the program reads and writes."""
-        return ops.COUNTERS + (("dsa_kv_rows_read", "dsa_key_blocks_written")
+        whole, counted from the shapes the program reads and writes, then
+        the query blocks of a prefill whose selection threshold the
+        `row_threshold` kernel found and those of them that broke ties
+        (`hybrid_ops.dsa_prefill`; 0 on a decode, which selects with
+        `lax.top_k`)."""
+        return ops.COUNTERS + (("dsa_kv_rows_read", "dsa_key_blocks_written",
+                                "dsa_select_kernel_blocks",
+                                "dsa_select_tie_blocks")
                                if self.cfg.index_topk else ())
 
     def __init__(self, cfg: HybridConfig):
@@ -415,9 +421,9 @@ class HybridDecoder(Layer):
 
     @staticmethod
     def _select_counts(counters, read):
-        """The expert counters with the selecting layers' K/V rows read and
-        key block rows written (`read`, a pair of counts a layer) behind
-        them; unchanged without such layers."""
+        """The expert counters with the selecting layers' K/V rows read, key
+        block rows written and query blocks thresholded and tied (`read`,
+        four counts a layer) behind them; unchanged without such layers."""
         if not read:
             return counters
         if counters is None:
@@ -497,7 +503,7 @@ class HybridDecoder(Layer):
                 picked_rows = blocks.rows_at(rows, tables, picked)
             # and one key block row a slot written
             read.append((picked_rows.shape[0] * picked_rows.shape[1],
-                         pos.shape[0]))
+                         pos.shape[0], 0, 0))
             return ops.dsa_decode(q[:, 0], picked_rows, picked, pos, w,
                                   cfg), blocks.SelectLayer(rows, keys)
         if mixer == "gqa":
@@ -606,12 +612,16 @@ class HybridDecoder(Layer):
                 blocks.write(cached.rows, row[None], tables, pos),
                 blocks.write_keys(cached.keys, key[None], tables))
             # each block of queries reads the key blocks up to its own; the
-            # keys are written a block row at a time
+            # keys are written a block row at a time; the kernel finds the
+            # threshold of every block wider than index_topk
             blocks_of = -(-x.shape[0] // cfg.index_block)
+            out, ties = ops.dsa_prefill(q, row, q_i, wts, key, w, cfg)
             read.append((cfg.index_block * blocks_of * (blocks_of + 1) // 2,
                          blocks.blocks_for_tokens(x.shape[0],
-                                                  cached.rows.shape[1])))
-            return ops.dsa_prefill(q, row, q_i, wts, key, w, cfg), new
+                                                  cached.rows.shape[1]),
+                         max(0, blocks_of - cfg.index_topk // cfg.index_block),
+                         ties))
+            return out, new
         if mixer == "gqa":
             q, row = ops.gqa_project(
                 x, w, cfg, jnp.arange(x.shape[0]) if cfg.gqa_rope else None)

@@ -7,9 +7,11 @@ selection in front: an indexer scores every cached position and attention
 reads only the `index_topk` it picks), EVA attention (exact softmax inside an
 aligned window, one learned summary row a chunk of every closed window), and
 the expert layer of a chip that holds a share of the experts (sigmoid,
-group-limited routing, or a plain softmax). Plain XLA;
-`text/models/hybrid.py` wires them into a model and `serving/blocks.py`
-holds the kinds of cache they read and write.
+group-limited routing, or a plain softmax). Plain XLA, but for the
+threshold of the learned selection in prefill (the Pallas kernel
+`ops/pallas/topk_threshold.py`); `text/models/hybrid.py` wires them into
+a model and `serving/blocks.py` holds the kinds of cache they read and
+write.
 
 Weights are bfloat16 (matmuls accumulate in float32), the residual stream,
 the norms, the router, the index scores and the recurrent state are
@@ -19,6 +21,8 @@ import math
 
 import jax
 import jax.numpy as jnp
+
+from ...ops.pallas.topk_threshold import row_threshold
 
 HIGHEST = jax.lax.Precision.HIGHEST
 KDA_CHUNK = 64
@@ -585,27 +589,35 @@ def _order_key(x):
     return jnp.where(b >> 31 == 1, ~b, b | jnp.uint32(1 << 31))
 
 
+def _kth_key(key, k):
+    """uint32 [...]: the largest t with at least k of each row of order
+    keys `key` [..., L] at or above it, found exactly by bisection on the
+    bits: 32 counting passes over the row, a bit each."""
+    def bit(i, kth):
+        cand = kth | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+        return jnp.where(jnp.sum(key >= cand[..., None], -1) >= k,
+                         cand, kth)
+
+    return jax.lax.fori_loop(0, 32, bit,
+                             jnp.zeros(key.shape[:-1], jnp.uint32))
+
+
 def dsa_select_mask(scores, k):
     """[..., L] bool: the k entries of largest `scores` [..., L] float32 of
     each row, ties to the lower index (a row's -inf entries count as the
     lowest, so a row with fewer than k finite ones keeps all of those and
     some of the others, for the caller's causal mask to drop). The k-th
-    largest is found exactly by bisection on the scores' bits: 32 counting
-    passes over the row, a bit each (on the chip a [512, 32 768] block takes
-    2.1 ms; two bits a pass 2.2, four 4.2, `lax.top_k` 18.2); positions in
-    order break ties only when a row has more at the k-th value than places
-    left."""
+    largest is `_kth_key` of the scores' order keys; positions in order
+    break ties only when a row has more at the k-th value than places left.
+    Prefill finds the k-th largest with the `row_threshold` kernel and
+    comes here for a block with such ties (`dsa_block_threshold`). On a
+    v5e the 32 passes over a [512, 32 768] block take 0.80 ms of device
+    time and the kernel 0.37 over its causal columns (`tools/op_bench.py
+    --dsa-select`, the last block of each bucket)."""
     with jax.named_scope("dsa_select"):
         k = min(k, scores.shape[-1])
         key = _order_key(scores)
-
-        def bit(i, kth):
-            cand = kth | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
-            return jnp.where(jnp.sum(key >= cand[..., None], -1) >= k,
-                             cand, kth)
-
-        kth = jax.lax.fori_loop(0, 32, bit,
-                                jnp.zeros(key.shape[:-1], jnp.uint32))
+        kth = _kth_key(key, k)
         above, at = key > kth[..., None], key == kth[..., None]
         room = k - jnp.sum(above, -1, keepdims=True)
         return jax.lax.cond(
@@ -635,20 +647,65 @@ def dsa_decode(q, rows, picked, pos, w, cfg):
         return _gqa_attend(q[:, None], rows, seen[:, None], w, cfg)[:, 0]
 
 
+def dsa_block_threshold(scores, lo, k):
+    """What the prefill's query block at position `lo` keeps, from its
+    index scores [blk, L] float32 (none read past its own key block): (kth
+    uint32 [blk], last int32 [blk], tie bool scalar), for `dsa_kept`. A
+    block no wider than k keeps every causal position (kth 0); a wider one
+    takes each row's k-th largest order key over its causal columns from
+    the `row_threshold` kernel. Where some row holds more keys at its kth
+    than the k - above places left, the block breaks the ties as
+    `dsa_select_mask` does, lower positions first (`tie`), and `last` is
+    the position of the last tie a row takes; else every tie is kept."""
+    blk, width = scores.shape
+    with jax.named_scope("dsa_select"):
+        kth, above, at = jax.lax.cond(
+            lo + blk > k,
+            lambda: row_threshold(scores, lo + blk, k),
+            lambda: (jnp.zeros((blk,), jnp.uint32),
+                     jnp.zeros((blk,), jnp.int32),
+                     jnp.zeros((blk,), jnp.int32)))
+        tie = jnp.any(at > k - above)
+
+        def last_tie():
+            pos = jnp.arange(width)
+            causal = pos[None, :] <= lo + jnp.arange(blk)[:, None]
+            took = dsa_select_mask(jnp.where(causal, scores, -jnp.inf),
+                                   k) & causal
+            took &= _order_key(scores) == kth[:, None]
+            return jnp.max(jnp.where(took, pos, -1), -1)
+
+        last = jax.lax.cond(tie, last_tie,
+                            lambda: jnp.full((blk,), width, jnp.int32))
+        return kth, last, tie
+
+
+def dsa_kept(scores, at, kth, last):
+    """bool: the scores [blk, K] at positions `at` that a block's rows keep
+    by their (kth, last) of `dsa_block_threshold` (causality aside)."""
+    key = _order_key(scores)
+    return (key > kth[:, None]) \
+        | ((key == kth[:, None]) & (at <= last[:, None]))
+
+
 def dsa_prefill(q, row, q_idx, wts, k_idx, w, cfg):
     """One request's own tokens [T] from position 0 (q [T, n, d], its rows
     [T, 2 kv d], the indexer's q_idx [T, m, e], wts [T, m], k_idx [T, e]):
     queries go `index_block` at a time. For a block, the index scores
-    against the key blocks up to its own (the triangle), the selection
-    (`dsa_select_mask` and causality), then attention over the selected
-    keys, masked dense a key block at a time, each key block read once: the
-    softmax is shifted by the running maximum of the selected scores, and
-    what is already summed is rescaled when a block raises it, so no score
-    lies above its shift and every row's largest term is exactly 1. Padding
-    sits after the real tokens, so causality keeps it out of every row that
-    is read."""
+    against the key blocks up to its own (the triangle), the selection,
+    then attention over the selected keys, masked dense a key block at a
+    time, each key block read once: the softmax is shifted by the running
+    maximum of the selected scores, and what is already summed is rescaled
+    when a block raises it, so no score lies above its shift and every
+    row's largest term is exactly 1. Padding sits after the real tokens, so
+    causality keeps it out of every row that is read.
+
+    The selection is `dsa_block_threshold`'s: a key block's part of it is
+    then a comparison with each row's threshold (`dsa_kept`). Returns (the
+    layer's output [T, H], the query blocks that broke ties, an int32
+    scalar)."""
     n, kv, d = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    t, blk = q.shape[0], cfg.index_block
+    t, blk, topk = q.shape[0], cfg.index_block, cfg.index_topk
     tp = -(-t // blk) * blk
 
     def pad(a):
@@ -662,8 +719,10 @@ def dsa_prefill(q, row, q_idx, wts, k_idx, w, cfg):
     # the shift before a query's first selected key: finite, so that a
     # block with nothing selected rescales by exp(0) and adds exp(-inf)
     floor = jnp.finfo(jnp.float32).min
+    rows = jnp.arange(blk)
 
-    def block(i, out):
+    def block(i, carry):
+        out, ties, scores = carry
         lo = i * blk
         q_i = jax.lax.dynamic_slice_in_dim(q_idx, lo, blk)
         w_i = jax.lax.dynamic_slice_in_dim(wts, lo, blk)
@@ -673,18 +732,20 @@ def dsa_prefill(q, row, q_idx, wts, k_idx, w, cfg):
                 k_idx, j * blk, blk))
             return jax.lax.dynamic_update_slice_in_dim(acc, s, j * blk, 1)
 
-        scores = jax.lax.fori_loop(0, i + 1, score, jnp.full(
-            (blk, tp), -jnp.inf, jnp.float32))
-        causal = jnp.arange(tp)[None, :] <= lo + jnp.arange(blk)[:, None]
-        chosen = dsa_select_mask(jnp.where(causal, scores, -jnp.inf),
-                                 cfg.index_topk) & causal
+        # the block's row of scores is written over the last block's: what
+        # lies past its own key block is never read
+        scores = jax.lax.fori_loop(0, i + 1, score, scores)
+        kth, last, tie = dsa_block_threshold(scores, lo, topk)
         q_b = jax.lax.dynamic_slice_in_dim(q, lo, blk)
 
         def attend(j, carry):
             top, total, acc = carry
             k_j, v_j = (jax.lax.dynamic_slice_in_dim(a, j * blk, blk)
                         for a in (k, v))
-            seen = jax.lax.dynamic_slice_in_dim(chosen, j * blk, blk, 1)
+            at_j = j * blk + rows[None, :]
+            seen = dsa_kept(jax.lax.dynamic_slice_in_dim(scores, j * blk,
+                                                         blk, 1),
+                            at_j, kth, last) & (at_j <= lo + rows[:, None])
             s = jnp.where(seen, mm("qkgd,lkd->kgql", q_b, k_j) * d ** -0.5,
                           -jnp.inf)
             new = jnp.maximum(top, jnp.max(s, -1))
@@ -701,11 +762,13 @@ def dsa_prefill(q, row, q_idx, wts, k_idx, w, cfg):
                 jnp.zeros(heads + (d,), jnp.float32)))
             o = (acc / total[..., None]).transpose(2, 0, 1, 3)
         return jax.lax.dynamic_update_slice_in_dim(
-            out, o.reshape(blk, n * d).astype(out.dtype), lo, 0)
+            out, o.reshape(blk, n * d).astype(out.dtype), lo, 0), \
+            ties + tie.astype(jnp.int32), scores
 
-    o = jax.lax.fori_loop(0, tp // blk, block,
-                          jnp.zeros((tp, n * d), row.dtype))
-    return mm("tc,ch->th", o[:t], w["wo"])
+    o, ties, _ = jax.lax.fori_loop(0, tp // blk, block, (
+        jnp.zeros((tp, n * d), row.dtype), jnp.int32(0),
+        jnp.zeros((blk, tp), jnp.float32)))
+    return mm("tc,ch->th", o[:t], w["wo"]), ties
 
 
 # ------------------------------------------------------------------- EVA

@@ -106,3 +106,18 @@ def test_op_bench_tool():
     import json
     rec = json.loads(out.stdout.strip().splitlines()[-1])
     assert rec["op"] == "matmul" and rec["jit_us"] > 0
+
+
+def test_op_bench_times_the_dsa_threshold():
+    """`--dsa-select`'s case at one narrow width: the XLA passes and the
+    threshold kernel both run and are timed (the kernel interpreted off
+    the chip, so the times here are no device's)."""
+    import os
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, os.path.join(root, "tools"))
+    import op_bench
+    rec = op_bench.bench_dsa_select(2560, repeat=1)
+    assert rec["shape"] == "512x2560" and rec["topk"] == 2048
+    assert rec["xla_passes_host_ms"] > 0 and rec["kernel_host_ms"] > 0
+    # no device time off the chip
+    assert rec["xla_passes_ms"] is None and rec["kernel_ms"] is None

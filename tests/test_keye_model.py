@@ -114,7 +114,7 @@ def test_within_topk_the_layer_is_dense_causal_attention(float32_matmuls, t):
     cfg = model_config(config)
     w, x, q, row, q_i, wts, key = mixer_inputs(config, cfg, t)
     dense = ops.gqa_prefill(q, row, w, cfg)
-    close(ops.dsa_prefill(q, row, q_i, wts, key, w, cfg), dense, 1e-5)
+    close(ops.dsa_prefill(q, row, q_i, wts, key, w, cfg)[0], dense, 1e-5)
     flat = ref.flat(config)
     close(ref.dsa_mixer(x, w, flat, "float32"), dense, 1e-5)
     # and the decode of the last token over every row
@@ -130,7 +130,7 @@ def test_past_topk_prefill_and_decode_are_the_reference(float32_matmuls, t):
     cfg = model_config(config)
     w, x, q, row, q_i, wts, key = mixer_inputs(config, cfg, t)
     want = ref.dsa_mixer(x, w, ref.flat(config), "float32")
-    close(ops.dsa_prefill(q, row, q_i, wts, key, w, cfg), want, 1e-5)
+    close(ops.dsa_prefill(q, row, q_i, wts, key, w, cfg)[0], want, 1e-5)
     pos = jnp.arange(t)
     picked = ops.dsa_select_decode(
         q_i, wts, jnp.broadcast_to(key[None], (t,) + key.shape), pos, cfg)
@@ -158,7 +158,7 @@ def test_large_qk_norm_gains_keep_prefill_finite_and_the_reference(
     at = jnp.arange(t)
     q, row = ops.gqa_project(x, w, cfg, at, jnp.float32)
     q_i, wts, key = ops.dsa_index_project(x, w, cfg, at, jnp.float32)
-    got = ops.dsa_prefill(q, row, q_i, wts, key, w, cfg)
+    got, _ = ops.dsa_prefill(q, row, q_i, wts, key, w, cfg)
     assert np.isfinite(np.asarray(got)).all()
     want = ref.dsa_mixer(x, w, ref.flat(config), "float32")
     close(got, want, 1e-5)
@@ -234,6 +234,141 @@ def test_the_threshold_is_exact_over_the_bits():
     for r, g in zip(rows, got):
         order = sorted(range(len(r)), key=lambda i: (-r[i], i))[:37]
         assert g.sum() == 37 and set(np.flatnonzero(g)) == set(order)
+
+
+# ------------------------------------------------ the threshold kernel
+
+def block_scores(case, b, width, seed=7):
+    """[b, width + b] float32 index scores of one query block whose row r
+    sees the columns up to width - b + r. What no row sees is filled with
+    values above every visible one, so a kernel that read them would count
+    them."""
+    rng = np.random.default_rng(seed)
+    s = rng.normal(size=(b, width + b))
+    if case == "ties":
+        s = np.round(s, 1)
+    elif case == "signs":
+        s = s * 10.0 ** rng.integers(-45, 38, s.shape)
+        s[:, :6] = [np.inf, -np.inf, -0.0, 0.0, 1e-45, -1e-45]
+        s[:, 6:12] = 1e-40                               # denormal ties
+    elif case == "ninf_tail":
+        s[:, width - b - 3:] = -np.inf
+    elif case == "few_finite":
+        s[:, 4:] = -np.inf
+    s = s.astype(np.float32)
+    seen = np.arange(width + b)[None] <= width - b + np.arange(b)[:, None]
+    return np.where(seen, s, np.float32(3e38)), seen
+
+
+def xla_bisection(scores, seen, k):
+    """(kth, above, at) by the XLA bisection, over the visible keys."""
+    key = np.asarray(jnp.where(seen, ops._order_key(jnp.asarray(scores)),
+                               jnp.uint32(0)))
+    kth = np.asarray(ops._kth_key(jnp.asarray(key), k))
+    return kth, (seen & (key > kth[:, None])).sum(1), \
+        (seen & (key == kth[:, None])).sum(1)
+
+
+@pytest.mark.parametrize("b,width,k", [(12, 12, 5), (96, 288, 37),
+                                       (72, 360, 100)],
+                         ids=["diagonal", "tiles", "wide_k"])
+@pytest.mark.parametrize("case", ["normal", "ties", "signs", "ninf_tail",
+                                  "few_finite"])
+def test_kernel_threshold_is_the_xla_bisection(case, b, width, k):
+    """The kernel's k-th largest visible order key of each row, and the
+    keys above and at it, are the XLA bisection's to the bit: ties, +-0,
+    +-inf, denormals, -inf tails, rows with fewer than k finite keys, the
+    diagonal key block alone, widths no multiple of the row tile or of the
+    lanes, and two row tiles."""
+    from paddle_tpu.ops.pallas.topk_threshold import row_threshold
+    scores, seen = block_scores(case, b, width)
+    got = [np.asarray(a) for a in row_threshold(
+        jnp.asarray(scores), jnp.int32(width), k, interpret=True)]
+    want = xla_bisection(scores, seen, k)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g, w)
+    if case == "few_finite":
+        # -inf's order key where a row sees k or more, else 0
+        assert (got[0] == np.where(seen.sum(1) < k, 0, 0x7FFFFF)).all()
+
+
+@pytest.mark.parametrize("ties", ["none", "all", "some"])
+@pytest.mark.parametrize("i", [0, 1, 3, 6])
+def test_block_selection_is_dsa_select_mask(ties, i):
+    """What a prefill query block keeps by its threshold (`dsa_kept` of
+    `dsa_block_threshold`: the kernel's threshold, the tie path) is what
+    `dsa_select_mask` keeps over its causal row, position for
+    position: a block within topk, the first past it, and wider ones; the
+    tie path engages where a row holds more at its k-th than places left,
+    and only there."""
+    blk, k, t = 4, 8, 28
+    rng = np.random.default_rng(11 + i)
+    scores = rng.normal(size=(blk, t)).astype(np.float32)
+    if ties == "all":
+        scores[:] = 0.5
+    elif ties == "some":
+        scores = np.round(scores, 0)
+    lo = i * blk
+    scores[:, lo + blk:] = 3e38        # past its own key block: never read
+    scores = jnp.asarray(scores)
+    kth, last, tie = ops.dsa_block_threshold(scores, lo, k)
+    pos = jnp.arange(t)
+    causal = pos[None, :] <= lo + jnp.arange(blk)[:, None]
+    got = ops.dsa_kept(scores, pos[None, :], kth, last) & causal
+    want = ops.dsa_select_mask(jnp.where(causal, scores, -jnp.inf), k) \
+        & causal
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert (np.asarray(got).sum(1)
+            == np.minimum(k, lo + 1 + np.arange(blk))).all()
+    # no tie within topk, every block past it when all scores are equal,
+    # none among distinct ones
+    assert not bool(tie) or lo + blk > k
+    if ties == "all":
+        assert bool(tie) == (lo + blk > k)
+    if ties == "none":
+        assert not bool(tie)
+
+
+def parent_prefill(q, row, q_i, wts, key, w, cfg):
+    """The prefill as it was before the kernel: every query's selection by
+    `dsa_select_mask` over its whole causal row, then attention over the
+    positions selected."""
+    t = q.shape[0]
+    causal = jnp.tril(jnp.ones((t, t), bool))
+    scores = ops.dsa_index_scores(q_i, wts, key)
+    chosen = ops.dsa_select_mask(jnp.where(causal, scores, -jnp.inf),
+                                 cfg.index_topk) & causal
+    return ops._gqa_attend(q[None], row[None], chosen[None], w, cfg)[0]
+
+
+@pytest.mark.parametrize("ties", ["none", "all", "some"])
+def test_prefill_over_many_blocks_is_the_parents(float32_matmuls, ties):
+    """`dsa_prefill` over a prompt of ten query blocks, eight of them past
+    topk, gives what the prefill gave before the kernel found its
+    thresholds, ties included; it counts the blocks that broke ties."""
+    config = tiny_config()
+    cfg = model_config(config)
+    t, blk, k = 40, cfg.index_block, cfg.index_topk
+    w, x, q, row, q_i, wts, key = mixer_inputs(config, cfg, t)
+    if ties == "all":
+        key = jnp.broadcast_to(key[:1], key.shape)
+    elif ties == "some":
+        key = jnp.repeat(key[::2], 2, axis=0)[:t]
+    got, tied = ops.dsa_prefill(q, row, q_i, wts, key, w, cfg)
+    close(got, parent_prefill(q, row, q_i, wts, key, w, cfg), 1e-5)
+    # a row ties where more of its scores equal its k-th largest than
+    # the places left above it (the tiny indexer's relu leaves exact 0s)
+    scores = np.asarray(ops.dsa_index_scores(q_i, wts, key))
+    tied_rows = []
+    for r in range(t):
+        top = np.sort(scores[r, :r + 1])[::-1]
+        kth = top[min(k, r + 1) - 1]
+        tied_rows.append(r + 1 > k and (top == kth).sum()
+                         > k - (top > kth).sum())
+    assert int(tied) == sum(any(tied_rows[i:i + blk])
+                            for i in range(0, t, blk))
+    if ties == "all":
+        assert int(tied) == t // blk - k // blk    # every block past topk
 
 
 # ---------------------------------------------------------- expert shares
@@ -528,6 +663,32 @@ def test_scheduler_serves_and_the_spans_count_the_selection():
     assert all(a["dsa_key_blocks_written"] == 2 * a["bucket"] // 4
                for a in prefills)
     assert all(a["dsa_key_blocks_written"] == 2 * 2 for a in waits)
+
+
+def test_the_spans_count_the_blocks_the_kernel_thresholded():
+    """A prefill's span counts the query blocks whose threshold the kernel
+    found (every block wider than topk, in both layers) and those of them
+    that broke ties; a decode, which selects by `lax.top_k`, counts 0."""
+    from paddle_tpu import profiler
+    eng = PagedGenerationEngine(build(tiny_config()), PagedEngineConfig(
+        slots=2, max_len=64, block_size=4))
+    log = profiler.span_log()
+    before = log.appended
+    eng.prefill(0, list(range(3, 20)))
+    eng.prefill(1, list(range(40, 70)))
+    for _ in range(2):
+        eng.decode()
+    spans = [s for s in log.window(0, 2**62)][-(log.appended - before):]
+    prefills = [s["attrs"] for s in spans if s["name"] == "serving::prefill"]
+    assert [a["bucket"] for a in prefills] == [32, 32]
+    for a in prefills:
+        # 8 query blocks of 4 a layer, the first 2 within topk 8
+        assert a["dsa_select_kernel_blocks"] == 2 * (8 - 2)
+        assert 0 <= a["dsa_select_tie_blocks"] <= 2 * (8 - 2)
+    waits = [s["attrs"] for s in spans if s["name"] == "serving::decode.wait"
+             and "call" in s["attrs"]]
+    assert waits and all(a["dsa_select_kernel_blocks"] == 0
+                         and a["dsa_select_tie_blocks"] == 0 for a in waits)
 
 
 SCATTER = re.compile(

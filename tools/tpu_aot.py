@@ -59,6 +59,7 @@ def kernel_cases():
     from paddle_tpu.ops.pallas.flash_attention import flash_attention
     from paddle_tpu.ops.pallas.paged_attention import (
         paged_attention, paged_decode_attention)
+    from paddle_tpu.ops.pallas.topk_threshold import row_threshold
 
     S = jax.ShapeDtypeStruct
     table = autotune._read_cache_file(autotune._SHIPPED_PATH)
@@ -135,6 +136,16 @@ def kernel_cases():
     decode_case(16, 1024, 128, 16, jnp.bfloat16)
     decode_case(16, 1024, 128, 8, jnp.float32)
     decode_case(32, 2048, 256, 16, jnp.bfloat16, slots=4)
+
+    def threshold_case(L, B=512, k=2048):
+        def fn(scores, width):
+            return row_threshold(scores, width, k, interpret=False)
+        cases.append((f"dsa_topk_threshold B{B} L{L} k{k}", fn,
+                      (S((B, L), jnp.float32), S((), jnp.int32))))
+
+    # the selecting prefill's threshold at its widest and narrowest bucket
+    threshold_case(32768)
+    threshold_case(10240)
     return cases
 
 
