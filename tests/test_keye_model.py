@@ -5,8 +5,8 @@ against the benchmark's plain reference, at tiny sizes (topk 8, blocks of 4,
 2 key/value heads) on seeded weights: the served path at contexts past topk,
 the layer at contexts within it against dense causal attention, prefill's
 and decode's selections against each other, the expert shares against the
-uncut layer, and the four sibling configurations' programs, which must not
-have moved."""
+uncut layer, and the key pool written in whole rows (the programs of every
+serve configuration are pinned in `test_serving_programs.py`)."""
 import json
 import os
 import re
@@ -21,8 +21,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from test_nemotron_h_model import (lowered_programs,           # noqa: E402
-                                   lowered_texts)
+from test_serving_programs import lowered_texts               # noqa: E402
 
 from benchmark.harness import reference_keye as ref            # noqa: E402
 from benchmark.harness import weights_keye as wk               # noqa: E402
@@ -715,47 +714,3 @@ def test_every_key_write_is_whole_block_rows():
             update, inserted, updates = m.group(1), m.group(2), m.group(5)
             assert (update, inserted) == ("1", "0"), (name, m.group(0))
             assert updates.split("x")[1] == str(width), (name, updates)
-
-
-# ------------------------------------ the siblings' programs did not move
-
-# sha256 of the lowered text of each executable of the four sibling
-# configurations' tiny engines, taken on the parent of the PR that brought
-# the selecting layer (b1b3e4b): the shared code (`gqa_project`, `route`,
-# `moe_share`, `HybridDecoder`, `blocks`, the engine) was extended, and
-# their programs are the parent's byte for byte. A PR that means to change
-# them takes new hashes.
-SIBLING_PROGRAMS = {
-    "ling3_flash_ep4_share": {
-        "decode": "ceee6759b3ba86d52fe7ebc951c5d1a705711cd97c2d4de2"
-                  "f181ff3bf49d726c",
-        "prefill[32]": "e377f3f9fed6a5089023034f9d525ef82480fa325829"
-                       "d2d74716fd31ec16f419",
-        "prefill[64]": "72c0e45f9ae7fc6b79e1056a696fec4eb930afa3db1e"
-                       "e986fd3fbbfb5622838f"},
-    "deepseek_v3_ep16_share": {
-        "decode": "a7de72a723f4fe21b93bf8e50aea66a391991fbe4f429e12"
-                  "ec3bc946ce008163",
-        "prefill[32]": "bd370268824ad12fd23fd5674b9ed65902647910342c"
-                       "841e0a3dcf7e4cd75b62",
-        "prefill[64]": "4a18a719e2fd39aca597b5a279d144e4e7610affb5d6"
-                       "76946763926806bef6e6"},
-    "nemotron3_nano_ep8_share": {
-        "decode": "5893e3ec35b54f45ca234965903979c23b59263421fc1202"
-                  "63be919a9b46b778",
-        "prefill[32]": "93ab8dc23ce96e52c0302a3b7a2c043a5e026b0acb71"
-                       "078a8682aa26bf45309a",
-        "prefill[64]": "9dd6aee7d44853dfab7a669088c0b0995377f21f31dd"
-                       "dbb8411cc528dd43a643"},
-    "evabyte_6p5b_pp4_stage": {
-        "decode": "273f33cafcf4c5c010bda907d0ac69392e6ab0f4750f541a"
-                  "73eb3ad643c8d66d",
-        "prefill[32]": "125e5c235a6c3767edc6ddd0dde164fb722eda9e2e63"
-                       "71c2ac58f3a5559e2103",
-        "prefill[64]": "1c01dbd751c711f3bf78adf7eb72ff7b15490ab415f5"
-                       "fc13adcd506240912771"}}
-
-
-@pytest.mark.parametrize("name", sorted(SIBLING_PROGRAMS))
-def test_sibling_lowered_programs_are_the_parents(name):
-    assert lowered_programs(name) == SIBLING_PROGRAMS[name]

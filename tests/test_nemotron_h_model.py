@@ -3,10 +3,9 @@ Mamba-2 state-space mixer, a grouped-query attention mixer, a relu2 expert
 layer's share) against the benchmark's plain reference, at tiny sizes on
 seeded weights: each mechanism alone, the chunked scan against the one-token
 step, the served path (prefill, then decode through the cache) against the
-reference's full forward, on logits, what a model of single-part blocks asks
-of the engine, and the two siblings' programs, which must not have moved."""
-import hashlib
-import importlib
+reference's full forward, on logits, and what a model of single-part blocks
+asks of the engine (the programs of every serve configuration are pinned in
+`test_serving_programs.py`)."""
 import json
 import os
 import sys
@@ -517,66 +516,3 @@ def test_what_the_model_cannot_be_combined_with_raises_at_construction():
     with pytest.raises(ValueError, match="max_len"):
         PagedGenerationEngine(model, PagedEngineConfig(
             slots=2, max_len=128, block_size=8))
-
-
-# ------------------------------------ the siblings' programs did not move
-
-# sha256 of the lowered text of each executable of the tiny Ling and
-# DeepSeek-V3 engines. Taken first on the parent of the PR that brought the
-# single-part blocks (2152d6a): the shared code (`HybridDecoder`,
-# `hybrid_ops.moe_share`) was extended, and their programs were the parent's
-# byte for byte. Taken anew in PR 37, which meant to change every paged
-# executable's signature (one packed int32 upload in, no key, no positions
-# out; the mathematics between is the same). The decode hashes taken anew
-# in PR 39: the previous call's output comes in beside the upload and feeds
-# every slot the host did not (the prefills did not move). A PR that means
-# to change them takes new hashes.
-SIBLING_PROGRAMS = {
-    "ling3_flash_ep4_share": {
-        "decode": "ceee6759b3ba86d52fe7ebc951c5d1a705711cd97c2d4de2"
-                  "f181ff3bf49d726c",
-        "prefill[32]": "e377f3f9fed6a5089023034f9d525ef82480fa325829"
-                       "d2d74716fd31ec16f419",
-        "prefill[64]": "72c0e45f9ae7fc6b79e1056a696fec4eb930afa3db1e"
-                       "e986fd3fbbfb5622838f"},
-    "deepseek_v3_ep16_share": {
-        "decode": "a7de72a723f4fe21b93bf8e50aea66a391991fbe4f429e12"
-                  "ec3bc946ce008163",
-        "prefill[32]": "bd370268824ad12fd23fd5674b9ed65902647910342c"
-                       "841e0a3dcf7e4cd75b62",
-        "prefill[64]": "4a18a719e2fd39aca597b5a279d144e4e7610affb5d6"
-                       "76946763926806bef6e6"}}
-
-
-def lowered_texts(name):
-    """{executable: its lowered text} of the tiny engine of the
-    configuration `name`, built as the benchmark builds it, and the
-    engine."""
-    config = tiny_of(full_config(name))
-    model = HybridDecoder(HybridConfig(**config["program"]["model_config"]))
-    model.eval()
-    weights = importlib.import_module(
-        f"benchmark.harness.{config['harness']['weights']}")
-    model.load_arrays(weights.named(config, 5, config["dtype"]["param"]))
-    eng = PagedGenerationEngine(model, PagedEngineConfig(
-        **config["program"]["paged_engine_config"]))
-    with blocks.attention_impl(eng.attention_impl):
-        out = {"decode": jax.jit(eng._decode_fn).lower(
-            *eng._decode_args()).as_text()}
-        for b in eng.config.prefill_buckets:
-            out[f"prefill[{b}]"] = jax.jit(
-                eng._make_prefill(b)._fn).lower(*eng._prefill_args(
-                    b, 0, np.zeros((b,), np.int32), 1, 0)).as_text()
-    return out, eng
-
-
-def lowered_programs(name):
-    """{executable: sha256 of its lowered text} of the tiny engine of the
-    configuration `name`, built as the benchmark builds it."""
-    return {k: hashlib.sha256(text.encode()).hexdigest()
-            for k, text in lowered_texts(name)[0].items()}
-
-
-@pytest.mark.parametrize("name", sorted(SIBLING_PROGRAMS))
-def test_sibling_lowered_programs_are_the_parents(name):
-    assert lowered_programs(name) == SIBLING_PROGRAMS[name]
