@@ -6,7 +6,9 @@ turn a compiled decoder into a serving engine:
 
   kv_cache.py     — static-shape preallocated KV cache (one decode
                     executable, ever; vLLM's preallocation insight)
-  blocks.py       — paged KV: fixed-size block pool + per-slot block
+  blocks.py       — the served-model protocol (what each layer of a
+                    model caches, what that allows and counts) and
+                    paged KV: fixed-size block pool + per-slot block
                     tables, refcounted for copy-on-write sharing; int8
                     pools with per-block per-head scales (ISSUE 11 —
                     2x the KV tokens per HBM byte, dequant in-kernel)

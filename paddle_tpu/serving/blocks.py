@@ -14,9 +14,12 @@ contiguous view before running the exact same masked math as the dense
 path — token-exact by construction, and the avals (pool, tables, pos)
 never change shape, so the decode step still compiles exactly once.
 
-Two halves:
+Three parts:
 
-  - device (pure jnp, used inside the jitted executables): `alloc_pools`,
+  - the served-model protocol (below the constants): what a model declares
+    it caches a layer, and `CacheLayout`, the one place the engine asks
+    what a layout allows and what its spans count.
+  - device (pure jnp, used inside the jitted executables): `alloc_layers`,
     `write` (scatter new tokens into their blocks), `gather`, `attend`
     (gather + `kv_cache.attend`).
   - host (the allocator): `BlockPool` — free list + per-block refcounts,
@@ -42,13 +45,13 @@ from ..observability import metrics as _metrics
 from . import kv_cache as kvc
 
 __all__ = ["BlockAllocError", "BlockPool", "PagedLayerKV",
-           "QuantPagedLayerKV", "PagedDecodeCache", "LatentSpec",
-           "StateSpec", "NoCache", "WindowSpec", "SelectSpec",
-           "LatentLayer", "StateLayer", "SelectLayer", "SlotStateStore",
-           "alloc_layers", "gather_rows", "rows_at", "write_keys",
-           "gather_keys", "window_of", "window_write", "alloc_pools",
-           "alloc_quant_pools", "write", "quant_write", "gather",
-           "gather_quant", "dequant", "attend", "attend_quant",
+           "QuantPagedLayerKV", "PagedDecodeCache", "ALLOWS", "CacheLayout",
+           "KVSpec", "LatentSpec", "StateSpec", "NoCache", "WindowSpec",
+           "SelectSpec", "LatentLayer", "StateLayer", "SelectLayer",
+           "SlotStateStore", "alloc_layers", "layout_bytes", "gather_rows",
+           "rows_at", "write_keys", "gather_keys", "window_of",
+           "window_write", "write", "write_kv", "read_kv", "quant_write",
+           "gather", "gather_quant", "dequant", "attend", "attend_quant",
            "attend_kernel", "attend_kernel_quant", "attention_impl",
            "attention_scope", "kernel_attends",
            "current_attention_impl", "blocks_for_tokens", "GARBAGE_BLOCK",
@@ -89,7 +92,8 @@ PagedLayerKV = collections.namedtuple("PagedLayerKV", ["k", "v"])
 QuantPagedLayerKV = collections.namedtuple(
     "QuantPagedLayerKV", ["k", "v", "k_scale", "v_scale"])
 
-# Whole-model paged cache: `layers` tuple of PagedLayerKV, `tables` int32
+# Whole-model paged cache: `layers` one array set a layer of the model's
+# layout (`alloc_layers`; PagedLayerKV for GPT's), `tables` int32
 # [slots, max_blocks_per_slot] physical block ids (0 == garbage), `pos`
 # int32 [slots] tokens written per slot — same role as DecodeCache.pos.
 # `valid` (optional, int32 [S] or None) is how many of a write's T tokens
@@ -104,46 +108,206 @@ PagedDecodeCache = collections.namedtuple(
     "PagedDecodeCache", ["layers", "tables", "pos", "valid", "slot"],
     defaults=(None, None))
 
-# What a layer of a model declares it caches (`model.cache_layout()`, one
-# spec a layer), and the arrays the engine allocates for each. The kinds
-# live side by side in one pool tuple, under one block table and one
-# allocator, and are donated together:
+# ------------------------------------------------- the served-model protocol
+# Every model a paged engine serves (GPT, and `text/models/hybrid.py`'s
+# HybridDecoder in its five configurations) declares
+#   cache_layout()             one spec a layer (below): what the layer caches
+#   serving_counters           the names of the int32 counters its forward
+#                              hands back behind the logits (GPT: none)
+#   prefill_keeps_last_row(T)  whether a prefill of T (bucket-padded)
+#                              positions hands back the last real token's row
+#                              of logits alone, [1, 1, V] (GPT: never)
+#   float32_parameters()       the parameters `weight_dtype="bfloat16"` leaves
+#                              float32 (GPT: none)
+# and its forward(ids [S, T], cache=PagedDecodeCache over those layers)
+# returns (logits [S, T, V], the new cache, counters int32
+# [len(serving_counters)]). Decode: S slots, T = 1, `cache.slot` None.
+# Prefill: S = 1, a bucket of T tokens from `cache.pos`, `cache.valid` its
+# real length, `cache.slot` the slot whose per-slot rows it fills.
+#
+# The kinds of spec live side by side in one pool tuple, under one block
+# table and one allocator, and are donated together (`alloc_layers` makes
+# each kind's arrays):
+#   KVSpec(heads, head_dim)  K and V of every head a token, paged:
+#       PagedLayerKV(k, v [num_blocks, block_size, heads, head_dim]), or
+#       QuantPagedLayerKV (int8 codes and their scales) in int8 pools
 #   LatentSpec(width)   one row of `width` values a token, paged like K/V:
-#                       LatentLayer(rows [num_blocks, block_size, width])
+#       LatentLayer(rows [num_blocks, block_size, width]). Softmax attention
+#       with few key/value heads is one: a token's keys and values of all
+#       its key/value heads are one row
 #   StateSpec(state, tail)  a fixed-size state a SLOT, whatever its length
-#                       (a linear-attention layer's matrix and the last
-#                       inputs of its short convolution): StateLayer(
-#                       state [slots, *state] float32, tail [slots, *tail])
-#   NoCache()           nothing: a layer that is a feed-forward alone. It
-#                       is its own (empty) layer in the pool tuple, so the
-#                       pool keeps one entry a layer
-#   WindowSpec(width, window, chunk)  rows of `width` values of two
-#                       lifetimes under the one table (below): a LatentLayer
+#       (a linear-attention or state-space layer's matrix and the last
+#       inputs of its short convolution): StateLayer(state [slots, *state]
+#       float32, tail [slots, *tail])
+#   WindowSpec(width, window, chunk)  rows of two lifetimes under the one
+#       table (the class says how): a LatentLayer
 #   SelectSpec(width, index_width, topk)  TWO rows a token under the one
-#                       table, paged alike: the row attention reads (`width`)
-#                       and the key an indexer scores to choose which `topk`
-#                       of those rows a query reads (`index_width`), in
-#                       arrays of their own, so that scoring every position
-#                       reads none of the wide rows: SelectLayer(rows
-#                       [num_blocks, block_size, width], keys [num_blocks,
-#                       block_size * index_width]: a block's keys side by
-#                       side in one row, so that a key narrower than the
-#                       chip's 128 lanes pads nothing and needs no layout of
-#                       its own; `write_keys` writes whole such rows and
-#                       `gather_keys` reads them)
-# Softmax attention with few key/value heads needs no third kind: a token's
-# keys and values of all its key/value heads are one LatentSpec row.
-# A model without `cache_layout` (GPT) caches K and V per layer, as above.
-LatentSpec = collections.namedtuple("LatentSpec", ["width"])
-StateSpec = collections.namedtuple("StateSpec", ["state", "tail"])
-NoCache = collections.namedtuple("NoCache", [])
+#       table, paged alike: the row attention reads (`width`) and the key an
+#       indexer scores to choose which `topk` of those rows a query reads
+#       (`index_width`), in arrays of their own, so that scoring every
+#       position reads none of the wide rows: SelectLayer(rows [num_blocks,
+#       block_size, width], keys [num_blocks, block_size * index_width]: a
+#       block's keys side by side in one row, so that a key narrower than
+#       the chip's 128 lanes pads nothing and needs no layout of its own;
+#       `write_keys` writes whole such rows and `gather_keys` reads them)
+#   NoCache()   nothing: a layer that is a feed-forward alone. It is its own
+#       (empty) layer in the pool tuple, so the pool keeps one entry a layer
+#
+# What a spec allows (`allows`, of ALLOWS): a KVSpec all of it, every other
+# kind none of it. A layout allows what every one of its layers does
+# (`CacheLayout.allows`), so a model with any other kind of layer is served
+# by PagedGenerationEngine alone, from position 0 (its prefix cache bypassed),
+# from float or bfloat16 pools and weights, through the gather arm, with no
+# adapters and no numerics taps; its blocks never leave the pool.
+#
+# What a spec counts, summed over the layers that declare it (where marked,
+# a layer's worth):
+#   serving::prefill      StateSpec ssm_tokens_scanned, ssm_tokens_valid;
+#                         WindowSpec (a layer's worth) eva_windows,
+#                         eva_chunks_summarised, eva_pairs; SelectSpec
+#                         dsa_index_pairs, dsa_selected_pairs
+#   serving::decode_step  SelectSpec dsa_rows_indexed, dsa_rows_selected
+#   serving::decode.wait  LatentSpec and WindowSpec latent_rows_held, and
+#                         under the gather arm latent_rows_read; SelectSpec
+#                         dsa_rows_selected
+#   serving::step         every kind but KVSpec state_slots_in_use,
+#                         state_bytes, latent_bytes_in_use and
+#                         prefix_cache_bypassed (the engine's numbers);
+#                         WindowSpec (a layer's worth) window_rows_held,
+#                         summary_rows_held
+# A KVSpec counts nothing. A new kind of cache is a spec here and the model
+# that declares it: the engine names none of them.
+ALLOWS = (
+    "prefix_sharing",   # a block one request wrote serves another's prefix
+    "block_moves",      # blocks leave the pool and come back: KV tiers,
+                        # extract_kv(_wire), adopt_kv, extract_prefix_kv,
+                        # restore_prefix
+    "int8",             # int8 pools and int8 decode weights
+    "kernel",           # the Pallas paged attention: `attention_impl`
+                        # "kernel", and the decode kernel where unset
+    "adapters",         # per-tenant LoRA adapters
+    "numerics_taps",    # the numerics taps
+    "any_engine")       # the dense, speculative, tensor- and pipeline-
+                        # parallel engines, not PagedGenerationEngine alone
+
+
+class _Spec:
+    """What a spec answers where its kind does not differ: one row a token
+    under the table (position p at entry p // block_size, a query at p
+    scores p + 1 rows), no bytes, nothing allowed, nothing counted but the
+    engine's `common` gauges on `serving::step`. A kind overrides what
+    differs: `blocks_for` the blocks a slot of n tokens holds,
+    `table_blocks` the entries a table of `max_len` positions has,
+    `entries` those that positions first..last write; `block_bytes` /
+    `slot_bytes` what a pool block / a slot pins in a layer of it; what
+    `layers` layers of it note on `serving::prefill` (a prompt of `length`
+    real tokens in a bucket of `bucket`), `serving::decode_step` and
+    `serving::decode.wait` (the active slots at `pos`; `read` the rows one
+    layer's gather arm reads, None under another arm); and its gauges on
+    `serving::step` (`pos` the positions last written)."""
+    __slots__ = ()
+    allows = frozenset()
+
+    def blocks_for(self, n_tokens, block_size):
+        return blocks_for_tokens(n_tokens, block_size)
+
+    def table_blocks(self, max_len, block_size):
+        return blocks_for_tokens(max_len, block_size)
+
+    def entries(self, first, last, block_size):
+        return range(int(first) // block_size, int(last) // block_size + 1)
+
+    def block_bytes(self, block_size, dtype):
+        return 0
+
+    def slot_bytes(self, dtype):
+        return 0
+
+    def prefill_notes(self, layers, bucket, length):
+        return {}
+
+    def step_notes(self, layers, pos):
+        return {}
+
+    def wait_notes(self, layers, pos, read):
+        return {}
+
+    def gauges(self, pos, common):
+        return common
+
+
+class _RowSpec(_Spec):
+    """A spec of one row of `width` values a token under the table."""
+    __slots__ = ()
+
+    def block_bytes(self, block_size, dtype):
+        return block_size * self.width * np.dtype(dtype).itemsize
+
+    def rows_held(self, pos):
+        """Rows the queries at positions `pos` (an array) score, together:
+        each slot's own, its new token's included."""
+        return int((np.asarray(pos) + 1).sum())
+
+    def wait_notes(self, layers, pos, read):
+        # the rows resident for the active slots, the new token's included,
+        # and those the gather arm reads of them: the dense view of every
+        # slot's whole table
+        notes = {"latent_rows_held": layers * self.rows_held(pos)}
+        if read is not None:
+            notes["latent_rows_read"] = layers * read
+        return notes
+
+
+class KVSpec(_Spec, collections.namedtuple("KVSpec",
+                                           ["heads", "head_dim"])):
+    """K and V of every head a token, paged: the layer every engine and
+    every option serves."""
+    __slots__ = ()
+    allows = frozenset(ALLOWS)
+
+    def block_bytes(self, block_size, dtype):
+        # K and V; an int8 block carries a float32 scale a head beside them
+        side = block_size * self.heads * self.head_dim \
+            * np.dtype(dtype).itemsize
+        if np.dtype(dtype) == np.int8:
+            side += 4 * self.heads
+        return 2 * side
+
+    def gauges(self, pos, common):
+        return {}
+
+
+class LatentSpec(_RowSpec,
+                 collections.namedtuple("LatentSpec", ["width"])):
+    __slots__ = ()
+
+
+class StateSpec(_Spec, collections.namedtuple("StateSpec",
+                                              ["state", "tail"])):
+    __slots__ = ()
+
+    def slot_bytes(self, dtype):
+        return 4 * int(np.prod(self.state)) \
+            + np.dtype(dtype).itemsize * int(np.prod(self.tail))
+
+    def prefill_notes(self, layers, bucket, length):
+        # positions the chunked scan ran (the bucket) and those that were a
+        # real token
+        return {"ssm_tokens_scanned": layers * bucket,
+                "ssm_tokens_valid": layers * length}
+
+
+class NoCache(_Spec, collections.namedtuple("NoCache", [])):
+    __slots__ = ()
+
+
 LatentLayer = collections.namedtuple("LatentLayer", ["rows"])
 StateLayer = collections.namedtuple("StateLayer", ["state", "tail"])
 SelectLayer = collections.namedtuple("SelectLayer", ["rows", "keys"])
 
 
-class SelectSpec(collections.namedtuple("SelectSpec",
-                                        ["width", "index_width", "topk"])):
+class SelectSpec(_Spec, collections.namedtuple(
+        "SelectSpec", ["width", "index_width", "topk"])):
     """A layer whose query at position p reads the rows of min(topk, p + 1)
     positions that an indexer picks, out of every position its slot holds
     (one `width` row and one `index_width` key a token, paged alike)."""
@@ -156,9 +320,32 @@ class SelectSpec(collections.namedtuple("SelectSpec",
         return n * (n + 1) // 2 if n <= k else k * (k + 1) // 2 \
             + (n - k) * k
 
+    def block_bytes(self, block_size, dtype):
+        return block_size * np.dtype(dtype).itemsize \
+            * (self.width + self.index_width)
 
-class WindowSpec(collections.namedtuple("WindowSpec",
-                                        ["width", "window", "chunk"])):
+    def prefill_notes(self, layers, bucket, length):
+        # (query, key) pairs the indexer scored over the prompt's real
+        # tokens, and those attention read
+        return {"dsa_index_pairs": layers * length * (length + 1) // 2,
+                "dsa_selected_pairs": layers * self.selected_pairs(length)}
+
+    def step_notes(self, layers, pos):
+        # the positions the indexer scores (the slots' own, the new token's
+        # included) and those attention selects of them (`topk` or all); the
+        # K/V rows the program read come back with its counters
+        held = np.asarray(pos).astype(np.int64) + 1
+        return {"dsa_rows_indexed": layers * int(held.sum()),
+                "dsa_rows_selected":
+                    layers * int(np.minimum(held, self.topk).sum())}
+
+    def wait_notes(self, layers, pos, read):
+        return {"dsa_rows_selected":
+                self.step_notes(layers, pos)["dsa_rows_selected"]}
+
+
+class WindowSpec(_RowSpec, collections.namedtuple(
+        "WindowSpec", ["width", "window", "chunk"])):
     """A layer that keeps a token's row only while the token's window of
     `window` positions is open, and one summary row per `chunk` positions
     for as long as the request lives (windows are aligned blocks: position
@@ -184,7 +371,6 @@ class WindowSpec(collections.namedtuple("WindowSpec",
         return self.window // block_size
 
     def table_blocks(self, max_len, block_size):
-        """Table entries a slot of up to `max_len` positions may use."""
         chunks = -(-int(max_len) // self.chunk)
         return self.ring_blocks(block_size) + -(-chunks // block_size)
 
@@ -218,6 +404,10 @@ class WindowSpec(collections.namedtuple("WindowSpec",
         return pos % self.window + 1, \
             pos // self.window * (self.window // self.chunk)
 
+    def rows_held(self, pos):
+        ring, summaries = self.visible_rows(pos)
+        return int(ring.sum()) + int(summaries.sum())
+
     def prefill_pairs(self, n_tokens):
         """(query, visible row) pairs of a prompt of `n_tokens` tokens from
         position 0: `sum(visible_rows(p))` over it, in closed form."""
@@ -227,16 +417,28 @@ class WindowSpec(collections.namedtuple("WindowSpec",
         return ring + (w // self.chunk) * (w * full * (full - 1) // 2
                                            + rest * full)
 
+    def prefill_notes(self, layers, bucket, length):
+        # what the window made of the prompt, a layer's worth: windows it
+        # spans, chunk summaries written, and the (query, visible row) pairs
+        # of its real tokens
+        return {"eva_windows": -(-length // self.window),
+                "eva_chunks_summarised": -(-length // self.chunk),
+                "eva_pairs": self.prefill_pairs(length)}
+
+    def gauges(self, pos, common):
+        ring, summaries = self.visible_rows(pos)
+        return {"window_rows_held": int(ring.sum()),
+                "summary_rows_held": int(summaries.sum()), **common}
+
 
 def window_of(layout, block_size=None):
     """The WindowSpec of a model's `cache_layout()`, or None where no layer
-    declares one (or there is no layout at all). One table a slot serves
-    every paged layer, so every paged layer must then declare the SAME
-    geometry: window layers beside one-row-a-token layers, or windows of two
-    sizes, would need a table each (ROADMAP B4's other half)."""
-    paged = {s for s in layout or () if isinstance(s, (LatentSpec,
-                                                       WindowSpec,
-                                                       SelectSpec))}
+    declares one. One table a slot serves every paged layer, so every paged
+    layer must then declare the SAME geometry: window layers beside
+    one-row-a-token layers, or windows of two sizes, would need a table each
+    (ROADMAP B4's other half)."""
+    paged = {s for s in layout if isinstance(s, (KVSpec, LatentSpec,
+                                                 WindowSpec, SelectSpec))}
     spec = next((s for s in paged if isinstance(s, WindowSpec)), None)
     if spec is None:
         return None
@@ -252,28 +454,92 @@ def window_of(layout, block_size=None):
     return spec
 
 
+class CacheLayout(tuple):
+    """A model's `cache_layout()`, one spec a layer, as the engine asks it:
+    what every layer allows, the geometry of the one table a slot has (a
+    window layout's, else one row a token), and what the layers count, each
+    spec answering for its own (the layers that declare the same spec
+    together)."""
+
+    def __init__(self, specs):
+        self.table = window_of(self) or _Spec()
+        # (spec, the layers that declare it), in the order of the layers
+        self._groups = [(spec, n) for (_, spec), n in collections.Counter(
+            (type(spec), spec) for spec in self).items()]
+
+    def allows(self, what):
+        """Whether every layer allows `what`, one of ALLOWS."""
+        if what not in ALLOWS:
+            raise ValueError(f"{what!r} is none of {ALLOWS}")
+        return all(what in spec.allows for spec in self)
+
+    def kinds(self):
+        """The kinds of spec, by name: what a refusal says it was."""
+        return ", ".join(sorted({type(spec).__name__ for spec in self}))
+
+    def blocks_for(self, n_tokens, block_size):
+        return self.table.blocks_for(n_tokens, block_size)
+
+    def table_blocks(self, max_len, block_size):
+        window_of(self, block_size)       # a window of whole blocks
+        return self.table.table_blocks(max_len, block_size)
+
+    def entries(self, first, last, block_size, limit):
+        """Table entries that tokens at positions first..last write, none
+        at or past `limit`."""
+        return [e for e in self.table.entries(first, last, block_size)
+                if e < limit]
+
+    def _summed(self, notes, *args):
+        """`notes` (a spec's method) of every group of layers, summed."""
+        out = {}
+        for spec, layers in self._groups:
+            for name, n in getattr(spec, notes)(layers, *args).items():
+                out[name] = out.get(name, 0) + n
+        return out
+
+    def prefill_notes(self, bucket, length):
+        return self._summed("prefill_notes", bucket, length)
+
+    def step_notes(self, pos):
+        return self._summed("step_notes", pos)
+
+    def wait_notes(self, pos, read):
+        return self._summed("wait_notes", pos, read)
+
+    def gauges(self, pos, common):
+        out = {}
+        for spec, _ in self._groups:
+            out.update(spec.gauges(pos, common))
+        return out
+
+
 def blocks_for_tokens(n_tokens, block_size):
     """Logical blocks needed to hold n_tokens."""
     return -(-int(n_tokens) // int(block_size))
 
 
-def alloc_pools(num_layers, num_blocks, block_size, num_heads, head_dim,
-                dtype=jnp.float32):
-    """Zeroed K/V pools for a whole model: one PagedLayerKV per layer."""
-    shape = (num_blocks, block_size, num_heads, head_dim)
-    return tuple(PagedLayerKV(jnp.zeros(shape, dtype),
-                              jnp.zeros(shape, dtype))
-                 for _ in range(num_layers))
-
-
 def alloc_layers(layout, num_blocks, block_size, slots, dtype):
-    """Zeroed cache arrays for a model that declares its layers' caches:
-    one LatentLayer, StateLayer, SelectLayer or (for a layer that caches
-    nothing) NoCache per spec of `layout`. Latent rows, indexer keys and
-    convolution tails take `dtype`; the recurrent state is float32."""
+    """Zeroed cache arrays for a layout: one PagedLayerKV (a
+    QuantPagedLayerKV where `dtype` is int8: int8 codes and a float32 scale
+    a block and head, [num_blocks, heads]), LatentLayer, StateLayer,
+    SelectLayer or (for a layer that caches nothing) NoCache per spec. K/V,
+    latent rows, indexer keys and convolution tails take `dtype`; the
+    recurrent state is float32."""
     out = []
     for spec in layout:
-        if isinstance(spec, (LatentSpec, WindowSpec)):
+        if isinstance(spec, KVSpec):
+            shape = (num_blocks, block_size, spec.heads, spec.head_dim)
+            if np.dtype(dtype) == np.int8:
+                scale = (num_blocks, spec.heads)
+                out.append(QuantPagedLayerKV(
+                    jnp.zeros(shape, jnp.int8), jnp.zeros(shape, jnp.int8),
+                    jnp.zeros(scale, jnp.float32),
+                    jnp.zeros(scale, jnp.float32)))
+            else:
+                out.append(PagedLayerKV(jnp.zeros(shape, dtype),
+                                        jnp.zeros(shape, dtype)))
+        elif isinstance(spec, (LatentSpec, WindowSpec)):
             out.append(LatentLayer(jnp.zeros(
                 (num_blocks, block_size, spec.width), dtype)))
         elif isinstance(spec, StateSpec):
@@ -293,31 +559,10 @@ def alloc_layers(layout, num_blocks, block_size, slots, dtype):
 
 
 def layout_bytes(layout, block_size, dtype):
-    """(bytes one pool block pins over the latent layers, bytes one slot's
-    state pins over the state layers)."""
-    item = np.dtype(dtype).itemsize
-    block = sum(block_size * item * (s.width + s.index_width)
-                if isinstance(s, SelectSpec) else block_size * s.width * item
-                for s in layout
-                if isinstance(s, (LatentSpec, WindowSpec, SelectSpec)))
-    slot = sum(4 * int(np.prod(s.state)) + item * int(np.prod(s.tail))
-               for s in layout if isinstance(s, StateSpec))
-    return block, slot
-
-
-def alloc_quant_pools(num_layers, num_blocks, block_size, num_heads,
-                      head_dim):
-    """Zeroed INT8 K/V pools + per-block per-head scale arrays: one
-    QuantPagedLayerKV per layer. At equal token capacity the pool bytes
-    are dtype-bytes/1 of the float pools, with a `4 * heads` bytes/block
-    scale overhead (~1/(block_size*head_dim) relative — negligible)."""
-    shape = (num_blocks, block_size, num_heads, head_dim)
-    sshape = (num_blocks, num_heads)
-    return tuple(QuantPagedLayerKV(jnp.zeros(shape, jnp.int8),
-                                   jnp.zeros(shape, jnp.int8),
-                                   jnp.zeros(sshape, jnp.float32),
-                                   jnp.zeros(sshape, jnp.float32))
-                 for _ in range(num_layers))
+    """(bytes one pool block pins over the layout's paged layers, bytes one
+    slot's own rows pin over its state layers), the pools of `dtype`."""
+    return (sum(spec.block_bytes(block_size, dtype) for spec in layout),
+            sum(spec.slot_bytes(dtype) for spec in layout))
 
 
 def write(pool, new, tables, pos):
@@ -338,6 +583,26 @@ def write(pool, new, tables, pos):
                                jnp.minimum(lb, nb - 1), axis=1)
     phys = jnp.where(lb < nb, phys, GARBAGE_BLOCK)
     return pool.at[phys, off].set(new.astype(pool.dtype))
+
+
+def write_kv(layer, k, v, tables, pos, valid=None):
+    """`write` of K and V [S, T, h, d] into one KVSpec layer's pools; a
+    QuantPagedLayerKV's through the quantizing write."""
+    if isinstance(layer, QuantPagedLayerKV):
+        kq, ks = quant_write(layer.k, layer.k_scale, k, tables, pos, valid)
+        vq, vs = quant_write(layer.v, layer.v_scale, v, tables, pos, valid)
+        return QuantPagedLayerKV(kq, vq, ks, vs)
+    return PagedLayerKV(write(layer.k, k, tables, pos),
+                        write(layer.v, v, tables, pos))
+
+
+def read_kv(layer, ids):
+    """K and V of the blocks `ids` of one KVSpec layer, [..., block_size,
+    h, d]: a QuantPagedLayerKV's dequantized."""
+    if isinstance(layer, QuantPagedLayerKV):
+        return (dequant(layer.k[ids], layer.k_scale[ids]),
+                dequant(layer.v[ids], layer.v_scale[ids]))
+    return layer.k[ids], layer.v[ids]
 
 
 def window_write(pool, new, tables, entry, row, live):

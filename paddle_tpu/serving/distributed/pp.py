@@ -212,7 +212,6 @@ class PipelineParallelPagedEngine(PagedGenerationEngine):
     # -- placement ------------------------------------------------------------
     def _alloc_state(self):
         from ...text.models.gpt import gpt_pipeline_stages
-        cfg = self._model.cfg
         c = self.config
         devices = jax.devices()
         modules = gpt_pipeline_stages(self._model, c.pp,
@@ -250,12 +249,12 @@ class PipelineParallelPagedEngine(PagedGenerationEngine):
                         for k, v in self._params.items()}
         self._buffers = {k: np.asarray(jax.device_get(v))
                          for k, v in self._buffers.items()}
-        heads, hd = cfg.num_heads, cfg.hidden_size // cfg.num_heads
         for st in self._stages:
-            raw = blocks.alloc_quant_pools(
-                st.layers, c.num_blocks, c.block_size, heads, hd) \
-                if self.kv_quantized else blocks.alloc_pools(
-                    st.layers, c.num_blocks, c.block_size, heads, hd)
+            # the stage's slice of the layout, its K/V pools float32
+            raw = blocks.alloc_layers(
+                self._layout[st.module.start:st.module.stop], c.num_blocks,
+                c.block_size, c.slots,
+                jnp.int8 if self.kv_quantized else jnp.float32)
             st.pool = tuple(type(l)(
                 *(jax.device_put(x, st.pool_sharding if x.ndim == 4
                                  else st.scale_sharding) for x in l))
@@ -691,20 +690,9 @@ class PipelineParallelPagedEngine(PagedGenerationEngine):
             slot = slot.astype(jnp.int32)
             row = jax.lax.dynamic_slice(tables, (slot, 0), (1, nb))
             zero = jnp.zeros((1,), jnp.int32)
-            npool = []
-            for layer, k, v in zip(pool, new_ks, new_vs):
-                if hasattr(layer, "k_scale"):
-                    kq, ksc = blocks.quant_write(layer.k, layer.k_scale,
-                                                 k[None], row, zero)
-                    vq, vsc = blocks.quant_write(layer.v, layer.v_scale,
-                                                 v[None], row, zero)
-                    npool.append(blocks.QuantPagedLayerKV(kq, vq, ksc,
-                                                          vsc))
-                else:
-                    npool.append(blocks.PagedLayerKV(
-                        blocks.write(layer.k, k[None], row, zero),
-                        blocks.write(layer.v, v[None], row, zero)))
-            return self._constrain_stage(st, tuple(npool))
+            npool = tuple(blocks.write_kv(layer, k[None], v[None], row, zero)
+                          for layer, k, v in zip(pool, new_ks, new_vs))
+            return self._constrain_stage(st, npool)
         return self._cached(adopt_fn, f"adopt_stage[{s}][{bucket}]")
 
     # -- observability / introspection ----------------------------------------

@@ -190,27 +190,24 @@ class GenerationEngine:
     refill) belongs to scheduler.Scheduler; this layer only computes."""
 
     def __init__(self, model, config=None, **kwargs):
-        from ..text.models.gpt import GPT, GPTForGeneration
+        from ..text.models.gpt import GPTForGeneration
         if isinstance(model, GPTForGeneration):
             model = model.gpt
-        if not isinstance(model, GPT):
-            # a model that says what each of its layers caches
-            # (`cache_layout`, docs/serving.md) is served by the paged
-            # engine itself and by none of its relatives
-            if not hasattr(model, "cache_layout"):
-                raise TypeError("GenerationEngine serves GPT-family models "
-                                f"; got {type(model).__name__}")
-            if type(self) is not PagedGenerationEngine:
-                raise TypeError(
-                    f"{type(model).__name__} declares its own cache layout:"
-                    f" PagedGenerationEngine serves it, "
-                    f"{type(self).__name__} does not (yet)")
-        self.config = config or EngineConfig(**kwargs)
+        self._model = model
+        # what each layer caches (serving/blocks.py has the protocol): what
+        # the layout allows decides which engines and options serve it
+        self._layout = blocks.CacheLayout(model.cache_layout())
+        if type(self) is not PagedGenerationEngine \
+                and not self._layout.allows("any_engine"):
+            raise TypeError(
+                f"the cache layout of {type(model).__name__} "
+                f"({self._layout.kinds()}) is served by "
+                f"PagedGenerationEngine, not by {type(self).__name__} (yet)")
+        self.config = self._configure(config or EngineConfig(**kwargs))
         if self.config.max_len > model.cfg.max_position_embeddings:
             raise ValueError(
                 f"max_len={self.config.max_len} exceeds the model's "
                 f"max_position_embeddings={model.cfg.max_position_embeddings}")
-        self._model = model
         self._params, self._buffers = functional_state(model)
         self._rng = jax.random.key(self.config.seed)
         self._last_tokens = np.zeros((self.config.slots,), np.int32)
@@ -263,6 +260,12 @@ class GenerationEngine:
                                     self._decode_donate)
         self._prefill = {}   # bucket -> cached-jitted fn
 
+    def _configure(self, config):
+        """The configuration as this engine serves the model: the paged
+        engine sizes its tables by the layout and resolves what is left
+        open."""
+        return config
+
     # the arguments of `_decode_fn` its executable updates in place: none
     # for the dense per-slot buffers; the paged engine names its pool
     _decode_donate = ()
@@ -313,9 +316,10 @@ class GenerationEngine:
         self._build_decode_params()
 
     # -- functional forward -------------------------------------------------
-    def _run_model(self, params, layers_k, layers_v, pos, ids,
+    def _run_dense(self, params, layers_k, layers_v, pos, ids,
                    adapters=None):
-        """GPT cached forward over raw arrays -> (logits, new k/v lists)."""
+        """GPT cached forward over the dense per-slot buffers (raw arrays)
+        -> (logits, new k/v lists)."""
         cache = kvc.DecodeCache(
             tuple(kvc.LayerKV(Tensor(k), Tensor(v))
                   for k, v in zip(layers_k, layers_v)),
@@ -480,7 +484,7 @@ class GenerationEngine:
         self._bump_decode_trace()            # trace-time only
         adapters, rng = self._split_extra(extra)
         with self._numerics_scope() as sink:
-            logits, nk, nv = self._run_model(params, gk, gv, pos,
+            logits, nk, nv = self._run_dense(params, gk, gv, pos,
                                              tokens[:, None],
                                              adapters=adapters)
             nxt = self._select_slots(logits[:, 0, :], key, *rng)
@@ -509,7 +513,7 @@ class GenerationEngine:
             lk = [f.k for f in fresh]
             lv = [f.v for f in fresh]
             with self._numerics_scope() as sink:
-                logits, nk, nv = self._run_model(params, lk, lv, local_pos,
+                logits, nk, nv = self._run_dense(params, lk, lv, local_pos,
                                                  ids[None, :])
                 slot = slot.astype(jnp.int32)
                 gk = [jax.lax.dynamic_update_slice(g, n, (slot, 0, 0, 0))
@@ -592,7 +596,7 @@ class GenerationEngine:
         executables take the bank's stacked arrays + per-slot adapter
         ids as extra runtime inputs (one new trace per executable —
         adapters change the program once, tenants never do)."""
-        if getattr(self, "_layout", None) is not None:   # dense engine: none
+        if not self._layout.allows("adapters"):
             raise NotImplementedError(
                 f"per-tenant adapters hook GPT's projections; "
                 f"{type(self._model).__name__} has none of them yet")
@@ -1104,39 +1108,8 @@ class PagedGenerationEngine(GenerationEngine):
 
     def __init__(self, model, config=None, **kwargs):
         config = config or PagedEngineConfig(**kwargs)
-        # what each layer caches: None for GPT (K and V per layer), else
-        # the model's own declaration (blocks.LatentSpec / StateSpec /
-        # NoCache);
-        # and the counters its forward returns with the logits
-        self._layout = model.cache_layout() \
-            if hasattr(model, "cache_layout") else None
-        self._counter_names = tuple(getattr(model, "serving_counters", ()))
         self.last_counters = {}
         self.state_store = None
-        self._latent_layers, self._state_layers = (
-            sum(isinstance(spec, kind) for spec in self._layout or ())
-            for kind in ((blocks.LatentSpec, blocks.WindowSpec),
-                         blocks.StateSpec))
-        # the layers whose queries read only the rows an indexer selects
-        self._select_specs = [spec for spec in self._layout or ()
-                              if isinstance(spec, blocks.SelectSpec)]
-        # a layout whose rows live one window and whose summaries live on
-        # (blocks.WindowSpec) sizes a slot itself: its table is as wide as
-        # ITS blocks for max_len, and full provisioning is that a slot
-        self._window = blocks.window_of(self._layout, config.block_size)
-        if self._window is not None:
-            per_slot = self._window.table_blocks(config.max_len,
-                                                 config.block_size)
-            if config.num_blocks == \
-                    1 + config.slots * config.max_blocks_per_slot:
-                config.num_blocks = 1 + config.slots * per_slot
-            config.max_blocks_per_slot = per_slot
-        # what the executables are traced with: the configured value, or
-        # the engine's own choice where the configuration leaves it open.
-        # This, not the spelled value, is what the executables' cache keys
-        # and the spans' `attend` carry
-        self.attention_impl = config.attention_impl \
-            or self._default_attention_impl(config)
         self._packs = {}     # executable -> the layout of its one upload
         # puts and fetches issued through `_put` / `_fetch`, the call
         # path's only two: its upload and wait spans note how many fell
@@ -1152,6 +1125,8 @@ class PagedGenerationEngine(GenerationEngine):
         self._inflight = None       # the call `ahead()` left unfetched
         self._ahead = False
         super().__init__(model, config)
+        # the counters the model's forward returns behind the logits
+        self._counter_names = tuple(self._model.serving_counters)
         # what the first call reads as the output of the call before it:
         # tokens, then the model's counters, as every decode call returns
         self._prev_out = jax.device_put(
@@ -1163,17 +1138,34 @@ class PagedGenerationEngine(GenerationEngine):
         self.trace_counts["adopt"] = {}
         self._adopt = {}
 
+    def _configure(self, config):
+        """A slot's table is as wide as the layout's blocks for max_len (a
+        window layout's rows live one window and its summaries live on, so
+        it sizes a slot itself), and full provisioning is that many blocks
+        a slot. The attention the executables are traced with is the
+        configured one, or the engine's own choice where the configuration
+        leaves it open: this, not the spelled value, is what the
+        executables' cache keys and the spans' `attend` carry."""
+        per_slot = self._layout.table_blocks(config.max_len,
+                                             config.block_size)
+        if config.num_blocks == 1 + config.slots * config.max_blocks_per_slot:
+            config.num_blocks = 1 + config.slots * per_slot
+        config.max_blocks_per_slot = per_slot
+        self.attention_impl = config.attention_impl \
+            or self._default_attention_impl(config)
+        return config
+
     def _default_attention_impl(self, config):
         """The paged attention an engine built without `attention_impl`
         traces: on a TPU, for a model that caches K and V in float pools,
         "decode_kernel" (`blocks.attention_impl`: one query a slot walks
         the block table in the Pallas decode kernel, prefill and verify
         windows gather); "gather" everywhere else: off the TPU the kernel
-        would be interpreted, a model with its own cache layout does not
-        attend through `blocks.attend`, int8 pools have no decode kernel,
-        and pools sharded over a mesh (`tp` > 1) meet a kernel that is one
-        device's program."""
-        if self._layout is not None or config.kv_dtype == "int8" \
+        would be interpreted, a layout that does not allow the kernel does
+        not attend through `blocks.attend`, int8 pools have no decode
+        kernel, and pools sharded over a mesh (`tp` > 1) meet a kernel that
+        is one device's program."""
+        if not self._layout.allows("kernel") or config.kv_dtype == "int8" \
                 or getattr(config, "tp", 1) > 1 \
                 or jax.default_backend() != "tpu":
             return "gather"
@@ -1214,67 +1206,58 @@ class PagedGenerationEngine(GenerationEngine):
         return self.config.kv_dtype == "int8"
 
     def _pool_dtype(self):
-        if self.config.kv_dtype == "bfloat16":
-            return jnp.dtype(jnp.bfloat16)
-        # "float32" reads: as the weights are (the embedding's type)
-        name = "wte.weight" if self._layout is None \
-            else next(iter(self._params))
-        return jnp.dtype(self._params[name].dtype)
+        """The pools' type: "bfloat16" and "int8" as spelled; "float32"
+        reads as the weights are (the first parameter's type)."""
+        if self.config.kv_dtype in ("bfloat16", "int8"):
+            return jnp.dtype(self.config.kv_dtype)
+        return jnp.dtype(next(iter(self._params.values())).dtype)
 
     def _alloc_state(self):
-        cfg = self._model.cfg
         c = self.config
-        if self._layout is not None:
-            self._check_layout_config()
+        self._check_layout_config()
         if c.weight_dtype == "bfloat16":
-            keep = self._model.float32_parameters() \
-                if hasattr(self._model, "float32_parameters") else ()
+            keep = self._model.float32_parameters()
             self._params = {
                 n: a if n in keep or a.dtype != jnp.float32
                 else a.astype(jnp.bfloat16)
                 for n, a in self._params.items()}
-        if self._layout is not None:
-            self._pool = blocks.alloc_layers(
-                self._layout, c.num_blocks, c.block_size, c.slots,
-                self._pool_dtype())
-            _, slot_bytes = blocks.layout_bytes(
-                self._layout, c.block_size, self._pool_dtype())
-            if slot_bytes:
-                self.state_store = blocks.SlotStateStore(c.slots,
-                                                         slot_bytes)
-        elif self.kv_quantized:
-            self._pool = blocks.alloc_quant_pools(
-                cfg.num_layers, c.num_blocks, c.block_size, cfg.num_heads,
-                cfg.hidden_size // cfg.num_heads)
-        else:
-            self._pool = blocks.alloc_pools(
-                cfg.num_layers, c.num_blocks, c.block_size, cfg.num_heads,
-                cfg.hidden_size // cfg.num_heads, self._pool_dtype())
+        self._pool = blocks.alloc_layers(
+            self._layout, c.num_blocks, c.block_size, c.slots,
+            self._pool_dtype())
+        _, slot_bytes = blocks.layout_bytes(self._layout, c.block_size,
+                                            self._pool_dtype())
+        if slot_bytes:
+            self.state_store = blocks.SlotStateStore(c.slots, slot_bytes)
         self._alloc_host_state()
 
     def _check_layout_config(self):
-        """What a model with its own cache layout cannot be combined with
-        yet raises here, at construction, and not in the middle of a
-        request (docs/serving.md lists them)."""
+        """What the model's cache layout does not allow raises here, at
+        construction, and not in the middle of a request (serving/blocks.py
+        says what each kind of layer allows)."""
         c = self.config
-        bad = [f"{k}={v!r}" for k, v, ok in (
-            ("kv_dtype", c.kv_dtype, ("float32", "bfloat16")),
-            ("weight_dtype", c.weight_dtype, ("float32", "bfloat16")),
-            ("attention_impl", self.attention_impl, ("gather",)),
-            ("enable_kv_tiers", c.enable_kv_tiers, (False,)),
-            ("numerics_taps", c.numerics_taps, (False,))) if v not in ok]
+        asked = (("kv_dtype", c.kv_dtype, "int8", c.kv_dtype == "int8"),
+                 ("weight_dtype", c.weight_dtype, "int8",
+                  c.weight_dtype == "int8"),
+                 ("attention_impl", self.attention_impl, "kernel",
+                  self.attention_impl != "gather"),
+                 ("enable_kv_tiers", c.enable_kv_tiers, "block_moves",
+                  c.enable_kv_tiers),
+                 ("numerics_taps", c.numerics_taps, "numerics_taps",
+                  c.numerics_taps))
+        bad = [f"{k}={v!r}" for k, v, what, on in asked
+               if on and not self._layout.allows(what)]
         if bad:
             raise ValueError(
-                f"{type(self._model).__name__} declares its own cache "
-                f"layout; the paged engine cannot serve it with "
+                f"the cache layout of {type(self._model).__name__} "
+                f"({self._layout.kinds()}) cannot be served with "
                 f"{', '.join(bad)}")
 
     def _require_kv_layout(self, what):
-        if self._layout is not None:
+        if not self._layout.allows("block_moves"):
             raise NotImplementedError(
-                f"{what} moves K/V blocks; {type(self._model).__name__} "
-                f"caches latent rows and per-slot state, which it cannot "
-                f"carry yet")
+                f"{what} moves K/V blocks; the cache layout of "
+                f"{type(self._model).__name__} ({self._layout.kinds()}) "
+                f"keeps its blocks in the pool")
 
     def _alloc_host_state(self):
         """The mesh-oblivious host half of the paged state: per-slot
@@ -1294,14 +1277,14 @@ class PagedGenerationEngine(GenerationEngine):
         # so mid-decode block growth evicts under the same requester
         self._slot_namespace = {}
         self.block_pool = blocks.BlockPool(c.num_blocks, c.block_size)
-        # block-identity prefix reuse is wrong for a model with its own
-        # cache layout, state or no state: the layout prefill runs a
-        # request's tokens from position 0 (it attends over its own tokens
-        # only, and a per-slot state at the end of a shared prefix is in no
-        # block). Its cache reports no hit and counts the lookups
+        # block-identity prefix reuse is wrong for a layout that does not
+        # allow it: its prefill runs a request's tokens from position 0 (it
+        # attends over its own tokens only, and a per-slot state at the end
+        # of a shared prefix is in no block). Its cache reports no hit and
+        # counts the lookups
         self.prefix_cache = PrefixCache(
             self.block_pool, c.block_size,
-            bypass=self._layout is not None) \
+            bypass=not self._layout.allows("prefix_sharing")) \
             if c.enable_prefix_cache else None
         # KV attribution ledger (observability.kvledger): because every
         # engine kind — paged, spec, tp, pp, spec_pp — funnels through
@@ -1347,45 +1330,31 @@ class PagedGenerationEngine(GenerationEngine):
         self.last_logits = None
 
     def _kv_block_bytes(self):
-        """HBM bytes one pool block pins across every layer and both
-        K/V sides, priced from the pool dtype — what turns the ledger's
-        per-tenant block counts into `serving_kv_bytes{tenant,kind}`.
-        Mirrors the bench's equal-byte-budget math: int8 blocks carry a
-        4-byte-per-head scale row next to the codes."""
-        cfg = self._model.cfg
-        c = self.config
-        if self._layout is not None:
-            return blocks.layout_bytes(self._layout, c.block_size,
-                                       self._pool_dtype())[0]
-        heads = cfg.num_heads
-        head_dim = cfg.hidden_size // heads
-        if self.kv_quantized:
-            per_side = c.block_size * heads * head_dim + 4 * heads
-        else:
-            try:
-                itemsize = np.dtype(
-                    self._params["wte.weight"].dtype).itemsize
-            except Exception:                            # noqa: BLE001
-                itemsize = 4
-            per_side = c.block_size * heads * head_dim * itemsize
-        return 2 * per_side * cfg.num_layers
+        """HBM bytes one pool block pins across every layer, priced from
+        the pool dtype (`blocks.layout_bytes`: int8 K/V blocks carry a
+        4-byte-per-head scale row next to the codes) — what turns the
+        ledger's per-tenant block counts into
+        `serving_kv_bytes{tenant,kind}`."""
+        return blocks.layout_bytes(self._layout, self.config.block_size,
+                                   self._pool_dtype())[0]
 
     # -- KV tier device I/O (ISSUE 18) --------------------------------------
+    # a K/V layer's arrays in a tier record, field by field: `k{layer}`,
+    # `v{layer}`, and in int8 pools the scale rows `ks{layer}`, `vs{layer}`
+    _TIER_NAMES = ("k", "v", "ks", "vs")
+
+    def _tier_names(self, li, layer):
+        return [f"{n}{li}" for n in self._TIER_NAMES[:len(layer)]]
+
     def _tier_read_block(self, blk):
         """TieredBlockStore's read callback: one physical block's
         whole-model KV as pool-NATIVE host numpy arrays — f32 slabs, or
         int8 codes + their scale rows for quantized pools (lossless
         either way). Eager gathers only; never a traced program."""
         blk = int(blk)
-        arrays = {}
-        for li, layer in enumerate(self._pool):
-            arrays[f"k{li}"] = np.asarray(jax.device_get(layer.k[blk]))
-            arrays[f"v{li}"] = np.asarray(jax.device_get(layer.v[blk]))
-            if hasattr(layer, "k_scale"):
-                arrays[f"ks{li}"] = np.asarray(
-                    jax.device_get(layer.k_scale[blk]), np.float32)
-                arrays[f"vs{li}"] = np.asarray(
-                    jax.device_get(layer.v_scale[blk]), np.float32)
+        arrays = {name: np.asarray(jax.device_get(x[blk]))
+                  for li, layer in enumerate(self._pool)
+                  for name, x in zip(self._tier_names(li, layer), layer)}
         return {"arrays": arrays, "quant": self.kv_quantized}
 
     def _tier_write_block(self, blk, arrays):
@@ -1399,21 +1368,11 @@ class PagedGenerationEngine(GenerationEngine):
         blk = int(blk)
         dev = {n: jax.device_put(np.asarray(a))
                for n, a in arrays.items()}
-        npool = []
-        for li, layer in enumerate(self._pool):
-            if hasattr(layer, "k_scale"):
-                npool.append(blocks.QuantPagedLayerKV(
-                    layer.k.at[blk].set(dev[f"k{li}"]),
-                    layer.v.at[blk].set(dev[f"v{li}"]),
-                    layer.k_scale.at[blk].set(dev[f"ks{li}"]),
-                    layer.v_scale.at[blk].set(dev[f"vs{li}"])))
-            else:
-                npool.append(blocks.PagedLayerKV(
-                    layer.k.at[blk].set(
-                        dev[f"k{li}"].astype(layer.k.dtype)),
-                    layer.v.at[blk].set(
-                        dev[f"v{li}"].astype(layer.v.dtype))))
-        self._pool = tuple(npool)
+        self._pool = tuple(
+            type(layer)(*(x.at[blk].set(dev[name].astype(x.dtype))
+                          for name, x in zip(self._tier_names(li, layer),
+                                             layer)))
+            for li, layer in enumerate(self._pool))
 
     def _tier_writer_fn(self, pool, idx, payload):
         """The batched tier-restore program: one fixed-shape scatter of
@@ -1425,19 +1384,9 @@ class PagedGenerationEngine(GenerationEngine):
         engine (`trace_counts["tier_restore"]`)."""
         self.trace_counts["tier_restore"] = \
             self.trace_counts.get("tier_restore", 0) + 1  # trace-time only
-        out = []
-        for layer, pl in zip(pool, payload):
-            if hasattr(layer, "k_scale"):
-                out.append(blocks.QuantPagedLayerKV(
-                    layer.k.at[idx].set(pl[0]),
-                    layer.v.at[idx].set(pl[1]),
-                    layer.k_scale.at[idx].set(pl[2]),
-                    layer.v_scale.at[idx].set(pl[3])))
-            else:
-                out.append(blocks.PagedLayerKV(
-                    layer.k.at[idx].set(pl[0].astype(layer.k.dtype)),
-                    layer.v.at[idx].set(pl[1].astype(layer.v.dtype))))
-        return tuple(out)
+        return tuple(type(layer)(*(x.at[idx].set(p.astype(x.dtype))
+                                   for x, p in zip(layer, pl)))
+                     for layer, pl in zip(pool, payload))
 
     def _tier_write_blocks(self, blks, arrays_list):
         """Batched tier restore for a whole chain run: pad the run to
@@ -1456,10 +1405,8 @@ class PagedGenerationEngine(GenerationEngine):
             idx[:m] = [int(b) for b in run]
             payload = []
             for li, layer in enumerate(self._pool):
-                names = (f"k{li}", f"v{li}", f"ks{li}", f"vs{li}") \
-                    if hasattr(layer, "k_scale") else (f"k{li}", f"v{li}")
                 lanes_pl = []
-                for n in names:
+                for n in self._tier_names(li, layer):
                     first = np.asarray(arrs[0][n])
                     pad = np.zeros((lanes,) + first.shape, first.dtype)
                     pad[:m] = [np.asarray(a[n]) for a in arrs]
@@ -1533,32 +1480,15 @@ class PagedGenerationEngine(GenerationEngine):
 
     # -- block accounting ----------------------------------------------------
     def _blocks_for(self, n_tokens):
-        """Blocks a slot of `n_tokens` tokens holds: one row a token, or
-        what the model's layout declares."""
-        bs = self.config.block_size
-        if self._window is not None:
-            return self._window.blocks_for(n_tokens, bs)
-        return blocks.blocks_for_tokens(n_tokens, bs)
+        """Blocks a slot of `n_tokens` tokens holds, as the layout says."""
+        return self._layout.blocks_for(n_tokens, self.config.block_size)
 
     def _table_entries(self, first, last):
         """The table entries that tokens at positions first..last write,
         none beyond the table."""
-        bs = self.config.block_size
-        if self._window is not None:
-            return self._window.entries(first, last, bs)
-        return range(first // bs, min(last // bs,
-                                      self.config.max_blocks_per_slot - 1)
-                     + 1)
-
-    def _rows_visible(self, back=0):
-        """(ring rows, summary rows) the active slots' queries at `_pos -
-        back` score, a layer's worth: what a window layout keeps of the
-        positions `kv_tokens_held` counts. None without such a layout."""
-        if self._window is None:
-            return None
-        ring, chunks = self._window.visible_rows(
-            np.maximum(self._pos[self._slot_active] - back, 0))
-        return int(ring.sum()), int(chunks.sum())
+        c = self.config
+        return self._layout.entries(first, last, c.block_size,
+                                    c.max_blocks_per_slot)
 
     def _alloc_blocks(self, n, requester=None):
         """Pool alloc with prefix-cache eviction as the pressure valve:
@@ -1718,57 +1648,37 @@ class PagedGenerationEngine(GenerationEngine):
         return jax.lax.bitcast_convert_type(seeds, jnp.uint32), gen
 
     # -- functional forward (paged) -----------------------------------------
-    def _run_model_paged(self, params, pool, tables, pos, ids, valid=None,
-                         adapters=None):
-        """GPT cached forward over the pool pytree (a tuple of
-        (Quant)PagedLayerKV of raw arrays) -> (logits, new pool).
-        `valid` [S]: real tokens per slot in this write (prefill passes
-        the unpadded suffix length so bucket padding stays out of a
-        quantized pool's block scales)."""
+    def _run_model(self, params, pool, tables, pos, ids, valid=None,
+                   slot=None, adapters=None):
+        """THE cached forward of the served model over the pool pytree (one
+        array set a layer of its layout, raw arrays) -> (logits, new pool,
+        counters int32 [len(serving_counters)]). `valid` [S]: real tokens
+        per slot in this write (a prefill's unpadded suffix length: bucket
+        padding stays out of a quantized pool's block scales and out of a
+        state); `slot`: the slot a prefill fills (its per-slot rows)."""
         cache = blocks.PagedDecodeCache(
             tuple(type(l)(*(Tensor(x) for x in l)) for l in pool),
             Tensor(tables), Tensor(pos),
-            None if valid is None else Tensor(valid))
+            None if valid is None else Tensor(valid),
+            None if slot is None else Tensor(slot))
         kwargs = {"cache": cache}
         if adapters is not None:
             kwargs["adapters"] = adapters
         out, _ = functional_call(
             self._model, params, self._buffers, args=(Tensor(ids),),
             kwargs=kwargs, train=False)
-        logits, new_cache = out
-        return (logits._data,
-                tuple(type(l)(*(x._data for x in l))
-                      for l in new_cache.layers))
-
-    def _run_layout_model(self, params, pool, tables, pos, ids, valid=None,
-                          slot=None):
-        """The cached forward of a model with its own cache layout ->
-        (logits, new pool, counters int32 [len(serving_counters)])."""
-        cache = blocks.PagedDecodeCache(
-            tuple(type(l)(*(Tensor(x) for x in l)) for l in pool),
-            Tensor(tables), Tensor(pos),
-            None if valid is None else Tensor(valid),
-            None if slot is None else Tensor(slot))
-        out, _ = functional_call(
-            self._model, params, self._buffers, args=(Tensor(ids),),
-            kwargs={"cache": cache}, train=False)
         logits, new_cache, counters = out
         return (logits._data,
                 tuple(type(l)(*(x._data for x in l))
                       for l in new_cache.layers), counters._data)
 
-    def _layout_decode_fn(self, params, pool, tables, pos, tokens, *rng):
-        """`_decode_fn` for a model with its own cache layout: the tokens
-        come back with the model's counters behind them in ONE int32
-        array, so the step's single fetch brings both."""
-        logits, npool, counters = self._run_layout_model(
-            params, pool, tables, pos, tokens[:, None])
-        nxt = self._select_slots(logits[:, 0, :], None, *rng)
-        out = (jnp.concatenate([nxt.astype(jnp.int32), counters]),
-               self._constrain_pools(npool))
-        if self.config.capture_logits:
-            out = out + (logits[:, 0, :],)
-        return out
+    def _with_counters(self, tokens, counters):
+        """A call's tokens with the model's counters behind them in ONE
+        int32 array, where it counts anything: the call's one fetch brings
+        both."""
+        if not self._counter_names:
+            return tokens
+        return jnp.concatenate([tokens.astype(jnp.int32), counters])
 
     # -- decode: ONE executable ---------------------------------------------
     def _decode_fn(self, params, pool, packed, prev, adapter_tree=None):
@@ -1786,9 +1696,6 @@ class PagedGenerationEngine(GenerationEngine):
         # trace from its packed (seed, gen)
         rng = self._unpack_rng(host["seeds"], host["gen"]) \
             if self._sampling else ()
-        if self._layout is not None:
-            return self._layout_decode_fn(params, pool, tables, pos, tokens,
-                                          *rng)
         adapters = None if adapter_tree is None else \
             {"slot": host["adapter"], "layers": adapter_tree["layers"]}
         with self._numerics_scope() as sink:
@@ -1810,7 +1717,7 @@ class PagedGenerationEngine(GenerationEngine):
                                    [w["q"] for w in quant],
                                    sat_threshold=127)
             with blocks.attention_scope("decode_attn"):
-                logits, npool = self._run_model_paged(
+                logits, npool, counters = self._run_model(
                     self._dequant_params(params), pool, tables, pos,
                     tokens[:, None], adapters=adapters)
             nxt = self._select_slots(logits[:, 0, :], None, *rng)
@@ -1818,7 +1725,8 @@ class PagedGenerationEngine(GenerationEngine):
             if adapters is not None:
                 _numerics.tap_tree("adapter.delta", adapters["layers"])
         # the positions are not returned: they are the host's to advance
-        out = (nxt, self._constrain_pools(npool))
+        out = (self._with_counters(nxt, counters),
+               self._constrain_pools(npool))
         if self.config.capture_logits:
             out = out + (logits[:, 0, :],)
         if sink is not None:
@@ -1827,6 +1735,10 @@ class PagedGenerationEngine(GenerationEngine):
 
     # -- prefill: one executable per SUFFIX bucket ---------------------------
     def _make_prefill(self, bucket):
+        # a model may say that a long prompt hands back the last real
+        # token's row of logits alone
+        keeps_last = self._model.prefill_keeps_last_row(bucket)
+
         def prefill_fn(params, pool, packed):
             self.trace_counts["prefill"][bucket] = \
                 self.trace_counts["prefill"].get(bucket, 0) + 1
@@ -1841,34 +1753,22 @@ class PagedGenerationEngine(GenerationEngine):
             if self._sampling:
                 seed, gen = self._unpack_rng(host["seed"], host["gen"])
                 key = jax.random.fold_in(jax.random.key(seed), gen)
-            if self._layout is not None:
-                # always from position 0 (the prefix cache is bypassed);
-                # first token and counters in one int32 array
-                logits, npool, counters = self._run_layout_model(
-                    params, pool, row, start[None], ids[None, :],
-                    valid=length[None], slot=slot)
-                # a model may say that a long prompt hands back the last
-                # real token's row alone
-                keeps = getattr(self._model, "prefill_keeps_last_row", None)
-                last = logits[0, 0] if keeps is not None and keeps(bucket) \
-                    else jax.lax.dynamic_index_in_dim(logits[0], length - 1,
-                                                      keepdims=False)
-                first = self._select(last[None, :], key).astype(jnp.int32)
-                return (jnp.concatenate([first, counters]),
-                        self._constrain_pools(npool))
             with self._numerics_scope() as sink:
                 with blocks.attention_scope("prefill_attn"):
-                    logits, npool = self._run_model_paged(
+                    logits, npool, counters = self._run_model(
                         params, pool, row, start[None], ids[None, :],
-                        valid=length[None])
-                last = jax.lax.dynamic_index_in_dim(logits[0], length - 1,
-                                                    keepdims=False)
-                first_token = self._select(last[None, :], key)[0]
+                        valid=length[None], slot=slot)
+                last = logits[0, 0] if keeps_last \
+                    else jax.lax.dynamic_index_in_dim(logits[0], length - 1,
+                                                      keepdims=False)
+                first = self._select(last[None, :], key)
                 _numerics.tap("prefill.logits", last[None, :])
-            npool = self._constrain_pools(npool)
-            if sink is None:
-                return first_token, npool
-            return first_token, npool, sink
+            # the first token and the model's counters in one int32 array
+            out = (self._with_counters(first, counters),
+                   self._constrain_pools(npool))
+            if sink is not None:
+                out = out + (sink,)
+            return out
         return self._cached(prefill_fn, f"prefill[{bucket}]",
                             donate_argnums=(1,))
 
@@ -1937,8 +1837,6 @@ class PagedGenerationEngine(GenerationEngine):
             self._tables[slot] = row
             self._slot_active[slot] = True
             self._slot_namespace[slot] = namespace
-            assert not (nshared and self._layout is not None), \
-                "the layout prefill starts at 0"
             if self.state_store is not None:
                 self.state_store.acquire(slot)
             seed, gen = rng if rng is not None \
@@ -2015,26 +1913,9 @@ class PagedGenerationEngine(GenerationEngine):
         # the pool that went in is gone: rebind before anything can raise
         first, self._pool = out[:2]
         _TRACER.note("pool_donated", self._pool_donated(pool_in))
-        if self._state_layers:
-            # positions the state layers' chunked scan ran (the bucket, in
-            # every one of them) and those that were a real token
-            _TRACER.note("ssm_tokens_scanned", self._state_layers * bucket)
-            _TRACER.note("ssm_tokens_valid", self._state_layers * length)
-        if self._window is not None:
-            # what the window layout made of the prompt, a layer's worth:
-            # windows it spans, chunk summaries written, and the (query,
-            # visible row) pairs of its real tokens
-            spec = self._window
-            _TRACER.note("eva_windows", -(-length // spec.window))
-            _TRACER.note("eva_chunks_summarised", -(-length // spec.chunk))
-            _TRACER.note("eva_pairs", spec.prefill_pairs(length))
-        if self._select_specs:
-            # (query, key) pairs the selecting layers' indexer scored over
-            # the prompt's real tokens, and those their attention read
-            _TRACER.note("dsa_index_pairs", len(self._select_specs)
-                         * length * (length + 1) // 2)
-            _TRACER.note("dsa_selected_pairs", sum(
-                s.selected_pairs(length) for s in self._select_specs))
+        # what the layout's layers made of the prompt
+        for name, n in self._layout.prefill_notes(bucket, length).items():
+            _TRACER.note(name, n)
         with _span("serving::prefill.wait"):
             before = self._fetches
             # the model's counters ride behind the first token
@@ -2154,20 +2035,6 @@ class PagedGenerationEngine(GenerationEngine):
         call, self._inflight = self._inflight, None
         return None if call is None else self._fetch_call(call)
 
-    def _select_attrs(self):
-        """What the selecting layers' decode reads this call, summed over
-        them, from the host's positions: the positions the indexer scores
-        (the slots' own, the new token's included) and those attention
-        selects of them (`topk` or all). The K/V rows the program read come
-        back with its counters (`dsa_kv_rows_read`)."""
-        if not self._select_specs:
-            return {}
-        held = self._pos[self._slot_active].astype(np.int64) + 1
-        return {
-            "dsa_rows_indexed": len(self._select_specs) * int(held.sum()),
-            "dsa_rows_selected": sum(int(np.minimum(held, s.topk).sum())
-                                     for s in self._select_specs)}
-
     def _wait_attrs(self, pool_in):
         """What the wait span of a call says of it, from the host's
         positions at its dispatch."""
@@ -2180,22 +2047,12 @@ class PagedGenerationEngine(GenerationEngine):
                 ((self._pos[self._slot_active] + c.block_size)
                  // c.block_size).sum())
             wait["attn_blocks_table"] = c.slots * c.max_blocks_per_slot
-        if self._latent_layers:
-            # the latent rows resident for the active slots, the new
-            # token's included, and those the arm that ran read of them:
-            # the gather arm builds the dense view of every slot's whole
-            # table, a layer
-            visible = self._rows_visible()
-            wait["latent_rows_held"] = self._latent_layers * (
-                int((self._pos[self._slot_active] + 1).sum())
-                if visible is None else sum(visible))
-            if self.attention_impl == "gather":
-                wait["latent_rows_read"] = self._latent_layers \
-                    * c.slots * c.max_blocks_per_slot * c.block_size
-        if self._select_specs:
-            # beside the rows the program says it read, on the same span
-            wait["dsa_rows_selected"] = \
-                self._select_attrs()["dsa_rows_selected"]
+        # what the layout's layers read: the gather arm builds the dense
+        # view of every slot's whole table, a layer
+        wait.update(self._layout.wait_notes(
+            self._pos[self._slot_active],
+            c.slots * c.max_blocks_per_slot * c.block_size
+            if self.attention_impl == "gather" else None))
         return wait
 
     def decode(self):
@@ -2224,7 +2081,8 @@ class PagedGenerationEngine(GenerationEngine):
                      "attend": self.attention_impl,
                      "ahead": int(self._inflight is not None),
                      "call": self._calls}
-            attrs.update(self._select_attrs())
+            attrs.update(self._layout.step_notes(
+                self._pos[self._slot_active]))
         with RecordEvent("serving::decode_step",
                          TracerEventType.UserDefined, attrs), \
                 blocks.attention_impl(self.attention_impl):
@@ -2309,11 +2167,7 @@ class PagedGenerationEngine(GenerationEngine):
         row, plen, nb = self._extract_row(slot)
         ks, vs = [], []
         for layer in self._pool:
-            if self.kv_quantized:
-                k = blocks.dequant(layer.k[row], layer.k_scale[row])
-                v = blocks.dequant(layer.v[row], layer.v_scale[row])
-            else:
-                k, v = layer.k[row], layer.v[row]      # [nb, bs, h, d]
+            k, v = blocks.read_kv(layer, row)            # [nb, bs, h, d]
             ks.append(self._strip_padding(k, nb, plen))
             vs.append(self._strip_padding(v, nb, plen))
         return ks, vs, plen
@@ -2466,19 +2320,9 @@ class PagedGenerationEngine(GenerationEngine):
             slot = slot.astype(jnp.int32)
             row = jax.lax.dynamic_slice(tables, (slot, 0), (1, nb))
             zero = jnp.zeros((1,), jnp.int32)
-            npool = []
-            for layer, k, v in zip(pool, new_ks, new_vs):
-                if hasattr(layer, "k_scale"):
-                    kq, ksc = blocks.quant_write(layer.k, layer.k_scale,
-                                                 k[None], row, zero)
-                    vq, vsc = blocks.quant_write(layer.v, layer.v_scale,
-                                                 v[None], row, zero)
-                    npool.append(blocks.QuantPagedLayerKV(kq, vq, ksc, vsc))
-                else:
-                    npool.append(blocks.PagedLayerKV(
-                        blocks.write(layer.k, k[None], row, zero),
-                        blocks.write(layer.v, v[None], row, zero)))
-            return self._constrain_pools(tuple(npool))
+            return self._constrain_pools(tuple(
+                blocks.write_kv(layer, k[None], v[None], row, zero)
+                for layer, k, v in zip(pool, new_ks, new_vs)))
         return self._cached(adopt_fn, f"adopt[{bucket}]",
                             donate_argnums=(0,))
 
@@ -2518,13 +2362,7 @@ class PagedGenerationEngine(GenerationEngine):
             blk = cache._entries.get(key)
             if blk is not None:
                 for li, layer in enumerate(self._pool):
-                    if self.kv_quantized:
-                        kb = blocks.dequant(layer.k[blk][None],
-                                            layer.k_scale[blk][None])[0]
-                        vb = blocks.dequant(layer.v[blk][None],
-                                            layer.v_scale[blk][None])[0]
-                    else:
-                        kb, vb = layer.k[blk], layer.v[blk]
+                    kb, vb = blocks.read_kv(layer, blk)
                     parts_k[li].append(
                         np.asarray(jax.device_get(kb), np.float32))
                     parts_v[li].append(
@@ -2668,23 +2506,20 @@ class PagedGenerationEngine(GenerationEngine):
         return self._pos.copy()
 
     def layout_gauges(self):
-        """What `serving::step` says of a model with its own cache layout
-        as a step ends: both kinds of cache in bytes (a model without
-        per-slot state holds 0 of it) and the prefix lookups that were
-        refused. None for a model that caches K and V."""
-        if self._layout is None:
-            return None
+        """What `serving::step` says of the cache as a step ends, as the
+        layout answers it from the engine's numbers: the per-slot state and
+        the paged rows in bytes and the prefix lookups that were refused
+        (nothing of a layout of K/V layers)."""
         store, cache = self.state_store, self.prefix_cache
-        visible = self._rows_visible(back=1)   # of the last token written
-        return {
-            **({} if visible is None else {
-                "window_rows_held": visible[0],
-                "summary_rows_held": visible[1]}),
-            "state_slots_in_use": 0 if store is None else store.in_use,
-            "state_bytes": 0 if store is None else store.bytes_in_use,
-            "latent_bytes_in_use":
-                self.block_pool.in_use * self.kv_block_bytes,
-            "prefix_cache_bypassed": 0 if cache is None else cache.bypassed}
+        return self._layout.gauges(
+            # of the last token written
+            np.maximum(self._pos[self._slot_active] - 1, 0),
+            {"state_slots_in_use": 0 if store is None else store.in_use,
+             "state_bytes": 0 if store is None else store.bytes_in_use,
+             "latent_bytes_in_use":
+                 self.block_pool.in_use * self.kv_block_bytes,
+             "prefix_cache_bypassed": 0 if cache is None
+             else cache.bypassed})
 
 
 def _engine_kind(config):

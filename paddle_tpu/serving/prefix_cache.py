@@ -150,12 +150,12 @@ class PrefixCache:
     def __init__(self, pool, block_size, bypass=False):
         self.pool = pool
         self.block_size = int(block_size)
-        # bypass: the engine prefills its model from position 0 (a model
-        # with its own cache layout: its prefill cannot continue from
-        # resident rows, and per-slot state is in no block), so reusing a
-        # prefix's blocks would be wrong. Every lookup then reports no hit
-        # (and is counted in `bypassed`), nothing is registered, and the
-        # pool's blocks go back when a request ends.
+        # bypass: the engine prefills its model from position 0 (a cache
+        # layout that does not allow prefix sharing: its prefill cannot
+        # continue from resident rows, and per-slot state is in no block),
+        # so reusing a prefix's blocks would be wrong. Every lookup then
+        # reports no hit (and is counted in `bypassed`), nothing is
+        # registered, and the pool's blocks go back when a request ends.
         self.bypass = bool(bypass)
         self.bypassed = 0
         self._entries = {}        # key -> block id

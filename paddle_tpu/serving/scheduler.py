@@ -898,9 +898,10 @@ class Scheduler:
             if req is not None)
         gauges = getattr(self.engine, "layout_gauges", None)
         if gauges is not None:
-            # a model with its own cache layout: per-slot state and paged
-            # rows in bytes, and the prefix lookups that were refused
-            attrs.update(gauges() or {})
+            # what the model's cache layout says of the step: per-slot
+            # state and paged rows in bytes and the prefix lookups that
+            # were refused (nothing for K/V layers)
+            attrs.update(gauges())
 
     def _step(self):
         self.apply_pending_swap()

@@ -299,7 +299,7 @@ class SpeculativeEngine(PagedGenerationEngine):
         # base and only pays in acceptance rate on adapted slots
         adapters, _ = self._split_extra(extra)
         with self._numerics_scope() as sink:
-            logits, npool = self._run_model_paged(
+            logits, npool, _ = self._run_model(
                 self._dequant_params(params), pool, tables, pos, window,
                 adapters=adapters)
             choices, n_acc, last = sampling.greedy_verify(logits, window)

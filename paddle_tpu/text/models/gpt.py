@@ -210,6 +210,23 @@ class GPT(Layer):
             tuple(_kvc.LayerKV(Tensor(l.k), Tensor(l.v)) for l in raw.layers),
             Tensor(raw.pos))
 
+    # -- what it serves from (serving/blocks.py has the protocol) ---------
+    serving_counters = ()
+
+    def cache_layout(self):
+        """K and V of every head a token, paged, in every layer."""
+        from ...serving import blocks as _blk
+        cfg = self.cfg
+        return (_blk.KVSpec(cfg.num_heads,
+                            cfg.hidden_size // cfg.num_heads),) \
+            * cfg.num_layers
+
+    def prefill_keeps_last_row(self, tokens):
+        return False
+
+    def float32_parameters(self):
+        return frozenset()
+
     def forward(self, input_ids, cache=None, adapters=None):
         B, S = input_ids.shape
         from ...tensor.creation import arange
@@ -239,9 +256,11 @@ class GPT(Layer):
                 _numerics.tap_layer(i, "act", x._data)
             logits = self._head(self.ln_f(x))
             if tables is not None:
+                # the paged engine's protocol: the new cache, no counters
                 from ...serving import blocks as _blk
-                return logits, _blk.PagedDecodeCache(tuple(new_layers),
-                                                     tables, pos + S)
+                return logits, _blk.PagedDecodeCache(
+                    tuple(new_layers), tables, pos + S), \
+                    Tensor(jnp.zeros((0,), jnp.int32))
             return logits, _kvc.DecodeCache(tuple(new_layers), pos + S)
         pos = arange(0, S, dtype="int64").unsqueeze(0)
         x = self.wte(input_ids) + self.wpe(pos)

@@ -35,11 +35,11 @@ followed by a feed-forward) or, without either, the rule "every
 head: `num_pred_heads` prediction heads of `vocab_size` side by side, of which
 the served step computes the first. `hybrid_ops.py` has the mathematics.
 
-The model serves through `serving.PagedGenerationEngine` like GPT does; what
-differs is that it tells the engine what each layer caches
-(`cache_layout()`), takes and returns that cache in `forward`, and hands
-back a few counters of its expert layers with the logits
-(`serving_counters`). docs/serving.md has the protocol.
+The model serves through `serving.PagedGenerationEngine` as GPT does, by the
+protocol `serving/blocks.py` states: it tells the engine what each layer
+caches (`cache_layout()`), takes and returns that cache in `forward`, and
+hands back a few counters of its expert layers with the logits
+(`serving_counters`).
 """
 from dataclasses import dataclass
 

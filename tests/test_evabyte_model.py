@@ -401,7 +401,7 @@ def test_ring_blocks_are_written_again_and_summaries_stay():
     summary block every 4 positions; what it holds is `blocks_for(pos)`."""
     model = build(tiny_config())
     eng = engine(model)
-    spec = eng._window
+    spec = eng._layout.table
     eng.prefill(0, list(range(1, 6)))
     assert eng.block_pool.in_use == spec.blocks_for(5, BS) == 3 + 2
     ring_after_first_window = None

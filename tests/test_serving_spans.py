@@ -181,9 +181,8 @@ def test_prefill_holds_upload_dispatch_wait_and_keeps_its_attrs(
         {"request_id": prefill["attrs"]["request_id"], "transfers": 1},
         {"request_id": prefill["attrs"]["request_id"]},
         {"request_id": prefill["attrs"]["request_id"], "fetches": 1}]
-    extra = set(engine._counter_names)
-    if engine._state_layers:
-        extra |= {"ssm_tokens_scanned", "ssm_tokens_valid"}
+    extra = set(engine._counter_names) | set(engine._layout.prefill_notes(
+        prefill["attrs"]["bucket"], prefill["attrs"]["length"]))
     assert set(prefill["attrs"]) == extra | {
         "attend", "bucket", "kv_dtype", "length", "paged", "pool_donated",
         "prefix_hit_tokens", "request_id", "slot"}
